@@ -13,16 +13,19 @@ turns the (alive channels -> seconds) ladders into per-atom marginal-latency
 cost vectors; ``prune.cost="latency_table"`` swaps them into the AtomNAS
 penalty — the search then optimizes what the serving fleet actually pays.
 
-Artifact contract: bench.py shape — exactly ONE JSON line on stdout, exit 0
-always (structured ``error`` field on failure), optional ``--out`` copy,
-provenance-stamped (bench.stamp_provenance: jax/jaxlib versions, platform,
-device kind, cpu-rehearsal flag). Entries measured on this 1-core rehearsal
-box carry ``cpu_rehearsal: true``; the real table is a TPU/accelerator run
-of the same command (ROADMAP item 3's hardware rung).
+Artifact contract: bench.py shape — exactly ONE JSON line on stdout,
+optional ``--out`` copy, provenance-stamped (bench.stamp_provenance:
+jax/jaxlib versions, platform, device kind, cpu-rehearsal flag). It measures
+on the chip or fails: with no TPU, or when the measurement raises, it prints
+no table and exits non-zero. ``--cpu-rehearsal`` asks, explicitly, for a
+table of XLA:CPU timings — it checks the table's plumbing (the checked-in
+``LATENCY_TABLE_r01_cpu_rehearsal.json`` is one, stamped ``cpu_rehearsal:
+true``) and must not steer a search meant for the chip: a search against it
+penalises CPU time (ROADMAP Queue 2 item 6).
 
 Usage: python scripts/latency_table.py [--arch mobilenet_v3_large]
            [--image-sizes 224] [--widths 0.375,0.6875,1.0] [--batch 8]
-           [--iters 12] [--out LATENCY_TABLE_r01_cpu_rehearsal.json]
+           [--iters 12] [--out LATENCY_TABLE.json] [--cpu-rehearsal]
 """
 
 from __future__ import annotations
@@ -170,11 +173,25 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--iters", type=int, default=12, help="timed runs per (block, width)")
     ap.add_argument("--out", default="", help="also write the JSON artifact here")
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="accept a non-TPU backend: a table of XLA:CPU timings that "
+                         "checks the plumbing and is stamped cpu_rehearsal")
     args = ap.parse_args(argv)
     widths = tuple(float(w) for w in args.widths.split(","))
     image_sizes = tuple(int(s) for s in args.image_sizes.split(","))
+    if len(widths) < 2:
+        raise SystemExit("latency_table: need >= 2 widths to fit a latency-vs-channels slope")
+
+    import jax
 
     from bench import stamp_provenance
+    from yet_another_mobilenet_series_tpu.utils import compile_cache
+
+    compile_cache.configure()
+    platform = jax.default_backend()
+    if platform != "tpu" and not args.cpu_rehearsal:
+        raise SystemExit(f"latency_table: no TPU: jax.default_backend() is {platform!r}; the "
+                         "table is measured on the chip (--cpu-rehearsal asks for a CPU one)")
 
     out = {
         "metric": f"{args.arch}_block_latency_table",
@@ -184,14 +201,8 @@ def main(argv=None) -> int:
         "vs_baseline_note": "a lookup-table artifact, not a throughput headline",
         "measured_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
-    try:
-        if len(widths) < 2:
-            raise ValueError("need >= 2 widths to fit a latency-vs-channels slope")
-        out.update(measure(args.arch, image_sizes, widths, max(1, args.batch),
-                           max(1, args.iters)))
-        out["value"] = float(len(out["entries"]))
-    except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
-        out["error"] = f"{type(e).__name__}: {e}"
+    out.update(measure(args.arch, image_sizes, widths, max(1, args.batch), max(1, args.iters)))
+    out["value"] = float(len(out["entries"]))
     stamp_provenance(out)
     line = json.dumps(out)
     print(line)

@@ -1,10 +1,23 @@
 #!/usr/bin/env python
 """Serving benchmark: latency/QPS per bucket + pipelined/bf16/chaos A/Bs.
 
-Prints exactly ONE JSON line on stdout in the bench.py artifact shape
-(tests/test_bench_contract.py contract: exit 0 always; a failed run emits
-``value: null`` with an ``error`` field, never a stack trace) and optionally
-writes it to a BENCH_SERVE_*.json via --out. Four measurements per run:
+Prints exactly ONE JSON line on stdout in the bench.py artifact shape and
+optionally writes it to a BENCH_SERVE_*.json via --out. It measures on the
+chip or fails: with no TPU it exits non-zero before measuring, and a
+measurement that raises emits ``value: null`` with an ``error`` field AND
+exits non-zero. ``--cpu-rehearsal`` asks, explicitly, for a run on whatever
+backend there is: what such a run COUNTS (dispatches, bytes, misroutes,
+bitwise verdicts) stands, and its rates are XLA:CPU timings, so its headline
+rides under a ``cpu_rehearsal_`` metric name and a unit that says so —
+never under a device metric's name. (``--partition`` is jax-free: a
+transport measurement on loopback sockets, the same on any host.)
+
+One process per chip: the ``--fleet``, ``--zoo`` and ``--overload`` parents
+never initialise a JAX backend while their replica subprocesses need the
+device — bundles are exported by a child that exits first, and what the
+parent runs in-process runs after the fleet has stopped.
+
+Four measurements per run:
 
 1. **direct** — engine.predict latency per (bucket, image_size), exact-bucket
    batches: p50/p99 ms + QPS (the BENCH_SERVE_r01 shape, now per size).
@@ -18,7 +31,7 @@ writes it to a BENCH_SERVE_*.json via --out. Four measurements per run:
    direct QPS per bucket plus the measured max |logit delta| vs fp32
    against the pinned BF16_PARITY_ATOL (serve/engine.py).
 4. **chained-vs-fused A/B** (``--fused``) — the serving twin of the training
-   dispatch probe (PROFILE.md): whole requests of K max-bucket chunks served
+   dispatch probe (scripts/bench_bn.py): whole requests of K max-bucket chunks served
    once through the per-chunk path (K dispatches, host staging between each)
    and once through the fused multi-chunk executables (serve/engine.py
    ``fuse_ladder``: ONE ``lax.scan`` dispatch per ladder piece). Per K:
@@ -175,6 +188,69 @@ def _percentile(sorted_vals, q):
         return 0.0
     idx = min(int(round(q * (len(sorted_vals) - 1))), len(sorted_vals) - 1)
     return sorted_vals[idx]
+
+
+# contract-test model presets (anything else is an arch name from models/zoo.py)
+_MODEL_PRESETS = {
+    "tiny": dict(arch="mobilenet_v2", num_classes=16, dropout=0.0,
+                 block_specs=[{"t": 2, "c": 8, "n": 1, "s": 2}, {"t": 2, "c": 16, "n": 1, "s": 2}]),
+    # the zoo's big tier at the tiny preset: deeper/wider than "tiny", so
+    # the cascade's FLOPs win is structural, not noise
+    "tiny_big": dict(arch="mobilenet_v2", num_classes=16, dropout=0.0,
+                     block_specs=[{"t": 4, "c": 24, "n": 2, "s": 2},
+                                  {"t": 4, "c": 48, "n": 2, "s": 2},
+                                  {"t": 4, "c": 96, "n": 1, "s": 1}]),
+}
+
+
+def _model_config(name):
+    from yet_another_mobilenet_series_tpu.config import ModelConfig
+
+    return ModelConfig(**_MODEL_PRESETS[name]) if name in _MODEL_PRESETS else ModelConfig(arch=name)
+
+
+def _export_child_main(jobs_json: str) -> int:
+    """``--export-child``: build and export the bundles a fleet-mode parent
+    asked for, then exit — releasing the device before any replica starts."""
+    import jax
+    import numpy as np
+
+    from yet_another_mobilenet_series_tpu.models import get_model
+    from yet_another_mobilenet_series_tpu.serve.export import export_bundle
+
+    for job in json.loads(jobs_json):
+        net = get_model(_model_config(job["model"]), job["image_size"])
+        params, state = net.init(jax.random.PRNGKey(job["seed"]))
+        kw = dict(job.get("export_kwargs") or {})
+        if job.get("calib_npy"):
+            kw["calib_images"] = np.load(job["calib_npy"])
+        export_bundle(net, params, state, job["out"], **kw)
+    return 0
+
+
+def _export_bundles_in_child(jobs: list[dict]) -> None:
+    """Random-init + export ``jobs`` in a child process that exits. A chip
+    belongs to one process at a time: a fleet-mode parent that called
+    ``net.init`` itself would hold the chip its replicas need."""
+    import subprocess
+
+    r = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--export-child", json.dumps(jobs)],
+        capture_output=True, text=True, timeout=900,
+    )
+    if r.returncode != 0:
+        raise RuntimeError(f"bundle export child failed rc={r.returncode}: {r.stderr[-800:]}")
+
+
+def _parent_must_be_off_the_backend() -> None:
+    """Called right before a fleet-mode parent starts its replicas: a parent
+    that has initialised a JAX backend holds the chip on a TPU host, and its
+    replicas would hang in backend init. Fail here, with the reason."""
+    from bench import backend_initialised
+
+    if backend_initialised():
+        raise RuntimeError("this parent initialised a JAX backend before its replicas "
+                           "started; on a TPU host it now holds the chip they need")
 
 
 def _hist_delta_quantiles(name, counts_before):
@@ -861,30 +937,20 @@ def measure_fleet(arch, image_size, buckets, *, replicas, requests, target_qps,
        hysteresis): the N-over-time trace must rise under the peak and
        fall after it.
     """
-    import jax
     import numpy as np
 
     from yet_another_mobilenet_series_tpu.cli.fleet import FleetSupervisor
-    from yet_another_mobilenet_series_tpu.config import ModelConfig
-    from yet_another_mobilenet_series_tpu.models import get_model
     from yet_another_mobilenet_series_tpu.obs.fleet import FleetFederation, FlightRecorder
     from yet_another_mobilenet_series_tpu.obs.registry import get_registry, quantiles_from_counts
     from yet_another_mobilenet_series_tpu.serve.autoscale import Autoscaler
-    from yet_another_mobilenet_series_tpu.serve.export import export_bundle
     from yet_another_mobilenet_series_tpu.serve.hedge import Hedger
     from yet_another_mobilenet_series_tpu.serve.router import Router
     from yet_another_mobilenet_series_tpu.serve.signals import SLOTracker
 
     reg = get_registry()
-    if arch == "tiny":  # same contract-test preset as measure()
-        mc = ModelConfig(arch="mobilenet_v2", num_classes=16, dropout=0.0,
-                         block_specs=[{"t": 2, "c": 8, "n": 1, "s": 2}, {"t": 2, "c": 16, "n": 1, "s": 2}])
-    else:
-        mc = ModelConfig(arch=arch)
-    net = get_model(mc, image_size)
-    params, state = net.init(jax.random.PRNGKey(0))
     bundle_dir = os.path.join(log_root, "bundle")
-    export_bundle(net, params, state, bundle_dir)
+    _export_bundles_in_child([{"model": arch, "image_size": image_size, "seed": 0,
+                               "out": bundle_dir}])
 
     replica_argv = [
         f"serve.bundle={bundle_dir}",
@@ -928,6 +994,7 @@ def measure_fleet(arch, image_size, buckets, *, replicas, requests, target_qps,
            "straggler": {"slot": straggler_slot, "latency_ms": straggler_ms,
                          "latency_rate": 0.3}}
     try:
+        _parent_must_be_off_the_backend()
         t0 = time.perf_counter()
         fleet.start()
         out["spawn_s"] = round(time.perf_counter() - t0, 2)
@@ -1306,15 +1373,11 @@ def measure_zoo(arch, image_size, *, requests, target_qps, seed, threshold,
     Buckets are pinned to [1] so every arm's answers are bitwise-comparable
     against the explicit-pin reference pass by construction (no padding
     variation between arms)."""
-    import jax
     import numpy as np
 
     from yet_another_mobilenet_series_tpu.cli.fleet import FleetSupervisor
-    from yet_another_mobilenet_series_tpu.config import ModelConfig
-    from yet_another_mobilenet_series_tpu.models import get_model
     from yet_another_mobilenet_series_tpu.obs.registry import get_registry
     from yet_another_mobilenet_series_tpu.serve.cascade import CascadeTier, softmax_margin
-    from yet_another_mobilenet_series_tpu.serve.export import export_bundle
     from yet_another_mobilenet_series_tpu.serve.router import Router
 
     reg = get_registry()
@@ -1322,27 +1385,18 @@ def measure_zoo(arch, image_size, *, requests, target_qps, seed, threshold,
     # two genuinely different cost tiers: the small tier is the contract-test
     # tiny preset (int8 weights), the big tier is deeper/wider so the
     # cascade's FLOPs win is structural, not noise
-    small_mc = ModelConfig(arch="mobilenet_v2", num_classes=16, dropout=0.0,
-                           block_specs=[{"t": 2, "c": 8, "n": 1, "s": 2},
-                                        {"t": 2, "c": 16, "n": 1, "s": 2}])
-    if arch == "tiny":
-        big_mc = ModelConfig(arch="mobilenet_v2", num_classes=16, dropout=0.0,
-                             block_specs=[{"t": 4, "c": 24, "n": 2, "s": 2},
-                                          {"t": 4, "c": 48, "n": 2, "s": 2},
-                                          {"t": 4, "c": 96, "n": 1, "s": 1}])
-    else:
-        big_mc = ModelConfig(arch=arch)
-    small_net = get_model(small_mc, image_size)
-    sp, ss = small_net.init(jax.random.PRNGKey(seed))
-    calib = rng.normal(0, 1, (8, image_size, image_size, 3)).astype("float32")
+    calib_npy = os.path.join(log_root, "calib.npy")
+    np.save(calib_npy, rng.normal(0, 1, (8, image_size, image_size, 3)).astype("float32"))
     small_dir = os.path.join(log_root, "small")
-    export_bundle(small_net, sp, ss, small_dir, model_name="small",
-                  quant_weights="int8", calib_images=calib,
-                  int8_top1_min=int8_top1_min)
-    big_net = get_model(big_mc, image_size)
-    bp, bs = big_net.init(jax.random.PRNGKey(seed + 1))
     big_dir = os.path.join(log_root, "big")
-    export_bundle(big_net, bp, bs, big_dir, model_name="big")
+    _export_bundles_in_child([
+        {"model": "tiny", "image_size": image_size, "seed": seed, "out": small_dir,
+         "calib_npy": calib_npy,
+         "export_kwargs": {"model_name": "small", "quant_weights": "int8",
+                           "int8_top1_min": int8_top1_min}},
+        {"model": "tiny_big" if arch == "tiny" else arch, "image_size": image_size,
+         "seed": seed + 1, "out": big_dir, "export_kwargs": {"model_name": "big"}},
+    ])
 
     def _meta(d):
         with open(os.path.join(d, "meta.json")) as f:
@@ -1406,6 +1460,7 @@ def measure_zoo(arch, image_size, *, requests, target_qps, seed, threshold,
                        "digest": big_meta.get("digest", "")[:12]},
            }}
     try:
+        _parent_must_be_off_the_backend()
         t0 = time.perf_counter()
         fleet.start()
         out["spawn_s"] = round(time.perf_counter() - t0, 2)
@@ -1648,7 +1703,11 @@ def _overload_round(admission, images, *, seed, n_requests, target_qps,
 
 def measure_overload(arch, image_size, buckets, *, storm_s, multiple, seed,
                      pace_ms, replicas, gray_requests, straggler_ms, log_root):
-    """The ``--overload`` measurement, two halves:
+    """The ``--overload`` measurement, two halves. The fleet half runs FIRST
+    and the in-process half after it has stopped: the in-process engine
+    initialises this process's backend, and a parent that holds the chip
+    leaves none for replica subprocesses (the artifact's sections keep
+    their names; only the running order follows the device).
 
     1. **brownout A/B** (in-process): ONE seeded open-loop Poisson storm at
        ``multiple`` x the measured closed-loop capacity, run twice through
@@ -1667,143 +1726,30 @@ def measure_overload(arch, image_size, buckets, *, storm_s, multiple, seed,
        so time-to-eject is measured, and completion-stamped latencies
        split at the ejection instant pin the tail recovering after it.
     """
-    import jax
     import numpy as np
 
     from yet_another_mobilenet_series_tpu.cli.fleet import FleetSupervisor
-    from yet_another_mobilenet_series_tpu.config import ModelConfig
-    from yet_another_mobilenet_series_tpu.models import get_model
     from yet_another_mobilenet_series_tpu.obs.registry import get_registry
     from yet_another_mobilenet_series_tpu.serve.admission import AdmissionController
     from yet_another_mobilenet_series_tpu.serve.brownout import BrownoutController
     from yet_another_mobilenet_series_tpu.serve.engine import InferenceEngine
-    from yet_another_mobilenet_series_tpu.serve.export import InferenceBundle, export_bundle, fold_network
+    from yet_another_mobilenet_series_tpu.serve.export import load_bundle
     from yet_another_mobilenet_series_tpu.serve.faults import FaultyEngine
     from yet_another_mobilenet_series_tpu.serve.pipeline import PipelinedBatcher
     from yet_another_mobilenet_series_tpu.serve.router import Router
     from yet_another_mobilenet_series_tpu.serve.signals import SignalReader
 
     reg = get_registry()
-    if arch == "tiny":  # same contract-test preset as measure()
-        mc = ModelConfig(arch="mobilenet_v2", num_classes=16, dropout=0.0,
-                         block_specs=[{"t": 2, "c": 8, "n": 1, "s": 2}, {"t": 2, "c": 16, "n": 1, "s": 2}])
-    else:
-        mc = ModelConfig(arch=arch)
-    net = get_model(mc, image_size)
-    params, state = net.init(jax.random.PRNGKey(0))
-    bundle = InferenceBundle(net=net, params=fold_network(net, params, state), meta={})
-    engine = InferenceEngine(bundle, buckets=buckets, image_size=image_size)
-    engine.warmup()
-    # deterministic capacity ceiling: every dispatch pays pace_ms at sync,
-    # so "3x capacity" means the same storm on a laptop and a server
-    paced = FaultyEngine(engine, seed=seed, latency_s=pace_ms / 1e3, latency_rate=1.0)
     rng = np.random.RandomState(seed)
     images = {c: rng.normal(0, 1, (image_size, image_size, 3)).astype("float32")
               for c in _OVERLOAD_CLASS_MIX}
     max_batch = max(buckets)
     out = {"image_size": image_size, "seed": seed, "storm_s": storm_s,
            "pace_ms": pace_ms, "class_mix": dict(_OVERLOAD_CLASS_MIX)}
-
-    def _stack():
-        b = PipelinedBatcher(paced, max_batch=max_batch, max_wait_ms=5.0,
-                             queue_depth=128, drain_timeout_s=60.0).start()
-        a = AdmissionController(b, max_retries=1, retry_backoff_ms=5.0,
-                                breaker_threshold=50, breaker_cooldown_s=0.5, seed=seed)
-        return b, a
-
-    # -- capacity calibration (closed loop, brownout off) --------------------
-    b, a = _stack()
-    warm_lat = []
-    n_warm, n_clients = 48, max_batch
-
-    def _warm_client(n):
-        img = images["interactive"]
-        for _ in range(n):
-            t0 = time.perf_counter()
-            a.submit(img, priority="interactive").result(timeout=60)
-            warm_lat.append(time.perf_counter() - t0)
-
-    t0 = time.perf_counter()
-    threads = [threading.Thread(target=_warm_client, args=(n_warm // n_clients,), daemon=True)
-               for _ in range(n_clients)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    warm_wall = time.perf_counter() - t0
-    b.stop()
-    warm_lat.sort()
-    capacity_qps = len(warm_lat) / warm_wall if warm_wall > 0 else 1.0
-    p50_ms = max(_percentile(warm_lat, 0.5) * 1e3, 0.5)
-    storm_qps = multiple * capacity_qps
-    # duration-driven storm: the ladder needs seconds of sustained overload
-    # to climb, so the request count follows the rate, not vice versa
-    requests = max(40, int(storm_qps * storm_s))
-    out["requests"] = requests
-    # interactive deadline: far above the healthy latency, far below what a
-    # sustained 3x backlog produces — the availability instrument
-    interactive_deadline_ms = max(8.0 * p50_ms, 100.0)
-    deadlines = {"interactive": interactive_deadline_ms}
-    out["capacity"] = {
-        "closed_loop_qps": round(capacity_qps, 2), "clients": n_clients,
-        "warm_p50_ms": round(p50_ms, 3), "storm_qps": round(storm_qps, 2),
-        "multiple": multiple,
-        "interactive_deadline_ms": round(interactive_deadline_ms, 1),
-    }
-
-    # -- the A/B: one seeded storm, brownout off vs on -----------------------
-    arms = {}
-    for mode in ("off", "on"):
-        b, a = _stack()
-        controller = None
-        if mode == "on":
-            controller = BrownoutController(
-                SignalReader(latency_family="serve.latency_seconds",
-                             signal_class="interactive",
-                             queue_depth_fn=a.queued_total),
-                (b, a),
-                interval_s=0.1,
-                up_p99_ms=max(4.0 * p50_ms, 40.0),
-                down_p99_ms=max(1.5 * p50_ms, 10.0),
-                up_queue_depth=1.5 * max_batch,
-                down_queue_depth=0.5 * max_batch,
-                hold_up_s=0.3, cooldown_s=0.5,
-                retry_after_s=1.0,
-                # stdout is the ONE-JSON-line artifact: transitions -> stderr
-                log_fn=lambda m: print(m, file=sys.stderr, flush=True),
-            ).start()
-        s0 = reg.snapshot()
-        rnd = _overload_round(a, images, seed=seed + 1, n_requests=requests,
-                              target_qps=storm_qps, deadline_ms_by_class=deadlines)
-        s1 = reg.snapshot()
-        rnd["shed_at_door_brownout"] = int(s1.get("serve.rejected_brownout", 0)
-                                           - s0.get("serve.rejected_brownout", 0))
-        if controller is not None:
-            # recovery: idle windows are relaxed; one level per cooldown
-            settle_until = time.monotonic() + 6 * controller._cooldown_s + 2.0
-            while controller.level > 0 and time.monotonic() < settle_until:
-                time.sleep(0.1)
-            trace = controller.trace
-            controller.stop()
-            rnd["brownout"] = {
-                "peak_level": max((r["level"] for r in trace), default=0),
-                "final_level": trace[-1]["level"] if trace else None,
-                "recovered_to_l0": bool(trace and trace[-1]["level"] == 0),
-                "transitions_up": sum(1 for r in trace if r["action"] == "up"),
-                "transitions_down": sum(1 for r in trace if r["action"] == "down"),
-                "trace": trace,
-            }
-        b.stop()
-        arms[mode] = rnd
-    out["storm"] = {
-        "off": arms["off"], "on": arms["on"],
-        "interactive_availability_off": arms["off"]["classes"]["interactive"]["availability"],
-        "interactive_availability_on": arms["on"]["classes"]["interactive"]["availability"],
-    }
-
     # -- gray failure: slow-but-alive replica, soft ejection + recovery ------
     bundle_dir = os.path.join(log_root, "bundle")
-    export_bundle(net, params, state, bundle_dir)
+    _export_bundles_in_child([{"model": arch, "image_size": image_size, "seed": 0,
+                               "out": bundle_dir}])
     replica_argv = [
         f"serve.bundle={bundle_dir}",
         f"data.image_size={image_size}",
@@ -1839,6 +1785,7 @@ def measure_overload(arch, image_size, buckets, *, storm_s, multiple, seed,
                                                 "latency_ms": straggler_ms,
                                                 "latency_rate": 1.0}}
     try:
+        _parent_must_be_off_the_backend()
         t0 = time.perf_counter()
         fleet.start()
         router.start()
@@ -1939,11 +1886,116 @@ def measure_overload(arch, image_size, buckets, *, storm_s, multiple, seed,
                 if gray["p99_ms_after_eject"] else None
             )
         out["gray"] = gray
-        out["cpu_rehearsal_note"] = _OVERLOAD_CPU_CAVEAT
-        return out
     finally:
         router.stop()
         fleet.stop()
+
+    # -- brownout A/B, in-process: the fleet is gone, this process may now
+    # take the device; it serves the bundle the replicas served
+    engine = InferenceEngine(load_bundle(bundle_dir), buckets=buckets, image_size=image_size)
+    engine.warmup()
+    # deterministic capacity ceiling: every dispatch pays pace_ms at sync,
+    # so "3x capacity" means the same storm on a laptop and a server
+    paced = FaultyEngine(engine, seed=seed, latency_s=pace_ms / 1e3, latency_rate=1.0)
+
+    def _stack():
+        b = PipelinedBatcher(paced, max_batch=max_batch, max_wait_ms=5.0,
+                             queue_depth=128, drain_timeout_s=60.0).start()
+        a = AdmissionController(b, max_retries=1, retry_backoff_ms=5.0,
+                                breaker_threshold=50, breaker_cooldown_s=0.5, seed=seed)
+        return b, a
+
+    # -- capacity calibration (closed loop, brownout off) --------------------
+    b, a = _stack()
+    warm_lat = []
+    n_warm, n_clients = 48, max_batch
+
+    def _warm_client(n):
+        img = images["interactive"]
+        for _ in range(n):
+            t0 = time.perf_counter()
+            a.submit(img, priority="interactive").result(timeout=60)
+            warm_lat.append(time.perf_counter() - t0)
+
+    t0 = time.perf_counter()
+    threads = [threading.Thread(target=_warm_client, args=(n_warm // n_clients,), daemon=True)
+               for _ in range(n_clients)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    warm_wall = time.perf_counter() - t0
+    b.stop()
+    warm_lat.sort()
+    capacity_qps = len(warm_lat) / warm_wall if warm_wall > 0 else 1.0
+    p50_ms = max(_percentile(warm_lat, 0.5) * 1e3, 0.5)
+    storm_qps = multiple * capacity_qps
+    # duration-driven storm: the ladder needs seconds of sustained overload
+    # to climb, so the request count follows the rate, not vice versa
+    requests = max(40, int(storm_qps * storm_s))
+    out["requests"] = requests
+    # interactive deadline: far above the healthy latency, far below what a
+    # sustained 3x backlog produces — the availability instrument
+    interactive_deadline_ms = max(8.0 * p50_ms, 100.0)
+    deadlines = {"interactive": interactive_deadline_ms}
+    out["capacity"] = {
+        "closed_loop_qps": round(capacity_qps, 2), "clients": n_clients,
+        "warm_p50_ms": round(p50_ms, 3), "storm_qps": round(storm_qps, 2),
+        "multiple": multiple,
+        "interactive_deadline_ms": round(interactive_deadline_ms, 1),
+    }
+
+    # -- the A/B: one seeded storm, brownout off vs on -----------------------
+    arms = {}
+    for mode in ("off", "on"):
+        b, a = _stack()
+        controller = None
+        if mode == "on":
+            controller = BrownoutController(
+                SignalReader(latency_family="serve.latency_seconds",
+                             signal_class="interactive",
+                             queue_depth_fn=a.queued_total),
+                (b, a),
+                interval_s=0.1,
+                up_p99_ms=max(4.0 * p50_ms, 40.0),
+                down_p99_ms=max(1.5 * p50_ms, 10.0),
+                up_queue_depth=1.5 * max_batch,
+                down_queue_depth=0.5 * max_batch,
+                hold_up_s=0.3, cooldown_s=0.5,
+                retry_after_s=1.0,
+                # stdout is the ONE-JSON-line artifact: transitions -> stderr
+                log_fn=lambda m: print(m, file=sys.stderr, flush=True),
+            ).start()
+        s0 = reg.snapshot()
+        rnd = _overload_round(a, images, seed=seed + 1, n_requests=requests,
+                              target_qps=storm_qps, deadline_ms_by_class=deadlines)
+        s1 = reg.snapshot()
+        rnd["shed_at_door_brownout"] = int(s1.get("serve.rejected_brownout", 0)
+                                           - s0.get("serve.rejected_brownout", 0))
+        if controller is not None:
+            # recovery: idle windows are relaxed; one level per cooldown
+            settle_until = time.monotonic() + 6 * controller._cooldown_s + 2.0
+            while controller.level > 0 and time.monotonic() < settle_until:
+                time.sleep(0.1)
+            trace = controller.trace
+            controller.stop()
+            rnd["brownout"] = {
+                "peak_level": max((r["level"] for r in trace), default=0),
+                "final_level": trace[-1]["level"] if trace else None,
+                "recovered_to_l0": bool(trace and trace[-1]["level"] == 0),
+                "transitions_up": sum(1 for r in trace if r["action"] == "up"),
+                "transitions_down": sum(1 for r in trace if r["action"] == "down"),
+                "trace": trace,
+            }
+        b.stop()
+        arms[mode] = rnd
+    out["storm"] = {
+        "off": arms["off"], "on": arms["on"],
+        "interactive_availability_off": arms["off"]["classes"]["interactive"]["availability"],
+        "interactive_availability_on": arms["on"]["classes"]["interactive"]["availability"],
+    }
+    out["cpu_rehearsal_note"] = _OVERLOAD_CPU_CAVEAT
+    return out
 
 
 _PARTITION_CPU_CAVEAT = (
@@ -2447,18 +2499,14 @@ def measure(arch, image_sizes, buckets, iters, conc_iters, ab_iters, max_infligh
     import jax.numpy as jnp
     import numpy as np
 
-    from yet_another_mobilenet_series_tpu.config import ModelConfig
     from yet_another_mobilenet_series_tpu.models import get_model
     from yet_another_mobilenet_series_tpu.serve.engine import BF16_PARITY_ATOL, InferenceEngine
     from yet_another_mobilenet_series_tpu.serve.export import InferenceBundle, fold_network
+    from yet_another_mobilenet_series_tpu.utils import compile_cache
 
-    if arch == "tiny":  # contract-test preset: 2 blocks, compiles in seconds
-        mc = ModelConfig(arch="mobilenet_v2", num_classes=16, dropout=0.0,
-                         block_specs=[{"t": 2, "c": 8, "n": 1, "s": 2}, {"t": 2, "c": 16, "n": 1, "s": 2}])
-    else:
-        mc = ModelConfig(arch=arch)
+    compile_cache.configure()
     base_size = image_sizes[0]
-    net = get_model(mc, base_size)
+    net = get_model(_model_config(arch), base_size)
     params, state = net.init(jax.random.PRNGKey(0))
     # non-trivial BN running stats (fresh init is mean=0/var=1): a fold of
     # the identity affine collapses random-init logits to ~1e-11, which
@@ -2728,9 +2776,41 @@ def main(argv=None) -> int:
                     help="seed for arrivals, class/size mix, and the fault schedule")
     ap.add_argument("--no-chaos", action="store_true", help="skip the chaos A/B")
     ap.add_argument("--out", default="", help="also write the JSON artifact here")
+    ap.add_argument("--cpu-rehearsal", action="store_true",
+                    help="accept a host without a TPU: counts stand, rates are XLA:CPU "
+                         "timings and the headline is named cpu_rehearsal_*")
+    ap.add_argument("--export-child", default="", help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    if args.export_child:
+        return _export_child_main(args.export_child)
     buckets = tuple(int(b) for b in args.buckets.split(","))
     image_sizes = tuple(int(s) for s in args.image_sizes.split(","))
+
+    def finish(out: dict) -> int:
+        """ONE JSON line (+ --out copy); non-zero when the measurement raised."""
+        line = json.dumps(out)
+        print(line)
+        if args.out:
+            with open(args.out, "w") as f:
+                f.write(line + "\n")
+        return 1 if "error" in out else 0
+
+    def headline(metric: str, unit: str) -> dict:
+        """A rate's name and unit: on a --cpu-rehearsal run both say so."""
+        if args.cpu_rehearsal:
+            return {"metric": f"cpu_rehearsal_{metric}",
+                    "unit": f"{unit} on XLA:CPU (rehearsal: not a device rate)"}
+        return {"metric": metric, "unit": unit}
+
+    if not args.partition and not args.cpu_rehearsal:
+        # found WITHOUT initialising a backend (device nodes + JAX_PLATFORMS):
+        # the fleet-mode parents must leave the chip to their replicas
+        from yet_another_mobilenet_series_tpu.cli.fleet import host_tpu_chips
+
+        if not host_tpu_chips():
+            raise SystemExit("serve_bench: no TPU on this host (or JAX_PLATFORMS keeps jax off "
+                             "it): this benchmark measures on the chip or fails; "
+                             "--cpu-rehearsal asks for a run whose rates are not device rates")
 
     if args.partition:
         # standalone like --fleet/--overload, but jax-free end to end: the
@@ -2768,14 +2848,9 @@ def main(argv=None) -> int:
             out.update({"platform": "cpu", "provenance": provenance(cpu_rehearsal=True),
                         "partition": m})
             out["value"] = m["rounds"]["blackhole"]["detection_s"]
-        except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
+        except Exception as e:  # noqa: BLE001 — structured error line, non-zero exit
             out["error"] = f"{type(e).__name__}: {e}"
-        line = json.dumps(out)
-        print(line)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(line + "\n")
-        return 0
+        return finish(out)
 
     if args.overload:
         # standalone like --fleet: the storm arms own their batcher stacks
@@ -2814,14 +2889,9 @@ def main(argv=None) -> int:
                         "provenance": provenance(), "overload": m})
             out["value"] = m["storm"]["interactive_availability_on"]
             shutil.rmtree(log_root, ignore_errors=True)
-        except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
+        except Exception as e:  # noqa: BLE001 — structured error line, non-zero exit
             out["error"] = f"{type(e).__name__}: {e} (replica logs under {log_root})"
-        line = json.dumps(out)
-        print(line)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(line + "\n")
-        return 0
+        return finish(out)
 
     if args.zoo:
         # standalone like --fleet: the zoo arms share one model-sharded
@@ -2861,14 +2931,9 @@ def main(argv=None) -> int:
                         "provenance": provenance(), "zoo": m})
             out["value"] = m["cost"]["cascade_vs_big_only"]
             shutil.rmtree(log_root, ignore_errors=True)
-        except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
+        except Exception as e:  # noqa: BLE001 — structured error line, non-zero exit
             out["error"] = f"{type(e).__name__}: {e} (replica logs under {log_root})"
-        line = json.dumps(out)
-        print(line)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(line + "\n")
-        return 0
+        return finish(out)
 
     if args.fleet:
         # the fleet measurement is standalone: replica subprocesses own the
@@ -2878,9 +2943,8 @@ def main(argv=None) -> int:
         import tempfile
 
         out = {
-            "metric": f"{args.arch}_fleet_requests_per_sec",
+            **headline(f"{args.arch}_fleet_requests_per_sec", "requests/sec"),
             "value": None,
-            "unit": "requests/sec",
             "vs_baseline": None,
             "vs_baseline_note": "first fleet round; single-replica rows live in BENCH_SERVE_r01..r05",
             "image_size": image_sizes[0],
@@ -2907,19 +2971,13 @@ def main(argv=None) -> int:
                         "provenance": provenance(), "fleet": m})
             out["value"] = m["hedge_ab"]["unhedged"]["qps"]
             shutil.rmtree(log_root, ignore_errors=True)
-        except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
+        except Exception as e:  # noqa: BLE001 — structured error line, non-zero exit
             out["error"] = f"{type(e).__name__}: {e} (replica logs under {log_root})"
-        line = json.dumps(out)
-        print(line)
-        if args.out:
-            with open(args.out, "w") as f:
-                f.write(line + "\n")
-        return 0
+        return finish(out)
 
     out = {
-        "metric": f"{args.arch}_serve_images_per_sec",
+        **headline(f"{args.arch}_serve_images_per_sec", "images/sec"),
         "value": None,
-        "unit": "images/sec",
         "vs_baseline": None,
         "vs_baseline_note": "BENCH_SERVE_r01 predates the concurrent-submit mode; direct rows are comparable",
         "image_size": image_sizes[0],
@@ -2942,14 +3000,9 @@ def main(argv=None) -> int:
                     quant_top1_min=args.quant_top1_min)
         out.update(m)
         out["value"] = m["peak_qps"]
-    except Exception as e:  # noqa: BLE001 — contract: structured error, exit 0
+    except Exception as e:  # noqa: BLE001 — structured error line, non-zero exit
         out["error"] = f"{type(e).__name__}: {e}"
-    line = json.dumps(out)
-    print(line)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(line + "\n")
-    return 0
+    return finish(out)
 
 
 if __name__ == "__main__":

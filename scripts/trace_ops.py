@@ -180,8 +180,8 @@ def main(argv=None) -> int:
         for k, v in agg["per_op"].most_common(top_n):
             print(f"  {k[:98]:<100} {v/total_ps*100:6.2f}%  {v/1e12*1000:8.3f} ms")
     if not printed_any:
-        # CPU-backend traces (the watcher's --cpu-rehearsal, the serving
-        # frontend's capture on this box) have no /device:TPU plane. The
+        # CPU-backend traces (a rehearsal, the serving frontend's capture
+        # on a host without a TPU) have no /device:TPU plane. The
         # planes list stays in the output so a trace with NO recognizable
         # plane (GPU backend, malformed dump) is still diagnosable, not a
         # silent zero.

@@ -2,11 +2,13 @@
 """Training chaos round: corrupt records + an injected NaN step + a SIGTERM
 preemption, then a resume — the serve_bench chaos A/B's training twin.
 
-Prints exactly ONE JSON line on stdout in the bench.py artifact shape
-(tests/test_bench_contract.py contract: exit 0 always; a failed round emits
-``value: null`` with an ``error`` field, never a stack trace) and optionally
-writes it via --out. Two rounds, both SUBPROCESSES of cli.train on the tiny
-fake-data config so the artifact reflects the real entry point end to end:
+Prints exactly ONE JSON line on stdout in the bench.py artifact shape (a
+failed round emits ``value: null`` with an ``error`` field and exits
+non-zero) and optionally writes it via --out. A drill of what the program
+COUNTS, not a device measurement: the children are held to the CPU backend
+(8 host devices) on any host and this parent never imports jax, so it needs
+no chip and takes none. Two rounds, both SUBPROCESSES of cli.train on the
+tiny fake-data config so the artifact reflects the real entry point end to end:
 
 1. **chaos round** — ``train.faults`` injects a seeded corrupt-record rate
    (the resilience wrapper must skip and count them), one NaN step (the
@@ -198,7 +200,7 @@ def main(argv=None) -> int:
     if args.out:
         with open(args.out, "w") as f:
             f.write(line + "\n")
-    return 0
+    return 1 if "error" in artifact else 0
 
 
 if __name__ == "__main__":

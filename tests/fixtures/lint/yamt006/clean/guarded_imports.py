@@ -1,10 +1,10 @@
-"""YAMT006 must stay silent: version-guarded imports are the sanctioned idiom
-(this is the shape of utils/compat.py)."""
+"""YAMT006 must stay silent: the public surface of the installed jax, and
+imports under an explicit version guard."""
 
-try:  # newer jax: public top-level export
-    from jax import shard_map
-except ImportError:  # jax <= 0.5
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+try:  # a guard says the author knew the module moves
+    from jax._src import core as jax_core  # noqa: F401
+except ImportError:
+    jax_core = None
 
-from jax import lax  # stable public surface is fine
-from jax.experimental import pallas  # experimental-but-present is not flagged
+from jax import lax, shard_map  # noqa: F401  stable public surface is fine
+from jax.experimental import pallas  # noqa: F401  experimental-but-present is not flagged

@@ -129,13 +129,13 @@ def _eval_cfg(fix, log_dir, **data_over):
 
 
 def test_full_scale_bn_mode_prediction_agreement(mbv2_fixture):
-    """The PROFILE.md round-3 decision rule's 'top-1-parity argument' for the
+    """The bn_mode adoption rule's 'top-1-parity argument' for the
     perf bn_modes (VERDICT r3 #5), at full scale: the imported MBV2's
     predictions on the 200 real JPEGs, forwarded in bfloat16 (the production
     training dtype — the only regime where `compute` differs from `folded`),
     must agree with the exact-mode predictions to within the same near-tie
     tolerance the acceptance tests grant decoder differences. This test is
-    the evidence `scripts/tpu_watch.py --allow-compute` cites: a >3% compute
+    the evidence an adoption of `compute` rests on: a >3% compute
     win on hardware is adoptable because its forward perturbation is below
     the noise the fixture already tolerates.
 

@@ -44,8 +44,6 @@ _DRIVER = """
 import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["TF_CPP_MIN_LOG_LEVEL"] = "2"
-import jax
-jax.config.update("jax_platforms", "cpu")
 from yet_another_mobilenet_series_tpu.cli.train import main
 main(sys.argv[1:])
 """

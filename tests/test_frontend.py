@@ -669,8 +669,6 @@ def test_trace_correlates_one_request_across_threads():
 _LISTEN_DRIVER = """
 import os, sys
 os.environ["TF_CPP_MIN_LOG_LEVEL"] = "2"
-import jax
-jax.config.update("jax_platforms", "cpu")
 from yet_another_mobilenet_series_tpu.cli.serve import main
 main(sys.argv[1:])
 """

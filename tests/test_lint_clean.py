@@ -24,7 +24,7 @@ PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "yet_another_mobilene
 SCRIPTS = pathlib.Path(__file__).resolve().parent.parent / "scripts"
 
 # the curated scripts/ subset: PRNG discipline and version-fragile imports
-# apply to standalone benches/watchers exactly as to package code; the
+# apply to standalone benches exactly as to package code; the
 # package-convention rules (logging sinks, config drift, donation idioms)
 # deliberately do not
 SCRIPT_RULES = {"YAMT002", "YAMT006"}

@@ -41,27 +41,27 @@ def test_clean_fixture_silent(rule_id):
 
 
 def test_line_suppression(tmp_path):
-    (tmp_path / "m.py").write_text("from jax import shard_map  # yamt-lint: disable=YAMT006\n")
+    (tmp_path / "m.py").write_text("from jax.experimental import maps  # yamt-lint: disable=YAMT006\n")
     assert analysis.run_lint([tmp_path]) == []
 
 
 def test_line_suppression_is_rule_scoped(tmp_path):
     # suppressing a DIFFERENT rule must not silence this one
-    (tmp_path / "m.py").write_text("from jax import shard_map  # yamt-lint: disable=YAMT001\n")
+    (tmp_path / "m.py").write_text("from jax.experimental import maps  # yamt-lint: disable=YAMT001\n")
     assert [f.rule for f in analysis.run_lint([tmp_path])] == ["YAMT006"]
 
 
 def test_file_suppression(tmp_path):
     (tmp_path / "m.py").write_text(
         "# yamt-lint: disable-file=YAMT006\n"
-        "from jax import shard_map\n"
         "from jax.experimental import maps\n"
+        "import jax._src.core\n"
     )
     assert analysis.run_lint([tmp_path]) == []
 
 
 def test_disable_all(tmp_path):
-    (tmp_path / "m.py").write_text("from jax import shard_map  # yamt-lint: disable=all\n")
+    (tmp_path / "m.py").write_text("from jax.experimental import maps  # yamt-lint: disable=all\n")
     assert analysis.run_lint([tmp_path]) == []
 
 
@@ -72,7 +72,7 @@ def test_suppression_in_docstring_is_not_a_suppression(tmp_path):
         '"""Example:  # yamt-lint: disable-file=YAMT006\n'
         'and inline:  # yamt-lint: disable=YAMT006\n'
         '"""\n'
-        "from jax import shard_map\n"
+        "from jax.experimental import maps\n"
     )
     assert [f.rule for f in analysis.run_lint([tmp_path])] == ["YAMT006"]
 
@@ -89,7 +89,7 @@ def test_stale_suppression_flagged(tmp_path):
 
 
 def test_live_suppression_not_flagged(tmp_path):
-    (tmp_path / "m.py").write_text("from jax import shard_map  # yamt-lint: disable=YAMT006\n")
+    (tmp_path / "m.py").write_text("from jax.experimental import maps  # yamt-lint: disable=YAMT006\n")
     assert analysis.check_suppressions([tmp_path]) == []
     assert analysis.run_lint([tmp_path]) == []
 

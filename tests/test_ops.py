@@ -250,7 +250,6 @@ def test_batchnorm_fused_vjp_sharded_grad_contract_matches_exact():
     this codebase uses (documented in ops/layers.py)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from yet_another_mobilenet_series_tpu.utils.compat import shard_map
 
     c = 4
     spec = ops.BatchNorm(c)
@@ -271,7 +270,7 @@ def test_batchnorm_fused_vjp_sharded_grad_contract_matches_exact():
             return jax.tree.map(lambda v: v[None], g), gx
 
         return jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+            jax.shard_map(body, mesh=mesh, in_specs=(P(), P("data"), P("data")),
                       out_specs=(P("data"), P("data")), check_vma=False)
         )(params, x, w)
 
@@ -337,7 +336,6 @@ def test_syncbn_equals_full_batch_bn(mode):
     bn_mode normalize variant."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from yet_another_mobilenet_series_tpu.utils.compat import shard_map
 
     c = 4
     spec = ops.BatchNorm(c)
@@ -352,7 +350,7 @@ def test_syncbn_equals_full_batch_bn(mode):
         return spec.apply(p, s, xx, train=True, axis_name="data", mode=mode)
 
     y, st = jax.jit(
-        shard_map(
+        jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(), P(), P("data")),

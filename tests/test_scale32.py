@@ -39,7 +39,6 @@ def _run_py32(code: str, timeout=1500) -> str:
 def test_dryrun_multichip_accepts_32_devices():
     out = _run_py32("""
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import __graft_entry__ as g
         g.dryrun_multichip(32)
         print("DRYRUN32 OK", len(jax.devices()))
@@ -50,7 +49,6 @@ def test_dryrun_multichip_accepts_32_devices():
 def test_zero_ragged_chunks_at_mesh_32():
     out = _run_py32("""
         import jax
-        jax.config.update("jax_platforms", "cpu")
         import numpy as np
         import jax.numpy as jnp
         from yet_another_mobilenet_series_tpu.config import config_from_dict

@@ -282,7 +282,7 @@ def test_remat_policy_validated():
 def test_bn_variants_converge_identically():
     """300 training steps under each bn_mode track the exact-mode loss
     trajectory (single device, f32) with bounded divergence — the
-    training-dynamics half of the PROFILE.md decision rule's top-1-parity
+    training-dynamics half of the bn_mode adoption rule's top-1-parity
     argument for `compute` (VERDICT r3 #5; the eval-forward half is
     test_acceptance_mbv2.py::test_full_scale_bn_mode_prediction_agreement).
 

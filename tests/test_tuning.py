@@ -1,10 +1,9 @@
 """The measurement→production loop (VERDICT r4 #2): a BENCH_TUNING.json
-written by the watcher's adoption step must change a REAL training run's
+holding a measured winner must change a REAL training run's
 effective step config when the run opts in via train.tuning_file — and must
 never be able to perturb eval accuracy (eval pins exact BN regardless).
 """
 
-import importlib.util
 import json
 import os
 
@@ -12,8 +11,6 @@ import pytest
 
 from yet_another_mobilenet_series_tpu.config import config_from_dict
 from yet_another_mobilenet_series_tpu.train import tuning as tuning_lib
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cfg(tmp_path, **train_over):
@@ -47,21 +44,14 @@ def test_validate_tuning_matches_bench_semantics():
             tuning_lib.validate_tuning(bad)
 
 
-def test_partition_flags_copies_agree():
-    """bench.py keeps a jax-free supervisor-side copy of partition_flags;
-    this pins the two implementations to identical behavior so they cannot
-    drift (train/tuning.py is the package-side source)."""
-    spec = importlib.util.spec_from_file_location("bench_mod", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    cases = ["--xla_latency_hiding_scheduler=true --xla_tpu_rwb_fusion=false",
-             "--xla_tpu_scoped_vmem_limit_kib=98304", ""]
-    for fs in cases:
-        assert bench.partition_flags(fs) == tuning_lib.partition_flags(fs)
+def test_partition_flags_splits_and_validates():
+    assert tuning_lib.partition_flags(
+        "--xla_latency_hiding_scheduler=true --xla_tpu_rwb_fusion=false") == (
+        "--xla_latency_hiding_scheduler=true", "--xla_tpu_rwb_fusion=false")
+    assert tuning_lib.partition_flags("") == ("", "")
     for bad in ("--xlatpu_x=1", "xla_y=2", "--other=3"):
-        for fn in (bench.partition_flags, tuning_lib.partition_flags):
-            with pytest.raises(ValueError):
-                fn(bad)
+        with pytest.raises(ValueError):
+            tuning_lib.partition_flags(bad)
 
 
 def test_apply_tuning_file_overrides_and_env(tmp_path, monkeypatch):

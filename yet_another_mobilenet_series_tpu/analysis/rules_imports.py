@@ -1,14 +1,11 @@
 """YAMT006 — version-fragile jax imports.
 
-``from jax import shard_map`` is exactly the one-line bug that broke all 5 of
-the seed's tier-1 collection errors under jax 0.4.37 (shard_map only moved to
-the top level in later releases); ``jax._src.*`` is private and reshuffles
-every minor release; ``jax.experimental.maps`` (xmap) was deleted; and
-``jax.experimental.shard_map`` is the OLD home, gone again in newer jax. The
-resilient spellings are ``utils/compat.py`` (which resolves shard_map across
-versions) or an explicit ``try/except ImportError`` version guard — imports
-inside such a guard are exempt, since that IS the sanctioned idiom (it is how
-utils/compat.py itself is written).
+``jax._src.*`` is private and reshuffles every minor release;
+``jax.experimental.maps`` (xmap) was deleted; ``jax.experimental.shard_map``
+is the old home of what is now ``jax.shard_map``. The package is written for
+the one jax it is installed with (0.9: ``jax.shard_map(..., check_vma=...)``)
+and carries no version switch; an import inside an explicit ``try/except
+ImportError`` guard is exempt, since such a guard says the author knew.
 """
 
 from __future__ import annotations
@@ -17,17 +14,15 @@ import ast
 
 from .core import Finding, Project, Rule, SourceFile, qualified_name, register
 
-_COMPAT = "utils/compat.py"
 # `from jax import X` names that only exist in some jax versions
 _FRAGILE_FROM_JAX = {
-    "shard_map": f"moved across jax releases; import it from {_COMPAT}",
     "maps": "jax.experimental.maps (xmap) was removed from jax",
 }
 # fragile module prefixes for `import X` / `from X import ...`
 _FRAGILE_MODULES = {
     "jax._src": "private jax internals, reshuffled every minor release",
     "jax.experimental.maps": "removed from jax (xmap is gone)",
-    "jax.experimental.shard_map": f"old home of shard_map, removed in newer jax; use {_COMPAT}",
+    "jax.experimental.shard_map": "old home of shard_map; spell it jax.shard_map",
 }
 _GUARD_EXCEPTIONS = {"ImportError", "ModuleNotFoundError", "Exception", "AttributeError"}
 
@@ -44,13 +39,13 @@ class FragileJaxImport(Rule):
     id = "YAMT006"
     name = "version-fragile-jax-import"
     description = (
-        "an import that only resolves on some jax versions (from jax import shard_map, "
-        "jax._src.*, jax.experimental.maps/shard_map) outside a try/except version guard"
+        "an import that only resolves on some jax versions (jax._src.*, "
+        "jax.experimental.maps/shard_map) outside a try/except version guard"
     )
 
     def check_file(self, src: SourceFile, project: Project) -> list[Finding]:
         # imports anywhere inside a try/except that catches ImportError are
-        # the sanctioned version-guard idiom (utils/compat.py) — exempt
+        # an explicit version guard — exempt
         guarded: set[int] = set()
         for node in src.nodes:
             if not isinstance(node, ast.Try):

@@ -8,6 +8,15 @@ as an ordinary frontend — same endpoints, same typed statuses, same
 one that survives the death of any of its processes. The supervisor process
 itself never imports jax: replicas own the device; the parent owns policy.
 
+One process per chip. A TPU chip belongs to the one process that opened it:
+a supervisor that initialised a backend would hold the chip its replicas
+need, and a second replica on a held chip hangs or fails in backend init.
+So on a TPU host (:func:`host_tpu_chips` > 0) every replica is spawned
+with an environment that shows it exactly one chip (:func:`one_chip_env`,
+slot i -> chip i), and a fleet asked for more replicas than the host has
+chips refuses at start-up instead of hanging. On a CPU host nothing is
+pinned and the replica count is unbounded.
+
 What runs here:
 
 - **spawn**: each replica is ``cli/serve.py`` with the SAME config plus per
@@ -37,6 +46,7 @@ sequentially (each bounded by its own SIGTERM drain), then exit 0.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import random
@@ -80,6 +90,30 @@ class FleetSpawnError(RuntimeError):
 # dead supervisor can never leak replicas — the process-level YAMT015
 # hazard, closed portably.
 ORPHAN_ENV = "YAMT_FLEET_PARENT"
+
+
+def host_tpu_chips() -> int:
+    """TPU chips this host exposes to this process, counted from the device
+    nodes libtpu opens (``/dev/vfio/<n>`` on v5e and later, ``/dev/accel<n>``
+    before) — never through jax, whose backend init would claim them. 0 on a
+    host without a TPU, or when ``JAX_PLATFORMS`` keeps jax off it."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return len(glob.glob("/dev/vfio/[0-9]*")) or len(glob.glob("/dev/accel[0-9]*"))
+
+
+def one_chip_env(chip: int, base: dict | None = None) -> dict:
+    """``base`` (default: this process's environment) narrowed so the child
+    sees exactly TPU chip ``chip`` as a one-chip topology of its own — the
+    libtpu convention for several processes on one host."""
+    env = dict(os.environ if base is None else base)
+    env.update({
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    })
+    return env
 
 
 class ReplicaHandle:
@@ -237,7 +271,10 @@ class FleetSupervisor:
         on_change=None,
         spawn_fn=None,
         logger=None,
+        chips: int | None = None,
     ):
+        # TPU chips to place replicas on, one each; 0 = a CPU host, no limit
+        self._chips = host_tpu_chips() if chips is None else chips
         self._replica_argv = list(replica_argv)
         self._log_dir = log_dir
         self._n_initial = max(1, int(replicas))
@@ -271,7 +308,19 @@ class FleetSupervisor:
         return ReplicaHandle(
             slot, argv, os.path.join(self._log_dir, f"r{slot}"),
             spawn_timeout_s=self._spawn_timeout_s,
+            env=one_chip_env(slot) if self._chips else None,
         ).spawn()
+
+    def check_placeable(self, n: int) -> None:
+        """Raise unless ``n`` replicas can each have a chip of their own."""
+        if self._chips and n > self._chips:
+            raise FleetSpawnError(
+                f"{n} replicas asked for, but this host exposes {self._chips} TPU "
+                "chip(s) and a chip belongs to one process at a time: a replica "
+                "without a chip of its own would hang in backend init. Run at most "
+                "one replica per chip (serve.fleet.replicas, "
+                "serve.fleet.autoscale.max_replicas)."
+            )
 
     def _emit(self, msg: str) -> None:
         if self._log is not None:
@@ -300,6 +349,7 @@ class FleetSupervisor:
     def start(self) -> "FleetSupervisor":
         if self._thread is not None:
             raise RuntimeError("fleet already started")
+        self.check_placeable(self._n_initial)
         with self._lock:
             for i in range(self._n_initial):
                 self._slots[i] = _Slot(i)
@@ -433,6 +483,7 @@ class FleetSupervisor:
         drains wait for exit). Shrink drains the NEWEST slots first. Returns
         the achieved count."""
         n = max(1, int(n))
+        self.check_placeable(n)
         with self._lock:
             wanted = sorted(s.idx for s in self._slots.values() if s.wanted)
             grow = n - len(wanted)
@@ -663,7 +714,9 @@ def run(cfg: Config, replica_argv: list[str]) -> dict:
     reg = obs_registry.get_registry()
     if cfg.obs.histogram_buckets:
         reg.set_default_buckets(cfg.obs.histogram_buckets)
-    reg.set_build_info(obs_device.build_info())  # no jax import: versions + git sha
+    # device=False: reading the platform would initialise a backend HERE and
+    # take the chip from the replicas this process is about to spawn
+    reg.set_build_info(obs_device.build_info(device=False))
     log.set_registry(reg)
     tracer = obs_trace.configure(enabled=bool(cfg.obs.trace), ring_size=cfg.obs.trace_ring_size,
                                  process_name="router")
@@ -818,6 +871,10 @@ def run(cfg: Config, replica_argv: list[str]) -> dict:
     frontend = autoscaler = chaos = brownout = watchdog = None
     try:
         if fleet is not None:
+            if fc.autoscale.enable:
+                # refuse NOW, not when the autoscaler first reaches for a
+                # chip that is not there
+                fleet.check_placeable(fc.autoscale.max_replicas)
             fleet.start()
         frontend = Frontend(
             serving_tier,

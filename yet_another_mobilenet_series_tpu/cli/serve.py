@@ -16,7 +16,10 @@ Three phases, all optional, driven by the ``serve:`` config block:
    continuous-batching one by default (``serve.pipelined``,
    serve/pipeline.py), or the legacy sync micro-batcher. Prints p50/p99
    end-to-end latency and QPS; with a log_dir, metrics + obs_registry.json
-   land where scripts/obs_report.py reads them.
+   land where scripts/obs_report.py reads them. The process exits non-zero
+   (:class:`LoadFailed`) when any request failed for a reason other than the
+   shedding the config asks for (a deadline, a full queue): an engine that
+   raises on the device must not look like a served load.
 3. **listen** (``serve.listen.enable`` or the ``--listen`` shorthand): the
    fault-tolerant front door — a loopback HTTP server (serve/frontend.py)
    in front of priority/QoS admission control, bounded retry, and a
@@ -52,7 +55,7 @@ from ..obs import trace as obs_trace
 from ..obs.watchdog import StallWatchdog
 from ..parallel import mesh as mesh_lib
 from ..serve.admission import AdmissionController
-from ..serve.batcher import MicroBatcher, QueueFull
+from ..serve.batcher import DeadlineExceeded, MicroBatcher, QueueFull
 from ..serve.brownout import BrownoutController
 from ..serve.engine import InferenceEngine
 from ..serve.signals import SignalReader
@@ -61,7 +64,22 @@ from ..serve.frontend import Frontend, write_listen_addr
 from ..serve.pipeline import PipelinedBatcher
 from ..serve import quant
 from ..serve.export import export_checkpoint, load_bundle
+from ..utils import compile_cache
 from ..utils.logging import Logger
+
+
+class LoadFailed(RuntimeError):
+    """The synthetic load finished with requests that failed for a reason
+    the config did not ask for (engine error, timeout, crashed client).
+    ``summary`` is the full count dict the run would have returned."""
+
+    def __init__(self, summary: dict):
+        self.summary = summary
+        super().__init__(
+            f"synthetic load: {summary['failed']} of {summary['requests']} requests failed, "
+            f"{summary['client_crashes']} client thread(s) crashed; "
+            f"first failure: {summary['first_failure'] or 'n/a'}"
+        )
 
 
 def _percentile(sorted_vals: list[float], q: float) -> float:
@@ -88,7 +106,7 @@ def _drive_load(cfg: Config, batcher: MicroBatcher, image_size: int, log: Logger
     rng = np.random.RandomState(0)
     image = _synthetic_image(rng, image_size, cfg.serve.quant.wire)
     latencies: list[float] = []
-    errors = {"shed": 0, "rejected": 0, "crashed": 0}
+    errors = {"shed": 0, "rejected": 0, "failed": 0, "crashed": 0, "first_failure": ""}
     lock = threading.Lock()
     counter = {"left": n_total}
 
@@ -107,9 +125,14 @@ def _drive_load(cfg: Config, batcher: MicroBatcher, image_size: int, log: Logger
                     errors["rejected"] += 1
                 time.sleep(0.001)  # back off, as a real client would
                 continue
-            except Exception:  # noqa: BLE001 — shed/engine failure: count, keep driving
+            except DeadlineExceeded:  # the shedding serve.deadline_ms asks for
                 with lock:
                     errors["shed"] += 1
+                continue
+            except Exception as e:  # noqa: BLE001 — engine failure/timeout: count, keep driving
+                with lock:
+                    errors["failed"] += 1
+                    errors["first_failure"] = errors["first_failure"] or f"{type(e).__name__}: {e}"
                 continue
             with lock:
                 latencies.append(time.perf_counter() - t0)
@@ -135,6 +158,8 @@ def _drive_load(cfg: Config, batcher: MicroBatcher, image_size: int, log: Logger
         "completed": len(latencies),
         "shed": errors["shed"],
         "rejected_full": errors["rejected"],
+        "failed": errors["failed"],
+        "first_failure": errors["first_failure"],
         "client_crashes": errors["crashed"],
         "wall_s": wall,
         "qps": len(latencies) / wall if wall > 0 else 0.0,
@@ -143,7 +168,8 @@ def _drive_load(cfg: Config, batcher: MicroBatcher, image_size: int, log: Logger
     }
     log.log(
         f"load: {summary['completed']}/{n_total} ok ({summary['shed']} shed, "
-        f"{summary['rejected_full']} rejected), {summary['qps']:.1f} qps, "
+        f"{summary['rejected_full']} rejected, {summary['failed']} failed), "
+        f"{summary['qps']:.1f} qps, "
         f"p50 {summary['p50_ms']:.2f} ms, p99 {summary['p99_ms']:.2f} ms"
     )
     return summary
@@ -372,6 +398,7 @@ def _listen(cfg: Config, engine, log: Logger, reg, tracer, zoo=None) -> dict:
 
 
 def run(cfg: Config) -> dict:
+    compile_cache.configure()  # before the first compile; replicas resolve the same dir
     is_coord = mesh_lib.is_coordinator()
     log = Logger(cfg.train.log_dir, enabled=is_coord, tensorboard=False)
     reg = obs_registry.get_registry()
@@ -479,6 +506,10 @@ def run(cfg: Config) -> dict:
                 result.update(_drive_load(cfg, batcher, cfg.data.image_size, log))
             finally:
                 batcher.stop()
+            if result["failed"] or result["client_crashes"]:
+                # the counts ride the exception; the finally below still
+                # writes obs_registry.json for the post-mortem
+                raise LoadFailed(result)
         if cfg.serve.listen.enable:
             result.update(_listen(cfg, engine, log, reg, tracer, zoo=zoo))
         return result

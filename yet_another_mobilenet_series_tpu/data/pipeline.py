@@ -25,21 +25,10 @@ import numpy as np
 from ..config import DataConfig
 from ..obs.registry import get_registry
 from ..utils.logging import emit
+from ..utils.tfutil import host_only_tf
 
-# tf is imported lazily: the heavy import (and its thread pools) should only
-# exist in processes that actually build an input pipeline.
-_tf = None
-
-
-def _tf_mod():
-    global _tf
-    if _tf is None:
-        import tensorflow as tf
-
-        tf.config.set_visible_devices([], "GPU")
-        tf.config.set_visible_devices([], "TPU")
-        _tf = tf
-    return _tf
+# tf is imported lazily and blind to accelerators (utils/tfutil.py)
+_tf_mod = host_only_tf
 
 
 # ---------------------------------------------------------------------------

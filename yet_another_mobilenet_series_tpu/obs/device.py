@@ -251,19 +251,28 @@ def _git_sha(repo_dir: str | None = None) -> str:
     return ""
 
 
-def build_info() -> dict:
+def build_info(*, device: bool = True) -> dict:
     """Version-attribution labels for the ``build_info`` metric family: git
     sha, jax/jaxlib versions, backend platform. A scraped fleet can group
-    replicas by exactly what they run."""
-    import jax
-    import jaxlib
+    replicas by exactly what they run.
 
-    return {
+    Versions come from package metadata, not ``import jax``. The platform
+    label is the one field that needs a live backend, and reading it
+    INITIALISES that backend — on a TPU host the calling process then owns
+    the chip. ``device=False`` is for supervisors (cli/fleet.py) whose
+    children need that chip: they report versions and sha only."""
+    from importlib import metadata
+
+    info = {
         "git_sha": _git_sha() or "unknown",
-        "jax_version": jax.__version__,
-        "jaxlib_version": jaxlib.__version__,
-        "platform": jax.default_backend(),
+        "jax_version": metadata.version("jax"),
+        "jaxlib_version": metadata.version("jaxlib"),
     }
+    if device:
+        import jax
+
+        info["platform"] = jax.default_backend()
+    return info
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ things the profiler window cannot see without forcing
 Because a dispatch span closes when the host call RETURNS (async dispatch,
 no device sync), tracing adds no host<->device round trips: an input-bound
 step shows a fat ``data/next`` span, a dispatch-bound one a fat
-``dispatch/*`` span, and a wedged tunnel an open span in the hang report.
+``dispatch/*`` span, and a hung collective an open span in the hang report.
 
 Beyond duration ("X") spans the tracer emits the Chrome-trace event kinds
 that correlate ONE request across threads (serve/context.py threads them

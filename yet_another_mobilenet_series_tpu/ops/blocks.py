@@ -212,8 +212,8 @@ class InvertedResidual:
 
         The depthwise chain is deliberately the plain XLA lowering: a Pallas
         fused dw+BN+act+mask eval kernel was built and A/B-measured on a real
-        v5e in round 2 and lost 10x end-to-end (ops/pallas_kernels.py keeps
-        the kernel + the numbers; PROFILE.md has the full verdict)."""
+        v5e before PR 1 and lost 10x end-to-end (ops/pallas_kernels.py keeps
+        the kernel and the verdict; ROADMAP.md's table the number)."""
         act = get_activation(self.active_fn)
         new_state = {}
         h = x

@@ -93,9 +93,9 @@ class Conv2D:
         """as_dot lowers a 1x1 ungrouped conv as an explicit matmul
         (`(N,H,W,Cin) @ (Cin,Cout)`): forward is the same contraction XLA
         canonicalizes 1x1 convs to, but the WEIGHT GRADIENT of a dot is
-        guaranteed to lower as another dot (MXU) — the round-2 trace showed
+        guaranteed to lower as another dot (MXU) — the pre-PR-1 trace showed
         25.3% of step time in `multiply_add_fusion` weight-grad reductions
-        (PROFILE.md), and this removes XLA's freedom to pick that lowering
+        (ROADMAP.md's table), and this removes XLA's freedom to pick that lowering
         for the 1x1s. No-op for k>1 or grouped convs. Param layout is
         unchanged (HWIO, reshaped at apply), so checkpoints are identical."""
         w = params["w"].astype(compute_dtype)
@@ -156,7 +156,7 @@ def _bn_moments(x, axis_name):
 def _bn_moments_dot(x, axis_name):
     """Batch moments computed as MXU contractions instead of VPU reduces —
     the round-4 attack candidate on the trace's 51.8% convert_reduce_fusion
-    share (PROFILE.md): s1 = ones·x is a plain dot; s2 = Σ_nhw x² is a
+    share (ROADMAP.md's table): s1 = ones·x is a plain dot; s2 = Σ_nhw x² is a
     C-batched self-contraction (batch dim C, contract NHW), whose bf16
     products are EXACT in the f32 accumulator (8-bit mantissas double to 16
     < 24). Forcing dot lowerings also forces the BACKWARD companions of the
@@ -311,7 +311,7 @@ class BatchNorm:
 
         - "exact"  — (f32(x) - mean) * (gamma*rsqrt(var+eps)) + beta. The
           round-2 TPU trace shows this step's 51.8% convert_reduce_fusion
-          cost concentrated around BN (PROFILE.md "Where the time goes");
+          cost concentrated around BN (ROADMAP.md's table);
           the f32-upcast expression shared between the stat-reduce and the
           normalize is the suspected extra-HBM-traffic source.
         - "folded" — per-channel scale = gamma*rsqrt(var+eps) and

@@ -29,7 +29,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.compat import shard_map
 from .mesh import DATA_AXIS
 
 
@@ -102,7 +101,7 @@ def init_opt_state(optimizer: optax.GradientTransformation, params, mesh: Mesh):
     each accumulator leaf is (n*chunk,) flat, device d holding shard d."""
     n = mesh.size
     specs = opt_state_specs(optimizer, params, n)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p: _local_init(optimizer, p, lax.axis_index(DATA_AXIS), n),
         mesh=mesh,
         in_specs=(P(),),

@@ -1,13 +1,12 @@
 """Measured-tuning consumption: the measurement→production loop (VERDICT r4
 missing #4 / next #2).
 
-`scripts/tpu_watch.py` adopts A/B + sweep winners into `BENCH_TUNING.json`;
-until round 5 the ONLY consumer was `bench.py`, so the driver's artifact
-measured the winner while real training launches stayed on the YAML
-defaults until a human edited them. `train.tuning_file` closes the loop: a
-production run pointed at the tuning file picks up the adopted step config
-(bn_mode / remat / remat_policy / conv1x1_dot / steps_per_dispatch) and XLA
-flags with provenance logged at startup.
+`BENCH_TUNING.json` holds the step config an A/B (scripts/bench_bn.py) and a
+flag sweep found best, with where each value came from. `bench.py` reads it,
+and `train.tuning_file` lets a production run adopt the same file: the run
+picks up the step config (bn_mode / remat / remat_policy / conv1x1_dot /
+steps_per_dispatch) and XLA flags with provenance logged at startup, so a
+measured winner reaches training without a hand-edited YAML.
 
 Validation is single-sourced here — `bench.py.load_tuning` delegates to
 `validate_tuning` — so the bench and the production CLI can never disagree
@@ -26,9 +25,8 @@ from typing import Any
 # step-config keys a tuning file may carry — the single source (bench.py
 # delegates here); 'flags' is env-level and handled separately
 TUNING_KEYS = ("bn_mode", "remat", "remat_policy", "conv1x1_dot", "steps_per_dispatch")
-# metadata keys the watcher's adoption step writes alongside the config
-# (scripts/tpu_watch.py _AB_KEYS/_DISPATCH_KEYS/_FLAG_KEYS); 'provisional'
-# marks a compute-family win whose parity evidence is synthetic-fixture only;
+# metadata keys written alongside the config by whoever adopts a winner;
+# 'provisional' marks a compute-family win whose parity evidence is synthetic-fixture only;
 # 'contention_invalidated'/'contention_note' mark an adoption whose measured
 # justification was skewed by host contention (ADVICE r5) — kept so the run
 # that consumes the tuning sees the warning, not just the decision artifact
@@ -124,13 +122,11 @@ def partition_flags(flags_str: str) -> tuple[str, str]:
     """Split a flag string into (XLA_FLAGS, LIBTPU_INIT_ARGS) halves.
 
     '--xla_tpu_*' flags are libtpu options: in host XLA_FLAGS they are a
-    fatal 'Unknown flag' abort at backend init (measured 2026-07-30,
-    PROFILE.md round 4); on PJRT TPUs libtpu consumes them from
+    fatal 'Unknown flag' abort at backend init (measured 2026-07-30);
+    on PJRT TPUs libtpu consumes them from
     LIBTPU_INIT_ARGS. The full '--xla_' prefix is required so near-miss
     typos ('--xlatpu_...') fail validation instead of reaching the backend
-    (ADVICE r4 #2). bench.py keeps a jax-free DUPLICATE for its supervisor
-    side (importing this module pulls jax via train/__init__); the two are
-    pinned identical by tests/test_tuning.py::test_partition_flags_copies_agree."""
+    (ADVICE r4 #2)."""
     xla, libtpu = [], []
     for tok in flags_str.split():
         if not tok.startswith("--xla_"):

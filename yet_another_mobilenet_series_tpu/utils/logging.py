@@ -17,6 +17,8 @@ from __future__ import annotations
 import sys
 import time
 
+from .tfutil import host_only_tf
+
 # the active coordinator Logger, so code without a Logger handle (the data
 # pipeline's host warnings) can still route through one via emit()
 _CURRENT: "Logger | None" = None
@@ -50,7 +52,9 @@ class Logger:
             self._jsonl_path = os.path.join(log_dir, "metrics.jsonl")
             if tensorboard:
                 try:
-                    import tensorflow as tf
+                    # accelerators hidden BEFORE the writer starts TF's
+                    # runtime: this is the first TF import of a training run
+                    tf = host_only_tf()
                 except Exception as e:  # TF missing or broken: degrade, once
                     global _TB_WARNED
                     if not _TB_WARNED:
@@ -97,7 +101,7 @@ class Logger:
             self._jsonl.flush()
         if self._tb is None:
             return
-        import tensorflow as tf
+        tf = host_only_tf()
 
         with self._tb.as_default():
             for k, v in row.items():
