@@ -1,0 +1,7 @@
+"""step.conv_dw_ms.train: see step_scopes_train.py, which computes every scope metric of a run once."""
+
+from benchmark.layer_metrics import step_scopes_train
+
+
+def read(ctx):
+    return step_scopes_train.metric(ctx, "step.conv_dw_ms.train")

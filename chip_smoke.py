@@ -126,61 +126,6 @@ def need(cond: bool, what: str) -> None:
         raise SmokeFailure(what)
 
 
-class CompileLog:
-    """Every backend compile and persistent-cache hit/miss JAX reports, each
-    compile stamped with where the program was: the host spans open at that
-    moment (obs/trace.py), the last logged train step and the number of
-    serving requests accepted so far (registry values). That turns 'steps
-    after the first cause no compilation' into a count."""
-
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self, registry):
-        import jax.monitoring as mon
-
-        from yet_another_mobilenet_series_tpu.obs.trace import get_tracer
-
-        self._reg = registry
-        self._tracer = get_tracer  # by call: each run() configures a new one
-        self.compiles: list[dict] = []
-        self.hits = self.misses = 0
-        mon.register_event_duration_secs_listener(self._on_duration)
-        mon.register_event_listener(self._on_event)
-
-    def close(self) -> None:
-        import jax.monitoring as mon
-
-        mon.unregister_event_duration_listener(self._on_duration)
-        mon.unregister_event_listener(self._on_event)
-
-    def _on_duration(self, name, secs, fun_name="", **_):
-        if name == self.COMPILE:
-            self.compiles.append({
-                "s": round(secs, 3), "fun": fun_name,
-                "open": [sp["name"] for sp in self._tracer().open_spans()],
-                "train_step": self._reg.gauge("train.step").value,
-                "serve_requests": self._reg.counter("serve.requests").value,
-            })
-
-    def _on_event(self, name, **_):
-        if name == self.HIT:
-            self.hits += 1
-        elif name == self.MISS:
-            self.misses += 1
-
-    def mark(self) -> tuple[int, int, int]:
-        return len(self.compiles), self.hits, self.misses
-
-    def since(self, mark) -> dict:
-        n0, h0, m0 = mark
-        new = self.compiles[n0:]
-        return {"compiles": len(new), "compile_s": round(sum(c["s"] for c in new), 2),
-                "cache_hits": self.hits - h0, "cache_misses": self.misses - m0,
-                "events": new}
-
-
 def device_memory(jax) -> list[dict]:
     out = []
     for d in jax.devices():
@@ -196,7 +141,7 @@ def device_memory(jax) -> list[dict]:
 # ---------------------------------------------------------------------------
 
 
-def train_phase(size: Size, n_dev: int, work: str, clog: CompileLog, registry, on_tpu: bool,
+def train_phase(size: Size, n_dev: int, work: str, clog, registry, on_tpu: bool,
                 extra: list[str]) -> dict:
     from yet_another_mobilenet_series_tpu.ckpt.manager import CheckpointManager
     from yet_another_mobilenet_series_tpu.cli import train as cli_train
@@ -214,12 +159,12 @@ def train_phase(size: Size, n_dev: int, work: str, clog: CompileLog, registry, o
         # parallel/dp.py's cross-replica parameter checksum at every log
         # boundary: a replica that diverged raises inside the run
         f"train.param_checksum_every={size.log_every}",
-        # host spans on: CompileLog reads which one a compile happened in
+        # host spans on: the compile watch reads which one a compile happened in
         "obs.trace=true",
         "train.resume=false", f"train.log_dir={log_dir}", *extra,
     ]
     say("train: cli.train.main " + " ".join(a for a in argv if not a.startswith("model.block_specs")))
-    registry.gauge("train.step").set(0)  # the stamp CompileLog reads; stale if the process trained before
+    registry.gauge("train.step").set(0)  # the stamp the compile watch reads; stale if the process trained before
     mark = clog.mark()
     t0 = time.perf_counter()
     result = cli_train.main(argv)
@@ -296,7 +241,7 @@ def train_phase(size: Size, n_dev: int, work: str, clog: CompileLog, registry, o
     return out
 
 
-def export_phase(size: Size, work: str, clog: CompileLog, registry) -> dict:
+def export_phase(size: Size, work: str, clog, registry) -> dict:
     from yet_another_mobilenet_series_tpu.cli import serve as cli_serve
 
     argv = [
@@ -324,7 +269,7 @@ def export_phase(size: Size, work: str, clog: CompileLog, registry) -> dict:
     return out
 
 
-def serve_phase(size: Size, work: str, clog: CompileLog, registry) -> tuple[dict, list]:
+def serve_phase(size: Size, work: str, clog, registry) -> tuple[dict, list]:
     """The bundle under load, then the front door. cli.serve.main runs in
     this (the main) thread and owns SIGTERM; a helper thread plays the
     operator: waits for the bound address, POSTs, checks health, SIGTERMs."""
@@ -508,6 +453,7 @@ def run(args) -> int:
     try:
         import jax
 
+        from yet_another_mobilenet_series_tpu.obs.device import install_compile_watch
         from yet_another_mobilenet_series_tpu.obs.registry import get_registry
         from yet_another_mobilenet_series_tpu.utils import compile_cache
     except ImportError as e:
@@ -543,7 +489,10 @@ def run(args) -> int:
         f"{entries} entries at start)")
 
     registry = get_registry()
-    clog = CompileLog(registry)
+    # the program's own compile watch (obs/device.py): every compile stamped
+    # with the open host spans, the train step and the request count, which
+    # turns 'steps after the first cause no compilation' into a count
+    clog = install_compile_watch()
     report: dict = {"rehearsal": args.rehearsal, "device": device, "versions": versions,
                     "compile_cache": {"dir": cache_dir, "entries_at_start": entries,
                                       "enabled": cache_on}}
@@ -568,7 +517,6 @@ def run(args) -> int:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1
     finally:
-        clog.close()
         shutil.rmtree(work, ignore_errors=True)
     report["wall_s"] = round(time.perf_counter() - t_start, 1)
 

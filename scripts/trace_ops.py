@@ -12,6 +12,23 @@ per-image block total next to the trace's aggregated op time, so a table
 whose provenance doesn't match the traced hardware shows up as a gross
 ratio mismatch instead of silently mis-weighting the NAS penalty.
 
+**By scope.** Where the directory holds a ``scope_table.json`` (cli/train.py
+writes one beside its profiler window's trace; obs/scopes.py), each device's
+op time is also printed by the program's own names — scope x phase
+(``conv_dw`` ``bwd``, ``bn_apply`` ``bwd``, ...: whose fusion it is) — with
+the share that resolved to no scope, then the time of the ops that CONTAIN
+each scope's reductions (XLA:TPU fuses a convolution with the BatchNorm sums
+around it, so both views are needed), and, as an UPPER bound only, each scope's share of the
+HBM roofline with bytes counted from the operand and result shapes in the
+event names (it over-counts what a fusion reads in part or keeps in fast
+memory, so it can pass 100%: a bound for ranking, not a metric).
+
+**Idle gaps by host span.** With ``obs.trace`` on, the program's host spans
+are in the same xplane file (``serve/stage``, ``data/next``, ...:
+obs/trace.py), so the longest stretches in which a device ran nothing are
+labelled with the spans open at their middle: "idle 41.0 ms: serve/stage+
+serve/h2d", not a bare gap.
+
 Usage: python scripts/trace_ops.py /path/to/trace_dir [top_n]
            [--check-table LATENCY_TABLE_r01_cpu_rehearsal.json]
 (finds the newest */vm.xplane.pb under the dir)
@@ -26,12 +43,33 @@ import os
 import re
 import sys
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from yet_another_mobilenet_series_tpu.obs import scopes  # noqa: E402
+from yet_another_mobilenet_series_tpu.obs.trace import SPAN_CATEGORIES  # noqa: E402
+
+# a v5e chip's published HBM bandwidth (benchmark/peaks.json): the roofline
+# bound printed by scope is an upper bound against THIS number
+V5E_HBM_BYTES_PER_S = 819e9
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
+                "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+_SHAPE = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([\d,]*)\]")
+
+
+def instruction_name(event_name: str) -> str:
+    """'%fusion.233 = f32[64]{0} fusion(...)' -> 'fusion.233': the HLO
+    instruction a device event ran, the key of a scope table. (A TPU event's
+    name is the instruction's whole HLO text; a CPU thunk's is the bare name.)"""
+    return event_name.split(" = ", 1)[0].strip().lstrip("%")
+
 
 def op_kind(name: str) -> str:
     """Collapse op numbering: 'fusion.123' -> 'fusion'. ONE definition for
     every backend's aggregation — the TPU and CPU rankings must never
     diverge on the collapse rule."""
-    return re.split(r"[.\d]", name, maxsplit=1)[0].lstrip("%")
+    return re.split(r"[.\d]", instruction_name(name), maxsplit=1)[0]
 
 
 def load_xspace(root: str):
@@ -90,6 +128,97 @@ def aggregate_device(plane) -> dict | None:
             "total_ps": max(total_ps, 1),
             "per_op": per_op, "per_cat": per_cat, "async_cat": async_cat,
             "modules": modules}
+
+
+def shape_bytes(event_name: str) -> int:
+    """Bytes of every array shape in a device event's name (the instruction's
+    HLO text: its result and its operands). An UPPER bound on what the
+    operation moves through HBM: a fusion may read an operand in part, and
+    an array in fast memory (``S(1)``) is not HBM traffic at all."""
+    total = 0
+    for dtype, dims in _SHAPE.findall(event_name):
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        total += n * _DTYPE_BYTES[dtype]
+    return total
+
+
+def device_ops(plane) -> list[tuple[str, int, int]]:
+    """(event name, start_ps, duration_ps) of every synchronous op on a
+    device plane's ``XLA Ops`` line(s); async ``-start`` windows left out, as
+    in :func:`aggregate_device`."""
+    out = []
+    for line in plane.lines:
+        if "XLA Ops" not in line.name:
+            continue
+        t0 = line.timestamp_ns * 1000
+        for ev in line.events:
+            meta = plane.event_metadata.get(ev.metadata_id)
+            name = meta.name if meta else "?"
+            if not op_kind(name).endswith("-start"):
+                out.append((name, t0 + ev.offset_ps, ev.duration_ps))
+    return out
+
+
+def program_spans(xs) -> list[tuple[str, int, int]]:
+    """(name, start_ps, duration_ps) of the program's host spans in the
+    trace: ``/host:CPU`` events named ``<cat>/<name>`` with a category of the
+    span taxonomy (obs/trace.py SPAN_CATEGORIES), from every thread."""
+    out = []
+    for plane in xs.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            t0 = line.timestamp_ns * 1000
+            for ev in line.events:
+                meta = plane.event_metadata.get(ev.metadata_id)
+                name = meta.name if meta else ""
+                if name.split("/", 1)[0] in SPAN_CATEGORIES and "/" in name:
+                    out.append((name, t0 + ev.offset_ps, ev.duration_ps))
+    return out
+
+
+NO_SPAN = "(no program span open)"
+
+
+def idle_gaps(ops, spans, n: int = 5) -> list[tuple[float, str]]:
+    """[(milliseconds, label)]: the longest stretches between a device's
+    first and last op in which it ran nothing, each labelled with the
+    program's host spans open at the gap's middle, joined by '+'
+    (benchmark/trace_reduce.py idle_gaps's method, the program's names)."""
+    busy: list[list[int]] = []
+    for _, start, dur in sorted(ops, key=lambda e: e[1]):
+        if dur <= 0:
+            continue
+        if busy and start <= busy[-1][1]:
+            busy[-1][1] = max(busy[-1][1], start + dur)
+        else:
+            busy.append([start, start + dur])
+    gaps = sorted(((b[0] - a[1], a[1]) for a, b in zip(busy, busy[1:])), reverse=True)[:n]
+    out = []
+    for length, start in gaps:
+        mid = start + length / 2
+        names = sorted({name for name, s, d in spans if s <= mid <= s + d})
+        out.append((length / 1e9, "+".join(names) or NO_SPAN))
+    return out
+
+
+def scope_rows(ops, table, hbm_bytes_per_s: float | None = None) -> list[dict]:
+    """One row per (scope, phase), most time first: ms, share of the summed
+    op time and, with a peak bandwidth, the roofline upper bound in percent
+    (bytes from shapes / bandwidth / time: see :func:`shape_bytes`)."""
+    times = scopes.time_by_scope(((instruction_name(n), d) for n, _, d in ops), table)
+    nbytes = scopes.time_by_scope(((instruction_name(n), shape_bytes(n)) for n, _, _ in ops), table)
+    total = sum(times.values()) or 1
+    rows = []
+    for key, ps in sorted(times.items(), key=lambda kv: -kv[1]):
+        row = {"scope": key[0], "phase": key[1], "ms": ps / 1e9, "share_pct": 100.0 * ps / total}
+        if hbm_bytes_per_s and ps > 0:
+            row["hbm_roofline_upper_pct"] = 100.0 * (nbytes[key] / hbm_bytes_per_s) / (ps / 1e12)
+        rows.append(row)
+    return rows
 
 
 def aggregate_host(xs) -> dict:
@@ -153,6 +282,8 @@ def main(argv=None) -> int:
     top_n = int(argv[1]) if len(argv) > 1 else 40
 
     xs, path = load_xspace(root)
+    scope_tables = scopes.read_scope_table(root)
+    spans = program_spans(xs)
     measured_ms = None
     printed_any = False
     for plane in xs.planes:
@@ -179,6 +310,26 @@ def main(argv=None) -> int:
         print(f"\n-- top {top_n} individual sync ops --")
         for k, v in agg["per_op"].most_common(top_n):
             print(f"  {k[:98]:<100} {v/total_ps*100:6.2f}%  {v/1e12*1000:8.3f} ms")
+        ops = device_ops(plane)
+        if scope_tables is not None:
+            table, inside = scope_tables
+            print(f"\n-- by scope and phase: whose fusion ({scopes.SCOPE_TABLE_FILE}; roofline column: HBM "
+                  "bytes from shapes, an UPPER bound) --")
+            for r in scope_rows(ops, table, V5E_HBM_BYTES_PER_S):
+                print(f"  {r['scope']:<12} {r['phase']:<4} {r['share_pct']:6.2f}%  {r['ms']:9.3f} ms"
+                      f"  roofline <= {r.get('hbm_roofline_upper_pct', 0.0):6.1f}%")
+            print("\n-- time of the ops that CONTAIN a scope's reduction or contraction (a convolution's "
+                  "fusion carries the BatchNorm sums around it; rows overlap) --")
+            named = [(instruction_name(n), d) for n, _, d in ops]
+            for name, ps in sorted(scopes.time_containing(named, table, inside).items(), key=lambda kv: -kv[1]):
+                print(f"  {name:<12}      {ps/total_ps*100:6.2f}%  {ps/1e9:9.3f} ms")
+        else:
+            print(f"\n(no {scopes.SCOPE_TABLE_FILE} under {root}: no table by scope; cli.train "
+                  "writes one beside its profiler window's trace)")
+        print(f"\n-- longest idle gaps, by the program's host spans ({len(spans)} span events "
+              "in the trace; obs.trace=true puts them there) --")
+        for ms, label in idle_gaps(ops, spans, 5):
+            print(f"  idle {ms:9.3f} ms: {label}")
     if not printed_any:
         # CPU-backend traces (a rehearsal, the serving frontend's capture
         # on a host without a TPU) have no /device:TPU plane. The

@@ -357,6 +357,127 @@ def test_tracer_module_singleton_configure():
         obs_trace._TRACER = prev
 
 
+def test_tracer_spans_are_profiler_annotations_on_their_own_threads_line(tmp_path):
+    """Under a real jax.profiler trace (CPU), an enabled tracer's spans are
+    in the profiler's own xplane file, on /host:CPU, named <cat>/<name>
+    exactly, each on the line of the thread that ran it — the same file and
+    clock a device's XLA Ops are on (scripts/trace_ops.py labels idle gaps
+    with them)."""
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = SpanTracer(ring_size=16)
+    assert tr._annotate is jax.profiler.TraceAnnotation
+
+    def worker():
+        with tr.span("serve/stage", "serve"):
+            time.sleep(0.002)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("dispatch/train_step", "dispatch", steps=1):
+            time.sleep(0.002)
+            t = threading.Thread(target=worker)
+            t.start()
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = next(p for p in ProfileData.from_file(path).planes if p.name == "/host:CPU")
+    where = {}
+    for i, line in enumerate(host.lines):  # one line a thread (their names may both be the process's)
+        for ev in line.events:
+            if ev.name in ("dispatch/train_step", "serve/stage"):
+                where[ev.name] = (i, ev.start_ns, ev.duration_ns)
+    assert set(where) == {"dispatch/train_step", "serve/stage"}
+    assert where["dispatch/train_step"][0] != where["serve/stage"][0]
+    outer, inner = where["dispatch/train_step"], where["serve/stage"]
+    assert inner[2] >= 2e6 and outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+    # and the tracer's own record is what it was
+    assert sorted(e["name"] for e in _x_events(tr)) == ["dispatch/train_step", "serve/stage"]
+
+
+def test_tracer_disabled_creates_no_annotation(monkeypatch):
+    """Off costs what it cost: span() hands back the shared null span itself,
+    and no TraceAnnotation is ever constructed."""
+    import jax
+
+    made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", lambda name: made.append(name))
+    off = SpanTracer(ring_size=4, enabled=False)
+    assert off._annotate is None
+    assert off.span("serve/stage", "serve") is obs_trace._NULL_SPAN
+    with off.span("data/next", "data"):
+        pass
+    off.instant("compile/f", "compile")
+    assert made == [] and off.to_chrome_trace()["traceEvents"][1:] == []
+
+
+def test_compile_watch_counts_compiles_and_names_the_open_span():
+    """The program's one jax.monitoring listener (obs/device.py, installed by
+    utils/compile_cache.configure): a compile is a count, seconds in a
+    histogram, an event stamped with the compiling thread's open spans, and
+    an instant compile/<fun_name> in the trace; cache hits and misses count."""
+    import threading
+
+    import jax
+    import jax.monitoring
+    import jax.numpy as jnp
+
+    from yet_another_mobilenet_series_tpu.obs.device import CompileWatch, install_compile_watch
+    from yet_another_mobilenet_series_tpu.utils import compile_cache
+
+    compile_cache.configure()  # every entry point's first call installs it
+    watch = install_compile_watch()
+    assert install_compile_watch() is watch  # one per process
+    reg = get_registry()
+    prev = obs_trace.get_tracer()
+    tr = obs_trace.configure(enabled=True, ring_size=64)
+    try:
+        mark = watch.mark()
+        before = reg.snapshot()
+        elsewhere = threading.Event()
+
+        def other_thread():  # a span open on ANOTHER thread is not where the compile happened
+            with tr.span("data/prefetch_fill", "data"):
+                elsewhere.wait(10)
+
+        t = threading.Thread(target=other_thread)
+        t.start()
+        try:
+            def only_compiled_here(x):
+                return jnp.tanh(x) * 3.25 + 0.125
+
+            with tr.span("dispatch/train_step", "dispatch"):
+                jax.block_until_ready(jax.jit(only_compiled_here)(jnp.ones((3,))))
+        finally:
+            elsewhere.set()
+            t.join()
+        # the persistent cache is off on the CPU: its events, as JAX would record them
+        jax.monitoring.record_event(CompileWatch.MISS)
+        jax.monitoring.record_event(CompileWatch.HIT)
+        got = watch.since(mark)
+        after = reg.snapshot()
+    finally:
+        obs_trace._TRACER = prev
+    mine = [e for e in got["events"] if "only_compiled_here" in e["fun"]]
+    assert len(mine) == 1 and got["compiles"] >= 1
+    assert mine[0]["open"] == ["dispatch/train_step"] and mine[0]["s"] >= 0
+    assert {"train_step", "serve_requests"} <= set(mine[0])
+    assert (got["cache_hits"], got["cache_misses"]) == (1, 1)
+    assert after["jax.backend_compiles"] - before.get("jax.backend_compiles", 0) == got["compiles"]
+    assert after["jax.cache_misses"] - before.get("jax.cache_misses", 0) == 1
+    assert after["jax.cache_hits"] - before.get("jax.cache_hits", 0) == 1
+    assert after["jax.backend_compile_seconds.count"] - before.get("jax.backend_compile_seconds.count", 0) \
+        == got["compiles"]
+    marks = [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "compile/jit(only_compiled_here)"]
+    assert len(marks) == 1 and marks[0]["ph"] == "i" and marks[0]["cat"] == "compile"
+    assert marks[0]["args"]["open"] == ["dispatch/train_step"]
+
+
 # ---------------------------------------------------------------------------
 # watchdog
 # ---------------------------------------------------------------------------

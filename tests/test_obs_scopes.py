@@ -1,0 +1,296 @@
+"""obs/scopes.py: the closed list of named scopes in the compiled train step,
+and the rule that reads them back (docs/OBSERVABILITY.md "Named scopes").
+
+On a two-block toy net's full train step compiled on the CPU: every listed
+scope the step contains appears in the scope table, forward and backward are
+told apart, every BN_MODES variant resolves to bn_*, and the scopes are
+metadata only (the compiled program is the same program without them).
+"""
+
+import collections
+import contextlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from yet_another_mobilenet_series_tpu.config import parse_cli
+from yet_another_mobilenet_series_tpu.models import get_model
+from yet_another_mobilenet_series_tpu.obs import scopes
+from yet_another_mobilenet_series_tpu.ops.layers import BN_MODES, BatchNorm
+from yet_another_mobilenet_series_tpu.parallel import dp, mesh as mesh_lib
+from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+APP = os.path.join(REPO, "yet_another_mobilenet_series_tpu", "apps", "mobilenet_v3_large.yml")
+# one plain block and one with SE, hswish and a residual; dropout and drop-connect on
+TOY = ("model.block_specs=[{exp: 16, c: 16, n: 1, s: 2, k: 3, act: relu}, "
+       "{exp: 48, c: 24, n: 2, s: 1, k: 5, act: hswish, se: 0.25}]")
+
+
+def lowered_step(*overrides, chips: int = 1):
+    cfg = parse_cli([f"app:{APP}", TOY, "model.num_classes=16", "model.drop_connect=0.2", "data.image_size=32",
+                     f"train.batch_size={4 * chips}", f"dist.num_devices={chips}", *overrides])
+    net = get_model(cfg.model, 32)
+    mesh = mesh_lib.make_mesh(chips)
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 4 * chips, 10, 1)
+    params_example, _ = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, params_example)
+    step = dp.make_dp_train_step(net, cfg, optimizer, lr_fn, mesh, params_example=params_example)
+    ts = jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0)))
+    batch = {"image": jax.ShapeDtypeStruct((4 * chips, 32, 32, 3), jnp.float32),
+             "label": jax.ShapeDtypeStruct((4 * chips,), jnp.int32)}
+    return step.lower(ts, batch, jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+
+@pytest.fixture(scope="module")
+def toy_text():
+    return lowered_step().compile().as_text()
+
+
+def _counts(text):
+    return collections.Counter(scopes.scope_table(text).values())
+
+
+# -- scope_of: the reading rule, on hand-written op_names -------------------
+
+
+@pytest.mark.parametrize("op_name, expect", [
+    ("jit(step)/jvp(bn_stats)/reduce_sum", ("bn_stats", "fwd")),
+    ("jit(step)/transpose(jvp(bn_apply))/reduce_sum", ("bn_apply", "bwd")),
+    # the innermost listed scope wins ...
+    ("jit(step)/jvp(bn_stats)/syncbn/psum", ("syncbn", "fwd")),
+    ("jit(step)/transpose(jvp(bn_stats))/syncbn/psum", ("syncbn", "bwd")),
+    # ... except that everything inside se is se: its pool, its activations, its matmuls
+    ("jit(step)/jvp(se)/pool/reduce_sum", ("se", "fwd")),
+    ("jit(step)/transpose(jvp(se))/act/jit(clip)/mul", ("se", "bwd")),
+    ("jit(step)/jvp(se)/dense/dot_general", ("se", "fwd")),
+    # a nested jit of jax's own between the scope and the primitive
+    ("jit(step)/jvp(act)/jit(clip)/max", ("act", "fwd")),
+    ("jit(step)/jvp(drop)/jit(_bernoulli)/jit(_uniform)/max", ("drop", "fwd")),
+    # outside autodiff: no phase
+    ("jit(step)/optim/mul", ("optim", "-")),
+    ("jit(step)/ema/add", ("ema", "-")),
+    ("jit(step)/loss/div", ("loss", "-")),
+    # SPMD and shard_map prefixes are seen through
+    ("jit(shard_fn)/shard_map/jvp(conv_dw)/conv_general_dilated", ("conv_dw", "fwd")),
+    ("jit(shard_fn)/jit(main)/shard_map/transpose(jvp(conv_pw))/dot_general", ("conv_pw", "bwd")),
+    ("jit(shard_fn)/shard_map/grad_sync/psum", ("grad_sync", "-")),
+    # under jax.checkpoint the recomputed forward is part of the backward pass
+    ("jit(step)/transpose(jvp(checkpoint))/rematted_computation/bn_apply/mul", ("bn_apply", "bwd")),
+    # a primitive that merely shares a scope's name is not a scope; neither is an unknown name
+    ("jit(step)/jvp(my_layer)/add", (scopes.UNSCOPED, "fwd")),
+    ("jit(step)/jit(_threefry_fold_in)/shift_right_logical", (scopes.UNSCOPED, "-")),
+    ("jit(step)/transpose(jvp())/convert_element_type", (scopes.UNSCOPED, "bwd")),
+    ("", (scopes.UNSCOPED, "-")),
+])
+def test_scope_of(op_name, expect):
+    assert scopes.scope_of(op_name) == expect
+
+
+def test_scope_rejects_a_name_off_the_list():
+    with scopes.scope("bn_stats"):
+        pass
+    with pytest.raises(ValueError, match="not in obs.scopes.SCOPES"):
+        scopes.scope("batchnorm")
+
+
+# -- the table of a compiled step --------------------------------------------
+
+
+def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
+    seen = {scope for scope, _ in scopes.scope_table(toy_text).values()}
+    # everything on the list except the collectives (one chip), AtomNAS (no
+    # masks, no penalty) and the guard (off)
+    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"}
+    assert expect <= seen, f"missing: {sorted(expect - seen)}"
+    assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
+
+
+def test_forward_and_backward_are_told_apart(toy_text):
+    counts = _counts(toy_text)
+    for scope in ("conv_dw", "conv_pw", "conv_full", "dense", "bn_stats", "bn_apply", "act", "se"):
+        assert counts[(scope, "fwd")] > 0 and counts[(scope, "bwd")] > 0, scope
+    # the update is outside autodiff: no phase at all
+    for scope in ("optim", "ema"):
+        assert counts[(scope, "-")] > 0 and not counts[(scope, "fwd")] and not counts[(scope, "bwd")]
+
+
+def test_collectives_are_named_on_a_mesh():
+    text = lowered_step(chips=2).compile().as_text()
+    counts = _counts(text)
+    assert counts[("syncbn", "fwd")] > 0 and counts[("syncbn", "bwd")] > 0
+    assert counts[("grad_sync", "-")] > 0
+    # the all-reduces themselves resolve: none is left unscoped
+    table = scopes.scope_table(text)
+    reduces = [name for name in table if name.startswith("all-reduce")]
+    assert reduces and all(table[name][0] in ("syncbn", "grad_sync", "loss") for name in reduces)
+
+
+@pytest.mark.parametrize("bn_mode", BN_MODES)
+def test_every_bn_mode_lands_in_bn_scopes(bn_mode):
+    """BatchNorm.apply alone under grad, lowered: every operation it traces,
+    in the forward and in the backward (the custom_vjp pair of fused_vjp
+    included), is under bn_stats or bn_apply, whatever the normalize variant."""
+    bn = BatchNorm(8)
+    params, state = bn.init()
+
+    def loss(params, x):
+        y, new_state = bn.apply(params, state, x, train=True, mode=bn_mode)
+        with scopes.scope("loss"):
+            return jnp.sum(y.astype(jnp.float32) ** 2), new_state
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, jnp.ones((2, 4, 4, 8), jnp.bfloat16)).as_text(debug_info=True)
+    # operations are located by their name-stack path, which starts at the jit
+    by = collections.Counter(scopes.scope_of(m) for m in re.findall(r'loc\("(jit\([^"]*)"', text))
+    assert {scope for scope, _ in by} == {"bn_stats", "bn_apply", "loss"}, by
+    for scope in ("bn_stats", "bn_apply"):
+        assert by[(scope, "fwd")] > 0 and by[(scope, "bwd")] > 0, by
+
+
+def test_time_by_scope_sums_and_reports_the_unresolved():
+    table = {"fusion.1": ("bn_stats", "fwd"), "fusion.2": ("bn_stats", "bwd"), "convolution.3": ("conv_pw", "fwd"),
+             "copy-done.4": (scopes.UNSCOPED, "-")}
+    events = [("fusion.1", 10.0), ("fusion.1", 10.0), ("fusion.2", 5.0), ("convolution.3", 20.0),
+              ("copy-done.4", 3.0), ("fusion.99", 2.0)]  # fusion.99: another compilation's name
+    by = scopes.time_by_scope(events, table)
+    assert by == {("bn_stats", "fwd"): 20.0, ("bn_stats", "bwd"): 5.0, ("conv_pw", "fwd"): 20.0,
+                  (scopes.UNSCOPED, "-"): 5.0}
+    assert scopes.unscoped_share(by) == pytest.approx(5.0 / 50.0)
+    # a table from an executable without the names: everything is unresolved, and says so
+    assert scopes.unscoped_share(scopes.time_by_scope(events, {})) == 1.0
+    assert scopes.unscoped_share({}) is None
+
+
+def test_scope_table_takes_a_fusion_without_a_name_from_its_root():
+    text = """
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %mul.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/jvp(bn_apply)/mul"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.7 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %fusion.8 = f32[8]{0} fusion(%fusion.7), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(act))/mul"}
+  ROOT %copy.9 = f32[8]{0} copy(%fusion.8)
+}
+"""
+    table = scopes.scope_table(text)
+    assert table["fusion.7"] == ("bn_apply", "fwd")  # its fused computation's root's
+    assert table["fusion.8"] == ("act", "bwd")  # its own
+    assert "copy.9" not in table and "a" not in table  # nothing to resolve: unscoped when timed
+
+
+def test_what_rides_in_a_convolutions_fusion():
+    """XLA:TPU names a fusion after its convolution and fuses the BatchNorm
+    sums around it in: scopes_inside lists the scopes of the reductions and
+    contractions in a fusion (elementwise riders are not passes of their own),
+    time_containing sums an op's time under each of them."""
+    text = """
+%inner.2 (p: f32[8,4]) -> f32[4] {
+  %p = f32[8,4]{1,0} parameter(0)
+  ROOT %reduce.5 = f32[4]{0} reduce(%p, %c), dimensions={0}, metadata={op_name="jit(f)/jvp(bn_stats)/reduce_sum"}
+}
+
+%fused_computation.1 (a: f32[8,4], /*index=1*/w: f32[4,4]) -> (f32[8,4], f32[4]) {
+  %a = f32[8,4]{1,0} parameter(0)
+  %convolution.3 = f32[8,4]{1,0} convolution(%a, %w), metadata={op_name="jit(f)/jvp(conv_pw)/conv_general_dilated"}
+  %max.4 = f32[8,4]{1,0} maximum(%convolution.3, %z), metadata={op_name="jit(f)/jvp(act)/max"}
+  %fusion.2 = f32[4]{0} fusion(%convolution.3), kind=kInput, calls=%inner.2
+  ROOT %tuple.6 = (f32[8,4]{1,0}, f32[4]{0}) tuple(%max.4, %fusion.2)
+}
+
+ENTRY %main (a: f32[8,4]) -> f32[8,4] {
+  %convert_reduce_fusion.1 = (f32[8,4]{1,0}, f32[4]{0}) fusion(%a, %w), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(f)/jvp(conv_pw)/conv_general_dilated"}
+  %fusion.9 = f32[8,4]{1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1b, metadata={op_name="jit(f)/jvp(act)/max"}
+}
+"""
+    table, inside = scopes.scope_table(text), scopes.scopes_inside(text)
+    assert table["convert_reduce_fusion.1"] == ("conv_pw", "fwd")
+    assert inside == {"convert_reduce_fusion.1": ("bn_stats", "conv_pw")}  # act rides along, no pass of its own
+    events = [("convert_reduce_fusion.1", 10.0), ("fusion.9", 2.0), ("copy-done.3", 1.0)]
+    assert scopes.time_containing(events, table, inside) == {
+        "conv_pw": 10.0, "bn_stats": 10.0, "act": 2.0, scopes.UNSCOPED: 1.0}
+
+
+def test_scope_table_round_trips_through_the_trace_directory(tmp_path, toy_text):
+    path = scopes.write_scope_table(str(tmp_path / "trace"), toy_text)
+    assert os.path.basename(path) == scopes.SCOPE_TABLE_FILE
+    assert scopes.read_scope_table(str(tmp_path / "trace")) == (scopes.scope_table(toy_text),
+                                                                scopes.scopes_inside(toy_text))
+    assert scopes.read_scope_table(str(tmp_path / "nothing")) is None
+
+
+# -- scopes are metadata: the same program without them ----------------------
+
+
+def _strip(text: str) -> str:
+    """Compiled HLO text without what names its source: the per-instruction
+    metadata and the module's tables of files, functions and stack frames."""
+    text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n(.+\n)*", "", text, flags=re.M)
+    return re.sub(r", metadata=\{[^}]*\}", "", text)
+
+
+@pytest.mark.parametrize("overrides", [(), ("train.remat=true", "train.remat_policy=save_conv")],
+                         ids=["plain", "remat_save_conv"])
+def test_scopes_add_nothing_to_the_compiled_step(monkeypatch, overrides):
+    """The step compiled with the scopes, and with jax.named_scope patched to
+    a no-op, metadata stripped: the same HLO, instruction for instruction.
+    Under the save_conv remat policy too, which keys on checkpoint_name."""
+    with_scopes = lowered_step(*overrides).compile().as_text()
+    assert "bn_stats" in with_scopes
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    without = lowered_step(*overrides).compile().as_text()
+    assert "bn_stats" not in without and "conv_dw" not in without
+    assert _strip(with_scopes) == _strip(without)
+
+
+def test_taxonomy_version_is_pinned_to_the_scope_sites():
+    """The compile cache's key carries TAXONOMY_VERSION (utils/compile_cache.py)
+    because JAX leaves metadata out of it: a scope added, renamed or moved
+    without a bump would read yesterday's names from the cache. This is the
+    reminder: the sites, file by file, as of the version below."""
+    pkg = os.path.join(REPO, "yet_another_mobilenet_series_tpu")
+    sites = {}
+    for root, _, files in os.walk(pkg):
+        for name in files:
+            if name.endswith(".py") and name != "scopes.py":
+                with open(os.path.join(root, name)) as f:
+                    found = re.findall(r'\bscope\((?:self\.scope_name|"(\w+)")\)', f.read())
+                if found:
+                    sites[os.path.relpath(os.path.join(root, name), pkg)] = sorted(found)
+    assert (scopes.TAXONOMY_VERSION, sites) == (1, {
+        "models/specs.py": ["drop"],
+        "ops/activations.py": ["act"],
+        "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
+        "ops/layers.py": ["", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
+                          "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
+        "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],
+        "train/guard.py": ["guard"],
+        "train/steps.py": ["ema", "grad_sync", "input", "loss", "loss", "nas_penalty", "optim", "syncbn"],
+    }), "a scope site changed: bump obs.scopes.TAXONOMY_VERSION, then update this pin"
+
+
+def test_compile_cache_key_carries_the_taxonomy_version(monkeypatch):
+    """configure() hashes TAXONOMY_VERSION into the persistent cache's key
+    through JAX's own hook; a jax without that hook gets the public flag that
+    puts ALL metadata in the key instead (never stale, costlier to iterate on)."""
+    from jax._src import cache_key
+
+    from yet_another_mobilenet_series_tpu.utils import compile_cache
+
+    monkeypatch.setattr(cache_key, "custom_hook", lambda: "")
+    compile_cache.configure()
+    assert cache_key.custom_hook() == f"yamt-scopes-{scopes.TAXONOMY_VERSION}"
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is False
+
+    monkeypatch.delattr(cache_key, "custom_hook")
+    try:
+        compile_cache.configure()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", False)

@@ -373,6 +373,10 @@ def _listen(cfg: Config, engine, log: Logger, reg, tracer, zoo=None) -> dict:
         stop_event.wait()
     finally:
         t0 = time.perf_counter()
+        # this drain's timeouts, not the process's: the counter is
+        # process-wide, and a process that served before (one test after
+        # another, chip_smoke.py's phases) may come here with it already moved
+        timeouts0 = reg.counter("serve.drain_timeouts").value
         if reg_client is not None:
             try:
                 # clean drain: leave the fleet NOW instead of via TTL lapse
@@ -392,7 +396,7 @@ def _listen(cfg: Config, engine, log: Logger, reg, tracer, zoo=None) -> dict:
         if watchdog is not None:
             watchdog.stop()
         drain_s = time.perf_counter() - t0
-        timeouts = int(reg.snapshot().get("serve.drain_timeouts", 0))
+        timeouts = int(reg.counter("serve.drain_timeouts").value - timeouts0)
         log.log(f"drained in {drain_s:.2f}s ({'clean' if not timeouts else 'DRAIN TIMEOUT'})")
     return {"listened": True, **addr, "drain_s": drain_s, "drain_timeouts": timeouts}
 

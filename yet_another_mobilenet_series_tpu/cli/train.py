@@ -44,6 +44,7 @@ from ..models.specs import Network
 from ..nas import masking, penalty, rematerialize
 from ..obs import device as obs_device
 from ..obs import registry as obs_registry
+from ..obs import scopes as obs_scopes
 from ..obs import trace as obs_trace
 from ..obs.watchdog import StallWatchdog
 from ..parallel import dp, mesh as mesh_lib
@@ -498,7 +499,7 @@ def _run_impl(cfg: Config, log: Logger, mesh, is_coord: bool, tracer, watchdog) 
 
 
 def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger,
-                      first_dispatch_s: float) -> None:
+                      first_dispatch_s: float, scope_table_dir: str = "") -> None:
     """Device-cost accounting for the compiled train step (obs/device.py):
     the first dispatch's host wall time (≈ trace + compile under async
     dispatch — the run never blocks on device execution here) lands in
@@ -506,7 +507,13 @@ def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger
     cost_analysis FLOPs/bytes into the ``train_step`` cost gauges. Lowering
     traces but does NOT compile, so the one-off cost is seconds of host
     time per trainer build — amortized to noise over a run. Telemetry only:
-    any failure is logged and swallowed, never fatal."""
+    any failure is logged and swallowed, never fatal.
+
+    ``scope_table_dir`` (the profiler window's trace directory, when one was
+    asked for): the same ``Lowered`` is compiled once more — a read from the
+    persistent cache on a chip — and the step's instruction -> (scope, phase)
+    table (obs/scopes.py) written there as ``scope_table.json``, which
+    scripts/trace_ops.py joins to the window's device events by name."""
     reg.histogram("obs.compile_seconds").observe(first_dispatch_s)
     reg.counter("obs.compiles").inc()
     # where the work is: rows of this batch resident on each local device.
@@ -528,6 +535,13 @@ def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger
             f"{cost.get('bytes', 0) / 1e6:.1f} MB accessed per step "
             f"(first dispatch {first_dispatch_s:.1f}s ≈ trace+compile)"
         )
+    if scope_table_dir:
+        try:
+            with tracer.span("dispatch/cost_analysis", "dispatch"):
+                path = obs_scopes.write_scope_table(scope_table_dir, lowered.compile())
+            log.log(f"train step scope table -> {path}")
+        except Exception as e:  # noqa: BLE001 — telemetry must never end a run
+            log.log(f"train step scope table unavailable ({type(e).__name__}: {e})")
 
 
 def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool, tracer,
@@ -676,8 +690,11 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                     cost_batch = b
                 if not cost_recorded:
                     cost_recorded = True
-                    _record_step_cost(trainer, ts, cost_batch, rng, reg, tracer, log,
-                                      time.perf_counter() - t_dispatch0)
+                    _record_step_cost(
+                        trainer, ts, cost_batch, rng, reg, tracer, log,
+                        time.perf_counter() - t_dispatch0,
+                        scope_table_dir=(cfg.train.log_dir + "/trace"
+                                         if cfg.train.profile_start_step and k_dispatch == 1 else ""))
                 steps_done += len(metric_list)
                 # per-sub-step host processing: metrics entries are lazy
                 # device arrays; nothing below syncs unless a cadence fires

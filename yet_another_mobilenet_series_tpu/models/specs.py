@@ -111,6 +111,7 @@ class Network:
         import jax
         import jax.numpy as jnp
 
+        from ..obs.scopes import scope
         from ..ops.activations import get_activation
         from ..ops.layers import dropout as dropout_fn
         from ..ops.layers import global_avg_pool
@@ -130,6 +131,10 @@ class Network:
         need_block_rng = rng is not None and train
         for i, blk in enumerate(self.blocks):
             mask = None if masks is None else masks.get(i)
+            block_rng = None
+            if need_block_rng and blk.drop_path > 0:
+                with scope("drop"):  # the stream's threefry fold is drop-connect's work too
+                    block_rng = jax.random.fold_in(rng, i)
             h, nbs[str(i)] = blk.apply(
                 params["blocks"][str(i)],
                 state["blocks"][str(i)],
@@ -140,7 +145,7 @@ class Network:
                 mask=mask,
                 bn_mode=bn_mode,
                 conv1x1_dot=conv1x1_dot,
-                rng=jax.random.fold_in(rng, i) if need_block_rng and blk.drop_path > 0 else None,
+                rng=block_rng,
             )
         new_state["blocks"] = nbs
         if self.head is not None:

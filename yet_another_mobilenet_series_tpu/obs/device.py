@@ -24,6 +24,19 @@ watchdog hang report for free:
   derives ``serve.achieved_flops_per_s`` = dispatched cost FLOPs ÷ measured
   ``serve.run_seconds`` — the "how much of the paper FLOPs did the wall
   clock actually deliver" number ROADMAP item 3's latency work keys on.
+- **the compile watch** — :func:`install_compile_watch` is the program's ONE
+  ``jax.monitoring`` listener (installed by ``utils/compile_cache.configure()``,
+  so in every entry point and in the benchmark): every backend compile and
+  persistent-cache hit or miss that JAX reports, whoever compiled — a jitted
+  call, an AOT ``lower().compile()``, a helper program — lands in the
+  ``jax.backend_compiles`` / ``jax.cache_hits`` / ``jax.cache_misses``
+  counters and the ``jax.backend_compile_seconds`` histogram, and in a
+  bounded list of events stamped with where the program was (the compiling
+  thread's open host spans, ``train.step``, ``serve.requests``); with the span tracer on, each
+  is also an instant ``compile/<fun_name>`` in ``obs_trace.json``. "Which step
+  recompiled" is a count in ``/metrics`` and a mark in the trace.
+  (``obs.compiles`` above counts only what goes through
+  :func:`timed_compile`.)
 - **memory telemetry** — :func:`install_memory_gauges` registers PULL gauges
   (read only at snapshot time — the existing log cadence — zero extra device
   syncs): per-device ``device.bytes_in_use.d<i>`` / peak / limit from
@@ -48,11 +61,13 @@ is wrapped and a miss records nothing.
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
 
 from .registry import MetricsRegistry, get_registry
+from .trace import get_tracer
 
 # per-key cost table: key -> {"flops", "bytes", "compile_seconds"} — the
 # compile_report() section of hang reports and the engine's dispatched-flops
@@ -145,6 +160,91 @@ def compile_report() -> dict:
     embedded in the watchdog hang report and printable from obs_report."""
     with _COSTS_LOCK:
         return {k: dict(v) for k, v in sorted(_COSTS.items())}
+
+
+# ---------------------------------------------------------------------------
+# the compile watch (jax.monitoring: costs nothing until something compiles)
+# ---------------------------------------------------------------------------
+
+
+class CompileWatch:
+    """Every backend compile and persistent-cache hit or miss JAX reports,
+    as registry metrics and as a bounded list of events, each compile stamped
+    with where the program was: the host spans open on the compiling thread
+    at that moment, the last logged train step and the number of serving requests
+    accepted so far. Registry and tracer are fetched by call: an entry point
+    configures its tracer after the watch is installed. One per process
+    (:func:`install_compile_watch`)."""
+
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self, keep: int = 4096):
+        import jax.monitoring as mon
+
+        # (sequence number, event): the number survives the ring dropping old ones
+        self._events: collections.deque = collections.deque(maxlen=keep)
+        self._lock = threading.Lock()
+        self._seq = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, secs, fun_name="", **_):
+        if name != self.COMPILE:
+            return
+        reg, tracer = get_registry(), get_tracer()
+        reg.counter("jax.backend_compiles").inc()
+        reg.histogram("jax.backend_compile_seconds").observe(secs)
+        event = {
+            "s": round(secs, 3), "fun": fun_name,
+            # a jitted call compiles on the thread that made it, so this
+            # thread's open spans are where the compile happened; another
+            # thread's (a prefetch worker mid-fill) are not
+            "open": [sp["name"] for sp in tracer.open_spans() if sp["tid"] == threading.get_ident()],
+            "train_step": reg.gauge("train.step").value,
+            "serve_requests": reg.counter("serve.requests").value,
+        }
+        with self._lock:
+            self._seq += 1
+            self._events.append((self._seq, event))
+        tracer.instant(f"compile/{fun_name}", "compile", **event)
+
+    def _on_event(self, name, **_):
+        if name == self.HIT:
+            get_registry().counter("jax.cache_hits").inc()
+        elif name == self.MISS:
+            get_registry().counter("jax.cache_misses").inc()
+
+    def mark(self) -> tuple[int, float, float]:
+        reg = get_registry()
+        with self._lock:
+            seq = self._seq
+        return seq, reg.counter("jax.cache_hits").value, reg.counter("jax.cache_misses").value
+
+    def since(self, mark) -> dict:
+        """What compiled and what the cache answered since :meth:`mark`:
+        {compiles, compile_s, cache_hits, cache_misses, events}."""
+        seq0, h0, m0 = mark
+        seq, hits, misses = self.mark()
+        with self._lock:
+            new = [e for n, e in self._events if n > seq0]
+        return {"compiles": seq - seq0, "compile_s": round(sum(e["s"] for e in new), 2),
+                "cache_hits": int(hits - h0), "cache_misses": int(misses - m0), "events": new}
+
+
+_COMPILE_WATCH: CompileWatch | None = None
+_COMPILE_WATCH_LOCK = threading.Lock()
+
+
+def install_compile_watch() -> CompileWatch:
+    """The process's compile watch, installed on first call (idempotent:
+    ``utils/compile_cache.configure()`` calls this from every entry point)."""
+    global _COMPILE_WATCH
+    with _COMPILE_WATCH_LOCK:
+        if _COMPILE_WATCH is None:
+            _COMPILE_WATCH = CompileWatch()
+        return _COMPILE_WATCH
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,20 @@ through the serving stack):
   threads (``register_thread``), so Perfetto shows ``serve-collect`` /
   ``serve-complete``, not raw thread ids.
 
+**On the profiler's clock too.** In a process that has imported jax, every
+duration span of an enabled tracer is also a ``jax.profiler.TraceAnnotation``
+named exactly like the span (``serve/stage``, ``data/next``: the taxonomy's
+``<cat>/<name>``), entered and left with the span on the thread that runs it.
+While a ``jax.profiler`` window is open (the train loop's, the serving
+frontend's ``POST /profile/start|stop``) the span therefore lands in the
+profiler's own xplane file, on the ``/host:CPU`` plane's line of its thread,
+on the same clock as the device's ``XLA Ops``: an idle gap of the device reads
+as the span the host was in (scripts/trace_ops.py), and no wall-clock
+alignment is needed inside one process. Outside a window an annotation
+costs a few hundred nanoseconds. Async, flow and instant marks stay in
+``obs_trace.json`` only. A process without jax (the fleet supervisor) has no
+profiler, and its spans make no annotation.
+
 The buffer is a fixed-size ring (``collections.deque(maxlen=...)``): a
 multi-day run keeps the last N events, never unbounded memory. Completed
 events are plain tuples; JSON rendering happens only at ``write()``.
@@ -45,10 +59,19 @@ from __future__ import annotations
 import collections
 import json
 import os
+import sys
 import threading
 import time
 
 from .registry import get_registry
+
+# The span taxonomy's categories (docs/OBSERVABILITY.md): a span is named
+# `<cat>/<name>`, and that is how a reader of the profiler's xplane tells the
+# program's annotations from everything else on a host thread's line
+# (scripts/trace_ops.py). `fleet` spans carry cat "serve"; `compile` is the
+# compile watch's instants (obs/device.py).
+SPAN_CATEGORIES = ("data", "dispatch", "sync", "prune", "eval", "ckpt", "rebuild", "serve",
+                   "fleet", "compile")
 
 
 class _NullSpan:
@@ -68,20 +91,28 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "t0_ns")
+    __slots__ = ("_tracer", "name", "cat", "args", "t0_ns", "_annotation")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str, args: dict | None):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = None
 
     def __enter__(self):
         self.t0_ns = time.perf_counter_ns()
         self._tracer._push(self)
+        annotate = self._tracer._annotate or self._tracer._find_annotate()
+        if annotate is not None:
+            # the same stretch on the profiler's clock, on this thread's line
+            self._annotation = annotate(self.name)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._pop(self, time.perf_counter_ns())
         return False
 
@@ -97,7 +128,7 @@ class SpanTracer:
         self.process_name = process_name
         # completed events: (ph, name, cat, t0_ns, dur_ns, tid, args, ev_id)
         # — ph "X" for duration spans (dur_ns set), "b"/"e" async and
-        # "s"/"t"/"f" flow events (ev_id set, dur 0)
+        # "s"/"t"/"f" flow events (ev_id set, dur 0), "i" instants (neither)
         self._events: collections.deque = collections.deque(maxlen=max(ring_size, 1))
         # open-span stacks keyed by thread id; each thread pushes/pops only
         # its own stack (GIL-atomic list ops), the watchdog reads copies
@@ -115,6 +146,18 @@ class SpanTracer:
         # within one process (the YAMT017 hazard is same-process intervals).
         self.origin_unix = time.time()
         self._pid = os.getpid()
+        # spans as profiler annotations (module docstring): jax's
+        # TraceAnnotation once the process has imported jax, None until then
+        self._annotate = None
+        self._find_annotate()
+
+    def _find_annotate(self):
+        """There is a profiler to annotate only in a process that has jax:
+        looked for, never imported, so the fleet supervisor stays free of it."""
+        jax = sys.modules.get("jax")
+        if self.enabled and jax is not None:
+            self._annotate = jax.profiler.TraceAnnotation
+        return self._annotate
 
     # -- hot path -----------------------------------------------------------
 
@@ -169,6 +212,11 @@ class SpanTracer:
             (ph, name, cat, time.perf_counter_ns(), 0, threading.get_ident(), args, ev_id)
         )
 
+    def instant(self, name: str, cat: str = "misc", **args) -> None:
+        """One point in time on the calling thread's row (``ph: i``): the
+        compile watch's ``compile/<fun_name>`` marks (obs/device.py)."""
+        self._mark("i", name, cat, None, args or None)
+
     # async (nestable, per-id waterfall rows) -------------------------------
 
     def async_begin(self, name: str, ev_id: int, cat: str = "serve", **args) -> None:
@@ -222,7 +270,7 @@ class SpanTracer:
     def to_chrome_trace(self) -> dict:
         """Chrome trace-event JSON object (load via chrome://tracing or
         https://ui.perfetto.dev). Complete ("X"), async ("b"/"e"), flow
-        ("s"/"t"/"f"), and metadata ("M") events, ts/dur in µs."""
+        ("s"/"t"/"f"), instant ("i") and metadata ("M") events, ts/dur in µs."""
         events: list[dict] = [
             {
                 "name": "process_name",
@@ -255,6 +303,8 @@ class SpanTracer:
             }
             if ph == "X":
                 ev["dur"] = dur_ns / 1e3
+            elif ph == "i":
+                ev["s"] = "t"  # an instant on its own thread's row
             else:
                 ev["id"] = ev_id
                 if ph == "f":

@@ -8,8 +8,12 @@ surrounding conv epilogues; no Pallas needed.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from ..obs.scopes import scope
 
 
 def relu(x):
@@ -43,16 +47,28 @@ def identity(x):
     return x
 
 
+def _scoped(fn):
+    """The activation under the `act` scope (obs/scopes.py), so that a
+    device trace times it by name at every call site."""
+
+    @functools.wraps(fn)
+    def scoped(x):
+        with scope("act"):
+            return fn(x)
+
+    return scoped
+
+
 _ACTIVATIONS = {
-    "relu": relu,
-    "relu6": relu6,
-    "hswish": hswish,
-    "h_swish": hswish,
-    "hsigmoid": hsigmoid,
-    "h_sigmoid": hsigmoid,
-    "swish": swish,
-    "silu": swish,
-    "sigmoid": sigmoid,
+    "relu": _scoped(relu),
+    "relu6": _scoped(relu6),
+    "hswish": _scoped(hswish),
+    "h_swish": _scoped(hswish),
+    "hsigmoid": _scoped(hsigmoid),
+    "h_sigmoid": _scoped(hsigmoid),
+    "swish": _scoped(swish),
+    "silu": _scoped(swish),
+    "sigmoid": _scoped(sigmoid),
     "identity": identity,
     "linear": identity,
 }

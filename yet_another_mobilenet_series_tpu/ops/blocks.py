@@ -27,6 +27,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..obs.scopes import scope
 from .activations import get_activation
 from .layers import Array, BatchNorm, Conv2D, Dense, global_avg_pool
 
@@ -93,13 +94,15 @@ class SqueezeExcite:
 
     def apply(self, params, x, *, compute_dtype=jnp.float32):
         # Squeeze/gate in float32: tiny FLOPs, and bf16 pooled moments cost
-        # accuracy in the gate.
-        s = global_avg_pool(x).astype(jnp.float32)  # (N, C)
-        s = s @ params["reduce"]["w"] + params["reduce"]["b"]
-        s = get_activation(self.inner_act)(s)
-        s = s @ params["expand"]["w"] + params["expand"]["b"]
-        gate = get_activation(self.gate_fn)(s).astype(x.dtype)
-        return x * gate[:, None, None, :]
+        # accuracy in the gate. Everything in here is timed as `se`, the
+        # pool and the activations too (obs/scopes.py's nesting rule).
+        with scope("se"):
+            s = global_avg_pool(x).astype(jnp.float32)  # (N, C)
+            s = s @ params["reduce"]["w"] + params["reduce"]["b"]
+            s = get_activation(self.inner_act)(s)
+            s = s @ params["expand"]["w"] + params["expand"]["b"]
+            gate = get_activation(self.gate_fn)(s).astype(x.dtype)
+            return x * gate[:, None, None, :]
 
 
 @dataclass(frozen=True)
@@ -237,7 +240,8 @@ class InvertedResidual:
         )
         h = act(h)
         if mask is not None:
-            h = h * mask.astype(h.dtype)
+            with scope("nas_mask"):
+                h = h * mask.astype(h.dtype)
         if self.se_channels:
             h = SqueezeExcite(self.expanded_channels, self.se_channels, self.se_inner_act, self.se_gate_fn).apply(
                 params["se"], h, compute_dtype=compute_dtype
@@ -251,16 +255,19 @@ class InvertedResidual:
         h = get_activation(self.project_act)(h)
         if self.has_residual:
             if train and self.drop_path > 0 and rng is not None:
-                keep_prob = 1.0 - self.drop_path
-                keep = jax.random.bernoulli(rng, keep_prob, (h.shape[0], 1, 1, 1))
-                h = h * (keep.astype(h.dtype) / jnp.asarray(keep_prob, h.dtype))
+                with scope("drop"):
+                    keep_prob = 1.0 - self.drop_path
+                    keep = jax.random.bernoulli(rng, keep_prob, (h.shape[0], 1, 1, 1))
+                    h = h * (keep.astype(h.dtype) / jnp.asarray(keep_prob, h.dtype))
             if mask is not None:
                 # A fully-masked block must equal identity exactly — without
                 # this gate the project BN's shift (beta - mean*scale) leaks
                 # through zeroed inputs, and rematerialization (which drops
                 # dead residual blocks, nas/rematerialize.py) would not be
                 # equivalent to masking.
-                any_alive = (jnp.max(mask) > 0).astype(h.dtype)
-                h = h * any_alive
-            h = h + x.astype(h.dtype)
+                with scope("nas_mask"):
+                    any_alive = (jnp.max(mask) > 0).astype(h.dtype)
+                    h = h * any_alive
+            with scope("residual"):
+                h = h + x.astype(h.dtype)
         return h, new_state

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..obs.scopes import scope
+
 Array = jax.Array
 
 # the BatchNorm.apply normalize variants (single source of truth — the step
@@ -81,6 +83,17 @@ class Conv2D:
         if self.in_channels % self.groups or self.out_channels % self.groups:
             raise ValueError(f"channels ({self.in_channels}->{self.out_channels}) not divisible by groups={self.groups}")
 
+    @property
+    def scope_name(self) -> str:
+        """Which obs/scopes.py scope this conv's work is timed under: the
+        depthwise convs run on the VPU, the 1x1s are MXU matmuls, everything
+        else (the stem, a dense or grouped k x k) is `conv_full`."""
+        if self.groups > 1 and self.groups == self.in_channels:
+            return "conv_dw"
+        if self.kernel_size == 1 and self.groups == 1:
+            return "conv_pw"
+        return "conv_full"
+
     def init(self, key) -> dict:
         k = self.kernel_size
         shape = (k, k, self.in_channels // self.groups, self.out_channels)
@@ -98,25 +111,26 @@ class Conv2D:
         (ROADMAP.md's table), and this removes XLA's freedom to pick that lowering
         for the 1x1s. No-op for k>1 or grouped convs. Param layout is
         unchanged (HWIO, reshaped at apply), so checkpoints are identical."""
-        w = params["w"].astype(compute_dtype)
-        x = x.astype(compute_dtype)
-        if as_dot and self.kernel_size == 1 and self.groups == 1:
-            if self.stride > 1:
-                # 1x1 stride-s conv == subsample then matmul (pad is 0)
-                x = x[:, :: self.stride, :: self.stride, :]
-            y = x @ w.reshape(self.in_channels, self.out_channels)
-        else:
-            pad = self.kernel_size // 2
-            y = lax.conv_general_dilated(
-                x,
-                w,
-                window_strides=(self.stride, self.stride),
-                padding=((pad, pad), (pad, pad)),
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=self.groups,
-            )
-        if self.use_bias:
-            y = y + params["b"].astype(compute_dtype)
+        with scope(self.scope_name):
+            w = params["w"].astype(compute_dtype)
+            x = x.astype(compute_dtype)
+            if as_dot and self.kernel_size == 1 and self.groups == 1:
+                if self.stride > 1:
+                    # 1x1 stride-s conv == subsample then matmul (pad is 0)
+                    x = x[:, :: self.stride, :: self.stride, :]
+                y = x @ w.reshape(self.in_channels, self.out_channels)
+            else:
+                pad = self.kernel_size // 2
+                y = lax.conv_general_dilated(
+                    x,
+                    w,
+                    window_strides=(self.stride, self.stride),
+                    padding=((pad, pad), (pad, pad)),
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    feature_group_count=self.groups,
+                )
+            if self.use_bias:
+                y = y + params["b"].astype(compute_dtype)
         # remat landmark: train.remat_policy="save_conv" saves exactly these
         # (the MXU results) and recomputes the cheap BN/act elementwise chain
         # in backward, so normalized activations are never materialized
@@ -135,9 +149,10 @@ def _finalize_moments(s1, s2, n_local, axis_name):
     modes apart below the parity tests' tolerance."""
     n = jnp.asarray(n_local, jnp.float32)
     if axis_name is not None:
-        s1 = lax.psum(s1, axis_name)
-        s2 = lax.psum(s2, axis_name)
-        n = lax.psum(n, axis_name)
+        with scope("syncbn"):
+            s1 = lax.psum(s1, axis_name)
+            s2 = lax.psum(s2, axis_name)
+            n = lax.psum(n, axis_name)
     mean = s1 / n
     var = jnp.maximum(s2 / n - jnp.square(mean), 0.0)  # biased
     return mean, var, n
@@ -185,11 +200,13 @@ def _bn_train_fused(x, gamma, beta, eps, axis_name):
 
 
 def _bn_train_fused_fwd_impl(x, gamma, beta, eps, axis_name):
-    mean, var, n = _bn_moments(x, axis_name)
-    inv = lax.rsqrt(var + eps)
-    scale = gamma * inv
-    bias = beta - mean * scale
-    y = (x.astype(jnp.float32) * scale + bias).astype(x.dtype)
+    with scope("bn_stats"):
+        mean, var, n = _bn_moments(x, axis_name)
+    with scope("bn_apply"):
+        inv = lax.rsqrt(var + eps)
+        scale = gamma * inv
+        bias = beta - mean * scale
+        y = (x.astype(jnp.float32) * scale + bias).astype(x.dtype)
     return y, mean, var, (inv, n)
 
 
@@ -246,16 +263,22 @@ def _bn_train_fused_bwd(eps, axis_name, res, cts):
     if isinstance(dy, zero):
         # nothing differentiates y either: all three gradients vanish
         return jnp.zeros_like(x), jnp.zeros_like(gamma), jnp.zeros_like(gamma)
-    dyf = dy.astype(jnp.float32)
-    x_hat = (x.astype(jnp.float32) - mean) * inv
-    dbeta = jnp.sum(dyf, axis=(0, 1, 2))
-    dgamma = jnp.sum(dyf * x_hat, axis=(0, 1, 2))
-    s1, s2 = dbeta, dgamma
-    if axis_name is not None:
-        s1 = lax.psum(s1, axis_name)
-        s2 = lax.psum(s2, axis_name)
-    dx = (gamma * inv) * (dyf - s1 / n - x_hat * (s2 / n))
-    return dx.astype(x.dtype), dgamma, dbeta
+    # the backward's two reductions are `bn_stats`, its elementwise pass
+    # `bn_apply`, like the forward halves they are the gradients of
+    with scope("bn_stats"):
+        dyf = dy.astype(jnp.float32)
+        x_hat = (x.astype(jnp.float32) - mean) * inv
+        dbeta = jnp.sum(dyf, axis=(0, 1, 2))
+        dgamma = jnp.sum(dyf * x_hat, axis=(0, 1, 2))
+        s1, s2 = dbeta, dgamma
+        if axis_name is not None:
+            with scope("syncbn"):
+                s1 = lax.psum(s1, axis_name)
+                s2 = lax.psum(s2, axis_name)
+    with scope("bn_apply"):
+        dx = (gamma * inv) * (dyf - s1 / n - x_hat * (s2 / n))
+        dx = dx.astype(x.dtype)
+    return dx, dgamma, dbeta
 
 
 _bn_train_fused = jax.custom_vjp(_bn_train_fused, nondiff_argnums=(3, 4))
@@ -352,30 +375,36 @@ class BatchNorm:
                 "var": (1.0 - m) * state["var"] + m * unbiased,
             }
 
+        # every mode's work lands in one of two scopes (obs/scopes.py): the
+        # batch moments and the running-stat update are `bn_stats`, the
+        # normalize is `bn_apply`
         if train and mode == "fused_vjp":
             y, mean, var = _bn_train_fused(x, params["gamma"], params["beta"], self.eps, axis_name)
-            # lax.psum of the literal 1 is constant-folded to the axis size
-            n = jnp.asarray(x.shape[0] * x.shape[1] * x.shape[2], jnp.float32)
-            if axis_name is not None:
-                n = n * lax.psum(1, axis_name)
-            return y, running(mean, var, n)
+            with scope("bn_stats"):
+                # lax.psum of the literal 1 is constant-folded to the axis size
+                n = jnp.asarray(x.shape[0] * x.shape[1] * x.shape[2], jnp.float32)
+                if axis_name is not None:
+                    n = n * lax.psum(1, axis_name)
+                return y, running(mean, var, n)
         if train:
-            moments = _bn_moments_dot if mode in ("sdot", "compute_sdot") else _bn_moments
-            mean, var, n = moments(x, axis_name)
-            new_state = running(mean, var, n)
+            with scope("bn_stats"):
+                moments = _bn_moments_dot if mode in ("sdot", "compute_sdot") else _bn_moments
+                mean, var, n = moments(x, axis_name)
+                new_state = running(mean, var, n)
         else:
             mean, var = state["mean"], state["var"]
             new_state = state
-        scale = lax.rsqrt(var + self.eps) * params["gamma"]
-        if mode == "exact":
-            y = (x.astype(jnp.float32) - mean) * scale + params["beta"]
-        elif mode in ("compute", "compute_sdot"):
-            bias = params["beta"] - mean * scale
-            y = x * scale.astype(out_dtype) + bias.astype(out_dtype)
-        else:  # "folded"/"sdot", and eval-mode "fused_vjp" (same expression)
-            bias = params["beta"] - mean * scale
-            y = x.astype(jnp.float32) * scale + bias
-        return y.astype(out_dtype), new_state
+        with scope("bn_apply"):
+            scale = lax.rsqrt(var + self.eps) * params["gamma"]
+            if mode == "exact":
+                y = (x.astype(jnp.float32) - mean) * scale + params["beta"]
+            elif mode in ("compute", "compute_sdot"):
+                bias = params["beta"] - mean * scale
+                y = x * scale.astype(out_dtype) + bias.astype(out_dtype)
+            else:  # "folded"/"sdot", and eval-mode "fused_vjp" (same expression)
+                bias = params["beta"] - mean * scale
+                y = x.astype(jnp.float32) * scale + bias
+            return y.astype(out_dtype), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -398,10 +427,11 @@ class Dense:
         return params
 
     def apply(self, params: dict, x: Array, *, compute_dtype=jnp.float32) -> Array:
-        y = x.astype(compute_dtype) @ params["w"].astype(compute_dtype)
-        if self.use_bias:
-            y = y + params["b"].astype(compute_dtype)
-        return y
+        with scope("dense"):
+            y = x.astype(compute_dtype) @ params["w"].astype(compute_dtype)
+            if self.use_bias:
+                y = y + params["b"].astype(compute_dtype)
+            return y
 
 
 # ---------------------------------------------------------------------------
@@ -421,15 +451,17 @@ def bn_scale_shift(gamma, beta, mean, var, eps: float = 1e-5):
 def global_avg_pool(x: Array, keepdims: bool = False) -> Array:
     """Mean over H,W. Computed in float32 (bf16 accumulation over 49+ terms
     loses precision that measurably hurts SE gates and the head)."""
-    return jnp.mean(x.astype(jnp.float32), axis=(1, 2), keepdims=keepdims).astype(x.dtype)
+    with scope("pool"):
+        return jnp.mean(x.astype(jnp.float32), axis=(1, 2), keepdims=keepdims).astype(x.dtype)
 
 
 def dropout(rng, x: Array, rate: float, train: bool) -> Array:
     if not train or rate == 0.0:
         return x
     keep = 1.0 - rate
-    mask = jax.random.bernoulli(rng, keep, x.shape)
-    return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
+    with scope("drop"):
+        mask = jax.random.bernoulli(rng, keep, x.shape)
+        return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
 
 
 def make_divisible(v: float, divisor: int = 8, min_value: int | None = None) -> int:

@@ -29,6 +29,7 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs.scopes import scope
 from .mesh import DATA_AXIS
 
 
@@ -68,17 +69,23 @@ def make_zero_update(optimizer: optax.GradientTransformation, n: int, axis_name:
             g2 = _pad_flat(g, n).reshape(n, chunk)
             return lax.psum_scatter(g2, axis_name, scatter_dimension=0, tiled=False) / n
 
-        g_sh = jax.tree.map(scatter, grads)
-        p_sh = shard_params_local(params, idx, n)
-        updates, new_opt_sh = optimizer.update(g_sh, opt_state_sh, p_sh)
-        new_p_sh = optax.apply_updates(p_sh, updates)
+        # obs/scopes.py: the two halves of the allreduce are `grad_sync`, the
+        # update of this device's shard `optim`
+        with scope("grad_sync"):
+            g_sh = jax.tree.map(scatter, grads)
+        with scope("optim"):
+            p_sh = shard_params_local(params, idx, n)
+            updates, new_opt_sh = optimizer.update(g_sh, opt_state_sh, p_sh)
+            new_p_sh = optax.apply_updates(p_sh, updates)
 
         def gather(ns, orig):
             full = lax.all_gather(ns, axis_name, tiled=True)  # (n*chunk,)
             return full[: orig.size].reshape(orig.shape).astype(orig.dtype)
 
-        new_params = jax.tree.map(gather, new_p_sh, params)
-        gnorm = jnp.sqrt(lax.psum(optax.global_norm(g_sh) ** 2, axis_name))
+        with scope("grad_sync"):
+            new_params = jax.tree.map(gather, new_p_sh, params)
+        with scope("optim"):
+            gnorm = jnp.sqrt(lax.psum(optax.global_norm(g_sh) ** 2, axis_name))
         return new_params, new_opt_sh, gnorm
 
     return update

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.registry import get_registry
+from ..obs.scopes import scope
 
 HEALTH_REPORT_NAME = "train_health.json"
 
@@ -56,8 +57,9 @@ def wrap_step_fn(step_fn):
 
     def guarded(ts, batch, rng):
         new_ts, metrics = step_fn(ts, batch, rng)
-        ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
-        rolled = jax.tree.map(lambda new, old: jnp.where(ok, new, old), new_ts, ts)
+        with scope("guard"):
+            ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(metrics["grad_norm"])
+            rolled = jax.tree.map(lambda new, old: jnp.where(ok, new, old), new_ts, ts)
         # the step counter always advances: LR schedule, RNG folding, and the
         # resume data-order arithmetic count CONSUMED batches, not applied
         # updates

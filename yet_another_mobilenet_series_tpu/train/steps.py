@@ -20,6 +20,7 @@ from jax import lax
 
 from ..config import Config
 from ..models.specs import Network
+from ..obs.scopes import scope
 from ..ops.layers import BN_MODES
 from .ema import ema_update
 from .losses import cross_entropy_label_smooth, topk_correct
@@ -231,24 +232,29 @@ def make_train_step(
     prep_input = _input_normalizer(cfg)
     mixer = make_batch_mixer(cfg)
 
+    # The named scopes below and in ops/ are the step's half of obs/scopes.py:
+    # metadata only, they name the compiled step's operations for a device
+    # trace and add none.
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
-        x = prep_input(batch["image"])
-        if mixer is not None:
-            # distinct stream from the forward's dropout/drop-path rngs
-            # (blocks fold small indices, classifier uses the raw key)
-            x, label_b, lam = mixer(jax.random.fold_in(rng, 0x6D6978), x, batch["label"])
+        with scope("input"):
+            x = prep_input(batch["image"])
+            if mixer is not None:
+                # distinct stream from the forward's dropout/drop-path rngs
+                # (blocks fold small indices, classifier uses the raw key)
+                x, label_b, lam = mixer(jax.random.fold_in(rng, 0x6D6978), x, batch["label"])
         logits, new_state = forward(params, state, x, masks, rng)
-        ce = cross_entropy_label_smooth(logits, batch["label"], cfg.optim.label_smoothing)
-        if mixer is not None:
-            # CE is linear in the target distribution, so the convex label
-            # combination IS the convex loss combination (smoothing included)
-            ce = lam * ce + (1.0 - lam) * cross_entropy_label_smooth(
-                logits, label_b, cfg.optim.label_smoothing)
-        pen = (
-            penalty_fn(params, masks, rho_mult=rho_mult, step=step)
-            if penalty_fn is not None
-            else jnp.zeros((), jnp.float32)
-        )
+        with scope("loss"):
+            ce = cross_entropy_label_smooth(logits, batch["label"], cfg.optim.label_smoothing)
+            if mixer is not None:
+                # CE is linear in the target distribution, so the convex label
+                # combination IS the convex loss combination (smoothing included)
+                ce = lam * ce + (1.0 - lam) * cross_entropy_label_smooth(
+                    logits, label_b, cfg.optim.label_smoothing)
+        if penalty_fn is not None:
+            with scope("nas_penalty"):
+                pen = penalty_fn(params, masks, rho_mult=rho_mult, step=step)
+        else:
+            pen = jnp.zeros((), jnp.float32)
         return ce + pen, (new_state, logits, ce, pen)
 
     def step_fn(ts: TrainState, batch, rng):
@@ -261,33 +267,39 @@ def make_train_step(
             # broadcasting device 0's updated running stats (DDP rank-0
             # buffer semantics, globally — incl. multi-host)
             idx = lax.axis_index(axis_name)
-            new_state = jax.tree.map(
-                lambda s: lax.psum(jnp.where(idx == 0, s, jnp.zeros_like(s)), axis_name), new_state
-            )
+            with scope("syncbn"):
+                new_state = jax.tree.map(
+                    lambda s: lax.psum(jnp.where(idx == 0, s, jnp.zeros_like(s)), axis_name), new_state
+                )
         if sharded_update is not None:
             new_params, new_opt_state, grad_norm = sharded_update(grads, ts.opt_state, ts.params)
         else:
             if axis_name is not None:
-                grads = lax.pmean(grads, axis_name)
-            updates, new_opt_state = optimizer.update(grads, ts.opt_state, ts.params)
-            new_params = optax.apply_updates(ts.params, updates)
-            grad_norm = optax.global_norm(grads)
-        new_ema_p = ema_update(cfg.ema, ts.ema_params, new_params, ts.step) if cfg.ema.enable else None
-        new_ema_s = ema_update(cfg.ema, ts.ema_state, new_state, ts.step) if cfg.ema.enable else None
+                with scope("grad_sync"):
+                    grads = lax.pmean(grads, axis_name)
+            with scope("optim"):
+                updates, new_opt_state = optimizer.update(grads, ts.opt_state, ts.params)
+                new_params = optax.apply_updates(ts.params, updates)
+                grad_norm = optax.global_norm(grads)
+        with scope("ema"):
+            new_ema_p = ema_update(cfg.ema, ts.ema_params, new_params, ts.step) if cfg.ema.enable else None
+            new_ema_s = ema_update(cfg.ema, ts.ema_state, new_state, ts.step) if cfg.ema.enable else None
 
-        correct = topk_correct(logits, batch["label"], ks=(1,))["top1"]
-        n = jnp.asarray(logits.shape[0], jnp.float32)
-        metrics = {
-            "loss": loss,
-            "ce": ce,
-            "penalty": pen,
-            "top1": correct / n,
-            "lr": lr_fn(ts.step),
-            "grad_norm": grad_norm,
-            "finite": jnp.isfinite(loss).astype(jnp.float32),
-        }
-        if axis_name is not None:
-            metrics = {k: lax.pmean(v, axis_name) for k, v in metrics.items()}
+        # the step's reported scalars are part of `loss`
+        with scope("loss"):
+            correct = topk_correct(logits, batch["label"], ks=(1,))["top1"]
+            n = jnp.asarray(logits.shape[0], jnp.float32)
+            metrics = {
+                "loss": loss,
+                "ce": ce,
+                "penalty": pen,
+                "top1": correct / n,
+                "lr": lr_fn(ts.step),
+                "grad_norm": grad_norm,
+                "finite": jnp.isfinite(loss).astype(jnp.float32),
+            }
+            if axis_name is not None:
+                metrics = {k: lax.pmean(v, axis_name) for k, v in metrics.items()}
         new_ts = ts.replace(
             step=ts.step + 1,
             params=new_params,

@@ -22,6 +22,25 @@ start-up, so the threshold is lowered to 0 here (unless the operator set
 ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``). Measured on a v5e: the full
 chip_smoke.py set-up, cold vs warm, is in PERF.md.
 
+**The scope stamp.** The program names the work inside its compiled steps
+with ``jax.named_scope`` (obs/scopes.py), and a device trace is read by those
+names. They are metadata, and JAX leaves metadata out of this cache's key
+(``jax_compilation_cache_include_metadata_in_key`` is off by default, with
+the warning that "executables loaded from the cache may have stale metadata,
+which may show up in, e.g., profiles"): a cache filled by a checkout from
+before a scope was added or moved hands back an executable with the old
+names, or none, for a program that is otherwise the same. Putting ALL
+metadata in the key would cure that and cost too much: source locations are
+metadata too, so any edit that shifts a line in any file a traced function
+lives in (a comment in ops/layers.py, a log line above the step call in
+cli/train.py) would miss the whole cache, a minute of compiling for the
+train step alone. So :func:`configure` hashes one thing more into the key,
+``obs.scopes.TAXONOMY_VERSION``, through ``jax._src.cache_key.custom_hook``
+(the hook JAX keeps for exactly this; it has no public home, so where a later
+JAX lacks it the all-metadata flag is set instead: never stale, slower to
+iterate on): the key moves when the taxonomy does, and only then. Measured on
+a v5e both ways: PERF.md section 6, PR 25.
+
 A process held to the CPU backend (``JAX_PLATFORMS=cpu``: this sandbox, the
 test suite, an explicit ``--cpu`` smoke) gets no directory from here, so the
 cache stays off: XLA:CPU compiles are cheap, its loader logs a page of
@@ -44,6 +63,27 @@ def configure() -> str | None:
     compile: JAX latches the directory at first use. Returns the directory
     in effect, or None when the cache is left off (CPU-only process)."""
     import jax
+
+    from ..obs import scopes
+    from ..obs.device import install_compile_watch
+
+    # every entry point comes through here before its first compile, so this
+    # is also where the program's compile watch (obs/device.py) goes in: a
+    # listener, free until something compiles, and wanted on the CPU too
+    install_compile_watch()
+    # "The scope stamp" (module docstring). custom_hook is JAX's own extension
+    # point for the cache key and has no public home (private jax internals
+    # move: YAMT006), so it is imported guarded and probed, and a jax without
+    # it gets the public, costlier flag
+    try:
+        from jax._src import cache_key
+    except ImportError:
+        cache_key = None
+    if callable(getattr(cache_key, "custom_hook", None)):
+        cache_key.custom_hook = lambda: f"yamt-scopes-{scopes.TAXONOMY_VERSION}"
+    else:
+        # never stale, at the price of a cache miss after any edit that shifts a line
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
     chosen = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not chosen:
