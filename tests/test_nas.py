@@ -155,6 +155,46 @@ def test_remat_exact_equivalence_with_branch_and_block_drop():
     )
 
 
+def test_conv_bn_pair_on_the_masked_supernet_and_its_fallback_after_a_shrink(monkeypatch):
+    """The supernet's parallel depthwise branches and masks sit after the
+    expand BN, so the conv + BN pair (ops/layers.py) leaves loss and every
+    gradient leaf of the masked train forward as they were at float32; and a
+    block the shrink cut to its input width keeps its expand conv
+    (force_expand) but falls back to the plain path by the shape rule."""
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    net = _supernet()
+    params, state = net.init(jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 32, 3))
+    imasks = {int(k): v for k, v in _random_masks(net, np.random.RandomState(0)).items()}
+
+    def grads():
+        def loss(p):
+            logits, _ = net.apply(p, state, x, train=True, masks=imasks)
+            return jnp.mean(jnp.square(logits - 1.0))
+
+        return jax.jit(jax.value_and_grad(loss))(params)
+
+    assert net.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False)[0] == 4  # blocks 1-3 and the head
+    loss_pair, g_pair = grads()
+    monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
+    loss_plain, g_plain = grads()
+    assert float(loss_pair) == float(loss_plain)
+    floor = 1e-6 * max(float(np.abs(np.asarray(g)).max()) for g in jax.tree.leaves(g_plain))
+    for got, want in zip(jax.tree.leaves(g_pair), jax.tree.leaves(g_plain)):
+        assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-4 * np.abs(np.asarray(want)).max() + floor
+    monkeypatch.undo()
+
+    import dataclasses
+
+    blk = net.blocks[1]
+    shrunk = dataclasses.replace(blk, expanded_channels=blk.in_channels, group_channels=(blk.in_channels,),
+                                 kernel_sizes=(3,), force_expand=True)
+    assert shrunk.has_expand
+    assert blk.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == (1, 2)
+    assert shrunk.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == (0, 2)
+
+
 @pytest.mark.slow
 def test_remat_slices_optimizer_and_ema_state():
     from yet_another_mobilenet_series_tpu.config import config_from_dict

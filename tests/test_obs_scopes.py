@@ -151,6 +151,42 @@ def test_every_bn_mode_lands_in_bn_scopes(bn_mode):
         assert by[(scope, "fwd")] > 0 and by[(scope, "bwd")] > 0, by
 
 
+def test_the_conv_bn_pair_lands_in_its_scopes():
+    """ops/layers.py's conv + BN pair under grad on a mesh, lowered: every
+    operation of its forward and of its custom backward is under conv_pw (the
+    convolutions and contractions, the small matrices of the closed form
+    too), bn_stats (the sums), bn_apply (the normalize and the per-channel
+    coefficients) or syncbn (the psums), with the phase autodiff marks."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import numpy as np
+
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    conv, bn = layers.Conv2D(8, 24, 1), BatchNorm(24)
+    conv_params = jax.eval_shape(lambda: conv.init(jax.random.PRNGKey(0)))
+    bn_params, bn_state = bn.init()
+    assert layers.conv_bn_pairs(conv, train=True, bn_mode="exact")
+
+    def loss(conv_params, bn_params, x):
+        y, _ = layers.conv_bn(conv, bn, conv_params, bn_params, bn_state, x, train=True, axis_name="data",
+                              compute_dtype=jnp.bfloat16)
+        with scopes.scope("loss"):
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    grads = jax.shard_map(jax.grad(loss, argnums=(0, 1, 2)), mesh=mesh, in_specs=(P(), P(), P("data")),
+                          out_specs=(P(), P(), P("data")), check_vma=False)
+    text = jax.jit(grads).lower(conv_params, bn_params, jax.ShapeDtypeStruct((4, 4, 4, 8), jnp.bfloat16)).as_text(
+        debug_info=True)
+    # inside a shard_map the name-stack paths start at the transform, not at a jit
+    paths = re.findall(r'loc\("((?:jvp|transpose)\([^"]*)"', text)
+    by = collections.Counter(scopes.scope_of(m) for m in paths)
+    assert {scope for scope, _ in by} == {"conv_pw", "bn_stats", "bn_apply", "syncbn", "loss"}, by
+    for scope in ("conv_pw", "bn_stats", "bn_apply", "syncbn"):
+        assert by[(scope, "fwd")] > 0 and by[(scope, "bwd")] > 0, by
+
+
 def test_time_by_scope_sums_and_reports_the_unresolved():
     table = {"fusion.1": ("bn_stats", "fwd"), "fusion.2": ("bn_stats", "bwd"), "convolution.3": ("conv_pw", "fwd"),
              "copy-done.4": (scopes.UNSCOPED, "-")}
@@ -260,14 +296,15 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         for name in files:
             if name.endswith(".py") and name != "scopes.py":
                 with open(os.path.join(root, name)) as f:
-                    found = re.findall(r'\bscope\((?:self\.scope_name|"(\w+)")\)', f.read())
+                    found = re.findall(r'\bscope\((?:(?:self|conv)\.scope_name|"(\w+)")\)', f.read())
                 if found:
                     sites[os.path.relpath(os.path.join(root, name), pkg)] = sorted(found)
-    assert (scopes.TAXONOMY_VERSION, sites) == (1, {
+    assert (scopes.TAXONOMY_VERSION, sites) == (2, {
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
-        "ops/layers.py": ["", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
+        # version 2: the conv + BN pair's forward and custom backward (PR 26)
+        "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],
         "train/guard.py": ["guard"],

@@ -366,6 +366,145 @@ def test_syncbn_equals_full_batch_bn(mode):
     np.testing.assert_allclose(np.asarray(st["var"]), np.asarray(st_ref["var"]), rtol=1e-5, atol=1e-6)
 
 
+# -- the 1x1 conv + BatchNorm pair (ops/layers.py conv_bn) -------------------
+
+
+def _conv_bn_case(cin, cexp, dtype, seed=0):
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    rs = np.random.RandomState(seed)
+    conv, bn = ops.Conv2D(cin, cexp, 1), ops.BatchNorm(cexp)
+    conv_params = conv.init(jax.random.PRNGKey(seed + 1))
+    _, bn_state = bn.init()
+    bn_params = {"gamma": jnp.asarray(rs.uniform(0.5, 1.5, cexp).astype(np.float32)),
+                 "beta": jnp.asarray(rs.uniform(-0.5, 0.5, cexp).astype(np.float32))}
+    x = jnp.asarray(rs.normal(0.3, 1.0, (8, 6, 6, cin)).astype(np.float32)).astype(dtype)
+    ct = jnp.asarray(rs.normal(0, 1.0, (8, 6, 6, cexp)).astype(np.float32))
+
+    def paired(cp, bp, xx, mode, axis_name=None, ct=ct):
+        y, st = layers.conv_bn(conv, bn, cp, bp, bn_state, xx, train=True, axis_name=axis_name,
+                               compute_dtype=dtype, bn_mode=mode)
+        return jnp.sum(y.astype(jnp.float32) * ct), (y, st)
+
+    def unpaired(cp, bp, xx, mode, axis_name=None, ct=ct):
+        e = conv.apply(cp, xx, compute_dtype=dtype)
+        y, st = bn.apply(bp, bn_state, e, train=True, axis_name=axis_name, mode=mode)
+        return jnp.sum(y.astype(jnp.float32) * ct), (y, st)
+
+    return conv, bn, conv_params, bn_params, x, ct, paired, unpaired
+
+
+@pytest.mark.parametrize("mode", ["exact", "folded", "fused_vjp"])
+@pytest.mark.parametrize("cin,cexp", [(16, 64), (40, 240)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_conv_bn_pair_matches_autodiff(dtype, cin, cexp, mode):
+    """conv_bn() on a widening 1x1 conv in training is the custom-VJP pair
+    whose backward never reads the conv's output: forward values and running
+    stats bit-equal to Conv2D then BatchNorm in the same bn_mode; dX, dW,
+    dgamma, dbeta equal to jax.vjp of plain Conv2D + BatchNorm(mode="exact"),
+    to 1e-5 of the largest entry in float32 and one bf16 ulp of it (2^-7) in
+    bfloat16: the float32 reference of the changed arithmetic."""
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    conv, _, conv_params, bn_params, x, _, paired, unpaired = _conv_bn_case(cin, cexp, dtype)
+    assert layers.conv_bn_pairs(conv, train=True, bn_mode=mode)
+    grad = lambda f, m: jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(conv_params, bn_params, x, m)
+    (_, (y, st)), (g_conv, g_bn, g_x) = grad(paired, mode)
+    (_, (y_same, st_same)), _ = grad(unpaired, mode)
+    assert y.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(y, np.float32), np.asarray(y_same, np.float32))
+    for k in ("mean", "var"):
+        np.testing.assert_array_equal(np.asarray(st[k]), np.asarray(st_same[k]))
+
+    _, (r_conv, r_bn, r_x) = grad(unpaired, "exact")
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    for name, got, want in (("dX", g_x, r_x), ("dW", g_conv["w"], r_conv["w"]),
+                            ("dgamma", g_bn["gamma"], r_bn["gamma"]), ("dbeta", g_bn["beta"], r_bn["beta"])):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert got.shape == want.shape
+        assert np.abs(got - want).max() <= tol * np.abs(want).max(), name
+
+
+def test_conv_bn_pair_in_bfloat16_is_no_further_from_float32_than_autodiff():
+    """The pair's bf16 gradients against the float32 gradients of the same
+    function: no worse than what autodiff through the bf16-rounded conv
+    output gives (its rounding no longer enters the two products)."""
+    cases = {dt: _conv_bn_case(40, 240, dt) for dt in (jnp.float32, jnp.bfloat16)}
+    _, _, cp, bp, x32, _, _, ref = cases[jnp.float32]
+    _, _, _, _, x16, _, paired, unpaired = cases[jnp.bfloat16]
+    x32 = x16.astype(jnp.float32)  # the same input values on both sides
+    grad = lambda f, xx: jax.grad(lambda *a: f(*a, "exact")[0], argnums=(0, 2))(cp, bp, xx)
+    (w32, dx32), (wp, dxp), (wa, dxa) = grad(ref, x32), grad(paired, x16), grad(unpaired, x16)
+    err = lambda a, b: float(np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32)).max()
+                             / np.abs(np.asarray(b, np.float32)).max())
+    assert err(wp["w"], w32["w"]) <= 1.5 * err(wa["w"], w32["w"]) + 1e-4
+    assert err(dxp, dx32) <= 1.5 * err(dxa, dx32) + 1e-4
+
+
+def test_conv_bn_pair_rejects_stat_cotangents():
+    """Like fused_vjp: a loss that differentiates the pair's batch statistics
+    fails where it is traced, and works on the unpaired path."""
+    _, _, conv_params, bn_params, x, _, paired, unpaired = _conv_bn_case(8, 24, jnp.float32)
+    stat_loss = lambda f: jax.grad(lambda cp: jnp.sum(f(cp, bn_params, x, "exact")[1][1]["mean"]))(conv_params)
+    with pytest.raises(TypeError, match="conv \\+ BatchNorm pair.*cotangents"):
+        stat_loss(paired)
+    assert np.all(np.isfinite(np.asarray(stat_loss(unpaired)["w"])))
+
+
+@pytest.mark.parametrize("conv, kw, expect", [
+    (ops.Conv2D(16, 64, 1), {}, True),
+    (ops.Conv2D(16, 64, 1), {"bn_mode": "folded"}, True),
+    (ops.Conv2D(16, 64, 1), {"bn_mode": "fused_vjp"}, True),
+    (ops.Conv2D(16, 64, 1), {"train": False}, False),  # eval, export, serving: the plain path
+    (ops.Conv2D(16, 64, 1), {"bn_mode": "compute"}, False),
+    (ops.Conv2D(16, 64, 1), {"bn_mode": "sdot"}, False),
+    (ops.Conv2D(16, 64, 1), {"bn_mode": "compute_sdot"}, False),
+    (ops.Conv2D(16, 64, 1), {"conv1x1_dot": True}, False),
+    (ops.Conv2D(64, 64, 1), {}, False),  # a pruned block shrunk to its input width: nothing to win
+    (ops.Conv2D(64, 16, 1), {}, False),  # the project conv's shape
+    (ops.Conv2D(3, 16, 3, 2), {}, False),  # the stem
+    (ops.Conv2D(16, 64, 1, 2), {}, False),
+    (ops.Conv2D(16, 64, 1, groups=2), {}, False),
+    (ops.Conv2D(16, 64, 1, use_bias=True), {}, False),
+])
+def test_conv_bn_pair_engages_by_what_the_site_is(conv, kw, expect):
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    kw = {"train": True, "bn_mode": "exact", **kw}
+    assert layers.conv_bn_pairs(conv, **kw) is expect
+    # and conv_bn() takes the custom-VJP pair exactly there
+    bn = ops.BatchNorm(conv.out_channels)
+    (bn_params, bn_state), conv_params = bn.init(), conv.init(jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(lambda x: layers.conv_bn(conv, bn, conv_params, bn_params, bn_state, x, **kw)[0])(
+        jnp.ones((2, 8, 8, conv.in_channels)))
+    assert ("_conv_bn_pair" in str(jaxpr)) is expect
+
+
+def test_conv_bn_pair_sharded_grad_contract_matches_syncbn_autodiff():
+    """The pair under shard_map on 4 devices (parallel/dp.py's contract,
+    check_vma=False as there): per device, dgamma/dbeta/dW are LOCAL partials
+    and dX is complete, each equal to autodiff of Conv2D + SyncBN 'exact'."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    _, _, conv_params, bn_params, x, ct, paired, unpaired = _conv_bn_case(8, 24, jnp.float32, seed=5)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+
+    def per_device_grads(fn):
+        def body(cp, bp, xx, cc):
+            (g_conv, g_bn, g_x) = jax.grad(lambda *a: fn(*a, "exact", "data", cc)[0], argnums=(0, 1, 2))(cp, bp, xx)
+            return jax.tree.map(lambda v: v[None], (g_conv, g_bn)), g_x
+
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P("data"), P("data")),
+                                     out_specs=(P("data"), P("data")), check_vma=False))(conv_params, bn_params, x, ct)
+
+    (gp, gxp), (gr, gxr) = per_device_grads(paired), per_device_grads(unpaired)
+    assert gp[1]["gamma"].shape == (4, 24) and gp[0]["w"].shape == (4, 1, 1, 8, 24)  # one partial per device
+    for got, want in zip(jax.tree.leaves((gp, gxp)), jax.tree.leaves((gr, gxr))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
+    # the partials differ between devices: nothing was psum'd on the way out
+    assert not np.allclose(np.asarray(gp[1]["gamma"][0]), np.asarray(gp[1]["gamma"][1]))
+
+
 def test_inverted_residual_shapes_and_residual():
     spec = ops.InvertedResidual(
         in_channels=16, out_channels=16, expanded_channels=48, stride=1,

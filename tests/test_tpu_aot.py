@@ -1,0 +1,134 @@
+"""Compiled for a DESCRIBED TPU v5e, never run: what the chip's compiler makes
+of the main path at real widths (the `on-chip-measurement` guide, section 2,
+third rehearsal). Nothing here gives a time; it gives operands, layouts and
+fusions, at no chip time, on every later PR.
+
+The topology is described inside a module fixture (only one process may hold
+libtpu; nothing touches it at import or collection), and this is the one file
+that does so: a second one could land on another xdist worker and skip.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from yet_another_mobilenet_series_tpu import ops
+from yet_another_mobilenet_series_tpu.obs import scopes
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# MobileNetV3-Large's second block at the benchmark's batch: the step's four
+# largest fusions all stream this block's expanded tensor (PERF.md section 5)
+BATCH, SIZE, CIN, CEXP, COUT = 512, 112, 16, 64, 24
+WIDE = f"bf16[{BATCH},{SIZE},{SIZE},{CEXP}]"
+
+
+@pytest.fixture(scope="module")
+def block_hlo(one_chip):
+    """ENTRY instructions of the one-block gradient: name -> (output shapes,
+    opcode, operand names, op_name), get-tuple-elements seen through."""
+    block = ops.InvertedResidual(CIN, COUT, CEXP, stride=2, kernel_sizes=(3,), active_fn="hswish")
+    params, state = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0)))
+    state = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+
+    def loss(params, x, ct):
+        y, _ = block.apply(params, state, x, train=True, compute_dtype=jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32) * ct)
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(lambda a: on_chip(a.shape, a.dtype), params),
+        on_chip((BATCH, SIZE, SIZE, CIN), jnp.bfloat16),
+        on_chip((BATCH, SIZE // 2, SIZE // 2, COUT), jnp.float32)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    instructions, alias = {}, {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\((.*)$", line)
+        if m is None:
+            continue
+        name, out, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        if opcode == "get-tuple-element":
+            index = int(re.search(r"index=(\d+)", rest).group(1))
+            alias[name] = (operands[0], index)
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        instructions[name] = (re.findall(r"\w+\[[\d,]*\]", out), opcode, operands, op_name.group(1) if op_name else "")
+
+    def shape_of(operand):
+        if operand in alias:
+            source, index = alias[operand]
+            return instructions[source][0][index]
+        shapes = instructions.get(operand, ([],))[0]
+        return shapes[0] if len(shapes) == 1 else None
+
+    return instructions, alias, shape_of, text
+
+
+def _wide_operands(block_hlo, name):
+    instructions, _, shape_of, _ = block_hlo
+    return [o for o in instructions[name][2] if shape_of(o) == WIDE]
+
+
+def test_no_expand_gradient_reads_the_expanded_activation(block_hlo):
+    """The conv + BN pair's point: of the fusions under transpose(jvp(conv_pw)),
+    none takes the expand conv's output E as an operand (they take D, the
+    gradient arriving at the BN's output, and the 16-channel input)."""
+    instructions, alias, _, _ = block_hlo
+    producer = [n for n, (out, opcode, _, op) in instructions.items()
+                if opcode == "fusion" and WIDE in out and scopes.scope_of(op) == ("conv_pw", "fwd")]
+    assert len(producer) == 1, producer
+    e_names = {producer[0]} | {g for g, (source, _) in alias.items() if source == producer[0]}
+    expand_grads = [n for n, (_, opcode, _, op) in instructions.items()
+                    if opcode == "fusion" and scopes.scope_of(op) == ("conv_pw", "bwd")]
+    assert expand_grads
+    wide_readers = [n for n in expand_grads if _wide_operands(block_hlo, n)]
+    assert len(wide_readers) == 2  # dX and the dW contraction, each reading D
+    for n in expand_grads:
+        assert not e_names & set(instructions[n][2]), f"{n} reads the expand conv's output"
+
+
+def test_the_wide_tensors_are_read_five_times_and_never_copied(block_hlo):
+    """Autodiff of conv then BN reads the two expanded-width tensors 7 times
+    (E: depthwise forward, its two gradients, the expand conv's two; D: the
+    expand conv's two); the pair leaves 5. And no relayout pays for it."""
+    instructions, _, _, _ = block_hlo
+    reads = sum(len(_wide_operands(block_hlo, n)) for n, (_, opcode, _, _) in instructions.items()
+                if opcode == "fusion")
+    writes = sum(out.count(WIDE) for out, opcode, _, _ in instructions.values() if opcode == "fusion")
+    assert (reads, writes) == (5, 2)
+    moved = [n for n, (out, opcode, _, _) in instructions.items()
+             if WIDE in out and opcode in ("copy", "transpose", "copy-start", "copy-done")]
+    assert not moved, moved
+
+
+def test_the_bn_gradient_sums_ride_in_the_fusion_that_produces_d(block_hlo):
+    """sum(D) and sum(D * x_hat) are the one place the pair's backward still
+    needs E: they cost no pass because they are fused into the depthwise
+    input-gradient fusion, which reads E for the activation's derivative."""
+    instructions, _, _, text = block_hlo
+    inside = scopes.scopes_inside(text)
+    d_producer = [n for n, (out, opcode, _, op) in instructions.items()
+                  if opcode == "fusion" and WIDE in out and scopes.scope_of(op)[1] == "bwd"]
+    assert len(d_producer) == 1, d_producer
+    assert scopes.scope_of(instructions[d_producer[0]][3]) == ("conv_dw", "bwd")
+    assert "bn_stats" in inside.get(d_producer[0], ())
+    # and nothing under bn_stats reads a wide tensor in a fusion of its own
+    alone = [n for n, (_, opcode, _, op) in instructions.items()
+             if opcode == "fusion" and scopes.scope_of(op)[0] == "bn_stats" and _wide_operands(block_hlo, n)]
+    assert not alone, alone

@@ -268,6 +268,70 @@ def test_remat_step_equals_plain_step(policy, bn_mode):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _loss_and_grads(net, params, state, batch, bn_mode, masks=None):
+    def loss(p):
+        logits, new_state = net.apply(p, state, batch["image"], train=True, bn_mode=bn_mode, masks=masks)
+        return jnp.mean(losses.cross_entropy_label_smooth(logits, batch["label"], 0.1)), new_state
+
+    return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+
+@pytest.mark.parametrize("bn_mode", ["exact", "folded", "fused_vjp"])
+def test_conv_bn_pair_leaves_loss_and_every_gradient_leaf_as_they_were(monkeypatch, bn_mode):
+    """The two-block net at float32, its expand convs and head lowered through
+    ops/layers.py's conv + BN pair (what a train step does) against the same
+    net with the pair switched off in the test: same loss, same BN state,
+    every gradient leaf equal to 1e-4 of its largest entry. The float32
+    reference a change of reduction order has to bring (ROADMAP Queue 2 item 6)."""
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    cfg = _tiny_cfg()
+    net = get_model(cfg.model, image_size=16)
+    assert net.conv_bn_pair_sites(bn_mode=bn_mode, conv1x1_dot=False) == (3, 5)  # two expands + the head; + two projects
+    params, state = net.init(jax.random.PRNGKey(0))
+    batch = {"image": jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16, 3)), "label": jnp.arange(8) % 4}
+    (loss_pair, state_pair), grads_pair = _loss_and_grads(net, params, state, batch, bn_mode)
+    monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
+    (loss_plain, state_plain), grads_plain = _loss_and_grads(net, params, state, batch, bn_mode)
+    assert float(loss_pair) == float(loss_plain)
+    for a, b in zip(jax.tree.leaves(state_pair), jax.tree.leaves(state_plain)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    flat_pair, flat_plain = (dict(jax.tree_util.tree_leaves_with_path(g)) for g in (grads_pair, grads_plain))
+    assert flat_pair.keys() == flat_plain.keys()
+    # BN's shift invariance makes some leaves (a bias-like direction ahead of a
+    # BN) pure cancellation: those are held to the largest gradient in the net
+    floor = 1e-6 * max(float(np.abs(np.asarray(g)).max()) for g in flat_plain.values())
+    for path, want in flat_plain.items():
+        want, got = np.asarray(want), np.asarray(flat_pair[path])
+        assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max() + floor, jax.tree_util.keystr(path)
+
+
+def test_train_step_reports_how_many_sites_the_pair_lowers():
+    """make_train_step sets the two registry gauges from the network and the
+    configured modes: train.conv_bn_pairs, train.conv_bn_pair_eligible."""
+    from yet_another_mobilenet_series_tpu.obs.registry import get_registry
+
+    for over, expect in (({}, 3.0), ({"bn_mode": "compute"}, 0.0), ({"conv1x1_dot": True}, 0.0)):
+        cfg = _tiny_cfg(train={"compute_dtype": "float32", **over})
+        net = get_model(cfg.model, image_size=16)
+        lr_fn = schedules.make_lr_schedule(cfg.schedule, 8, 1, 100)
+        params, _ = net.init(jax.random.PRNGKey(0))
+        steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn)
+        assert get_registry().gauge("train.conv_bn_pairs").value == expect
+        assert get_registry().gauge("train.conv_bn_pair_eligible").value == 5.0
+
+
+@pytest.mark.parametrize("arch, expect", [("mobilenet_v3_large", (15, 30)), ("efficientnet_b0", (16, 32))])
+def test_conv_bn_pair_sites_of_the_benchmarks_networks(arch, expect):
+    """14 of MobileNetV3-Large's 15 blocks (the first has no expand) and its
+    head; all 15 expanding blocks of EfficientNet-B0 and its head."""
+    from yet_another_mobilenet_series_tpu.config import ModelConfig
+
+    net = get_model(ModelConfig(arch=arch), 224)
+    assert net.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == expect
+    assert net.conv_bn_pair_sites(bn_mode="sdot", conv1x1_dot=False) == (0, expect[1])
+
+
 def test_remat_policy_validated():
     cfg = _tiny_cfg(train={"compute_dtype": "float32", "remat": True, "remat_policy": "nope"})
     net = get_model(cfg.model, image_size=16)

@@ -94,6 +94,14 @@ class Network:
         params["classifier"] = self.classifier.init(keys[-1])
         return params, state
 
+    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+        """(sites a train step lowers through ops/layers.py's conv + BN pair,
+        1x1 conv + BN sites in the network): what `train.conv_bn_pairs` and
+        `train.conv_bn_pair_eligible` report."""
+        parts = [self.stem, *self.blocks] + ([self.head] if self.head is not None else [])
+        counts = [p.conv_bn_pair_sites(bn_mode=bn_mode, conv1x1_dot=conv1x1_dot) for p in parts]
+        return sum(c[0] for c in counts), sum(c[1] for c in counts)
+
     def apply(
         self,
         params,

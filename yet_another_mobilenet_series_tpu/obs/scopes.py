@@ -73,7 +73,7 @@ SCOPES = (
 # filled before a scope was added, renamed or moved hands back an executable
 # with the old names in it. BUMP IT with any such change;
 # tests/test_obs_scopes.py pins it to the list of scope sites.
-TAXONOMY_VERSION = 1
+TAXONOMY_VERSION = 2
 UNSCOPED = "unscoped"
 PHASES = ("fwd", "bwd", "-")
 

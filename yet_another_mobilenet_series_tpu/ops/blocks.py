@@ -29,7 +29,7 @@ import jax.numpy as jnp
 
 from ..obs.scopes import scope
 from .activations import get_activation
-from .layers import Array, BatchNorm, Conv2D, Dense, global_avg_pool
+from .layers import Array, BatchNorm, Conv2D, Dense, conv_bn, conv_bn_pairs, global_avg_pool, is_conv1x1_bn_site
 
 
 @dataclass(frozen=True)
@@ -54,6 +54,11 @@ class ConvBNAct:
     def bn(self) -> BatchNorm:
         return BatchNorm(self.out_channels, self.bn_momentum, self.bn_eps)
 
+    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+        """(sites a train step lowers through the conv + BN pair, 1x1 conv + BN sites)."""
+        return (int(conv_bn_pairs(self.conv, train=True, bn_mode=bn_mode, conv1x1_dot=conv1x1_dot)),
+                int(is_conv1x1_bn_site(self.conv)))
+
     def init(self, key):
         params = {"conv": self.conv.init(key)}
         bn_p, bn_s = self.bn.init()
@@ -62,8 +67,8 @@ class ConvBNAct:
 
     def apply(self, params, state, x, *, train, axis_name=None, compute_dtype=jnp.float32, bn_mode="exact",
               conv1x1_dot=False):
-        y = self.conv.apply(params["conv"], x, compute_dtype=compute_dtype, as_dot=conv1x1_dot)
-        y, bn_s = self.bn.apply(params["bn"], state["bn"], y, train=train, axis_name=axis_name, mode=bn_mode)
+        y, bn_s = conv_bn(self.conv, self.bn, params["conv"], params["bn"], state["bn"], x, train=train,
+                          axis_name=axis_name, compute_dtype=compute_dtype, bn_mode=bn_mode, conv1x1_dot=conv1x1_dot)
         y = get_activation(self.active_fn)(y)
         return y, {"bn": bn_s}
 
@@ -169,6 +174,22 @@ class InvertedResidual:
     def _bn(self, c):
         return BatchNorm(c, self.bn_momentum, self.bn_eps)
 
+    @property
+    def _expand_conv(self) -> Conv2D:
+        return Conv2D(self.in_channels, self.expanded_channels, 1)
+
+    @property
+    def _project_conv(self) -> Conv2D:
+        return Conv2D(self.expanded_channels, self.out_channels, 1)
+
+    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+        """(sites a train step lowers through the conv + BN pair, 1x1 conv + BN
+        sites). Only the expand conv goes through conv_bn(): the project conv's
+        INPUT is the wide tensor, so there the pair has nothing to shed."""
+        expand = self.has_expand and conv_bn_pairs(self._expand_conv, train=True, bn_mode=bn_mode,
+                                                   conv1x1_dot=conv1x1_dot)
+        return int(expand), int(self.has_expand) + 1
+
     def _branches(self):
         """Yields (branch_index, kernel_size, group_channels, offset) —
         single source of truth for the expanded-channel layout used by both
@@ -182,7 +203,7 @@ class InvertedResidual:
         keys = jax.random.split(key, 3 + len(self.kernel_sizes))
         params, state = {}, {}
         if self.has_expand:
-            params["expand"] = Conv2D(self.in_channels, self.expanded_channels, 1).init(keys[0])
+            params["expand"] = self._expand_conv.init(keys[0])
             params["expand_bn"], state["expand_bn"] = self._bn(self.expanded_channels).init()
         for i, (k, g) in enumerate(zip(self.kernel_sizes, self.group_channels)):
             params[f"dw{i}_k{k}"] = Conv2D(g, g, k, self.stride, groups=g).init(keys[1 + i])
@@ -193,7 +214,7 @@ class InvertedResidual:
             params["se"] = SqueezeExcite(
                 self.expanded_channels, self.se_channels, self.se_inner_act, self.se_gate_fn
             ).init(keys[-2])
-        params["project"] = Conv2D(self.expanded_channels, self.out_channels, 1).init(keys[-1])
+        params["project"] = self._project_conv.init(keys[-1])
         params["project_bn"], state["project_bn"] = self._bn(self.out_channels).init()
         return params, state
 
@@ -221,11 +242,10 @@ class InvertedResidual:
         new_state = {}
         h = x
         if self.has_expand:
-            h = Conv2D(self.in_channels, self.expanded_channels, 1).apply(
-                params["expand"], h, compute_dtype=compute_dtype, as_dot=conv1x1_dot
-            )
-            h, new_state["expand_bn"] = self._bn(self.expanded_channels).apply(
-                params["expand_bn"], state["expand_bn"], h, train=train, axis_name=axis_name, mode=bn_mode
+            h, new_state["expand_bn"] = conv_bn(
+                self._expand_conv, self._bn(self.expanded_channels), params["expand"], params["expand_bn"],
+                state["expand_bn"], h, train=train, axis_name=axis_name, compute_dtype=compute_dtype,
+                bn_mode=bn_mode, conv1x1_dot=conv1x1_dot,
             )
             h = act(h)
         branches = []
@@ -246,9 +266,7 @@ class InvertedResidual:
             h = SqueezeExcite(self.expanded_channels, self.se_channels, self.se_inner_act, self.se_gate_fn).apply(
                 params["se"], h, compute_dtype=compute_dtype
             )
-        h = Conv2D(self.expanded_channels, self.out_channels, 1).apply(
-            params["project"], h, compute_dtype=compute_dtype, as_dot=conv1x1_dot
-        )
+        h = self._project_conv.apply(params["project"], h, compute_dtype=compute_dtype, as_dot=conv1x1_dot)
         h, new_state["project_bn"] = self._bn(self.out_channels).apply(
             params["project_bn"], state["project_bn"], h, train=train, axis_name=axis_name, mode=bn_mode
         )

@@ -194,19 +194,34 @@ def _bn_moments_dot(x, axis_name):
     return _finalize_moments(s1, s2, n_local, axis_name)
 
 
+def _bn_normalize(x, mean, var, gamma, beta, eps, mode):
+    """The normalize of BatchNorm.apply, by ``mode`` (documented there)."""
+    scale = lax.rsqrt(var + eps) * gamma
+    if mode == "exact":
+        y = (x.astype(jnp.float32) - mean) * scale + beta
+    elif mode in ("compute", "compute_sdot"):
+        bias = beta - mean * scale
+        y = x * scale.astype(x.dtype) + bias.astype(x.dtype)
+    else:  # "folded"/"sdot", and "fused_vjp" (same expression)
+        bias = beta - mean * scale
+        y = x.astype(jnp.float32) * scale + bias
+    return y.astype(x.dtype)
+
+
 def _bn_train_fused(x, gamma, beta, eps, axis_name):
     y, mean, var, _ = _bn_train_fused_fwd_impl(x, gamma, beta, eps, axis_name)
     return y, mean, var
 
 
-def _bn_train_fused_fwd_impl(x, gamma, beta, eps, axis_name):
+def _bn_train_fused_fwd_impl(x, gamma, beta, eps, axis_name, mode="fused_vjp"):
+    """The train-mode forward of the two custom-VJP users (fused_vjp, and the
+    conv + BN pair in any of its modes): y, the moments, and what their
+    closed-form backwards keep of them."""
     with scope("bn_stats"):
         mean, var, n = _bn_moments(x, axis_name)
     with scope("bn_apply"):
+        y = _bn_normalize(x, mean, var, gamma, beta, eps, mode)
         inv = lax.rsqrt(var + eps)
-        scale = gamma * inv
-        bias = beta - mean * scale
-        y = (x.astype(jnp.float32) * scale + bias).astype(x.dtype)
     return y, mean, var, (inv, n)
 
 
@@ -218,6 +233,42 @@ def _bn_train_fused_fwd(x, gamma, beta, eps, axis_name):
     # residuals are the bf16 input + per-channel f32 stats — x_hat and any
     # f32 copy of the activation are recomputed, never stored
     return (y, mean, var), (x, gamma, mean, inv, n)
+
+
+def _refuse_stat_cotangents(who, dmean_ct, dvar_ct):
+    """The closed-form BN backwards (fused_vjp's, and the conv + BN pair's)
+    discard the cotangents of their mean/var outputs by contract: anything
+    but a symbolic zero there is refused where the step is traced. Returns
+    the SymbolicZero type for the caller's own check of dy."""
+    zero = jax.custom_derivatives.SymbolicZero
+    if not (isinstance(dmean_ct, zero) and isinstance(dvar_ct, zero)):
+        raise TypeError(
+            f"{who} received non-zero cotangents for the batch "
+            "mean/var outputs; its closed-form backward discards them by "
+            "contract. A loss term differentiating the batch statistics "
+            "(e.g. a stat regularizer) must differentiate a plain BatchNorm "
+            "under an autodiff bn_mode ('exact'/'folded'), or extend the "
+            "closed form."
+        )
+    return zero
+
+
+def _bn_grad_sums(x, dy, mean, inv, axis_name):
+    """The two reductions of the closed-form BN backward, one pass over
+    (x, dy): (f32 dy, x̂, dβ, dγ, psum dβ, psum dγ). dβ/dγ stay LOCAL partials
+    (the contract in _bn_train_fused_bwd); the psum'd pair feeds dx. They are
+    `bn_stats`, like the forward sums they are the gradients of."""
+    with scope("bn_stats"):
+        dyf = dy.astype(jnp.float32)
+        x_hat = (x.astype(jnp.float32) - mean) * inv
+        dbeta = jnp.sum(dyf, axis=(0, 1, 2))
+        dgamma = jnp.sum(dyf * x_hat, axis=(0, 1, 2))
+        s1, s2 = dbeta, dgamma
+        if axis_name is not None:
+            with scope("syncbn"):
+                s1 = lax.psum(s1, axis_name)
+                s2 = lax.psum(s2, axis_name)
+    return dyf, x_hat, dbeta, dgamma, s1, s2
 
 
 def _bn_train_fused_bwd(eps, axis_name, res, cts):
@@ -251,30 +302,11 @@ def _bn_train_fused_bwd(eps, axis_name, res, cts):
     del eps  # static; backward needs only the saved residuals
     x, gamma, mean, inv, n = res
     dy, dmean_ct, dvar_ct = cts
-    zero = jax.custom_derivatives.SymbolicZero
-    if not (isinstance(dmean_ct, zero) and isinstance(dvar_ct, zero)):
-        raise TypeError(
-            "bn_mode='fused_vjp' received non-zero cotangents for the batch "
-            "mean/var outputs; its closed-form backward discards them by "
-            "contract. A loss term differentiating the batch statistics "
-            "(e.g. a stat regularizer) must use an autodiff bn_mode "
-            "('exact'/'folded') or extend _bn_train_fused_bwd."
-        )
+    zero = _refuse_stat_cotangents("bn_mode='fused_vjp'", dmean_ct, dvar_ct)
     if isinstance(dy, zero):
         # nothing differentiates y either: all three gradients vanish
         return jnp.zeros_like(x), jnp.zeros_like(gamma), jnp.zeros_like(gamma)
-    # the backward's two reductions are `bn_stats`, its elementwise pass
-    # `bn_apply`, like the forward halves they are the gradients of
-    with scope("bn_stats"):
-        dyf = dy.astype(jnp.float32)
-        x_hat = (x.astype(jnp.float32) - mean) * inv
-        dbeta = jnp.sum(dyf, axis=(0, 1, 2))
-        dgamma = jnp.sum(dyf * x_hat, axis=(0, 1, 2))
-        s1, s2 = dbeta, dgamma
-        if axis_name is not None:
-            with scope("syncbn"):
-                s1 = lax.psum(s1, axis_name)
-                s2 = lax.psum(s2, axis_name)
+    dyf, x_hat, dbeta, dgamma, s1, s2 = _bn_grad_sums(x, dy, mean, inv, axis_name)
     with scope("bn_apply"):
         dx = (gamma * inv) * (dyf - s1 / n - x_hat * (s2 / n))
         dx = dx.astype(x.dtype)
@@ -285,6 +317,116 @@ _bn_train_fused = jax.custom_vjp(_bn_train_fused, nondiff_argnums=(3, 4))
 # symbolic_zeros=True so the backward can DETECT (and reject) a real
 # cotangent on the mean/var outputs rather than silently dropping it
 _bn_train_fused.defvjp(_bn_train_fused_fwd, _bn_train_fused_bwd, symbolic_zeros=True)
+
+
+# ---------------------------------------------------------------------------
+# 1x1 conv + train-mode BatchNorm, differentiated as one pair
+# ---------------------------------------------------------------------------
+
+# the bn_modes whose forwards differ by re-association only: one backward serves them
+CONV_BN_PAIR_MODES = ("exact", "folded", "fused_vjp")
+
+
+def is_conv1x1_bn_site(conv: Conv2D) -> bool:
+    """A 1x1, stride-1, ungrouped, bias-free conv: with the BatchNorm that
+    directly follows it, a site the pair below could lower."""
+    return conv.kernel_size == 1 and conv.stride == 1 and conv.groups == 1 and not conv.use_bias
+
+
+def conv_bn_pairs(conv: Conv2D, *, train: bool, bn_mode: str, conv1x1_dot: bool = False) -> bool:
+    """Whether conv_bn() lowers this conv and its BatchNorm through the pair:
+    decided from what the site is, never by an option. The output has to be
+    WIDER than the input: the backward trades two passes over the conv's
+    output for passes over its input, each `in/out` of a wide one, so at
+    ratio 1 (a pruned supernet block shrunk to its input width) there is
+    nothing to win. The MXU-dot statistics, the bf16 normalize and
+    `conv1x1_dot` keep their own paths."""
+    return (train and is_conv1x1_bn_site(conv) and conv.out_channels > conv.in_channels
+            and bn_mode in CONV_BN_PAIR_MODES and not conv1x1_dot)
+
+
+def _conv_bn_pair(conv, eps, axis_name, mode, x, w, gamma, beta):
+    y, mean, var, _ = _conv_bn_pair_fwd_impl(conv, eps, axis_name, mode, x, w, gamma, beta)
+    return y, mean, var
+
+
+def _conv_bn_pair_fwd_impl(conv, eps, axis_name, mode, x, w, gamma, beta):
+    # the unpaired path's expressions, unchanged: the forward fuses as before
+    e = conv.apply({"w": w}, x, compute_dtype=x.dtype)
+    y, mean, var, (inv, n) = _bn_train_fused_fwd_impl(e, gamma, beta, eps, axis_name, mode)
+    return y, mean, var, (e, inv, n)
+
+
+def _conv_bn_pair_fwd(conv, eps, axis_name, mode, x, w, gamma, beta):
+    x, w, gamma, beta = x.value, w.value, gamma.value, beta.value
+    y, mean, var, (e, inv, n) = _conv_bn_pair_fwd_impl(conv, eps, axis_name, mode, x, w, gamma, beta)
+    # e is the buffer the consumer of y (the depthwise conv's backward) keeps
+    # alive anyway; everything else is the narrow input or per-channel
+    return (y, mean, var), (x, w, e, gamma, mean, inv, n)
+
+
+def _conv_bn_pair_bwd(conv, eps, axis_name, mode, res, cts):
+    """The closed-form BN backward (_bn_train_fused_bwd's contract: dγ/dβ
+    local partials, dx complete, n GLOBAL, stat cotangents refused) with
+    x̂ = (X W − mean)·inv substituted and the contractions re-associated, so
+    that neither conv gradient reads the conv's output E. With D = dy,
+    a = γ·inv, b = psum(Σ D)/n, c = psum(Σ D·x̂)/n, X flattened to (M, Cin):
+
+        S = XᵀX,  r = Xᵀ1                                 (narrow input only)
+        dW = (XᵀD)·a − r⊗(a·b) − (S W − r⊗mean)·(inv·a·c)
+        K  = (W·(inv·a·c)) Wᵀ,   k = W (a·b − mean·inv·a·c)
+        dX = D (W·a)ᵀ − X K − k
+
+    S, r and XᵀD are LOCAL sums, so dW is the local partial the step's pmean
+    (or ZeRO's psum_scatter) combines. Σ D·x̂ is the one place that still
+    needs E; it costs no pass because both sums fuse into whatever produces
+    D, which reads E for the activation's derivative (tests/test_tpu_aot.py
+    pins that on the compiled block). W is the conv's weight as the forward
+    used it (rounded to the compute dtype); the per-channel algebra is f32."""
+    del eps, mode  # static; the modes' forwards differ by re-association only
+    x, w, e, gamma, mean, inv, n = res
+    dy, dmean_ct, dvar_ct = cts
+    zero = _refuse_stat_cotangents("the conv + BatchNorm pair", dmean_ct, dvar_ct)
+    if isinstance(dy, zero):
+        return jnp.zeros_like(x), jnp.zeros_like(w), jnp.zeros_like(gamma), jnp.zeros_like(gamma)
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    _, _, dbeta, dgamma, s1, s2 = _bn_grad_sums(e, dy, mean, inv, axis_name)
+    with scope("bn_apply"):
+        a = gamma * inv
+        ab = a * (s1 / n)
+        iac = inv * a * (s2 / n)
+    with scope(conv.scope_name):
+        cin, cout = conv.in_channels, conv.out_channels
+        w2 = w.astype(x.dtype).astype(f32).reshape(cin, cout)
+        wide = jnp.concatenate([dy, x, jnp.ones(x.shape[:-1] + (1,), x.dtype)], axis=-1)
+        sums = jnp.einsum("nhwi,nhwo->io", x, wide, preferred_element_type=f32)
+        xtd, s, r = sums[:, :cout], sums[:, cout:cout + cin], sums[:, -1]
+        dw = xtd * a - jnp.outer(r, ab) - (jnp.matmul(s, w2, precision=hi) - jnp.outer(r, mean)) * iac
+        k_mat = jnp.matmul(w2 * iac, w2.T, precision=hi)
+        k_vec = jnp.matmul(w2, ab - mean * iac, precision=hi)
+        mat = jnp.concatenate([(w2 * a).T, -k_mat], axis=0).astype(x.dtype)
+        dx = jnp.einsum("nhwo,oi->nhwi", wide[..., :cout + cin], mat, preferred_element_type=f32) - k_vec
+        dx, dw = dx.astype(x.dtype), dw.reshape(w.shape).astype(w.dtype)
+    return dx, dw, dgamma, dbeta
+
+
+_conv_bn_pair = jax.custom_vjp(_conv_bn_pair, nondiff_argnums=(0, 1, 2, 3))
+_conv_bn_pair.defvjp(_conv_bn_pair_fwd, _conv_bn_pair_bwd, symbolic_zeros=True)
+
+
+def conv_bn(conv: Conv2D, bn: "BatchNorm", conv_params: dict, bn_params: dict, bn_state: dict, x: Array, *,
+            train: bool, axis_name: str | None = None, compute_dtype=jnp.float32, bn_mode: str = "exact",
+            conv1x1_dot: bool = False) -> tuple[Array, dict]:
+    """A conv directly followed by its BatchNorm: (y, new BN state). Where
+    conv_bn_pairs() says so, the two are differentiated as one pair whose
+    backward works from the gradient and the conv's INPUT alone; values are
+    those of the two applied in turn either way."""
+    if conv_bn_pairs(conv, train=train, bn_mode=bn_mode, conv1x1_dot=conv1x1_dot):
+        y, mean, var = _conv_bn_pair(conv, bn.eps, axis_name, bn_mode, x.astype(compute_dtype),
+                                     conv_params["w"], bn_params["gamma"], bn_params["beta"])
+        return y, bn.running_after(bn_state, mean, var, y, axis_name)
+    y = conv.apply(conv_params, x, compute_dtype=compute_dtype, as_dot=conv1x1_dot)
+    return bn.apply(bn_params, bn_state, y, train=train, axis_name=axis_name, mode=bn_mode)
 
 
 @dataclass(frozen=True)
@@ -365,46 +507,40 @@ class BatchNorm:
         """
         if mode not in BN_MODES:
             raise ValueError(f"unknown bn mode {mode!r}")
-        out_dtype = x.dtype
-
-        def running(mean, var, n):
-            m = self.momentum
-            unbiased = var * (n / jnp.maximum(n - 1.0, 1.0))
-            return {
-                "mean": (1.0 - m) * state["mean"] + m * mean,
-                "var": (1.0 - m) * state["var"] + m * unbiased,
-            }
-
         # every mode's work lands in one of two scopes (obs/scopes.py): the
         # batch moments and the running-stat update are `bn_stats`, the
         # normalize is `bn_apply`
         if train and mode == "fused_vjp":
             y, mean, var = _bn_train_fused(x, params["gamma"], params["beta"], self.eps, axis_name)
-            with scope("bn_stats"):
-                # lax.psum of the literal 1 is constant-folded to the axis size
-                n = jnp.asarray(x.shape[0] * x.shape[1] * x.shape[2], jnp.float32)
-                if axis_name is not None:
-                    n = n * lax.psum(1, axis_name)
-                return y, running(mean, var, n)
+            return y, self.running_after(state, mean, var, x, axis_name)
         if train:
             with scope("bn_stats"):
                 moments = _bn_moments_dot if mode in ("sdot", "compute_sdot") else _bn_moments
                 mean, var, n = moments(x, axis_name)
-                new_state = running(mean, var, n)
+                new_state = self._running(state, mean, var, n)
         else:
             mean, var = state["mean"], state["var"]
             new_state = state
         with scope("bn_apply"):
-            scale = lax.rsqrt(var + self.eps) * params["gamma"]
-            if mode == "exact":
-                y = (x.astype(jnp.float32) - mean) * scale + params["beta"]
-            elif mode in ("compute", "compute_sdot"):
-                bias = params["beta"] - mean * scale
-                y = x * scale.astype(out_dtype) + bias.astype(out_dtype)
-            else:  # "folded"/"sdot", and eval-mode "fused_vjp" (same expression)
-                bias = params["beta"] - mean * scale
-                y = x.astype(jnp.float32) * scale + bias
-            return y.astype(out_dtype), new_state
+            return _bn_normalize(x, mean, var, params["gamma"], params["beta"], self.eps, mode), new_state
+
+    def _running(self, state: dict, mean, var, n) -> dict:
+        m = self.momentum
+        unbiased = var * (n / jnp.maximum(n - 1.0, 1.0))
+        return {
+            "mean": (1.0 - m) * state["mean"] + m * mean,
+            "var": (1.0 - m) * state["var"] + m * unbiased,
+        }
+
+    def running_after(self, state: dict, mean, var, x: Array, axis_name) -> dict:
+        """The running-stat update for a custom-VJP forward, which hands back
+        the batch moments of ``x`` but not their global count."""
+        with scope("bn_stats"):
+            # lax.psum of the literal 1 is constant-folded to the axis size
+            n = jnp.asarray(x.shape[0] * x.shape[1] * x.shape[2], jnp.float32)
+            if axis_name is not None:
+                n = n * lax.psum(1, axis_name)
+            return self._running(state, mean, var, n)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ from jax import lax
 
 from ..config import Config
 from ..models.specs import Network
+from ..obs.registry import get_registry
 from ..obs.scopes import scope
 from ..ops.layers import BN_MODES
 from .ema import ema_update
@@ -217,6 +218,11 @@ def make_train_step(
         # until someone flips remat on
         raise ValueError(f"unknown train.remat_policy {cfg.train.remat_policy!r}")
     _check_bn_mode(cfg)
+    # how often ops/layers.py's conv + BN pair engages in this step: decided
+    # there from each site's shape and these two modes, reported here
+    pairs, eligible = net.conv_bn_pair_sites(bn_mode=cfg.train.bn_mode, conv1x1_dot=cfg.train.conv1x1_dot)
+    get_registry().gauge("train.conv_bn_pairs").set(pairs)
+    get_registry().gauge("train.conv_bn_pair_eligible").set(eligible)
     if cfg.train.remat:
         # recompute activations during backward: HBM for FLOPs
         # (jax.checkpoint; SURVEY.md §0 HBM-bandwidth note)
