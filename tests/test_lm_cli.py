@@ -1,0 +1,61 @@
+"""Three steps of the token family through the normal entry point, cli/train.py
+on apps/glm_4_7_flash_ep8_share.yml at a toy size (CPU): the seeded token
+source, the one step skeleton under parallel/dp.py, the log-boundary gauges,
+eval on the held-out stream, and a checkpoint that restores as a TokenModel.
+"""
+
+import json
+import os
+
+import numpy as np
+
+from yet_another_mobilenet_series_tpu.ckpt.manager import CheckpointManager
+from yet_another_mobilenet_series_tpu.cli import train as cli_train
+from yet_another_mobilenet_series_tpu.config import DataConfig
+from yet_another_mobilenet_series_tpu.data import pipeline
+from yet_another_mobilenet_series_tpu.models import TokenModel
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+APP = os.path.join(REPO, "yet_another_mobilenet_series_tpu", "apps", "glm_4_7_flash_ep8_share.yml")
+TOY = ["model.num_classes=256", "model.lm.hidden_size=64", "model.lm.num_hidden_layers=3",
+       "model.lm.num_attention_heads=4", "model.lm.q_lora_rank=24", "model.lm.kv_lora_rank=16",
+       "model.lm.qk_nope_head_dim=12", "model.lm.qk_rope_head_dim=4", "model.lm.v_head_dim=16",
+       "model.lm.intermediate_size=160", "model.lm.moe_intermediate_size=48", "model.lm.n_routed_experts=16",
+       "model.lm.num_experts_per_tok=2", "model.lm.seq_len=32"]
+
+
+def test_three_steps_through_cli_train(tmp_path):
+    log_dir = str(tmp_path / "log")
+    final = cli_train.main([f"app:{APP}", *TOY, "data.fake_train_size=6", "train.epochs=1", "train.log_every=1",
+                            f"train.log_dir={log_dir}", "dist.num_devices=1"])
+    assert final["epoch"] == 1.0 and final["eval_n"] == 2 * 2 * 32 and np.isfinite(final["eval_loss"])
+    with open(os.path.join(log_dir, "metrics.jsonl")) as f:
+        rows = [json.loads(line) for line in f if '"train/' in line]
+    assert len(rows) == 3
+    last = rows[-1]
+    assert last["train/moe_dropped"] == 0.0 and last["train/moe_assignments_here"] > 0
+    assert abs(last["train/ce"] - np.log(256)) < 0.2 and abs(last["train/ce_mtp"] - np.log(256)) < 0.2
+    assert last["train/loss"] == last["train/ce"] + 0.3 * last["train/ce_mtp"] or abs(
+        last["train/loss"] - last["train/ce"] - 0.3 * last["train/ce_mtp"]) < 1e-5
+    assert "train/gnorm/layer_1/experts" in last and "train/gnorm/mtp/eh_proj" in last
+    with open(os.path.join(log_dir, "obs_registry.json")) as f:
+        registry = json.load(f)
+    assert registry["train.moe_dropped"] == 0.0 and registry["train.moe_load_max_over_mean"] >= 1.0
+    assert registry["train.tokens_per_s"] > 0 and registry["train.moe_assignments_here"] > 0
+    mgr = CheckpointManager(log_dir + "/ckpt")
+    step, net, _ = mgr.restore_spec()
+    mgr.close()
+    assert step == 3 and isinstance(net, TokenModel) and net.vocab == 256 and net.experts_held == 2
+
+
+def test_token_batches_are_seeded_zipf_and_resume_where_they_left():
+    cfg = DataConfig(dataset="fake", loader="tokens", seq_len=30)
+    a = [b["tokens"] for _, b in zip(range(4), pipeline.token_batches(cfg, 8, 500, seed=3))]
+    b = [b["tokens"] for _, b in zip(range(2), pipeline.token_batches(cfg, 8, 500, seed=3, start_step=2))]
+    assert a[0].shape == (8, 32) and a[0].dtype == np.int32 and not np.array_equal(a[0], a[1])
+    assert np.array_equal(a[2], b[0]) and np.array_equal(a[3], b[1])  # batch i is a function of (seed, i)
+    ids = np.concatenate([x["tokens"].ravel() for x in pipeline.token_batches(cfg, 64, 500, seed=1, num_batches=20)])
+    counts = np.bincount(ids, minlength=500)
+    assert ids.min() >= 0 and ids.max() < 500
+    assert 1.7 < counts[0] / counts[1] < 2.3 and counts[0] > 5 * counts[9]  # p(id) ~ 1 / (id + 1)
+    assert len(list(pipeline.token_batches(cfg, 8, 500, seed=0, num_batches=3))) == 3

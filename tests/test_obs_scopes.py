@@ -30,6 +30,10 @@ TOY = ("model.block_specs=[{exp: 16, c: 16, n: 1, s: 2, k: 3, act: relu}, "
        "{exp: 48, c: 24, n: 2, s: 1, k: 5, act: hswish, se: 0.25}]")
 
 
+TOKEN_SCOPES = {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "moe_router", "moe_dispatch",
+                "moe_experts", "moe_combine", "mtp_merge", "lm_head"}
+
+
 def lowered_step(*overrides, chips: int = 1):
     cfg = parse_cli([f"app:{APP}", TOY, "model.num_classes=16", "model.drop_connect=0.2", "data.image_size=32",
                      f"train.batch_size={4 * chips}", f"dist.num_devices={chips}", *overrides])
@@ -103,8 +107,8 @@ def test_scope_rejects_a_name_off_the_list():
 def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
     seen = {scope for scope, _ in scopes.scope_table(toy_text).values()}
     # everything on the list except the collectives (one chip), AtomNAS (no
-    # masks, no penalty) and the guard (off)
-    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"}
+    # masks, no penalty), the guard (off) and the token models' scopes
+    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES
     assert expect <= seen, f"missing: {sorted(expect - seen)}"
     assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
 
@@ -285,6 +289,45 @@ def test_scopes_add_nothing_to_the_compiled_step(monkeypatch, overrides):
     assert _strip(with_scopes) == _strip(without)
 
 
+@pytest.mark.parametrize("overrides, digest", [
+    ((), "bc356c6ecdd1f249"), (("train.remat=true", "train.remat_policy=save_conv"), "0b8f9908387f86a2")],
+    ids=["plain", "remat_save_conv"])
+def test_the_cnn_step_is_the_program_it_was_before_the_token_family(overrides, digest):
+    """train/steps.py has ONE step skeleton for two families since PR 27. The
+    toy CNN step's lowered module (StableHLO text: no locations, no machine in
+    it) is, byte for byte, what the commit before lowered: the digests below
+    were taken there (14d60ea). A change that means to alter the CNN step
+    takes a new digest, and says so."""
+    import hashlib
+
+    text = lowered_step(*overrides).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_token_model_scopes_resolve():
+    """The token family's step on the CPU: every new scope is in its table,
+    the hand-written attention backward's under `bwd`. (What the TPU compiler
+    names itself, `ragged-dot-none.2`, is the benchmark reader's to resolve:
+    tests/benchmark_tests/test_glm_cell.py.)"""
+    from test_lm import LM, VOCAB
+
+    from yet_another_mobilenet_series_tpu.config import ModelConfig, config_from_dict
+
+    cfg = config_from_dict({"optim": {"optimizer": "adamw"}, "ema": {"enable": False},
+                            "train": {"compute_dtype": "float32", "batch_size": 2}})
+    net = get_model(ModelConfig(arch="glm4_moe_lite", num_classes=VOCAB, lm=LM))
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 2, 10, 1)
+    params_example, _ = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, params_example)
+    step = dp.make_dp_train_step(net, cfg, optimizer, lr_fn, mesh_lib.make_mesh(1), params_example=params_example)
+    ts = jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, LM.seq_len + 2), jnp.int32)}
+    text = step.lower(ts, batch, jax.ShapeDtypeStruct((2,), jnp.uint32)).compile().as_text()
+    seen = set(scopes.scope_table(text).values())
+    assert TOKEN_SCOPES | {"loss", "optim", "residual"} <= {scope for scope, _ in seen}
+    assert {("attn_core", "fwd"), ("attn_core", "bwd")} <= seen
+
+
 def test_taxonomy_version_is_pinned_to_the_scope_sites():
     """The compile cache's key carries TAXONOMY_VERSION (utils/compile_cache.py)
     because JAX leaves metadata out of it: a scope added, renamed or moved
@@ -299,11 +342,17 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
                     found = re.findall(r'\bscope\((?:(?:self|conv)\.scope_name|"(\w+)")\)', f.read())
                 if found:
                     sites[os.path.relpath(os.path.join(root, name), pkg)] = sorted(found)
-    assert (scopes.TAXONOMY_VERSION, sites) == (2, {
+    assert (scopes.TAXONOMY_VERSION, sites) == (4, {
+        # versions 3 and 4: the token-model family's scopes (PR 27; 3 was its first draft, whose
+        # executables may still sit in a chip machine's cache)
+        "models/lm.py": ["embed", "lm_head", "loss", "loss", "moe_combine", "moe_router", "mtp_merge", "residual",
+                         "residual", "residual", "rope"],
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
         # version 2: the conv + BN pair's forward and custom backward (PR 26)
+        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "mlp", "moe_combine",
+                      "moe_dispatch", "moe_experts", "moe_router", "norm", "rope"],
         "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],

@@ -39,7 +39,7 @@ import numpy as np
 from ..ckpt.manager import CheckpointCorrupt, CheckpointManager
 from ..config import Config, parse_cli
 from .. import data as data_lib
-from ..models import get_model
+from ..models import TokenModel, get_model
 from ..models.specs import Network
 from ..nas import masking, penalty, rematerialize
 from ..obs import device as obs_device
@@ -414,6 +414,8 @@ def run(cfg: Config) -> dict:
         jax.distributed.initialize()
     if cfg.data.dataset == "fake" and cfg.data.fake_num_classes is None:
         cfg = dc.replace(cfg, data=dc.replace(cfg.data, fake_num_classes=cfg.model.num_classes))
+    if cfg.data.loader == "tokens" and not cfg.data.seq_len:
+        cfg = dc.replace(cfg, data=dc.replace(cfg.data, seq_len=cfg.model.lm.seq_len))
     is_coord = mesh_lib.is_coordinator()
     log = Logger(cfg.train.log_dir, enabled=is_coord, tensorboard=bool(cfg.train.log_dir))
     mesh = mesh_lib.make_mesh(cfg.dist.num_devices)
@@ -464,9 +466,15 @@ def run(cfg: Config) -> dict:
 
 def _run_impl(cfg: Config, log: Logger, mesh, is_coord: bool, tracer, watchdog) -> dict:
     net = get_model(cfg.model, cfg.data.image_size)
-    prof = profile_network(net)
-    arch_name = cfg.model.network_spec or f"{cfg.model.arch} x{cfg.model.width_mult}"
-    log.log(f"model {arch_name}: {prof.total_params/1e6:.2f}M params, {prof.total_macs/1e6:.1f}M MACs")
+    if isinstance(net, TokenModel):
+        log.log(f"model {net.arch}: {net.param_count()/1e6:.2f}M params held here "
+                f"({net.experts_held} of {net.lm.n_routed_experts} experts a layer, share "
+                f"{net.lm.expert_share_index} of {net.lm.expert_shares}; {net.vocab} vocabulary rows; "
+                f"{net.lm.seq_len} tokens a sequence)")
+    else:
+        prof = profile_network(net)
+        arch_name = cfg.model.network_spec or f"{cfg.model.arch} x{cfg.model.width_mult}"
+        log.log(f"model {arch_name}: {prof.total_params/1e6:.2f}M params, {prof.total_macs/1e6:.1f}M MACs")
     reg = obs_registry.get_registry()
 
     ckpt = CheckpointManager(
@@ -519,7 +527,7 @@ def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger
     # where the work is: rows of this batch resident on each local device.
     # Read beside device.bytes_in_use.d<i>, it tells a data-parallel step
     # from one that runs on a single chip of the mesh.
-    for shard in batch["image"].addressable_shards:
+    for shard in jax.tree.leaves(batch)[0].addressable_shards:
         reg.gauge(f"train.batch_rows.d{shard.device.id}").set(shard.data.shape[0])
     try:
         with tracer.span("dispatch/cost_analysis", "dispatch"):
@@ -760,6 +768,14 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                         with tracer.span("sync/log_metrics", "sync", step=step_i):
                             snap = metric_log.snapshot_and_reset(num_chips=trainer.mesh.size)
                         reg.gauge("train.step").set(step_i)
+                        if isinstance(trainer.net, TokenModel):
+                            # a sequence is this loop's "image"; the expert layer's counters
+                            # (ops/lm.py) are step scalars like any other
+                            reg.gauge("train.tokens_per_s").set(
+                                snap.get("images_per_sec", 0.0) * trainer.net.lm.seq_len)
+                            for name in ("moe_assignments_here", "moe_load_max_over_mean", "moe_dropped"):
+                                if name in snap:
+                                    reg.gauge("train." + name).set(snap[name])
                         if cfg.prune.enable:
                             snap["effective_macs"] = masking.mask_summary(trainer.net, ts.masks)["effective_macs"]
                             if cfg.prune.rho_schedule == "adaptive":

@@ -97,6 +97,52 @@ class ModelConfig:
     active_fn: str | None = None
     # If true, classifier bias is zero-initialized (standard).
     dtype: str = "float32"  # param dtype; compute may be bf16 (train.compute_dtype)
+    # shapes of a token model; read only by the archs of models/lm.py
+    lm: LMConfig = field(default_factory=lambda: LMConfig())
+
+
+@dataclass(frozen=True)
+class LMConfig:
+    """Shapes of a token model (``model.arch: glm4_moe_lite``; models/lm.py),
+    under the keys of the published ``config.json``. The defaults are
+    GLM-4.7-Flash's widths. ``model.num_classes`` is the number of vocabulary
+    rows held here (embedding, head, token ids and the loss are over that
+    slice).
+
+    The model is ONE SHARE of an expert-parallel deployment: the router keeps
+    its published width ``n_routed_experts`` and its ``num_experts_per_tok``;
+    this share holds ``n_routed_experts / expert_shares`` experts of every
+    expert layer, those of index ``expert_share_index``, and computes their
+    part of the result. What the absent experts would add is left out."""
+
+    hidden_size: int = 2048
+    # dense + expert layers held here (the MTP module is counted apart)
+    num_hidden_layers: int = 5
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    num_nextn_predict_layers: int = 1  # 0 or 1 multi-token-prediction module
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    expert_shares: int = 8
+    expert_share_index: int = 0
+    # tokens of one sequence; a batch row carries seq_len + 2 ids (the next
+    # and the next-but-one token are the two heads' targets)
+    seq_len: int = 8192
+    mtp_loss_weight: float = 0.3
+    # the router's selection bias moves by rate * sign(mean load - load) a step
+    router_bias_rate: float = 1e-3
+    init_std: float = 0.02
 
 
 @dataclass(frozen=True)
@@ -114,7 +160,11 @@ class DataConfig:
     fake_train_size: int = 6400
     fake_eval_size: int = 640
     # input pipeline
-    loader: str = "tfdata"  # tfdata | native | synthetic
+    loader: str = "tfdata"  # tfdata | native | synthetic | tokens
+    # loader=tokens (token models; data/pipeline.py token_batches): ids of one
+    # row, before the two target ids (0 = model.lm.seq_len, filled in by
+    # cli/train.py), drawn by a Zipf law over the vocabulary
+    seq_len: int = 0
     shuffle_buffer: int = 16384
     prefetch: int = 4  # host-side tf.data prefetch depth
     # device-HBM prefetch depth (batches pinned on the mesh ahead of compute;
@@ -174,6 +224,9 @@ class DataConfig:
 @dataclass(frozen=True)
 class OptimConfig:
     optimizer: str = "rmsprop"  # rmsprop | sgd | adamw
+    # adamw only; the defaults are optax.scale_by_adam's
+    adam_b1: float = 0.9
+    adam_b2: float = 0.999
     momentum: float = 0.9
     # TF-style RMSProp constants (eps inside the sqrt; SURVEY.md §7 hard part 2)
     rmsprop_decay: float = 0.9
@@ -1122,6 +1175,7 @@ def _build(dc_type, data: Mapping[str, Any], path: str = ""):
 
 _SECTION_TYPES = {
     "ModelConfig": ModelConfig,
+    "LMConfig": LMConfig,
     "DataConfig": DataConfig,
     "OptimConfig": OptimConfig,
     "ScheduleConfig": ScheduleConfig,

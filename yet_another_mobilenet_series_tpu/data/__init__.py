@@ -9,6 +9,8 @@ Valid combinations:
   dataset=imagenet + loader=tfdata   -> TFRecord shards via tf.data
   dataset=fake     + loader=tfdata   -> synthetic learnable data
   dataset=folder   + loader=native   -> ImageFolder tree via native/ C++
+  dataset=fake     + loader=synthetic -> one device-resident image batch
+  dataset=fake     + loader=tokens   -> seeded Zipf token ids (token models)
 """
 
 from __future__ import annotations
@@ -20,7 +22,8 @@ from . import pipeline as _pipeline
 
 
 def _check(cfg: DataConfig) -> None:
-    ok = {("imagenet", "tfdata"), ("fake", "tfdata"), ("folder", "native"), ("fake", "synthetic")}
+    ok = {("imagenet", "tfdata"), ("fake", "tfdata"), ("folder", "native"), ("fake", "synthetic"),
+          ("fake", "tokens")}
     if (cfg.dataset, cfg.loader) not in ok:
         raise ValueError(
             f"unsupported data config: dataset={cfg.dataset!r} loader={cfg.loader!r}; valid: {sorted(ok)}"
@@ -80,6 +83,10 @@ def make_train_source(cfg: DataConfig, local_batch: int, seed: int, process_inde
         # position-independent by construction (the same device-resident
         # batch forever) — nothing to skip
         src = _pipeline.synthetic_device_batches(cfg, local_batch, cfg.fake_num_classes or 1000)
+    elif cfg.loader == "tokens":
+        # each host its own stream, continued at the restored step
+        src = _pipeline.token_batches(cfg, local_batch, cfg.fake_num_classes or 1000,
+                                      seed + 7919 * process_index, start_step=start_step)
     else:
         ds = _pipeline.make_train_dataset(cfg, local_batch, seed, process_index, process_count,
                                           start_step=start_step)
@@ -119,5 +126,10 @@ def make_eval_source(cfg: DataConfig, local_batch: int, process_index: int = 0, 
                     ) from None
 
         return gen()
+    if cfg.loader == "tokens":
+        # a held-out stream (another seed), the same number of batches on every host
+        batches = max(cfg.fake_eval_size // max(local_batch * process_count, 1), 1)
+        return _pipeline.token_batches(cfg, local_batch, cfg.fake_num_classes or 1000,
+                                       0x6576616C + process_index, num_batches=batches)
     ds = _pipeline.make_eval_dataset(cfg, local_batch, process_index, process_count)
     return _pipeline.as_numpy(ds)

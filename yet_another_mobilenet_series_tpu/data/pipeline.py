@@ -673,3 +673,29 @@ def synthetic_device_batches(cfg: DataConfig, local_batch: int, num_classes: int
     }
     while True:
         yield batch
+
+
+def zipf_cdf(vocab: int) -> np.ndarray:
+    """Cumulative probabilities of ids 0..vocab-1 under Zipf's law, p(id) ~ 1 / (id + 1)."""
+    weights = 1.0 / np.arange(1, vocab + 1, dtype=np.float64)
+    return np.cumsum(weights / weights.sum())
+
+
+def token_batches(cfg: DataConfig, local_batch: int, vocab: int, seed: int, *, start_step: int = 0,
+                  num_batches: int | None = None) -> Iterator[dict]:
+    """The device-batch loader's token form: {'tokens': (local_batch,
+    seq_len + 2) int32}, each row one document of ids drawn independently by
+    Zipf's law over the vocabulary slice (a token model's two heads read the
+    next and the next-but-one id as targets, hence + 2). Seeded per step, so
+    a resumed run continues the stream: batch i is a function of (seed, i).
+    What there is to learn is the unigram distribution, which is enough for
+    a falling loss; `num_batches` makes the finite eval pass."""
+    if cfg.seq_len <= 0:
+        raise ValueError("data.loader=tokens needs data.seq_len (cli/train.py fills it from model.lm.seq_len)")
+    cdf = zipf_cdf(vocab)
+    step = start_step
+    while num_batches is None or step < start_step + num_batches:
+        rng = np.random.default_rng([seed, step])
+        ids = np.searchsorted(cdf, rng.random((local_batch, cfg.seq_len + 2)), side="right")
+        yield {"tokens": np.minimum(ids, vocab - 1).astype(np.int32)}
+        step += 1

@@ -5,14 +5,18 @@ from __future__ import annotations
 import dataclasses
 
 from ..config import ModelConfig
+from .lm import LM_ARCHS, TokenModel, token_model
 from .specs import ArchDef, Network, build_network
 from .zoo import ARCHS, get_arch
 
-__all__ = ["ArchDef", "Network", "build_network", "get_arch", "get_model", "ARCHS"]
+__all__ = ["ArchDef", "Network", "TokenModel", "build_network", "get_arch", "get_model", "ARCHS", "LM_ARCHS"]
 
 
-def get_model(cfg: ModelConfig, image_size: int = 224) -> Network:
-    """Resolve a ModelConfig into a concrete Network spec."""
+def get_model(cfg: ModelConfig, image_size: int = 224) -> Network | TokenModel:
+    """Resolve a ModelConfig into a concrete Network spec, or, for an arch of
+    `LM_ARCHS`, into a TokenModel (models/lm.py; `image_size` is not read)."""
+    if cfg.arch in LM_ARCHS and not cfg.network_spec:
+        return token_model(cfg)
     if cfg.network_spec:
         # a serialized Network (e.g. searched_arch.json emitted by an AtomNAS
         # run) IS the architecture; classifier width must match num_classes

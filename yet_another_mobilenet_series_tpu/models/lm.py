@@ -1,0 +1,296 @@
+"""The token-model family beside `specs.Network`: `glm4_moe_lite`
+(GLM-4.7-Flash): token embedding, dense blocks then expert blocks of
+multi-head latent attention, one multi-token-prediction module, an untied
+head, cross-entropy over the vocabulary slice held here.
+
+A `TokenModel` is one SHARE of an expert-parallel deployment (config.LMConfig):
+attention is whole, each expert layer holds `experts_held` of the
+`n_routed_experts` the router scores, embedding and head hold `vocab` rows.
+On one chip the share runs without an exchange, and computes exactly its own
+part: ops/lm.py `expert_layer`.
+
+The train step takes the family through three methods where it takes a
+`Network` through `apply` (train/steps.py): `init`, `loss` (tokens in,
+`(loss, (new_state, scalars))` out) and `eval_counts`. The only state is the
+router's selection bias of each expert layer, which the forward moves once a
+step from the step's own counts: non-gradient state, carried where a CNN
+carries its BatchNorm statistics. The plain float32 reference of the same
+equations is models/lm_reference.py, which shares no function with this file.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import zlib
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..config import LMConfig, ModelConfig
+from ..obs.scopes import scope
+from ..ops import lm as ops
+
+LM_ARCHS = ("glm4_moe_lite",)
+# Tokens whose logits over the vocabulary slice are held at once (each such block is a jax.checkpoint).
+LOSS_BLOCK = 2048
+
+
+def _key(name: str) -> int:
+    """A fold_in constant per parameter name, so that a tensor's draw does not
+    depend on which other tensors exist (the reference's init reads the same
+    tree, not the same stream)."""
+    return zlib.crc32(name.encode()) & 0x7FFFFFFF
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenModel:
+    arch: str
+    vocab: int  # rows of the vocabulary held here
+    lm: LMConfig
+
+    # ---- shapes -----------------------------------------------------------
+
+    @property
+    def experts_held(self) -> int:
+        return self.lm.n_routed_experts // self.lm.expert_shares
+
+    @property
+    def block_names(self) -> tuple[str, ...]:
+        """Main blocks in order, then the MTP module's block."""
+        names = tuple(f"layer_{i}" for i in range(self.lm.num_hidden_layers))
+        return names + (("mtp",) if self.lm.num_nextn_predict_layers else ())
+
+    def is_dense(self, block: str) -> bool:
+        return block != "mtp" and int(block.split("_")[1]) < self.lm.first_k_dense_replace
+
+    def validate(self) -> None:
+        c = self.lm
+        if c.n_routed_experts % c.expert_shares or not 0 <= c.expert_share_index < c.expert_shares:
+            raise ValueError(f"{c.n_routed_experts} experts do not divide into {c.expert_shares} shares "
+                             f"with a share of index {c.expert_share_index}")
+        if c.num_nextn_predict_layers not in (0, 1):
+            raise ValueError("num_nextn_predict_layers is 0 or 1")
+        if c.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+        if not 0 < c.first_k_dense_replace <= c.num_hidden_layers:
+            raise ValueError("first_k_dense_replace must be in [1, num_hidden_layers]")
+
+    def param_count(self) -> int:
+        shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0))[0])
+        return sum(int(x.size) for x in jax.tree.leaves(shapes))
+
+    # ---- parameters and state --------------------------------------------
+
+    def _init_block(self, key, block: str) -> dict:
+        c = self.lm
+        h, heads = c.hidden_size, c.num_attention_heads
+
+        def w(name, *shape):
+            return c.init_std * jax.random.normal(jax.random.fold_in(key, _key(name)), shape, jnp.float32)
+
+        def mlp(name, width, *lead):
+            return {"gate": w(name + "g", *lead, h, width), "up": w(name + "u", *lead, h, width),
+                    "down": w(name + "d", *lead, width, h)}
+
+        p = {
+            "attn_norm": jnp.ones((h,), jnp.float32),
+            "mlp_norm": jnp.ones((h,), jnp.float32),
+            "attn": {
+                "q_a": w("q_a", h, c.q_lora_rank),
+                "q_norm": jnp.ones((c.q_lora_rank,), jnp.float32),
+                "q_b": w("q_b", c.q_lora_rank, heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
+                "kv_a": w("kv_a", h, c.kv_lora_rank + c.qk_rope_head_dim),
+                "kv_norm": jnp.ones((c.kv_lora_rank,), jnp.float32),
+                "kv_b": w("kv_b", c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim)),
+                "o": w("o", heads * c.v_head_dim, h),
+            },
+        }
+        if self.is_dense(block):
+            p["mlp"] = mlp("mlp", c.intermediate_size)
+        else:
+            p["router"] = w("rout", h, c.n_routed_experts)
+            p["shared"] = mlp("shar", c.moe_intermediate_size * c.n_shared_experts)
+            p["experts"] = mlp("exp", c.moe_intermediate_size, self.experts_held)
+        return p
+
+    def init(self, key) -> tuple[dict, dict]:
+        """(params, state): float32 weights ~ N(0, init_std), norm gains 1;
+        state = each expert block's router bias, zeros."""
+        self.validate()
+        c = self.lm
+        h = c.hidden_size
+        params: dict[str, Any] = {
+            "embed": c.init_std * jax.random.normal(jax.random.fold_in(key, _key("embed")), (self.vocab, h)),
+            "head": c.init_std * jax.random.normal(jax.random.fold_in(key, _key("head")), (h, self.vocab)),
+            "final_norm": jnp.ones((h,), jnp.float32),
+        }
+        for i, block in enumerate(self.block_names):
+            params[block] = self._init_block(jax.random.fold_in(key, 1000 + i), block)
+        if c.num_nextn_predict_layers:
+            params["mtp"].update(
+                eh_proj=c.init_std * jax.random.normal(jax.random.fold_in(key, _key("eh")), (2 * h, h)),
+                h_norm=jnp.ones((h,), jnp.float32), e_norm=jnp.ones((h,), jnp.float32),
+                final_norm=jnp.ones((h,), jnp.float32))
+        state = {block: {"router_bias": jnp.zeros((c.n_routed_experts,), jnp.float32)}
+                 for block in self.block_names if not self.is_dense(block)}
+        return params, state
+
+    # ---- forward ----------------------------------------------------------
+
+    def _block(self, block: str, p: dict, bias, x, cos, sin):
+        c = self.lm
+        a = ops.mla_attention(
+            p["attn"], ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps), cos, sin,
+            heads=c.num_attention_heads, nope=c.qk_nope_head_dim, rope=c.qk_rope_head_dim,
+            v_dim=c.v_head_dim, kv_rank=c.kv_lora_rank, eps=c.rms_norm_eps)
+        with scope("residual"):
+            x = x + a
+        y = ops.rms_norm(x, p["mlp_norm"], c.rms_norm_eps)
+        if self.is_dense(block):
+            out = ops.gated_mlp(p["mlp"], y)
+            with scope("residual"):
+                return x + out, None
+        routed, load, counters, ids = ops.expert_layer(
+            p, bias, y, top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
+            held=self.experts_held, share_index=c.expert_share_index)
+        shared = ops.gated_mlp(p["shared"], y)
+        with scope("residual"):
+            return x + shared + routed, (load, counters, ids)
+
+    def _head_loss(self, head_w, hidden, targets):
+        """Summed cross-entropy, and how many targets rank first and among the
+        first five, over a (tokens, h) block: float32 logits over the slice,
+        never more than `LOSS_BLOCK` tokens of them at once."""
+        tokens = hidden.shape[0]
+        block = min(LOSS_BLOCK, tokens)
+        if tokens % block:
+            raise ValueError(f"{tokens} tokens are not a multiple of the loss block {block}")
+
+        def chunk(carry, xs):
+            hid, tgt = xs
+            with scope("lm_head"):
+                logits = jnp.dot(hid, head_w.astype(hid.dtype), preferred_element_type=jnp.float32)
+            with scope("loss"):
+                own = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+                nll = jax.nn.logsumexp(logits, axis=-1) - own
+                above = jnp.sum(lax.stop_gradient(logits) > lax.stop_gradient(own)[:, None], axis=-1)
+                out = jnp.stack([jnp.sum(nll), jnp.sum(above < 1).astype(jnp.float32),
+                                 jnp.sum(above < 5).astype(jnp.float32)])
+            return carry + out, None
+
+        chunk = jax.checkpoint(chunk)
+        xs = (hidden.reshape(tokens // block, block, -1), targets.reshape(tokens // block, block))
+        totals, _ = lax.scan(chunk, jnp.zeros((3,), jnp.float32), xs)
+        return totals
+
+    def forward(self, params, state, tokens, *, compute_dtype=jnp.float32, axis_name: str | None = None):
+        """tokens (B, seq_len + 2) -> (per-head totals {head: [nll sum, top-1,
+        top-5]}, new_state, counters, selected). Head `main` predicts token
+        i + 1 from tokens[..i], head `mtp` token i + 2 from the main model's
+        last hidden state at i and the embedding of token i + 1. new_state:
+        each router bias after the sign rule's one move. selected: each
+        expert block's chosen expert ids, (B * seq_len, top_k)."""
+        c = self.lm
+        seq = tokens.shape[1] - 2
+        if seq != c.seq_len:
+            raise ValueError(f"a batch row holds {tokens.shape[1]} ids, model.lm.seq_len + 2 = {c.seq_len + 2} expected")
+        with scope("rope"):
+            cos, sin = ops.rope_tables(seq, c.qk_rope_head_dim, c.rope_theta)
+        with scope("embed"):
+            # one gather for both heads' inputs: positions 0..seq of every row
+            # (float32 rows, then the cast: a frequent token's gradient is summed in float32)
+            emb = params["embed"][tokens[:, :seq + 1]].astype(compute_dtype)
+
+        def run(block, x, p, bias):
+            fn = lambda x_, p_, b_: self._block(block, p_, b_, x_, cos, sin)  # noqa: E731
+            return jax.checkpoint(fn)(x, p, bias)  # a layer keeps its input; the backward recomputes the rest
+
+        new_state, selected, per_block = {}, {}, []
+
+        def through(block, x):
+            bias = state[block]["router_bias"] if block in state else None
+            x, routed = run(block, x, params[block], bias)
+            if routed is not None:
+                load, counters, selected[block] = routed
+                with scope("moe_router"):
+                    if axis_name is not None:
+                        load = lax.psum(load, axis_name)
+                    new_state[block] = {"router_bias": bias + c.router_bias_rate * jnp.sign(jnp.mean(load) - load)}
+                per_block.append(counters)
+            return x
+
+        x = emb[:, :seq]
+        for block in self.block_names:
+            if block != "mtp":
+                x = through(block, x)
+        heads = {}
+        hidden = ops.rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        heads["main"] = self._head_loss(params["head"], hidden.reshape(-1, c.hidden_size),
+                                        tokens[:, 1:seq + 1].reshape(-1))
+        if c.num_nextn_predict_layers:
+            m = params["mtp"]
+            with scope("mtp_merge"):
+                merged = jnp.concatenate([ops.rms_norm(x, m["h_norm"], c.rms_norm_eps),
+                                          ops.rms_norm(emb[:, 1:], m["e_norm"], c.rms_norm_eps)], axis=-1)
+                y = merged @ m["eh_proj"].astype(compute_dtype)
+            y = through("mtp", y)
+            hidden = ops.rms_norm(y, m["final_norm"], c.rms_norm_eps)
+            heads["mtp"] = self._head_loss(params["head"], hidden.reshape(-1, c.hidden_size),
+                                           tokens[:, 2:seq + 2].reshape(-1))
+        with scope("moe_combine"):
+            counters = {
+                "moe_assignments_here": sum(b["assignments_here"] for b in per_block),
+                "moe_dropped": sum(b["dropped"] for b in per_block),
+                "moe_load_max_over_mean": jnp.max(jnp.stack([b["load_max_over_mean"] for b in per_block])),
+            } if per_block else {}
+        return heads, new_state, counters, selected
+
+    def loss(self, params, state, batch, *, compute_dtype=jnp.float32, axis_name: str | None = None):
+        """The train step's loss: `(loss, (new_state, scalars))`, loss =
+        CE_main + mtp_loss_weight * CE_mtp, each the mean over this shard's
+        tokens."""
+        tokens = batch["tokens"]
+        heads, new_state, counters, _ = self.forward(params, state, tokens, compute_dtype=compute_dtype,
+                                                     axis_name=axis_name)
+        with scope("loss"):
+            n = jnp.asarray(tokens.shape[0] * self.lm.seq_len, jnp.float32)
+            ce = heads["main"][0] / n
+            scalars = {"ce": ce, "top1": heads["main"][1] / n, **counters}
+            loss = ce
+            if "mtp" in heads:
+                scalars["ce_mtp"] = heads["mtp"][0] / n
+                loss = ce + self.lm.mtp_loss_weight * scalars["ce_mtp"]
+        return loss, (new_state, scalars)
+
+    def eval_counts(self, params, state, batch, *, compute_dtype=jnp.float32) -> dict:
+        """The main head's summed counts over a batch, in the form the eval
+        loop adds up: top1, top5, n, loss_sum."""
+        heads = self.forward(params, state, batch["tokens"], compute_dtype=compute_dtype)[0]
+        nll, top1, top5 = heads["main"]
+        n = jnp.asarray(batch["tokens"].shape[0] * self.lm.seq_len, jnp.float32)
+        return {"top1": top1, "top5": top5, "n": n, "loss_sum": nll}
+
+    def grad_scalars(self, grads: dict) -> dict:
+        """Gradient norms by group, as step scalars: embedding, head, `W_eh`,
+        and per block its attention, router, held experts, shared or dense
+        MLP and norm gains. What the benchmark holds against the reference."""
+        def norm(tree):
+            return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(tree)))
+
+        out = {"gnorm/embed": norm(grads["embed"]), "gnorm/head": norm(grads["head"]),
+               "gnorm/final_norm": norm(grads["final_norm"])}
+        for block in self.block_names:
+            g = grads[block]
+            for name in ("attn", "mlp", "router", "shared", "experts", "eh_proj"):
+                if name in g:
+                    out[f"gnorm/{block}/{name}"] = norm(g[name])
+            out[f"gnorm/{block}/norms"] = norm([v for k, v in g.items() if k.endswith("norm")])
+        return out
+
+
+def token_model(cfg: ModelConfig) -> TokenModel:
+    model = TokenModel(arch=cfg.arch, vocab=cfg.num_classes, lm=cfg.lm)
+    model.validate()
+    return model

@@ -1,0 +1,159 @@
+"""The plain reference of the `glm4_moe_lite` family (GLM-4.7-Flash): forward,
+loss and, through `jax.grad`, gradients, in straightforward `jax.numpy`,
+float32, under `jax.default_matmul_precision("highest")`.
+
+It follows the published layer equations and shares no function with the
+program (models/lm.py, ops/lm.py). Where the program is clever this is not:
+keys and values are expanded per head, with the one shared `k_rope` head
+repeated; the causal mask is a dense S x S array; the held experts are a
+Python loop in which every expert sees every token under a 0/1 mask; nothing
+is sorted, blocked or recomputed. It reads the program's parameter tree
+(`TokenModel.init`) and a plain dict of sizes (:func:`dims_of`).
+
+Given a share (`expert_shares`, `expert_share_index`) it leaves out the same
+absent experts as the program; with `expert_shares=1` it is the uncut layer.
+
+Departures from the published description: none in the equations. Not in
+`config.json`, and therefore assumed (the configuration's file lists them):
+the RoPE pairing (channel i with i + d/2), the MTP loss weight, the bias
+update rate.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+DIM_KEYS = ("hidden_size", "num_hidden_layers", "first_k_dense_replace", "num_attention_heads", "kv_lora_rank",
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "n_routed_experts", "num_experts_per_tok",
+            "routed_scaling_factor", "num_nextn_predict_layers", "rms_norm_eps", "rope_theta", "expert_shares",
+            "expert_share_index", "mtp_loss_weight", "router_bias_rate")
+
+
+def dims_of(lm_config) -> dict:
+    """The sizes the reference reads, from anything with those attributes."""
+    return {k: getattr(lm_config, k) for k in DIM_KEYS}
+
+
+def rms_norm(x, gain, eps):
+    return x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
+
+
+def rope(x, theta):
+    """x (S, heads, d): position s rotates the pair (i, i + d/2) by s * theta^(-2i/d)."""
+    seq, _, d = x.shape
+    half = d // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None, None] * freq[None, None, :]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle), b * jnp.cos(angle) + a * jnp.sin(angle)], -1)
+
+
+def mla(p, x, d):
+    """One sequence x (S, h) through multi-head latent attention."""
+    seq = x.shape[0]
+    heads, nope, rope_d, v_d = (d["num_attention_heads"], d["qk_nope_head_dim"], d["qk_rope_head_dim"],
+                                d["v_head_dim"])
+    c_q = rms_norm(x @ p["q_a"], p["q_norm"], d["rms_norm_eps"])
+    q = (c_q @ p["q_b"]).reshape(seq, heads, nope + rope_d)
+    kv_a = x @ p["kv_a"]
+    c_kv = rms_norm(kv_a[:, :d["kv_lora_rank"]], p["kv_norm"], d["rms_norm_eps"])
+    kv = (c_kv @ p["kv_b"]).reshape(seq, heads, nope + v_d)
+    q_rope = rope(q[..., nope:], d["rope_theta"])
+    k_rope = rope(kv_a[:, None, d["kv_lora_rank"]:], d["rope_theta"])  # one head
+    k_rope = jnp.repeat(k_rope, heads, axis=1)  # ... shared by all
+    k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)  # per-head keys (S, heads, nope + rope)
+    q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+    scores = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(nope + rope_d)
+    mask = jnp.tril(jnp.ones((seq, seq), bool))  # dense S x S
+    probs = jax.nn.softmax(jnp.where(mask[None], scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("hqk,khd->qhd", probs, kv[..., nope:])
+    return out.reshape(seq, heads * v_d) @ p["o"]
+
+
+def gated_mlp(gate, up, down, x):
+    return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+
+
+def experts(p, bias, x, d):
+    """(routed output of this share (S, h), assignments per expert (E,))."""
+    n, k = d["n_routed_experts"], d["num_experts_per_tok"]
+    held = n // d["expert_shares"]
+    first = d["expert_share_index"] * held
+    scores = jax.nn.sigmoid(x @ p["router"])
+    _, chosen = jax.lax.top_k(scores + bias, k)  # selection: scores + bias
+    chosen = jax.nn.one_hot(chosen, n).sum(axis=1)  # (S, E) 0/1
+    weight = chosen * scores  # weights: the scores themselves
+    weight = weight / weight.sum(axis=-1, keepdims=True) * d["routed_scaling_factor"]
+    out = jnp.zeros_like(x)
+    for j in range(held):
+        e = p["experts"]
+        out = out + weight[:, first + j, None] * gated_mlp(e["gate"][j], e["up"][j], e["down"][j], x)
+    return out, chosen.sum(axis=0)
+
+
+def block(p, bias, x, d, dense):
+    x = x + mla(p["attn"], rms_norm(x, p["attn_norm"], d["rms_norm_eps"]), d)
+    y = rms_norm(x, p["mlp_norm"], d["rms_norm_eps"])
+    if dense:
+        return x + gated_mlp(p["mlp"]["gate"], p["mlp"]["up"], p["mlp"]["down"], y), None
+    routed, load = experts(p, bias, y, d)
+    return x + gated_mlp(p["shared"]["gate"], p["shared"]["up"], p["shared"]["down"], y) + routed, load
+
+
+def cross_entropy(logits, targets):
+    return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - logits[jnp.arange(targets.shape[0]), targets])
+
+
+def sequence(params, state, ids, d):
+    """One row of seq + 2 ids -> (main logits (S, V), MTP logits or None, load by expert block)."""
+    seq = ids.shape[0] - 2
+    loads = {}
+    x = params["embed"][ids[:seq]]
+    for i in range(d["num_hidden_layers"]):
+        name = f"layer_{i}"
+        dense = i < d["first_k_dense_replace"]
+        x, load = block(params[name], None if dense else state[name]["router_bias"], x, d, dense)
+        if load is not None:
+            loads[name] = load
+    main = rms_norm(x, params["final_norm"], d["rms_norm_eps"]) @ params["head"]
+    mtp = None
+    if d["num_nextn_predict_layers"]:
+        m = params["mtp"]
+        merged = jnp.concatenate([rms_norm(x, m["h_norm"], d["rms_norm_eps"]),
+                                  rms_norm(params["embed"][ids[1:seq + 1]], m["e_norm"], d["rms_norm_eps"])], -1)
+        y, loads["mtp"] = block(m, state["mtp"]["router_bias"], merged @ m["eh_proj"], d, False)
+        mtp = rms_norm(y, m["final_norm"], d["rms_norm_eps"]) @ params["head"]
+    return main, mtp, loads
+
+
+def loss_and_aux(params, state, tokens, d):
+    """tokens (B, S + 2) -> (loss, {"ce", "ce_mtp", "new_state", "logits",
+    "mtp_logits"}): loss = CE_main + mtp_loss_weight * CE_mtp, means over all
+    B * S tokens; new_state = the router biases after the sign rule."""
+    with jax.default_matmul_precision("highest"):
+        seq = tokens.shape[1] - 2
+        n = tokens.shape[0] * seq
+        ce = ce_mtp = 0.0
+        loads: dict = {}
+        logits, mtp_logits = [], []
+        for ids in tokens:
+            main, mtp, load = sequence(params, state, ids, d)
+            ce = ce + cross_entropy(main, ids[1:seq + 1]) / n
+            if mtp is not None:
+                ce_mtp = ce_mtp + cross_entropy(mtp, ids[2:seq + 2]) / n
+                mtp_logits.append(mtp)
+            logits.append(main)
+            loads = {k: loads.get(k, 0.0) + v for k, v in load.items()}
+        new_state = {k: {"router_bias": state[k]["router_bias"]
+                         + d["router_bias_rate"] * jnp.sign(jnp.mean(v) - v)} for k, v in loads.items()}
+        loss = ce + d["mtp_loss_weight"] * ce_mtp
+        return loss, {"ce": ce, "ce_mtp": ce_mtp, "new_state": new_state, "loads": loads,
+                      "logits": jnp.stack(logits), "mtp_logits": jnp.stack(mtp_logits) if mtp_logits else None}
+
+
+def loss_and_grads(params, state, tokens, d):
+    """((loss, aux), gradients of the loss by parameter)."""
+    return jax.value_and_grad(loss_and_aux, has_aux=True)(params, state, tokens, d)

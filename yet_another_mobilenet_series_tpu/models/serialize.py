@@ -12,8 +12,12 @@ from __future__ import annotations
 
 from typing import Any
 
+import dataclasses
+
+from ..config import LMConfig
 from ..ops.blocks import ConvBNAct, InvertedResidual
 from ..ops.layers import Dense
+from .lm import TokenModel
 from .specs import Network
 
 # v2 adds the ``inference`` marker: True means the weight tree next to the
@@ -68,7 +72,11 @@ def _dense_to_dict(d: Dense) -> dict:
     return {"in_features": d.in_features, "out_features": d.out_features, "use_bias": d.use_bias, "init_std": d.init_std}
 
 
-def network_to_dict(net: Network, *, inference: bool = False) -> dict[str, Any]:
+def network_to_dict(net: Network | TokenModel, *, inference: bool = False) -> dict[str, Any]:
+    if isinstance(net, TokenModel):
+        # a token model is its sizes: nothing is pruned, folded or served yet
+        return {"schema": _SCHEMA_VERSION, "inference": False, "token_model": net.arch, "vocab": net.vocab,
+                "lm": dataclasses.asdict(net.lm)}
     return {
         "schema": _SCHEMA_VERSION,
         "inference": inference,
@@ -83,11 +91,13 @@ def network_to_dict(net: Network, *, inference: bool = False) -> dict[str, Any]:
     }
 
 
-def network_from_dict(d: dict[str, Any]) -> Network:
+def network_from_dict(d: dict[str, Any]) -> Network | TokenModel:
     # v1 payloads are a strict subset of v2 (no "inference" marker): the spec
     # fields are identical, so the read path accepts both.
     if d.get("schema") not in (1, _SCHEMA_VERSION):
         raise ValueError(f"unsupported network schema {d.get('schema')!r}")
+    if "token_model" in d:
+        return TokenModel(arch=d["token_model"], vocab=d["vocab"], lm=LMConfig(**d["lm"]))
 
     def _blk(bd):
         bd = dict(bd)

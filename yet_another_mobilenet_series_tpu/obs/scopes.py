@@ -57,8 +57,21 @@ SCOPES = (
     "se",           # ops/blocks.py SqueezeExcite, whole (see the nesting rule)
     "drop",         # dropout and drop-connect (stochastic depth)
     "pool",         # global average pool (outside SE)
-    "residual",     # the residual add of an inverted-residual block
+    "residual",     # the residual add of an inverted-residual block, and of a token model's blocks
     "nas_mask",     # AtomNAS channel masks over the expanded channels
+    # the token models' compute (ops/lm.py, models/lm.py):
+    "embed",        # token embedding gather (both heads' inputs), and its scatter-add backward
+    "norm",         # RMSNorm, every one: pre-attention, pre-MLP, the latents', final, the MTP merge's
+    "rope",         # rotary tables and the rotation of q_rope and the shared k_rope
+    "attn_proj",    # MLA's matmuls: q_a, q_b, kv_a, kv_b, o
+    "attn_core",    # scores, causal mask, float32 softmax, values: a query block at a time
+    "mlp",          # SiLU-gated MLP: the dense layer's and every shared expert's
+    "moe_router",   # gate matmul, sigmoid, top-k of scores + bias, weights, counts, the bias update
+    "moe_dispatch", # sort of the assignments, held experts first, and the gather of their rows
+    "moe_experts",  # the held experts' grouped matmuls (lax.ragged_dot) and their SiLU gate
+    "moe_combine",  # un-sort, weight and sum over the selected experts; the layer's counters
+    "mtp_merge",    # the MTP module's W_eh over [norm(h) ; norm(emb)]
+    "lm_head",      # the output head over the vocabulary slice, a block of tokens at a time
     "loss",         # label-smoothed CE and the step's reported scalars (top-1, lr, their pmean)
     "nas_penalty",  # AtomNAS FLOPs-weighted BN-gamma L1
     "optim",        # optimizer update and apply, global gradient norm (plain and ZeRO shard)
@@ -73,7 +86,7 @@ SCOPES = (
 # filled before a scope was added, renamed or moved hands back an executable
 # with the old names in it. BUMP IT with any such change;
 # tests/test_obs_scopes.py pins it to the list of scope sites.
-TAXONOMY_VERSION = 2
+TAXONOMY_VERSION = 4
 UNSCOPED = "unscoped"
 PHASES = ("fwd", "bwd", "-")
 

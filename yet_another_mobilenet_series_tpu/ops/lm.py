@@ -1,0 +1,313 @@
+"""Layers of the token-model family (models/lm.py): RMSNorm, rotary
+embedding, causal multi-head latent attention, the SiLU-gated MLP and the
+expert layer of one expert-parallel share.
+
+Everything is plain `jax.numpy`/`lax` for XLA as it is. Weights are float32
+and cast to the compute dtype where they are used; norms, the router, the
+softmax and the loss are float32 whatever the compute dtype. Each piece of
+work sits under its named scope (obs/scopes.py).
+
+Two things keep a long sequence inside a chip's memory:
+
+- :func:`causal_attention` never holds more than one tile of scores
+  (`ATTN_BLOCK` query rows by as many key rows): one loop body meets every
+  tile on or below the diagonal (the causal prefix: about half the products
+  of the full square are never formed) under a running softmax, and its
+  hand-written backward makes each tile again from the rows' log-sum-exp
+  instead of keeping any probabilities.
+- :func:`expert_layer` sorts the (token, expert) assignments so that those of
+  the experts HELD HERE come first, grouped by expert, and multiplies them
+  with `lax.ragged_dot` (a grouped matmul that skips the rows outside its
+  groups). Every assignment to a held expert is computed whatever the load:
+  nothing is dropped and there is no capacity. Assignments to experts that
+  other shares hold are left out; nothing stands in for them.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..obs.scopes import scope
+
+Array = jax.Array
+
+
+def rms_norm(x: Array, gain: Array, eps: float) -> Array:
+    """x * rsqrt(mean(x^2) + eps) * gain over the last axis, in float32."""
+    with scope("norm"):
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+        return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_tables(seq_len: int, dim: int, theta: float) -> tuple[Array, Array]:
+    """(cos, sin), each (seq_len, dim // 2), float32."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
+    """Rotate (..., S, heads, dim): channel i pairs with channel i + dim/2
+    (the half-split convention of the Hugging Face implementations)."""
+    with scope("rope"):
+        x32 = x.astype(jnp.float32)
+        a, b = jnp.split(x32, 2, axis=-1)
+        c, s = cos[:, None, :], sin[:, None, :]
+        return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1).astype(x.dtype)
+
+
+# Query rows, and key rows, of one tile of scores: what causal_attention holds at once.
+ATTN_BLOCK = 512
+
+
+def _tile_scores(q, k, first_q, first_k, scale):
+    """Masked float32 scores of query rows [first_q, ...) against key rows
+    [first_k, ...): q (B, H, bq, D), k (B, H, bk, D) -> (B, H, bq, bk)."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    rows = first_q + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
+    cols = first_k + lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
+    return jnp.where(cols <= rows, s, -jnp.inf)
+
+
+def _rows(x, i, block):
+    """Rows [i * block, (i + 1) * block) of the sequence axis of (B, H, S, ...)."""
+    return lax.dynamic_slice_in_dim(x, i * block, block, axis=2)
+
+
+def _add_rows(x, rows, i, block):
+    return lax.dynamic_update_slice_in_dim(x, _rows(x, i, block) + rows.astype(x.dtype), i * block, axis=2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked_attention(q, k, v, scale, block):
+    return _blocked_attention_fwd(q, k, v, scale, block)[0]
+
+
+def _blocked_attention_fwd(q, k, v, scale, block):
+    """ONE loop body for every tile: query block i meets key blocks 0..i (the
+    causal prefix; the tiles above the diagonal are never formed) under a
+    running row maximum and row sum. Kept for the backward pass: the output
+    and each row's log-sum-exp, never a tile."""
+    with scope("attn_core"):
+        b, h, seq, _ = q.shape
+
+        def query_block(i, carry):
+            out, lse = carry
+            qi = _rows(q, i, block)
+
+            def key_block(j, state):
+                top, total, acc = state
+                s = _tile_scores(qi, _rows(k, j, block), i * block, j * block, scale)
+                # the row maximum behind a barrier: left to itself XLA:TPU turns "reduce,
+                # broadcast back, subtract" over a row into a reduce-window as wide as the
+                # row (work quadratic in the row; PERF.md, PR 27)
+                new_top = jnp.maximum(top, lax.optimization_barrier(jnp.max(s, axis=-1)))
+                weights = jnp.exp(s - new_top[..., None])
+                keep = jnp.exp(top - new_top)
+                acc = acc * keep[..., None] + jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype),
+                                                         _rows(v, j, block), preferred_element_type=jnp.float32)
+                return new_top, total * keep + jnp.sum(weights, axis=-1), acc
+
+            zeros = jnp.zeros((b, h, block), jnp.float32)
+            top, total, acc = lax.fori_loop(0, i + 1, key_block, (
+                zeros - jnp.inf, zeros, jnp.zeros((b, h, block, v.shape[-1]), jnp.float32)))
+            out = lax.dynamic_update_slice_in_dim(out, (acc / total[..., None]).astype(out.dtype), i * block, axis=2)
+            return out, lax.dynamic_update_slice_in_dim(lse, top + jnp.log(total), i * block, axis=2)
+
+        out, lse = lax.fori_loop(0, seq // block, query_block, (
+            jnp.zeros((b, h, seq, v.shape[-1]), v.dtype), jnp.zeros((b, h, seq), jnp.float32)))
+        return out, (q, k, v, out, lse)
+
+
+def _blocked_attention_bwd(scale, block, kept, g):
+    """Each key block once: its dK and dV gather over the query blocks i >= j
+    that see it, each tile's probabilities made again from the row's
+    log-sum-exp; dQ is added into its rows as the tiles go by."""
+    q, k, v, out, lse = kept
+    with scope("attn_core"):
+        b, h, seq, _ = q.shape
+        # sum_k P dP of every row, which the softmax's backward subtracts: it is g . out
+        inner = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+
+        def key_block(j, carry):
+            dq, dk, dv = carry
+            kj, vj = _rows(k, j, block), _rows(v, j, block)
+
+            def query_block(i, state):
+                dq, dkj, dvj = state
+                qi, gi = _rows(q, i, block), _rows(g, i, block)
+                s = _tile_scores(qi, kj, i * block, j * block, scale)
+                probs = jnp.exp(s - _rows(lse, i, block)[..., None])
+                dvj = dvj + jnp.einsum("bhqk,bhqd->bhkd", probs.astype(g.dtype), gi, preferred_element_type=jnp.float32)
+                dp = jnp.einsum("bhqd,bhkd->bhqk", gi, vj, preferred_element_type=jnp.float32)
+                ds = (probs * (dp - _rows(inner, i, block)[..., None]) * scale).astype(q.dtype)
+                dq = _add_rows(dq, jnp.einsum("bhqk,bhkd->bhqd", ds, kj, preferred_element_type=jnp.float32), i, block)
+                dkj = dkj + jnp.einsum("bhqk,bhqd->bhkd", ds, qi, preferred_element_type=jnp.float32)
+                return dq, dkj, dvj
+
+            dq, dkj, dvj = lax.fori_loop(j, seq // block, query_block, (
+                dq, jnp.zeros(kj.shape, jnp.float32), jnp.zeros(vj.shape, jnp.float32)))
+            return (dq, lax.dynamic_update_slice_in_dim(dk, dkj.astype(dk.dtype), j * block, axis=2),
+                    lax.dynamic_update_slice_in_dim(dv, dvj.astype(dv.dtype), j * block, axis=2))
+
+        dq, dk, dv = lax.fori_loop(0, seq // block, key_block, (
+            jnp.zeros(q.shape, jnp.float32), jnp.zeros_like(k), jnp.zeros_like(v)))
+        return dq.astype(q.dtype), dk, dv
+
+
+_blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
+
+
+def causal_attention(q: Array, k: Array, v: Array, *, scale: float, block: int | None = None) -> Array:
+    """Causal softmax(q k^T * scale) v, float32 softmax, a tile of `block`
+    query rows by `block` key rows at a time, forward and backward (the
+    backward recomputes each tile). q, k (B, S, H, D); v (B, S, H, Dv) ->
+    (B, S, H, Dv)."""
+    seq = q.shape[1]
+    block = min(block or ATTN_BLOCK, seq)
+    if seq % block:
+        raise ValueError(f"sequence length {seq} is not a multiple of the attention block {block}")
+    with scope("attn_core"):
+        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))  # heads lead: batch and head are the tiles' batch axes
+        return jnp.swapaxes(_blocked_attention(q, k, v, scale, block), 1, 2)
+
+
+def mla_attention(p: dict, x: Array, cos: Array, sin: Array, *, heads: int, nope: int, rope: int,
+                  v_dim: int, kv_rank: int, eps: float) -> Array:
+    """Multi-head latent attention (DeepSeek-V2's MLA, as glm4_moe_lite has
+    it), training form: the latents are expanded to per-head keys and values.
+    `k_rope` is ONE head, shared by all `heads`. x (B, S, h) -> (B, S, h)."""
+    cd = x.dtype
+    b, s, _ = x.shape
+    with scope("attn_proj"):
+        c_q = x @ p["q_a"].astype(cd)
+        kv_a = x @ p["kv_a"].astype(cd)
+    c_q = rms_norm(c_q, p["q_norm"], eps)
+    c_kv = rms_norm(kv_a[..., :kv_rank], p["kv_norm"], eps)
+    with scope("attn_proj"):
+        q = (c_q @ p["q_b"].astype(cd)).reshape(b, s, heads, nope + rope)
+        kv = (c_kv @ p["kv_b"].astype(cd)).reshape(b, s, heads, nope + v_dim)
+    q_rope = apply_rope(q[..., nope:], cos, sin)
+    k_rope = apply_rope(kv_a[..., None, kv_rank:], cos, sin)
+    with scope("attn_core"):
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, heads, rope))], axis=-1)
+    out = causal_attention(q, k, kv[..., nope:], scale=(nope + rope) ** -0.5)
+    with scope("attn_proj"):
+        return out.reshape(b, s, heads * v_dim) @ p["o"].astype(cd)
+
+
+def gated_mlp(p: dict, x: Array) -> Array:
+    """down(silu(gate x) * up x): the dense MLP and the shared expert."""
+    cd = x.dtype
+    with scope("mlp"):
+        return (jax.nn.silu(x @ p["gate"].astype(cd)) * (x @ p["up"].astype(cd))) @ p["down"].astype(cd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of(x: Array, order: Array, inverse: Array, repeat: int) -> Array:
+    """`jnp.repeat(x, repeat, axis=0)[order]` for a PERMUTATION `order` of the
+    repeated rows, with inverse `inverse`, without holding the repeated array.
+    The cotangent is a gather by the inverse and a sum over each row's
+    copies, where autodiff would scatter-add."""
+    return x[order // repeat]
+
+
+def _rows_of_fwd(x, order, inverse, repeat):
+    return x[order // repeat], inverse
+
+
+def _rows_of_bwd(repeat, inverse, g):
+    return jnp.sum(g[inverse].reshape(-1, repeat, g.shape[-1]), axis=1), None, None
+
+
+_rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@jax.custom_vjp
+def _unsort(x: Array, order: Array, inverse: Array) -> Array:
+    """`x[inverse]`: sorted rows back in assignment order; the cotangent is `g[order]`."""
+    return x[inverse]
+
+
+def _unsort_fwd(x, order, inverse):
+    return x[inverse], order
+
+
+def _unsort_bwd(order, g):
+    return g[order], None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def route(router_w: Array, bias: Array, x: Array, *, top_k: int, scaling: float):
+    """`noaux_tc` routing without groups. x (T, h) -> (expert ids (T, k),
+    weights (T, k) float32, assignments per expert (E,) float32).
+
+    scores = sigmoid(W_r x) in float32; the SELECTION is the top-k of
+    scores + bias, the WEIGHTS are the selected scores themselves (no bias),
+    divided by their sum, times `scaling`. `bias` is state, not a parameter:
+    it gets no gradient."""
+    with scope("moe_router"):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, ids = lax.top_k(scores + lax.stop_gradient(bias)[None, :], top_k)
+        picked = jnp.take_along_axis(scores, ids, axis=-1)
+        weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
+        load = jnp.sum(jax.nn.one_hot(ids, scores.shape[-1], dtype=jnp.float32), axis=(0, 1))
+        return ids, weights, load
+
+
+def expert_layer(p: dict, bias: Array, x: Array, *, top_k: int, scaling: float, held: int,
+                 share_index: int):
+    """The routed experts of ONE expert-parallel share: routes every token
+    over ALL experts (`p["router"]` keeps its published width) and returns the
+    sum, over the selected experts that this share holds (ids
+    ``[share_index * held, (share_index + 1) * held)``; `p["experts"]` holds
+    exactly those), of weight * expert(token). The shared expert is not in
+    here. x (B, S, h) -> (y (B, S, h), load over all experts (E,), counters,
+    the selected expert ids (B * S, top_k)).
+    """
+    cd = x.dtype
+    b, s, h = x.shape
+    tokens = b * s
+    xf = x.reshape(tokens, h)
+    ids, weights, load = route(p["router"], bias, xf, top_k=top_k, scaling=scaling)
+    first = share_index * held
+    with scope("moe_dispatch"):
+        flat = ids.reshape(-1)
+        here = (flat >= first) & (flat < first + held)
+        # held experts first, grouped by expert; the rest, which no group covers, last
+        order = jnp.argsort(jnp.where(here, flat - first, held), stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = load[first:first + held].astype(jnp.int32)
+        in_group = (jnp.arange(flat.shape[0]) < jnp.sum(group_sizes))[:, None]
+        rows = _rows_of(xf, order, inverse, top_k)
+        # rows outside the groups are never written by the grouped matmul, in
+        # either pass: a select keeps what they hold out of both
+        rows = jnp.where(in_group, rows, jnp.zeros((), cd))
+    with scope("moe_experts"):
+        e = p["experts"]
+        hidden = (jax.nn.silu(lax.ragged_dot(rows, e["gate"].astype(cd), group_sizes))
+                  * lax.ragged_dot(rows, e["up"].astype(cd), group_sizes))
+        out = lax.ragged_dot(hidden, e["down"].astype(cd), group_sizes)
+    with scope("moe_combine"):
+        out = jnp.where(in_group, out, jnp.zeros((), cd))
+        out = _unsort(out, order, inverse).reshape(tokens, top_k, h)
+        y = jnp.sum(out * weights[..., None].astype(cd), axis=1)
+        # counted from what the grouped matmul WROTE, not from the ids: an assignment to a
+        # held expert whose row came back all zero was not computed
+        written = jnp.any(lax.stop_gradient(out) != 0, axis=-1)
+        held_load = group_sizes.astype(jnp.float32)
+        counters = {
+            "assignments_here": jnp.sum(here.astype(jnp.float32)),
+            "dropped": jnp.sum((here.reshape(tokens, top_k) & ~written).astype(jnp.float32)),
+            "load_max_over_mean": jnp.max(held_load) / jnp.maximum(jnp.mean(held_load), 1.0),
+        }
+    return y.reshape(b, s, h), load, counters, ids

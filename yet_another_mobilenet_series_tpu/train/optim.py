@@ -103,7 +103,7 @@ def make_optimizer(
     elif cfg.optimizer == "adamw":
         # decoupled variant kept for experimentation; wd handled above stays
         # coupled unless weight_decay==0 here.
-        txs.append(optax.scale_by_adam())
+        txs.append(optax.scale_by_adam(b1=cfg.adam_b1, b2=cfg.adam_b2))
     else:
         raise ValueError(f"unknown optimizer {cfg.optimizer!r}")
     if not lr_applied:

@@ -19,6 +19,7 @@ import optax
 from jax import lax
 
 from ..config import Config
+from ..models.lm import TokenModel
 from ..models.specs import Network
 from ..obs.registry import get_registry
 from ..obs.scopes import scope
@@ -31,7 +32,7 @@ from .losses import cross_entropy_label_smooth, topk_correct
 class TrainState:
     step: jax.Array
     params: Any
-    state: Any  # BN running stats
+    state: Any  # BN running stats; a token model's router biases
     opt_state: Any
     ema_params: Any  # None when EMA disabled
     ema_state: Any
@@ -51,7 +52,7 @@ def train_state_to_dict(ts: TrainState) -> dict:
 
 
 def init_train_state(
-    net: Network, cfg: Config, optimizer: optax.GradientTransformation, rng, *, with_opt: bool = True
+    net: Network | TokenModel, cfg: Config, optimizer: optax.GradientTransformation, rng, *, with_opt: bool = True
 ) -> TrainState:
     """with_opt=False leaves opt_state None — the ZeRO path builds its
     sharded accumulators on the mesh instead (parallel/zero.py)."""
@@ -169,33 +170,16 @@ def make_batch_mixer(cfg: Config):
     return mix
 
 
-def make_train_step(
-    net: Network,
-    cfg: Config,
-    optimizer: optax.GradientTransformation,
-    lr_fn: Callable,
-    *,
-    axis_name: str | None = None,
-    penalty_fn: Callable[[Any, Mapping[str, Any]], jax.Array] | None = None,
-    sharded_update: Callable | None = None,
-):
-    """Returns step_fn(ts, batch, rng) -> (ts, metrics).
-
-    ``penalty_fn(params, masks)`` is the AtomNAS FLOPs-weighted BN-gamma L1
-    hook (SURVEY.md §3.2); None for plain training. ``batch`` is
-    {'image': (N,H,W,C), 'label': (N,)} already on device.
-
-    ``sharded_update(grads_local, opt_state_shard, params)`` replaces the
-    replicated pmean+optax update with the ZeRO cross-replica sharded update
-    (parallel/zero.py); it receives un-averaged local grads (the mean rides
-    the psum_scatter).
-    """
+def _image_loss(net: Network, cfg: Config, axis_name: str | None, penalty_fn):
+    """The CNN family's loss and its reported scalars: `loss_fn(params, state,
+    batch, masks, rho_mult, step, rng) -> (loss, (new_state, aux))` and
+    `report(aux, batch, grads) -> scalars`."""
     compute_dtype = _dtype(cfg.train.compute_dtype)
     # dist.sync_bn=False: per-replica batch statistics in the NORMALIZATION
     # (grad allreduce still uses axis_name) — the reference's non-SyncBN DDP
     # mode. DDP broadcasts rank 0's buffers, so the updated running stats are
-    # explicitly broadcast from device 0 below; without that the "replicated"
-    # state would silently diverge across replicas (and across hosts).
+    # explicitly broadcast from device 0 in the step; without that the
+    # "replicated" state would silently diverge across replicas (and hosts).
     bn_axis = axis_name if cfg.dist.sync_bn else None
 
     def forward(params, state, image, masks, rng):
@@ -261,11 +245,66 @@ def make_train_step(
                 pen = penalty_fn(params, masks, rho_mult=rho_mult, step=step)
         else:
             pen = jnp.zeros((), jnp.float32)
-        return ce + pen, (new_state, logits, ce, pen)
+        return ce + pen, (new_state, (logits, ce, pen))
+
+    def report(aux, batch, grads):
+        logits, ce, pen = aux
+        correct = topk_correct(logits, batch["label"], ks=(1,))["top1"]
+        n = jnp.asarray(logits.shape[0], jnp.float32)
+        return {"ce": ce, "penalty": pen, "top1": correct / n}
+
+    return loss_fn, report, bn_axis
+
+
+def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None):
+    """The token family's loss (models/lm.py `TokenModel.loss`) in the same
+    two pieces. Its state (router biases) is made identical across replicas
+    inside the loss, so there is no axis the step would have to repair."""
+    compute_dtype = _dtype(cfg.train.compute_dtype)
+    if cfg.prune.enable or cfg.optim.mixup_alpha or cfg.optim.cutmix_alpha:
+        raise ValueError(f"model.arch {net.arch!r} is a token model: prune.enable, mixup and cutmix are image-only")
+
+    def loss_fn(params, state, batch, masks, rho_mult, step, rng):
+        return net.loss(params, state, batch, compute_dtype=compute_dtype, axis_name=axis_name)
+
+    def report(aux, batch, grads):
+        return {**aux, **net.grad_scalars(grads)}
+
+    return loss_fn, report, axis_name
+
+
+def make_train_step(
+    net: Network | TokenModel,
+    cfg: Config,
+    optimizer: optax.GradientTransformation,
+    lr_fn: Callable,
+    *,
+    axis_name: str | None = None,
+    penalty_fn: Callable[[Any, Mapping[str, Any]], jax.Array] | None = None,
+    sharded_update: Callable | None = None,
+):
+    """Returns step_fn(ts, batch, rng) -> (ts, metrics): ONE skeleton
+    (gradients, their sync, the optimizer, EMA, the reported scalars) around
+    the family's loss, `(loss, (new_state, aux))`: `_image_loss` for a
+    `Network` (``batch`` = {'image': (N,H,W,C), 'label': (N,)}), `_token_loss`
+    for a `TokenModel` ({'tokens': (N, seq_len + 2)}), already on device.
+
+    ``penalty_fn(params, masks)`` is the AtomNAS FLOPs-weighted BN-gamma L1
+    hook (SURVEY.md §3.2); None for plain training.
+
+    ``sharded_update(grads_local, opt_state_shard, params)`` replaces the
+    replicated pmean+optax update with the ZeRO cross-replica sharded update
+    (parallel/zero.py); it receives un-averaged local grads (the mean rides
+    the psum_scatter).
+    """
+    if isinstance(net, TokenModel):
+        loss_fn, report, bn_axis = _token_loss(net, cfg, axis_name)
+    else:
+        loss_fn, report, bn_axis = _image_loss(net, cfg, axis_name, penalty_fn)
 
     def step_fn(ts: TrainState, batch, rng):
         rng = jax.random.fold_in(rng, ts.step)
-        (loss, (new_state, logits, ce, pen)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, (new_state, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             ts.params, ts.state, batch, ts.masks, ts.rho_mult, ts.step, rng
         )
         if axis_name is not None and bn_axis is None:
@@ -293,13 +332,9 @@ def make_train_step(
 
         # the step's reported scalars are part of `loss`
         with scope("loss"):
-            correct = topk_correct(logits, batch["label"], ks=(1,))["top1"]
-            n = jnp.asarray(logits.shape[0], jnp.float32)
             metrics = {
                 "loss": loss,
-                "ce": ce,
-                "penalty": pen,
-                "top1": correct / n,
+                **report(aux, batch, grads),
                 "lr": lr_fn(ts.step),
                 "grad_norm": grad_norm,
                 "finite": jnp.isfinite(loss).astype(jnp.float32),
@@ -319,7 +354,7 @@ def make_train_step(
     return step_fn
 
 
-def make_eval_step(net: Network, cfg: Config, *, axis_name: str | None = None):
+def make_eval_step(net: Network | TokenModel, cfg: Config, *, axis_name: str | None = None):
     """Returns eval_fn(params, state, batch, masks) -> summed metric counts
     {'top1','top5','n','loss_sum'} — allreduce-able AverageMeter counts
     (SURVEY.md §2 #13). Runs on EMA shadow weights when the caller passes
@@ -331,11 +366,21 @@ def make_eval_step(net: Network, cfg: Config, *, axis_name: str | None = None):
     training config can never perturb reported accuracy. (The bn_mode
     perturbation itself is measured — on purpose, via net.apply directly —
     by test_acceptance_mbv2.py::test_full_scale_bn_mode_prediction_agreement.)"""
+    compute_dtype = _dtype(cfg.train.compute_dtype)
+    if isinstance(net, TokenModel):
+        # the main head's next-token counts, in the same four sums
+
+        def eval_tokens(params, state, batch, masks):
+            metrics = net.eval_counts(params, state, batch, compute_dtype=compute_dtype)
+            if axis_name is not None:
+                metrics = {k: lax.psum(v, axis_name) for k, v in metrics.items()}
+            return metrics
+
+        return eval_tokens
     # the value is ignored here (eval pins exact), but a misspelled
     # train.bn_mode must still fail fast in an eval-only run rather than
     # only when a train step is ever built (ADVICE r4 #4)
     _check_bn_mode(cfg)
-    compute_dtype = _dtype(cfg.train.compute_dtype)
 
     prep_input = _input_normalizer(cfg)
 
