@@ -63,10 +63,25 @@ def _assert_structural_sweep(sw, *, saturated=False, ring=False):
         # back produces
         assert 1.0 <= sw["modes"][mode]["dispatches_per_wakeup"] <= 2.0, mode
     # the structural dispatch claim: coalesced overflow rides the fused scan
-    # (2 chunks -> 1 dispatch), halving dispatches/request vs chained
+    # (2 chunks -> 1 dispatch), halving dispatches/request vs chained. It is a
+    # claim about FULL batches, and whether a live sweep's batches fill is the
+    # scheduler's doing: a client thread not run within max_wait_ms leaves a
+    # batch to flush short, and a short batch is one piece either way (under
+    # six xdist workers the tiny sweep read fills of 0.75-0.86 and ratios of
+    # 0.58-0.69, and failed here; PR 29). So the halving is held where the
+    # artifact is a saturated rehearsal or the run itself shows full batches,
+    # and in every run what no clock moves: engine pieces per coalesced
+    # batch (dispatches/request x rows/batch) lie between one and two.
+    def pieces_per_batch(mode):
+        v = sw["modes"][mode]
+        return v["dispatches_per_request"] * v["avg_fill"] * sw["max_batch"]
+
     for chained, fused in (("sync", "fused"), ("pipelined", "overlapped")):
-        assert sw["modes"][fused]["dispatches_per_request"] <= (
-            0.55 * sw["modes"][chained]["dispatches_per_request"]), (chained, fused)
+        for mode in (chained, fused):
+            assert 0.98 <= pieces_per_batch(mode) <= 2.02, (mode, sw["modes"][mode])
+        if saturated or min(sw["modes"][m]["avg_fill"] for m in (chained, fused)) >= 0.99:
+            assert sw["modes"][fused]["dispatches_per_request"] <= (
+                0.55 * sw["modes"][chained]["dispatches_per_request"]), (chained, fused)
     dpw = sw["modes"]["overlapped"]["dispatches_per_wakeup"]
     assert dpw is not None and dpw >= 1.0
     if saturated:

@@ -76,12 +76,17 @@ def test_whole_package_lint_stays_fast():
     # stretching identical runs ±40%, and the minimum estimates the true
     # compute cost — a complexity regression raises every sample, noise
     # only some (each run rebuilds its Project, so nothing is amortized).
+    # The clock is the child's CPU time, not the wall: the linter is one
+    # thread of pure Python, and under the driver's six xdist workers on
+    # eight cores the wall of the SAME work read 5.70s best-of-3 (7.7-9.0s
+    # beside 14 busy loops) where its CPU time read 3.97s, 3.2s idle (PR 29):
+    # time spent waiting for a core is the suite's, not the linter's.
     code = (
         "import pathlib, time\n"
         "from yet_another_mobilenet_series_tpu.analysis import run_lint\n"
         f"pkg = pathlib.Path({str(PACKAGE)!r})\n"
         "best = min(\n"
-        "    (lambda t0: (run_lint([pkg]), time.perf_counter() - t0)[1])(time.perf_counter())\n"
+        "    (lambda t0: (run_lint([pkg]), time.process_time() - t0)[1])(time.process_time())\n"
         "    for _ in range(3)\n"
         ")\n"
         "print(best)\n"
@@ -91,7 +96,7 @@ def test_whole_package_lint_stays_fast():
     )
     assert out.returncode == 0, out.stderr
     elapsed = float(out.stdout.strip().splitlines()[-1])
-    assert elapsed < 5.0, f"run_lint over the package took {elapsed:.2f}s best-of-3 (bar: 5s)"
+    assert elapsed < 5.0, f"run_lint over the package took {elapsed:.2f}s of CPU best-of-3 (bar: 5s)"
 
 
 def test_apps_ymls_are_covered():

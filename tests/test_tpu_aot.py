@@ -13,6 +13,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -31,30 +32,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# MobileNetV3-Large's second block at the benchmark's batch: the step's four
-# largest fusions all stream this block's expanded tensor (PERF.md section 5)
-BATCH, SIZE, CIN, CEXP, COUT = 512, 112, 16, 64, 24
-WIDE = f"bf16[{BATCH},{SIZE},{SIZE},{CEXP}]"
-
-
-@pytest.fixture(scope="module")
-def block_hlo(one_chip):
-    """ENTRY instructions of the one-block gradient: name -> (output shapes,
-    opcode, operand names, op_name), get-tuple-elements seen through."""
-    block = ops.InvertedResidual(CIN, COUT, CEXP, stride=2, kernel_sizes=(3,), active_fn="hswish")
-    params, state = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0)))
-    state = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
-
-    def loss(params, x, ct):
-        y, _ = block.apply(params, state, x, train=True, compute_dtype=jnp.bfloat16)
-        return jnp.sum(y.astype(jnp.float32) * ct)
-
-    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        jax.tree.map(lambda a: on_chip(a.shape, a.dtype), params),
-        on_chip((BATCH, SIZE, SIZE, CIN), jnp.bfloat16),
-        on_chip((BATCH, SIZE // 2, SIZE // 2, COUT), jnp.float32)).compile()
-    text = compiled.as_text()
+def _entry_instructions(text):
+    """ENTRY instructions of compiled HLO text: name -> (output shapes,
+    opcode, operand names, op_name), and the get-tuple-elements as aliases
+    name -> (source, index)."""
     entry = text[text.index("\nENTRY "):]
     instructions, alias = {}, {}
     for line in entry.splitlines():
@@ -69,6 +50,33 @@ def block_hlo(one_chip):
             continue
         op_name = re.search(r'op_name="([^"]*)"', rest)
         instructions[name] = (re.findall(r"\w+\[[\d,]*\]", out), opcode, operands, op_name.group(1) if op_name else "")
+    return instructions, alias
+
+
+# MobileNetV3-Large's second block at the benchmark's batch: the step's four
+# largest fusions all stream this block's expanded tensor (PERF.md section 5)
+BATCH, SIZE, CIN, CEXP, COUT = 512, 112, 16, 64, 24
+WIDE = f"bf16[{BATCH},{SIZE},{SIZE},{CEXP}]"
+
+
+@pytest.fixture(scope="module")
+def block_hlo(one_chip):
+    """ENTRY instructions of the one-block gradient, get-tuple-elements seen through."""
+    block = ops.InvertedResidual(CIN, COUT, CEXP, stride=2, kernel_sizes=(3,), active_fn="hswish")
+    params, state = jax.eval_shape(lambda: block.init(jax.random.PRNGKey(0)))
+    state = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), state)
+
+    def loss(params, x, ct):
+        y, _ = block.apply(params, state, x, train=True, compute_dtype=jnp.bfloat16)
+        return jnp.sum(y.astype(jnp.float32) * ct)
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.tree.map(lambda a: on_chip(a.shape, a.dtype), params),
+        on_chip((BATCH, SIZE, SIZE, CIN), jnp.bfloat16),
+        on_chip((BATCH, SIZE // 2, SIZE // 2, COUT), jnp.float32)).compile()
+    text = compiled.as_text()
+    instructions, alias = _entry_instructions(text)
 
     def shape_of(operand):
         if operand in alias:
@@ -132,3 +140,59 @@ def test_the_bn_gradient_sums_ride_in_the_fusion_that_produces_d(block_hlo):
     alone = [n for n, (_, opcode, _, op) in instructions.items()
              if opcode == "fusion" and scopes.scope_of(op)[0] == "bn_stats" and _wide_operands(block_hlo, n)]
     assert not alone, alone
+
+
+# One attention layer of the token cell (glm47flash_train_2x8k): 2 x 8,192 tokens, 20 heads, head dims 256 / 256
+ATTN = (2, 8192, 20, 256)
+
+
+@pytest.fixture(scope="module")
+def attention_hlo(one_chip):
+    """`ops.lm.causal_attention`'s value and three gradients at the cell's
+    shapes: a TPU lowering, so `lax.platform_dependent` takes the kernels of
+    ops/lm_attention.py, and Mosaic compiles them (~3 s)."""
+    from yet_another_mobilenet_series_tpu.ops import lm
+
+    assert lm.lm_attention.fuses(ATTN[1], lm.ATTN_BLOCK, ATTN[3], ATTN[3], jnp.bfloat16)
+
+    def loss(q, k, v, ct):
+        return jnp.sum(lm.causal_attention(q, k, v, scale=ATTN[3] ** -0.5).astype(jnp.float32) * ct)
+
+    operand = jax.ShapeDtypeStruct(ATTN, jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        operand, operand, operand, jax.ShapeDtypeStruct(ATTN, jnp.float32, sharding=one_chip)).compile()
+    return compiled.as_text()
+
+
+def test_attention_is_two_mosaic_kernels_under_its_scope(attention_hlo):
+    """One custom call forward and one backward, each under `attn_core` with
+    its phase, carrying the kernel's name; no loop is left."""
+    instructions, _ = _entry_instructions(attention_hlo)
+    kernels = {n: scopes.scope_of(op) for n, (_, opcode, _, op) in instructions.items() if opcode == "custom-call"}
+    assert sorted(kernels.values()) == [("attn_core", "bwd"), ("attn_core", "fwd")], kernels
+    assert sorted(n.split(".")[0] for n in kernels) == ["causal_attention_bwd", "causal_attention_fwd"]
+    assert attention_hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r"\bwhile\(", attention_hlo)
+
+
+def test_no_tile_of_scores_and_no_float32_dq_reaches_hbm(attention_hlo):
+    """What the tile loops paid for: no buffer of a tile's shape in any
+    dtype (`f32[2,20,512,512]`, or the kernel's own 512 x 512 block), no
+    dynamic-update-slice (the loops added dQ's rows into a float32 buffer
+    tile by tile), and the kernels hand back dQ in the operands' dtype."""
+    from yet_another_mobilenet_series_tpu.ops import lm
+
+    block = lm.ATTN_BLOCK
+    assert not re.search(rf"\[(\d+,)*{block},{block}\]", attention_hlo)
+    assert "dynamic-update-slice" not in attention_hlo
+    instructions, _ = _entry_instructions(attention_hlo)
+    (outs,) = [out for n, (out, opcode, _, _) in instructions.items() if n.startswith("causal_attention_bwd")]
+    assert outs == [f"bf16[{ATTN[0]},{ATTN[2] * ATTN[3]},{ATTN[1]}]"] * 3  # dq, dk, dv, features leading
+    whole = ATTN[0] * ATTN[1] * ATTN[2] * ATTN[3]
+
+    def elements(shape):
+        return int(np.prod([int(n) for n in re.findall(r"\d+", shape.split("[")[1])]))
+
+    float32_whole = [n for n, (out, _, _, op) in instructions.items() if scopes.scope_of(op)[0] == "attn_core"
+                     and any(o.startswith("f32[") and elements(o) >= whole for o in out)]
+    assert not float32_whole, float32_whole

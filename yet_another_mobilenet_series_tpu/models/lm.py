@@ -31,6 +31,7 @@ from jax import lax
 from ..config import LMConfig, ModelConfig
 from ..obs.scopes import scope
 from ..ops import lm as ops
+from ..ops import lm_attention
 
 LM_ARCHS = ("glm4_moe_lite",)
 # Tokens whose logits over the vocabulary slice are held at once (each such block is a jax.checkpoint).
@@ -76,6 +77,17 @@ class TokenModel:
             raise ValueError("qk_rope_head_dim must be even")
         if not 0 < c.first_k_dense_replace <= c.num_hidden_layers:
             raise ValueError("first_k_dense_replace must be in [1, num_hidden_layers]")
+
+    def attention_sites(self, compute_dtype) -> tuple[int, int]:
+        """(attention layers, those whose shapes ops/lm_attention.py's fused
+        kernels take): what `train.attn_sites` reports and, where the step is
+        lowered for a TPU, `train.attn_fused_sites` (train/steps.py). The
+        predicate is the one `ops.causal_attention` dispatches on."""
+        c = self.lm
+        sites = len(self.block_names)
+        fits = lm_attention.fuses(c.seq_len, min(ops.ATTN_BLOCK, c.seq_len), c.qk_nope_head_dim + c.qk_rope_head_dim,
+                                  c.v_head_dim, compute_dtype)
+        return sites, sites if fits else 0
 
     def param_count(self) -> int:
         shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0))[0])

@@ -2,19 +2,21 @@
 embedding, causal multi-head latent attention, the SiLU-gated MLP and the
 expert layer of one expert-parallel share.
 
-Everything is plain `jax.numpy`/`lax` for XLA as it is. Weights are float32
-and cast to the compute dtype where they are used; norms, the router, the
-softmax and the loss are float32 whatever the compute dtype. Each piece of
-work sits under its named scope (obs/scopes.py).
+Everything is plain `jax.numpy`/`lax` for XLA as it is, but attention on a
+TPU (below). Weights are float32 and cast to the compute dtype where they are
+used; norms, the router, the softmax and the loss are float32 whatever the
+compute dtype. Each piece of work sits under its named scope (obs/scopes.py).
 
 Two things keep a long sequence inside a chip's memory:
 
 - :func:`causal_attention` never holds more than one tile of scores
-  (`ATTN_BLOCK` query rows by as many key rows): one loop body meets every
-  tile on or below the diagonal (the causal prefix: about half the products
-  of the full square are never formed) under a running softmax, and its
-  hand-written backward makes each tile again from the rows' log-sum-exp
-  instead of keeping any probabilities.
+  (`ATTN_BLOCK` query rows by as many key rows): every tile on or below the
+  diagonal (the causal prefix: about half the products of the full square are
+  never formed) is met under a running softmax, and a hand-written backward
+  makes each tile again from the rows' log-sum-exp instead of keeping any
+  probabilities. Where the step is lowered for a TPU and the shapes fit, the
+  tiles live in VMEM inside two fused kernels (ops/lm_attention.py); one loop
+  body for XLA meets them everywhere else, and is the kernels' oracle.
 - :func:`expert_layer` sorts the (token, expert) assignments so that those of
   the experts HELD HERE come first, grouped by expert, and multiplies them
   with `lax.ragged_dot` (a grouped matmul that skips the rows outside its
@@ -32,6 +34,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs.scopes import scope
+from . import lm_attention
 
 Array = jax.Array
 
@@ -88,76 +91,97 @@ def _blocked_attention(q, k, v, scale, block):
     return _blocked_attention_fwd(q, k, v, scale, block)[0]
 
 
+def _by_lowering(kernels, loops, scale, block, *operands):
+    """The fused kernels of ops/lm_attention.py where the shapes fit them
+    (`lm_attention.fuses`) AND the step is lowered for a TPU, else the tile
+    loops below. `lax.platform_dependent` decides at lowering, so a compile
+    for a described chip from a CPU process takes the kernels and a CPU the
+    loops; at shapes the kernels do not take the lowering is the loops' alone."""
+    q, v = operands[0], operands[2]
+    loops = functools.partial(loops, scale=scale, block=block)
+    if not lm_attention.fuses(q.shape[2], block, q.shape[3], v.shape[3], q.dtype):
+        return loops(*operands)
+    return lax.platform_dependent(*operands, tpu=functools.partial(kernels, scale=scale, block=block), default=loops)
+
+
 def _blocked_attention_fwd(q, k, v, scale, block):
-    """ONE loop body for every tile: query block i meets key blocks 0..i (the
-    causal prefix; the tiles above the diagonal are never formed) under a
-    running row maximum and row sum. Kept for the backward pass: the output
-    and each row's log-sum-exp, never a tile."""
+    """Kept for the backward pass: the output and each row's log-sum-exp,
+    never a tile."""
     with scope("attn_core"):
-        b, h, seq, _ = q.shape
-
-        def query_block(i, carry):
-            out, lse = carry
-            qi = _rows(q, i, block)
-
-            def key_block(j, state):
-                top, total, acc = state
-                s = _tile_scores(qi, _rows(k, j, block), i * block, j * block, scale)
-                # the row maximum behind a barrier: left to itself XLA:TPU turns "reduce,
-                # broadcast back, subtract" over a row into a reduce-window as wide as the
-                # row (work quadratic in the row; PERF.md, PR 27)
-                new_top = jnp.maximum(top, lax.optimization_barrier(jnp.max(s, axis=-1)))
-                weights = jnp.exp(s - new_top[..., None])
-                keep = jnp.exp(top - new_top)
-                acc = acc * keep[..., None] + jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype),
-                                                         _rows(v, j, block), preferred_element_type=jnp.float32)
-                return new_top, total * keep + jnp.sum(weights, axis=-1), acc
-
-            zeros = jnp.zeros((b, h, block), jnp.float32)
-            top, total, acc = lax.fori_loop(0, i + 1, key_block, (
-                zeros - jnp.inf, zeros, jnp.zeros((b, h, block, v.shape[-1]), jnp.float32)))
-            out = lax.dynamic_update_slice_in_dim(out, (acc / total[..., None]).astype(out.dtype), i * block, axis=2)
-            return out, lax.dynamic_update_slice_in_dim(lse, top + jnp.log(total), i * block, axis=2)
-
-        out, lse = lax.fori_loop(0, seq // block, query_block, (
-            jnp.zeros((b, h, seq, v.shape[-1]), v.dtype), jnp.zeros((b, h, seq), jnp.float32)))
+        out, lse = _by_lowering(lm_attention.attention_fwd, loops_fwd, scale, block, q, k, v)
         return out, (q, k, v, out, lse)
 
 
+def loops_fwd(q, k, v, scale, block):
+    """ONE loop body for every tile: query block i meets key blocks 0..i (the
+    causal prefix; the tiles above the diagonal are never formed) under a
+    running row maximum and row sum."""
+    b, h, seq, _ = q.shape
+
+    def query_block(i, carry):
+        out, lse = carry
+        qi = _rows(q, i, block)
+
+        def key_block(j, state):
+            top, total, acc = state
+            s = _tile_scores(qi, _rows(k, j, block), i * block, j * block, scale)
+            # the row maximum behind a barrier: left to itself XLA:TPU turns "reduce,
+            # broadcast back, subtract" over a row into a reduce-window as wide as the
+            # row (work quadratic in the row; PERF.md, PR 27)
+            new_top = jnp.maximum(top, lax.optimization_barrier(jnp.max(s, axis=-1)))
+            weights = jnp.exp(s - new_top[..., None])
+            keep = jnp.exp(top - new_top)
+            acc = acc * keep[..., None] + jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype),
+                                                     _rows(v, j, block), preferred_element_type=jnp.float32)
+            return new_top, total * keep + jnp.sum(weights, axis=-1), acc
+
+        zeros = jnp.zeros((b, h, block), jnp.float32)
+        top, total, acc = lax.fori_loop(0, i + 1, key_block, (
+            zeros - jnp.inf, zeros, jnp.zeros((b, h, block, v.shape[-1]), jnp.float32)))
+        out = lax.dynamic_update_slice_in_dim(out, (acc / total[..., None]).astype(out.dtype), i * block, axis=2)
+        return out, lax.dynamic_update_slice_in_dim(lse, top + jnp.log(total), i * block, axis=2)
+
+    return lax.fori_loop(0, seq // block, query_block, (
+        jnp.zeros((b, h, seq, v.shape[-1]), v.dtype), jnp.zeros((b, h, seq), jnp.float32)))
+
+
 def _blocked_attention_bwd(scale, block, kept, g):
+    with scope("attn_core"):
+        return _by_lowering(lm_attention.attention_bwd, loops_bwd, scale, block, *kept, g)
+
+
+def loops_bwd(q, k, v, out, lse, g, scale, block):
     """Each key block once: its dK and dV gather over the query blocks i >= j
     that see it, each tile's probabilities made again from the row's
     log-sum-exp; dQ is added into its rows as the tiles go by."""
-    q, k, v, out, lse = kept
-    with scope("attn_core"):
-        b, h, seq, _ = q.shape
-        # sum_k P dP of every row, which the softmax's backward subtracts: it is g . out
-        inner = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    seq = q.shape[2]
+    # sum_k P dP of every row, which the softmax's backward subtracts: it is g . out
+    inner = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
-        def key_block(j, carry):
-            dq, dk, dv = carry
-            kj, vj = _rows(k, j, block), _rows(v, j, block)
+    def key_block(j, carry):
+        dq, dk, dv = carry
+        kj, vj = _rows(k, j, block), _rows(v, j, block)
 
-            def query_block(i, state):
-                dq, dkj, dvj = state
-                qi, gi = _rows(q, i, block), _rows(g, i, block)
-                s = _tile_scores(qi, kj, i * block, j * block, scale)
-                probs = jnp.exp(s - _rows(lse, i, block)[..., None])
-                dvj = dvj + jnp.einsum("bhqk,bhqd->bhkd", probs.astype(g.dtype), gi, preferred_element_type=jnp.float32)
-                dp = jnp.einsum("bhqd,bhkd->bhqk", gi, vj, preferred_element_type=jnp.float32)
-                ds = (probs * (dp - _rows(inner, i, block)[..., None]) * scale).astype(q.dtype)
-                dq = _add_rows(dq, jnp.einsum("bhqk,bhkd->bhqd", ds, kj, preferred_element_type=jnp.float32), i, block)
-                dkj = dkj + jnp.einsum("bhqk,bhqd->bhkd", ds, qi, preferred_element_type=jnp.float32)
-                return dq, dkj, dvj
+        def query_block(i, state):
+            dq, dkj, dvj = state
+            qi, gi = _rows(q, i, block), _rows(g, i, block)
+            s = _tile_scores(qi, kj, i * block, j * block, scale)
+            probs = jnp.exp(s - _rows(lse, i, block)[..., None])
+            dvj = dvj + jnp.einsum("bhqk,bhqd->bhkd", probs.astype(g.dtype), gi, preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bhqd,bhkd->bhqk", gi, vj, preferred_element_type=jnp.float32)
+            ds = (probs * (dp - _rows(inner, i, block)[..., None]) * scale).astype(q.dtype)
+            dq = _add_rows(dq, jnp.einsum("bhqk,bhkd->bhqd", ds, kj, preferred_element_type=jnp.float32), i, block)
+            dkj = dkj + jnp.einsum("bhqk,bhqd->bhkd", ds, qi, preferred_element_type=jnp.float32)
+            return dq, dkj, dvj
 
-            dq, dkj, dvj = lax.fori_loop(j, seq // block, query_block, (
-                dq, jnp.zeros(kj.shape, jnp.float32), jnp.zeros(vj.shape, jnp.float32)))
-            return (dq, lax.dynamic_update_slice_in_dim(dk, dkj.astype(dk.dtype), j * block, axis=2),
-                    lax.dynamic_update_slice_in_dim(dv, dvj.astype(dv.dtype), j * block, axis=2))
+        dq, dkj, dvj = lax.fori_loop(j, seq // block, query_block, (
+            dq, jnp.zeros(kj.shape, jnp.float32), jnp.zeros(vj.shape, jnp.float32)))
+        return (dq, lax.dynamic_update_slice_in_dim(dk, dkj.astype(dk.dtype), j * block, axis=2),
+                lax.dynamic_update_slice_in_dim(dv, dvj.astype(dv.dtype), j * block, axis=2))
 
-        dq, dk, dv = lax.fori_loop(0, seq // block, key_block, (
-            jnp.zeros(q.shape, jnp.float32), jnp.zeros_like(k), jnp.zeros_like(v)))
-        return dq.astype(q.dtype), dk, dv
+    dq, dk, dv = lax.fori_loop(0, seq // block, key_block, (
+        jnp.zeros(q.shape, jnp.float32), jnp.zeros_like(k), jnp.zeros_like(v)))
+    return dq.astype(q.dtype), dk, dv
 
 
 _blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
@@ -165,9 +189,11 @@ _blocked_attention.defvjp(_blocked_attention_fwd, _blocked_attention_bwd)
 
 def causal_attention(q: Array, k: Array, v: Array, *, scale: float, block: int | None = None) -> Array:
     """Causal softmax(q k^T * scale) v, float32 softmax, a tile of `block`
-    query rows by `block` key rows at a time, forward and backward (the
-    backward recomputes each tile). q, k (B, S, H, D); v (B, S, H, Dv) ->
-    (B, S, H, Dv)."""
+    query rows by `block` key rows at a time and never one kept, forward and
+    backward (the backward recomputes each tile): in the fused TPU kernels of
+    ops/lm_attention.py where the shapes fit them and the step is lowered for
+    a TPU, else in the loops over tiles. q, k (B, S, H, D); v (B, S, H, Dv)
+    -> (B, S, H, Dv)."""
     seq = q.shape[1]
     block = min(block or ATTN_BLOCK, seq)
     if seq % block:

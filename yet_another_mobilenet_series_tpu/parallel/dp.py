@@ -65,7 +65,8 @@ def make_dp_train_step(
             params_example, _ = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
         opt_spec = zero.opt_state_specs(optimizer, params_example, mesh.size)
     inner = make_train_step(
-        net, cfg, optimizer, lr_fn, axis_name=DATA_AXIS, penalty_fn=penalty_fn, sharded_update=sharded_update
+        net, cfg, optimizer, lr_fn, axis_name=DATA_AXIS, penalty_fn=penalty_fn, sharded_update=sharded_update,
+        platform=mesh.devices.flat[0].platform,
     )
     if cfg.train.guard.enable:
         # device-side non-finite skip-and-rollback (train/guard.py). MUST

@@ -256,13 +256,19 @@ def _image_loss(net: Network, cfg: Config, axis_name: str | None, penalty_fn):
     return loss_fn, report, bn_axis
 
 
-def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None):
+def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: str | None):
     """The token family's loss (models/lm.py `TokenModel.loss`) in the same
     two pieces. Its state (router biases) is made identical across replicas
     inside the loss, so there is no axis the step would have to repair."""
     compute_dtype = _dtype(cfg.train.compute_dtype)
     if cfg.prune.enable or cfg.optim.mixup_alpha or cfg.optim.cutmix_alpha:
         raise ValueError(f"model.arch {net.arch!r} is a token model: prune.enable, mixup and cutmix are image-only")
+    # how many attention layers this step lowers through ops/lm_attention.py's fused kernels. A
+    # PREDICTION of the lowering, not a reading of it: ops/lm.py decides from each call's shapes (the
+    # same predicate) and the platform the step is in fact lowered for; here `platform` stands for that
+    sites, fitting = net.attention_sites(compute_dtype)
+    get_registry().gauge("train.attn_sites").set(sites)
+    get_registry().gauge("train.attn_fused_sites").set(fitting if (platform or jax.default_backend()) == "tpu" else 0)
 
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
         return net.loss(params, state, batch, compute_dtype=compute_dtype, axis_name=axis_name)
@@ -282,6 +288,7 @@ def make_train_step(
     axis_name: str | None = None,
     penalty_fn: Callable[[Any, Mapping[str, Any]], jax.Array] | None = None,
     sharded_update: Callable | None = None,
+    platform: str | None = None,
 ):
     """Returns step_fn(ts, batch, rng) -> (ts, metrics): ONE skeleton
     (gradients, their sync, the optimizer, EMA, the reported scalars) around
@@ -296,9 +303,17 @@ def make_train_step(
     replicated pmean+optax update with the ZeRO cross-replica sharded update
     (parallel/zero.py); it receives un-averaged local grads (the mean rides
     the psum_scatter).
+
+    ``platform`` is that of the devices the step will be lowered for (the
+    mesh's, which parallel/dp.py hands in; the default backend's when left
+    out). It moves nothing in the step: the gauges that PREDICT which
+    lowering a platform-dependent piece takes read it
+    (`train.attn_fused_sites`). A step built without it and then compiled
+    ahead of time for another platform than the default backend's reports the
+    default backend's count.
     """
     if isinstance(net, TokenModel):
-        loss_fn, report, bn_axis = _token_loss(net, cfg, axis_name)
+        loss_fn, report, bn_axis = _token_loss(net, cfg, axis_name, platform)
     else:
         loss_fn, report, bn_axis = _image_loss(net, cfg, axis_name, penalty_fn)
 
