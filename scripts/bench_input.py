@@ -2,8 +2,8 @@
 """Input-pipeline throughput benchmark (SURVEY.md §7 hard part 4: host decode
 can bottleneck a ≤8h/350-epoch run — 'measure images/sec/chip headroom
 early'). Measures images/sec of each available pipeline in isolation (no
-device compute), so it can be compared against bench.py's model-step
-images/sec/chip to see which side bounds a training run.
+device compute), so it can be compared against the benchmark's model-step
+images/sec/chip (benchmark/run.py) to see which side bounds a training run.
 
 Usage:
   python scripts/bench_input.py --pipeline fake                 # tf.data synthetic

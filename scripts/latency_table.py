@@ -13,9 +13,9 @@ turns the (alive channels -> seconds) ladders into per-atom marginal-latency
 cost vectors; ``prune.cost="latency_table"`` swaps them into the AtomNAS
 penalty — the search then optimizes what the serving fleet actually pays.
 
-Artifact contract: bench.py shape — exactly ONE JSON line on stdout,
-optional ``--out`` copy, provenance-stamped (bench.stamp_provenance:
-jax/jaxlib versions, platform, device kind, cpu-rehearsal flag). It measures
+Artifact contract: exactly ONE JSON line on stdout, optional ``--out``
+copy, provenance-stamped (scripts/provenance.py: jax/jaxlib versions,
+platform, device kind, cpu-rehearsal flag). It measures
 on the chip or fails: with no TPU, or when the measurement raises, it prints
 no table and exits non-zero. ``--cpu-rehearsal`` asks, explicitly, for a
 table of XLA:CPU timings — it checks the table's plumbing (the checked-in
@@ -184,8 +184,8 @@ def main(argv=None) -> int:
 
     import jax
 
-    from bench import stamp_provenance
     from yet_another_mobilenet_series_tpu.utils import compile_cache
+    from scripts.provenance import provenance
 
     compile_cache.configure()
     platform = jax.default_backend()
@@ -203,7 +203,7 @@ def main(argv=None) -> int:
     }
     out.update(measure(args.arch, image_sizes, widths, max(1, args.batch), max(1, args.iters)))
     out["value"] = float(len(out["entries"]))
-    stamp_provenance(out)
+    out["provenance"] = provenance()
     line = json.dumps(out)
     print(line)
     if args.out:

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Serving benchmark: latency/QPS per bucket + pipelined/bf16/chaos A/Bs.
 
-Prints exactly ONE JSON line on stdout in the bench.py artifact shape and
+Prints exactly ONE JSON line on stdout (metric / value / unit / provenance) and
 optionally writes it to a BENCH_SERVE_*.json via --out. It measures on the
 chip or fails: with no TPU it exits non-zero before measuring, and a
 measurement that raises emits ``value: null`` with an ``error`` field AND
@@ -30,8 +30,7 @@ Four measurements per run:
 3. **fp32-vs-bf16 A/B** — a second engine with compute_dtype=bfloat16,
    direct QPS per bucket plus the measured max |logit delta| vs fp32
    against the pinned BF16_PARITY_ATOL (serve/engine.py).
-4. **chained-vs-fused A/B** (``--fused``) — the serving twin of the training
-   dispatch probe (scripts/bench_bn.py): whole requests of K max-bucket chunks served
+4. **chained-vs-fused A/B** (``--fused``): whole requests of K max-bucket chunks served
    once through the per-chunk path (K dispatches, host staging between each)
    and once through the fused multi-chunk executables (serve/engine.py
    ``fuse_ladder``: ONE ``lax.scan`` dispatch per ladder piece). Per K:
@@ -246,7 +245,7 @@ def _parent_must_be_off_the_backend() -> None:
     """Called right before a fleet-mode parent starts its replicas: a parent
     that has initialised a JAX backend holds the chip on a TPU host, and its
     replicas would hang in backend init. Fail here, with the reason."""
-    from bench import backend_initialised
+    from scripts.provenance import backend_initialised
 
     if backend_initialised():
         raise RuntimeError("this parent initialised a JAX backend before its replicas "
@@ -2621,14 +2620,14 @@ def measure(arch, image_sizes, buckets, iters, conc_iters, ab_iters, max_infligh
         for k in snap
         if k.startswith("serve.") and k.endswith(".count") and snap[k] > 0
     }
-    from bench import provenance
+    from scripts.provenance import provenance
 
     dev = jax.devices()[0]
     out = {
         "platform": dev.platform,
         "device_kind": dev.device_kind,
         "n_chips": len(jax.devices()),
-        # shared bench provenance stamp (bench.py): jax/jaxlib versions +
+        # shared provenance stamp (scripts/provenance.py): jax/jaxlib versions +
         # cpu-rehearsal flag, so every serving artifact is attributable
         "provenance": provenance(),
         "warmup_compile_s": round(warmup_s, 2),
@@ -2841,7 +2840,7 @@ def main(argv=None) -> int:
                 flap_period_s=1.0,
                 flap_down_s=0.5,
             )
-            from bench import provenance
+            from scripts.provenance import provenance
 
             # no backend is ever touched: a loopback rehearsal by
             # construction (the real multi-host run is the ROADMAP rung)
@@ -2882,7 +2881,7 @@ def main(argv=None) -> int:
             )
             import jax
 
-            from bench import provenance
+            from scripts.provenance import provenance
 
             dev = jax.devices()[0]
             out.update({"platform": dev.platform, "device_kind": dev.device_kind,
@@ -2924,7 +2923,7 @@ def main(argv=None) -> int:
             )
             import jax
 
-            from bench import provenance
+            from scripts.provenance import provenance
 
             dev = jax.devices()[0]
             out.update({"platform": dev.platform, "device_kind": dev.device_kind,
@@ -2964,7 +2963,7 @@ def main(argv=None) -> int:
             )
             import jax
 
-            from bench import provenance
+            from scripts.provenance import provenance
 
             dev = jax.devices()[0]
             out.update({"platform": dev.platform, "device_kind": dev.device_kind,

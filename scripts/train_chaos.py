@@ -2,7 +2,7 @@
 """Training chaos round: corrupt records + an injected NaN step + a SIGTERM
 preemption, then a resume — the serve_bench chaos A/B's training twin.
 
-Prints exactly ONE JSON line on stdout in the bench.py artifact shape (a
+Prints exactly ONE JSON line on stdout in serve_bench.py's artifact shape (a
 failed round emits ``value: null`` with an ``error`` field and exits
 non-zero) and optionally writes it via --out. A drill of what the program
 COUNTS, not a device measurement: the children are held to the CPU backend
@@ -171,7 +171,7 @@ def main(argv=None) -> int:
 
         log_dir = tempfile.mkdtemp(prefix="yamt_train_chaos_")
 
-    from bench import provenance
+    from scripts.provenance import provenance
 
     artifact = {
         "metric": "train_chaos_recovered_steps",
@@ -180,7 +180,7 @@ def main(argv=None) -> int:
         "vs_baseline": None,
         "platform": "cpu",
         "log_dir": log_dir,
-        # shared bench provenance stamp (bench.py). cpu_rehearsal is pinned:
+        # shared provenance stamp (scripts/provenance.py). cpu_rehearsal is pinned:
         # the children run under JAX_PLATFORMS=cpu and this parent process
         # never imports jax, so the stamp cannot infer it
         "provenance": provenance(cpu_rehearsal=True),
@@ -204,6 +204,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # match the bench.py contract: a SIGTERM'd driver still gets the artifact
+    # the artifact contract: a SIGTERM'd driver still gets the artifact
     signal.signal(signal.SIGTERM, signal.SIG_DFL)
     sys.exit(main())

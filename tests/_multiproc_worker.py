@@ -67,10 +67,6 @@ def main():
         # 36 each, batch 16 -> 3 padded batches/host (equalization exercised)
         data = {"dataset": "fake", "image_size": 32, "fake_train_size": 1280, "fake_eval_size": 72}
         epochs = 2.0
-    # fake scenario also exercises grouped dispatch under REAL multi-process
-    # jax.distributed (2 steps/jit call; cross-host collectives inside the
-    # unrolled program). folder's 1 step/epoch never reaches a full group.
-    steps_per_dispatch = 2 if scenario in ("fake", "fake4") else 1
     cfg = config_from_dict({
         "name": "multiproc",
         "model": {
@@ -90,7 +86,6 @@ def main():
             "batch_size": 64,
             "eval_batch_size": 32,
             "epochs": epochs,
-            "steps_per_dispatch": steps_per_dispatch,
             "log_every": 2,
             "compute_dtype": "float32",
             "log_dir": tmpdir,

@@ -1,10 +1,7 @@
 """The artifact contract for the benchmark entry points: ONE parsed JSON line
 on stdout, and NO result under a device metric's name without the device.
 
-- bench.py measures on the chip or fails: here, without a TPU, it exits
-  non-zero and prints no `images/sec/chip`; `--cpu` is a control-flow smoke
-  whose line carries no rate at all (tests/test_zz_chip_path.py pins both).
-- scripts/serve_bench.py: the serving benchmark emits the same artifact
+- scripts/serve_bench.py: the serving benchmark emits one artifact
   shape (BENCH_SERVE_*.json — p50/p99 latency + QPS per batch bucket) and
   is fast enough to stay in the tier-1 gate via its tiny preset. These runs
   are on the CPU, so they ask for it (`--cpu-rehearsal`): the counts they
@@ -438,7 +435,7 @@ def _assert_fused_ab(fz):
 
 
 def test_serve_bench_emits_parsed_artifact(tmp_path):
-    """scripts/serve_bench.py: exactly one JSON line, bench.py artifact
+    """scripts/serve_bench.py: exactly one JSON line, the artifact
     shape, p50/p99/QPS per (bucket, image_size) plus the sync-vs-pipelined
     and fp32-vs-bf16 A/B sections — the BENCH_SERVE_* contract."""
     out_path = tmp_path / "BENCH_SERVE_test.json"
@@ -463,7 +460,7 @@ def test_serve_bench_emits_parsed_artifact(tmp_path):
     assert out["unit"].startswith("images/sec on XLA:CPU (rehearsal")
     assert out["vs_baseline"] is None  # no serving reference divisor exists
     assert out["platform"]
-    # the shared provenance stamp (bench.py): every bench artifact is
+    # the shared provenance stamp (scripts/provenance.py): every bench artifact is
     # version/hardware attributable
     prov = out["provenance"]
     assert prov["jax_version"] and prov["jaxlib_version"] and prov["python"]

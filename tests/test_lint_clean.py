@@ -63,40 +63,60 @@ def test_scripts_lint_clean_under_curated_subset():
     )
 
 
+# the linter's CPU time over the CPU time of parsing and walking the same
+# files five times; see test_whole_package_lint_stays_fast
+LINT_COST_BAR = 3.5
+
+
 def test_whole_package_lint_stays_fast():
-    # un-cached end-to-end runs, interprocedural layer included (measured
-    # ~3.3-4.5s on the 1-core box with the full 25-rule set, so the 5s bar
-    # trips on a complexity regression, not machine noise). Timed in a
-    # FRESH subprocess: 500-odd tests into a tier-1 session, pytest's
-    # warning capture and stray daemon threads were measured inflating the
-    # same run past 6s — that noise belongs to the suite, not the linter,
-    # and it's the linter this bar gates. The child times only run_lint
-    # (imports excluded; analysis/ is pure-stdlib, ~0.3s to load) and
-    # reports the MIN of three runs: this box's scheduler was measured
-    # stretching identical runs ±40%, and the minimum estimates the true
-    # compute cost — a complexity regression raises every sample, noise
-    # only some (each run rebuilds its Project, so nothing is amortized).
-    # The clock is the child's CPU time, not the wall: the linter is one
-    # thread of pure Python, and under the driver's six xdist workers on
-    # eight cores the wall of the SAME work read 5.70s best-of-3 (7.7-9.0s
-    # beside 14 busy loops) where its CPU time read 3.97s, 3.2s idle (PR 29):
-    # time spent waiting for a core is the suite's, not the linter's.
+    """An un-cached whole-package run, interprocedural layer included, must
+    stay effectively free, or people stop running it. The bar is RELATIVE: a
+    bar in seconds (5 s of CPU until PR 30) holds on one machine only, and
+    the driver's read more than that for work the builder's did in 3.97.
+
+    Measured in a FRESH subprocess (pytest's warning capture and stray daemon
+    threads of a 700-test session are the suite's cost, not the linter's),
+    on the child's CPU clock (the linter is one thread of pure Python; time
+    spent waiting for a core under six xdist workers is the suite's too), and
+    against a yardstick timed beside it in the same child: `ast.parse` +
+    `ast.walk` over the very files the linter reads, five times, which is
+    the same kind of work (pure Python over ASTs and dicts, bound by the
+    same caches) and grows with the package as the linter's irreducible part
+    does. Each is the MIN of three interleaved samples: a slower or busier
+    core stretches both, a complexity regression in a rule or in the
+    summary fixpoint raises every sample of the linter's alone, and the
+    package growing by a third moves neither side of the ratio. Each
+    run_lint rebuilds its Project, so nothing is amortized. On the builder's
+    machine the ratio read 1.98-2.07 alone and 2.03-2.48 beside five busy
+    xdist workers at PR 30, 2.34-2.61 beside five at PR 31 (CHANGES.md has
+    the readings): the bar trips at 1.5x the usual reading, 1.3x the worst."""
     code = (
-        "import pathlib, time\n"
+        "import ast, pathlib, time\n"
         "from yet_another_mobilenet_series_tpu.analysis import run_lint\n"
+        "from yet_another_mobilenet_series_tpu.analysis.core import collect_paths\n"
         f"pkg = pathlib.Path({str(PACKAGE)!r})\n"
-        "best = min(\n"
-        "    (lambda t0: (run_lint([pkg]), time.process_time() - t0)[1])(time.process_time())\n"
-        "    for _ in range(3)\n"
-        ")\n"
-        "print(best)\n"
+        "sources = [pathlib.Path(p).read_text() for p in collect_paths([pkg])[0]]\n"
+        "def cpu(fn):\n"
+        "    t0 = time.process_time()\n"
+        "    fn()\n"
+        "    return time.process_time() - t0\n"
+        "def yardstick():\n"
+        "    for _ in range(5):\n"
+        "        for src in sources:\n"
+        "            for _ in ast.walk(ast.parse(src)):\n"
+        "                pass\n"
+        "runs = [(cpu(lambda: run_lint([pkg])), cpu(yardstick)) for _ in range(3)]\n"
+        "print(min(r[0] for r in runs), min(r[1] for r in runs))\n"
     )
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120,
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=300,
     )
     assert out.returncode == 0, out.stderr
-    elapsed = float(out.stdout.strip().splitlines()[-1])
-    assert elapsed < 5.0, f"run_lint over the package took {elapsed:.2f}s of CPU best-of-3 (bar: 5s)"
+    lint, walk = (float(v) for v in out.stdout.strip().splitlines()[-1].split())
+    print(f"run_lint {lint:.2f}s CPU, yardstick {walk:.2f}s CPU, ratio {lint / walk:.3f}")
+    assert lint / walk < LINT_COST_BAR, (
+        f"run_lint over the package took {lint:.2f}s of CPU best-of-3, {lint / walk:.2f}x the "
+        f"{walk:.2f}s that parsing and walking the same files five times took beside it (bar: {LINT_COST_BAR}x)")
 
 
 def test_apps_ymls_are_covered():

@@ -175,7 +175,7 @@ def test_conv_bn_pair_on_the_masked_supernet_and_its_fallback_after_a_shrink(mon
 
         return jax.jit(jax.value_and_grad(loss))(params)
 
-    assert net.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False)[0] == 4  # blocks 1-3 and the head
+    assert net.conv_bn_pair_sites()[0] == 4  # blocks 1-3 and the head
     loss_pair, g_pair = grads()
     monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
     loss_plain, g_plain = grads()
@@ -191,8 +191,8 @@ def test_conv_bn_pair_on_the_masked_supernet_and_its_fallback_after_a_shrink(mon
     shrunk = dataclasses.replace(blk, expanded_channels=blk.in_channels, group_channels=(blk.in_channels,),
                                  kernel_sizes=(3,), force_expand=True)
     assert shrunk.has_expand
-    assert blk.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == (1, 2)
-    assert shrunk.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == (0, 2)
+    assert blk.conv_bn_pair_sites() == (1, 2)
+    assert shrunk.conv_bn_pair_sites() == (0, 2)
 
 
 @pytest.mark.slow
@@ -302,122 +302,3 @@ def test_prune_event_matches_legacy_host_semantics():
     m4, r4 = event_hit(params, masks, rho, jnp.asarray(2))
     np.testing.assert_array_equal(np.asarray(m4["1"]), np.asarray(masks["1"]))
     np.testing.assert_allclose(float(r4), 0.95, rtol=1e-6)
-
-
-def test_grouped_search_step_equals_singles():
-    """VERDICT r4 next #4: k-step grouped dispatch WITH pruning active equals
-    k single dispatches — masks bit-identical (threshold decisions), rho_mult
-    identical, params within the grouped path's cross-step-fusion tolerance.
-    The event runs host-gated after each single dispatch and in-device after
-    each grouped sub-step; both share one jitted make_prune_event program."""
-    from yet_another_mobilenet_series_tpu.config import config_from_dict
-    from yet_another_mobilenet_series_tpu.parallel import dp, mesh as mesh_lib
-    from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
-
-    cfg = config_from_dict({
-        "model": {"arch": "atomnas_supernet", "num_classes": 4, "dropout": 0.0,
-                  "block_specs": [
-                      {"t": 6, "c": 8, "n": 2, "s": 2, "k": [3, 5]},
-                      {"t": 6, "c": 12, "n": 1, "s": 2, "k": [3, 5], "se": 0.25},
-                  ]},
-        "optim": {"optimizer": "sgd", "weight_decay": 0.0},
-        "schedule": {"schedule": "constant", "base_lr": 0.05,
-                     "scale_by_batch": False, "warmup_epochs": 0.0},
-        "ema": {"enable": False},
-        "train": {"compute_dtype": "float32"},
-        # normalize_cost (default) keeps the per-atom L1 gradient small —
-        # with raw-MACs costs one SGD step blasts the seeded gammas far past
-        # the threshold magnitude and no atom ever dies
-        "prune": {"enable": True, "rho": 1e-4, "mask_interval": 2, "gamma_threshold": 0.12,
-                  "target_flops": 1.0, "rho_schedule": "adaptive", "rho_adapt_rate": 0.05},
-        "dist": {"sync_bn": True},
-    })
-    net = get_model(cfg.model, image_size=16)
-    m = mesh_lib.make_mesh(8)
-    lr_fn = schedules.make_lr_schedule(cfg.schedule, 16, 1, 100)
-    params, _ = net.init(jax.random.PRNGKey(0))
-    opt = optim.make_optimizer(cfg.optim, lr_fn, params)
-    pen = penalty.make_penalty_fn(net, cfg.prune)
-    step = dp.make_dp_train_step(net, cfg, opt, lr_fn, m, penalty_fn=pen)
-    event = jax.jit(masking.make_prune_event(net, cfg.prune, stop_step=100))
-
-    def fresh_ts():
-        ts = steps.init_train_state(net, cfg, opt, jax.random.PRNGKey(0))
-        # seed some gammas below threshold: deaths at events (steps 2 and 4)
-        p = jax.tree.map(jnp.copy, ts.params)
-        g = np.asarray(p["blocks"]["0"]["dw_bn"]["gamma"]).copy()
-        g[1:4] = 0.01
-        p["blocks"]["0"]["dw_bn"]["gamma"] = jnp.asarray(g)
-        return mesh_lib.replicate(
-            ts.replace(params=p, masks=masking.init_masks(net)), m)
-
-    rng = jax.random.PRNGKey(9)
-    batches = [
-        mesh_lib.shard_batch({
-            "image": np.asarray(jax.random.normal(jax.random.PRNGKey(20 + i), (16, 16, 16, 3))),
-            "label": np.asarray((jnp.arange(16) + i) % 4),
-        }, m)
-        for i in range(4)
-    ]
-
-    ts_single = fresh_ts()
-    init_alive = float(sum(np.asarray(v).sum() for v in jax.device_get(ts_single.masks).values()))
-    for i, b in enumerate(batches):
-        ts_single, _ = step(ts_single, b, rng)
-        if (i + 1) % cfg.prune.mask_interval == 0:  # host gate, like the CLI
-            masks, rho = event(ts_single.params, ts_single.masks,
-                               ts_single.rho_mult, ts_single.step)
-            ts_single = ts_single.replace(masks=masks, rho_mult=rho)
-
-    grouped = dp.make_grouped_train_step(step, 2, event_fn=event)
-    ts_grp = fresh_ts()
-    ts_grp, _ = grouped(ts_grp, tuple(batches[:2]), rng)
-    ts_grp, _ = grouped(ts_grp, tuple(batches[2:]), rng)
-
-    ms, mg = jax.device_get(ts_single.masks), jax.device_get(ts_grp.masks)
-    for k in ms:
-        np.testing.assert_array_equal(np.asarray(ms[k]), np.asarray(mg[k]), err_msg=f"masks[{k}]")
-    # the search actually pruned (the equality is not vacuous)
-    final_alive = float(sum(np.asarray(v).sum() for v in ms.values()))
-    assert final_alive < init_alive
-    # adaptive rho advanced identically (2 events, never reached): 1.05^2
-    np.testing.assert_allclose(float(ts_single.rho_mult), 1.05 ** 2, rtol=1e-6)
-    np.testing.assert_allclose(float(ts_grp.rho_mult), float(ts_single.rho_mult), rtol=1e-7)
-    for a, b in zip(jax.tree.leaves(jax.device_get(ts_single.params)),
-                    jax.tree.leaves(jax.device_get(ts_grp.params))):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
-
-    # epoch-TAIL composition: with 5 steps and k=2 the CLI dispatches
-    # [grouped, grouped, single]; a cadence step landing on the single tail
-    # (interval=5 -> event only at step 5) must still fire the event via the
-    # host path (cli/train.py gates it on len(metric_list)==1, not on
-    # grouping being off — the round-5 review caught the tail being dropped)
-    import dataclasses as dc_
-
-    cfg_t = dc_.replace(cfg, prune=dc_.replace(cfg.prune, mask_interval=5))
-    event_t = jax.jit(masking.make_prune_event(net, cfg_t.prune, stop_step=100))
-    b5 = batches + [mesh_lib.shard_batch({
-        "image": np.asarray(jax.random.normal(jax.random.PRNGKey(30), (16, 16, 16, 3))),
-        "label": np.asarray(jnp.arange(16) % 4)}, m)]
-
-    ts_s = fresh_ts()
-    for i, b in enumerate(b5):
-        ts_s, _ = step(ts_s, b, rng)
-        if (i + 1) % 5 == 0:
-            masks, rho = event_t(ts_s.params, ts_s.masks, ts_s.rho_mult, ts_s.step)
-            ts_s = ts_s.replace(masks=masks, rho_mult=rho)
-
-    grouped_t = dp.make_grouped_train_step(step, 2, event_fn=event_t)
-    ts_g = fresh_ts()
-    ts_g, _ = grouped_t(ts_g, tuple(b5[:2]), rng)
-    ts_g, _ = grouped_t(ts_g, tuple(b5[2:4]), rng)
-    ts_g, _ = step(ts_g, b5[4], rng)  # the tail single dispatch...
-    masks, rho = event_t(ts_g.params, ts_g.masks, ts_g.rho_mult, ts_g.step)
-    ts_g = ts_g.replace(masks=masks, rho_mult=rho)  # ...takes the host path
-
-    ms, mg = jax.device_get(ts_s.masks), jax.device_get(ts_g.masks)
-    for k in ms:
-        np.testing.assert_array_equal(np.asarray(ms[k]), np.asarray(mg[k]),
-                                      err_msg=f"tail masks[{k}]")
-    assert float(sum(np.asarray(v).sum() for v in ms.values())) < init_alive  # event fired
-    np.testing.assert_allclose(float(ts_g.rho_mult), float(ts_s.rho_mult), rtol=1e-7)

@@ -2,7 +2,7 @@
 eviction, Chrome-trace schema), stall watchdog (fires on an injected stall,
 silent on a healthy loop), Logger integration (TF-less degrade, registry
 snapshots in scalars rows), the fake-data train smoke (trace + snapshot
-artifacts for steps_per_dispatch 1 and >1), and scripts/obs_report.py."""
+artifacts), and scripts/obs_report.py."""
 
 import importlib.util
 import json
@@ -675,7 +675,7 @@ def test_emit_routes_through_active_logger(capsys):
 # ---------------------------------------------------------------------------
 
 
-def _smoke_cfg(tmp_path, k_dispatch):
+def _smoke_cfg(tmp_path):
     return config_from_dict({
         "name": "obs-smoke",
         "model": {
@@ -689,7 +689,6 @@ def _smoke_cfg(tmp_path, k_dispatch):
         "train": {
             "batch_size": 32, "eval_batch_size": 16, "epochs": 1, "log_every": 1,
             "compute_dtype": "float32", "log_dir": str(tmp_path),
-            "steps_per_dispatch": k_dispatch,
         },
         # trace on; generous watchdog deadline proves it stays silent on a
         # healthy loop even with compiles in the gap
@@ -698,9 +697,8 @@ def _smoke_cfg(tmp_path, k_dispatch):
     })
 
 
-@pytest.mark.parametrize("k_dispatch", [1, 2])
-def test_train_smoke_emits_trace_and_registry_snapshot(tmp_path, k_dispatch):
-    result = cli_train.run(_smoke_cfg(tmp_path, k_dispatch))
+def test_train_smoke_emits_trace_and_registry_snapshot(tmp_path):
+    result = cli_train.run(_smoke_cfg(tmp_path))
     assert result["epoch"] == pytest.approx(1.0)
 
     # valid Chrome-trace JSON with spans from all five core categories
@@ -712,14 +710,7 @@ def test_train_smoke_emits_trace_and_registry_snapshot(tmp_path, k_dispatch):
         assert e["ts"] >= 0 and e["dur"] >= 0
     cats = {e["cat"] for e in evts}
     assert {"data", "dispatch", "sync", "eval", "ckpt"} <= cats, cats
-    names = {e["name"] for e in evts}
-    if k_dispatch > 1:
-        # spans COMPOSE with grouped dispatch instead of forcing it off
-        assert "dispatch/grouped_step" in names
-        grouped = next(e for e in evts if e["name"] == "dispatch/grouped_step")
-        assert grouped["args"]["steps"] == k_dispatch
-    else:
-        assert "dispatch/train_step" in names
+    assert "dispatch/train_step" in {e["name"] for e in evts}
 
     # registry snapshot written at run end
     snap = json.loads((tmp_path / "obs_registry.json").read_text())

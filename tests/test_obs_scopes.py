@@ -3,7 +3,7 @@ and the rule that reads them back (docs/OBSERVABILITY.md "Named scopes").
 
 On a two-block toy net's full train step compiled on the CPU: every listed
 scope the step contains appears in the scope table, forward and backward are
-told apart, every BN_MODES variant resolves to bn_*, and the scopes are
+told apart, BatchNorm resolves to bn_*, and the scopes are
 metadata only (the compiled program is the same program without them).
 """
 
@@ -19,7 +19,7 @@ import pytest
 from yet_another_mobilenet_series_tpu.config import parse_cli
 from yet_another_mobilenet_series_tpu.models import get_model
 from yet_another_mobilenet_series_tpu.obs import scopes
-from yet_another_mobilenet_series_tpu.ops.layers import BN_MODES, BatchNorm
+from yet_another_mobilenet_series_tpu.ops.layers import BatchNorm
 from yet_another_mobilenet_series_tpu.parallel import dp, mesh as mesh_lib
 from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
 
@@ -133,16 +133,14 @@ def test_collectives_are_named_on_a_mesh():
     assert reduces and all(table[name][0] in ("syncbn", "grad_sync", "loss") for name in reduces)
 
 
-@pytest.mark.parametrize("bn_mode", BN_MODES)
-def test_every_bn_mode_lands_in_bn_scopes(bn_mode):
+def test_batchnorm_lands_in_bn_scopes():
     """BatchNorm.apply alone under grad, lowered: every operation it traces,
-    in the forward and in the backward (the custom_vjp pair of fused_vjp
-    included), is under bn_stats or bn_apply, whatever the normalize variant."""
+    in the forward and in the backward, is under bn_stats or bn_apply."""
     bn = BatchNorm(8)
     params, state = bn.init()
 
     def loss(params, x):
-        y, new_state = bn.apply(params, state, x, train=True, mode=bn_mode)
+        y, new_state = bn.apply(params, state, x, train=True)
         with scopes.scope("loss"):
             return jnp.sum(y.astype(jnp.float32) ** 2), new_state
 
@@ -170,7 +168,7 @@ def test_the_conv_bn_pair_lands_in_its_scopes():
     conv, bn = layers.Conv2D(8, 24, 1), BatchNorm(24)
     conv_params = jax.eval_shape(lambda: conv.init(jax.random.PRNGKey(0)))
     bn_params, bn_state = bn.init()
-    assert layers.conv_bn_pairs(conv, train=True, bn_mode="exact")
+    assert layers.conv_bn_pairs(conv, train=True)
 
     def loss(conv_params, bn_params, x):
         y, _ = layers.conv_bn(conv, bn, conv_params, bn_params, bn_state, x, train=True, axis_name="data",
@@ -275,12 +273,11 @@ def _strip(text: str) -> str:
     return re.sub(r", metadata=\{[^}]*\}", "", text)
 
 
-@pytest.mark.parametrize("overrides", [(), ("train.remat=true", "train.remat_policy=save_conv")],
-                         ids=["plain", "remat_save_conv"])
+@pytest.mark.parametrize("overrides", [(), ("train.remat=true",)], ids=["plain", "remat"])
 def test_scopes_add_nothing_to_the_compiled_step(monkeypatch, overrides):
     """The step compiled with the scopes, and with jax.named_scope patched to
     a no-op, metadata stripped: the same HLO, instruction for instruction.
-    Under the save_conv remat policy too, which keys on checkpoint_name."""
+    Under jax.checkpoint too."""
     with_scopes = lowered_step(*overrides).compile().as_text()
     assert "bn_stats" in with_scopes
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
@@ -289,15 +286,32 @@ def test_scopes_add_nothing_to_the_compiled_step(monkeypatch, overrides):
     assert _strip(with_scopes) == _strip(without)
 
 
-@pytest.mark.parametrize("overrides, digest", [
-    ((), "bc356c6ecdd1f249"), (("train.remat=true", "train.remat_policy=save_conv"), "0b8f9908387f86a2")],
-    ids=["plain", "remat_save_conv"])
+@pytest.mark.parametrize("overrides, digest", [((), "bddcadd2b0fb7542"), (("train.remat=true",), "44bade5e264b5f77")],
+                         ids=["plain", "remat"])
 def test_the_cnn_step_is_the_program_it_was_before_the_token_family(overrides, digest):
-    """train/steps.py has ONE step skeleton for two families since PR 27. The
+    r"""train/steps.py has ONE step skeleton for two families since PR 27. The
     toy CNN step's lowered module (StableHLO text: no locations, no machine in
-    it) is, byte for byte, what the commit before lowered: the digests below
-    were taken there (14d60ea). A change that means to alter the CNN step
-    takes a new digest, and says so."""
+    it) is pinned by digest. `plain` was bc356c6ecdd1f249 from 14d60ea to PR
+    29; PR 30's deletion (here since PR 31) took Conv2D.apply's
+    checkpoint_name landmark out, which lowers to nothing but was counted
+    where jax numbers the module's private functions (`@clip_89` became
+    `@clip_87`): the text is 7b6c8ef's but for those suffixes and the compiled
+    HLO, under _strip, 7b6c8ef's byte for byte. Taking BatchNorm's mode switch out
+    (PR 31) moved neither: the cells never took that branch. A change that means
+    to alter the CNN step takes a new digest, and says so. To check the
+    equality again, in each of two trees (`git archive 7b6c8ef | tar -x -C
+    $P`, and this one):
+
+        cd $TREE/tests && JAX_PLATFORMS=cpu PYTHONPATH=$TREE python -c "
+        import hashlib, re, test_obs_scopes as t
+        low = t.lowered_step()
+        sha = lambda s: hashlib.sha256(s.encode()).hexdigest()[:16]
+        print(sha(t._strip(low.compile().as_text())), sha(re.sub(r'(@\w+?)_\d+\b', r'\1', low.as_text())))"
+
+    prints `a8494252204cf0c2 9d565d1460e09351` in both (jax 0.9.0, XLA:CPU);
+    with `t.lowered_step('train.remat=true')` here and there (7b6c8ef's
+    default remat policy, `full`, is the plain jax.checkpoint that stays),
+    `d9f95915585aff34 9b4e5a03066168a7`."""
     import hashlib
 
     text = lowered_step(*overrides).as_text()
@@ -353,7 +367,9 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # version 2: the conv + BN pair's forward and custom backward (PR 26)
         "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "mlp", "moe_combine",
                       "moe_dispatch", "moe_experts", "moe_router", "norm", "rope"],
-        "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
+        # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name
+        # in a compiled cell moved, so no bump
+        "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],
         "train/guard.py": ["guard"],

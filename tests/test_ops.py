@@ -60,36 +60,6 @@ def test_conv2d_matches_torch(cin, cout, k, stride, groups):
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-4, atol=1e-5)
 
 
-def test_conv1x1_as_dot_matches_conv_lowering():
-    """as_dot (the round-3 weight-grad MXU experiment, train.conv1x1_dot)
-    must be a pure lowering change: forward values and weight gradients
-    match the conv_general_dilated path, including the stride>1 subsample
-    case; k>1 and grouped convs ignore the flag entirely."""
-    for cin, cout, stride in [(8, 16, 1), (8, 16, 2), (16, 5, 1)]:
-        spec = ops.Conv2D(cin, cout, 1, stride)
-        params = spec.init(jax.random.PRNGKey(0))
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 9, cin))
-
-        y_conv = spec.apply(params, x)
-        y_dot = spec.apply(params, x, as_dot=True)
-        np.testing.assert_allclose(np.asarray(y_dot), np.asarray(y_conv), rtol=1e-5, atol=1e-6)
-
-        def loss(p, as_dot):
-            return jnp.sum(jnp.square(spec.apply(p, x, as_dot=as_dot)))
-
-        g_conv = jax.grad(loss)(params, False)["w"]
-        g_dot = jax.grad(loss)(params, True)["w"]
-        np.testing.assert_allclose(np.asarray(g_dot), np.asarray(g_conv), rtol=1e-4, atol=1e-5)
-
-    # non-1x1 / grouped: flag is a no-op (same lowering, identical values)
-    dw = ops.Conv2D(8, 8, 3, 1, groups=8)
-    pdw = dw.init(jax.random.PRNGKey(2))
-    xdw = jax.random.normal(jax.random.PRNGKey(3), (2, 7, 7, 8))
-    np.testing.assert_array_equal(
-        np.asarray(dw.apply(pdw, xdw, as_dot=True)), np.asarray(dw.apply(pdw, xdw))
-    )
-
-
 def test_batchnorm_matches_torch_train_and_eval():
     import torch
 
@@ -122,232 +92,128 @@ def test_batchnorm_matches_torch_train_and_eval():
     assert same_state is new_state
 
 
-def test_batchnorm_modes_equivalent():
-    """The bn_mode perf variants (ops/layers.py; the round-2 trace's 52%
-    BN-reduction attack) must be semantics-preserving: statistics bit-exact
-    in every mode; "folded" normalize within f32 re-association rounding of
-    "exact"; "compute" within bf16 tolerance on bf16 inputs."""
-    c = 12
-    spec = ops.BatchNorm(c)
-    params, state = spec.init()
-    rs = np.random.RandomState(0)
-    params["gamma"] = jnp.asarray(rs.uniform(0.5, 1.5, c).astype(np.float32))
-    params["beta"] = jnp.asarray(rs.uniform(-0.5, 0.5, c).astype(np.float32))
-    x = jnp.asarray(rs.normal(2.0, 3.0, (8, 7, 7, c)).astype(np.float32))
-
-    for train in (True, False):
-        y_exact, st_exact = spec.apply(params, state, x, train=train, mode="exact")
-        y_folded, st_folded = spec.apply(params, state, x, train=train, mode="folded")
-        y_compute, st_compute = spec.apply(params, state, x, train=train, mode="compute")
-        for st in (st_folded, st_compute):
-            for k in ("mean", "var"):
-                np.testing.assert_array_equal(np.asarray(st[k]), np.asarray(st_exact[k]))
-        np.testing.assert_allclose(np.asarray(y_folded), np.asarray(y_exact), rtol=2e-6, atol=2e-6)
-        np.testing.assert_allclose(np.asarray(y_compute), np.asarray(y_exact), rtol=2e-2, atol=2e-2)
-
-    # bf16 activations (the real training dtype): folded stays within one
-    # bf16 ulp of exact after the output cast; gradients agree too.
-    xb = x.astype(jnp.bfloat16)
-    yb_exact, _ = spec.apply(params, state, xb, train=True, mode="exact")
-    yb_folded, _ = spec.apply(params, state, xb, train=True, mode="folded")
-    yb_compute, _ = spec.apply(params, state, xb, train=True, mode="compute")
-    np.testing.assert_allclose(
-        np.asarray(yb_folded, np.float32), np.asarray(yb_exact, np.float32), rtol=1e-2, atol=1e-2
-    )
-    np.testing.assert_allclose(
-        np.asarray(yb_compute, np.float32), np.asarray(yb_exact, np.float32), rtol=4e-2, atol=4e-2
-    )
-
-    def loss(p, mode):
-        y, _ = spec.apply(p, state, x, train=True, mode=mode)
-        return jnp.sum(jnp.square(y) * jnp.cos(jnp.arange(y.size).reshape(y.shape)))
-
-    g_exact = jax.grad(loss)(params, "exact")
-    g_folded = jax.grad(loss)(params, "folded")
-    for k in ("gamma", "beta"):
-        np.testing.assert_allclose(np.asarray(g_folded[k]), np.asarray(g_exact[k]), rtol=1e-4, atol=1e-4)
-
-    with pytest.raises(ValueError):
-        spec.apply(params, state, x, train=True, mode="nope")
+def _bn_closed_form_float64(x, dy, gamma, eps, shards):
+    """BatchNorm's training backward written out in float64 NumPy, under the
+    step's per-device contract (ops/layers.py _bn_grad_sums): moments and n
+    over the WHOLE batch, dβ = Σ dy and dγ = Σ dy·x̂ per shard of the batch,
+    dx = γ·inv·(dy − Σ_all dy/n − x̂·Σ_all dy·x̂/n)."""
+    x, dy, gamma = (np.asarray(v, np.float64) for v in (x, dy, gamma))
+    n = x.shape[0] * x.shape[1] * x.shape[2]
+    mean = x.mean(axis=(0, 1, 2))
+    inv = 1.0 / np.sqrt(x.var(axis=(0, 1, 2)) + eps)
+    x_hat = (x - mean) * inv
+    s1, s2 = dy.sum(axis=(0, 1, 2)), (dy * x_hat).sum(axis=(0, 1, 2))
+    dx = gamma * inv * (dy - s1 / n - x_hat * (s2 / n))
+    rows = x.shape[0] // shards
+    part = lambda v: np.stack([v[i * rows:(i + 1) * rows].sum(axis=(0, 1, 2)) for i in range(shards)])
+    return {"mean": mean, "inv": inv, "dbeta": part(dy), "dgamma": part(dy * x_hat), "s1": s1, "s2": s2, "dx": dx}
 
 
-def test_batchnorm_fused_vjp_matches_autodiff():
-    """mode='fused_vjp': forward values equal 'folded' bit-for-bit, running
-    stats equal every other mode's, and the closed-form backward reproduces
-    autodiff-through-the-moments gradients for x, gamma, AND beta."""
-    c = 12
+@pytest.mark.parametrize("shards", [1, 4], ids=["one_device", "syncbn_4"])
+@pytest.mark.parametrize("shape", [(8, 7, 7, 12), (16, 3, 3, 4), (4, 5, 6, 24)], ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_batchnorm_backward_matches_closed_form_in_float64(dtype, shape, shards):
+    """Autodiff through BatchNorm's batch moments gives dx, dγ and dβ of the
+    closed form, and _bn_grad_sums (the conv + BN pair's half of it) gives its
+    four sums: each against the mathematics in float64, not against each
+    other. With 4 devices, under parallel/dp.py's shard_map (check_vma=False):
+    dγ/dβ are one LOCAL partial a device, dx is complete."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from yet_another_mobilenet_series_tpu.ops import layers
+
+    c = shape[-1]
     spec = ops.BatchNorm(c)
     params, state = spec.init()
     rs = np.random.RandomState(3)
     params["gamma"] = jnp.asarray(rs.uniform(0.5, 1.5, c).astype(np.float32))
     params["beta"] = jnp.asarray(rs.uniform(-0.5, 0.5, c).astype(np.float32))
-    x = jnp.asarray(rs.normal(1.0, 2.0, (8, 7, 7, c)).astype(np.float32))
+    # x and the cotangent hold values the dtype represents, so the reference sees what the program sees
+    x = jnp.asarray(rs.normal(1.0, 2.0, shape).astype(np.float32)).astype(dtype)
+    dy = jnp.asarray(rs.normal(0, 1, shape).astype(np.float32)).astype(dtype)
+    want = _bn_closed_form_float64(x.astype(jnp.float32), dy.astype(jnp.float32), params["gamma"], spec.eps, shards)
+    axis_name = "data" if shards > 1 else None
 
-    y_folded, st_folded = spec.apply(params, state, x, train=True, mode="folded")
-    y_fused, st_fused = spec.apply(params, state, x, train=True, mode="fused_vjp")
-    np.testing.assert_array_equal(np.asarray(y_fused), np.asarray(y_folded))
-    for k in ("mean", "var"):
-        np.testing.assert_allclose(np.asarray(st_fused[k]), np.asarray(st_folded[k]), rtol=1e-6)
+    def body(p, xx, dd):
+        def local_loss(p, xx):
+            y, _ = spec.apply(p, state, xx, train=True, axis_name=axis_name)
+            return jnp.sum(y.astype(jnp.float32) * dd.astype(jnp.float32))
 
-    w = jnp.asarray(rs.normal(0, 1, (8, 7, 7, c)).astype(np.float32))
+        g, gx = jax.grad(local_loss, argnums=(0, 1))(p, xx)
+        mean, inv = (jnp.asarray(want[k], jnp.float32) for k in ("mean", "inv"))
+        dbeta, dgamma, s1, s2 = layers._bn_grad_sums(xx, dd, mean, inv, axis_name)
+        # the raw per-device partials, laid out on the data axis
+        return jax.tree.map(lambda v: v[None], (g, {"dbeta": dbeta, "dgamma": dgamma, "s1": s1, "s2": s2})), gx
 
-    def loss(p, xx, mode):
-        y, _ = spec.apply(p, state, xx, train=True, mode=mode)
-        return jnp.sum(y * w)  # non-trivial cotangent
+    if shards > 1:
+        mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+        body = jax.shard_map(body, mesh=mesh, in_specs=(P(), P("data"), P("data")),
+                             out_specs=(P("data"), P("data")), check_vma=False)
+    (g, sums), gx = jax.jit(body)(params, x, dy)
 
-    (g_exact, gx_exact) = jax.grad(loss, argnums=(0, 1))(params, x, "exact")
-    (g_fused, gx_fused) = jax.grad(loss, argnums=(0, 1))(params, x, "fused_vjp")
-    np.testing.assert_allclose(np.asarray(gx_fused), np.asarray(gx_exact), rtol=1e-4, atol=1e-5)
-    for k in ("gamma", "beta"):
-        np.testing.assert_allclose(np.asarray(g_fused[k]), np.asarray(g_exact[k]), rtol=1e-4, atol=1e-5)
+    def close(name, got, ref, tol):
+        got, ref = np.asarray(got, np.float64), np.asarray(ref)
+        assert got.shape == ref.shape, name
+        assert np.abs(got - ref).max() <= tol * np.abs(ref).max(), name
 
-    # eval mode falls back to the folded expression (no custom vjp needed)
-    y_eval_fused, _ = spec.apply(params, st_fused, x, train=False, mode="fused_vjp")
-    y_eval_folded, _ = spec.apply(params, st_folded, x, train=False, mode="folded")
-    np.testing.assert_array_equal(np.asarray(y_eval_fused), np.asarray(y_eval_folded))
-
-
-def test_batchnorm_fused_vjp_rejects_stat_cotangents():
-    """ADVICE r3 #1: the closed-form backward DISCARDS the mean/var output
-    cotangents by contract (they feed only the never-differentiated running
-    stats). With symbolic_zeros enforcement, a loss term that reads the
-    batch statistics must fail LOUDLY at trace time under fused_vjp rather
-    than silently training with zero stat-gradients."""
-    spec = ops.BatchNorm(4)
-    params, state = spec.init()
-    x = jnp.asarray(np.random.RandomState(0).normal(0, 1, (2, 3, 3, 4)).astype(np.float32))
-
-    def stat_loss(p):
-        _, st = spec.apply(p, state, x, train=True, mode="fused_vjp")
-        return jnp.sum(st["mean"])  # differentiates the batch statistics
-
-    with pytest.raises(TypeError, match="fused_vjp.*cotangents"):
-        jax.grad(stat_loss)(params)
-
-    # the same loss is fine under the autodiff modes
-    def stat_loss_folded(p):
-        _, st = spec.apply(p, state, x, train=True, mode="folded")
-        return jnp.sum(st["mean"])
-
-    g = jax.grad(stat_loss_folded)(params)
-    assert all(np.all(np.isfinite(np.asarray(v))) for v in g.values())
+    # f32 sums over at most 392 rows; dx comes back in x's dtype (one bf16 ulp of the largest entry is 2^-7)
+    assert gx.dtype == dtype
+    close("dx", gx, want["dx"], 1e-5 if dtype == jnp.float32 else 2.0 ** -7)
+    for name, got in (("dgamma", g["gamma"]), ("dbeta", g["beta"]), ("dgamma", sums["dgamma"]), ("dbeta", sums["dbeta"])):
+        close(name, got, want[name], 1e-5)
+    for name in ("s1", "s2"):  # the psum'd pair: every device holds the global sum
+        close(name, sums[name], np.broadcast_to(want[name], (shards, c)), 1e-5)
 
 
-def test_batchnorm_fused_vjp_sharded_grad_contract_matches_exact():
-    """The per-device gradient CONTRACT under shard_map: fused_vjp's custom
-    backward must produce the same per-device partial gradients of the LOCAL
-    loss that autodiff of 'exact' produces (local dγ/dβ sums, global n) —
-    the convention train/steps.py's grad pmean (and the ZeRO psum_scatter)
-    assumes for every mode. A psum'd dγ/dβ inside the custom bwd would pass
-    a globally-normalized comparison but train BN affine params at
-    device_count× the gradient through the real step (caught by review in
-    round 3; this test pins the seam per-device, no normalization games).
-
-    check_vma=False deliberately matches parallel/dp.py's shard_maps: under
-    the new vma semantics the cotangent of a replicated param is auto-psum'd
-    OUTSIDE a custom_vjp's view, so fused_vjp is only contract-correct in
-    check_vma=False contexts — which is what every production shard_map in
-    this codebase uses (documented in ops/layers.py)."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-
-    c = 4
-    spec = ops.BatchNorm(c)
-    params, state = spec.init()
-    x = jax.random.normal(jax.random.PRNGKey(0), (16, 3, 3, c))
-    w = jax.random.normal(jax.random.PRNGKey(1), (16, 3, 3, c))
-    mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
-
-    def per_device_grads(mode):
-        def local_loss(p, xx, ww):
-            y, _ = spec.apply(p, state, xx, train=True, axis_name="data", mode=mode)
-            return jnp.sum(y * ww)
-
-        def body(p, xx, ww):
-            g, gx = jax.grad(local_loss, argnums=(0, 1))(p, xx, ww)
-            # return the RAW per-device partials, laid out on the data axis,
-            # so the contract is compared device by device
-            return jax.tree.map(lambda v: v[None], g), gx
-
-        return jax.jit(
-            jax.shard_map(body, mesh=mesh, in_specs=(P(), P("data"), P("data")),
-                      out_specs=(P("data"), P("data")), check_vma=False)
-        )(params, x, w)
-
-    g_exact, gx_exact = per_device_grads("exact")
-    g_fused, gx_fused = per_device_grads("fused_vjp")
-    for k in ("gamma", "beta"):
-        assert g_fused[k].shape == (8, c)  # one partial per device
-        np.testing.assert_allclose(np.asarray(g_fused[k]), np.asarray(g_exact[k]), rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(gx_fused), np.asarray(gx_exact), rtol=1e-4, atol=1e-5)
-
-
-def test_batchnorm_sdot_stats_match_reduce():
-    """mode='sdot' (MXU-dot batch statistics, the round-4 A/B candidate):
-    values, gradients, and running stats must match 'folded' (identical
-    normalize expression) within f32 accumulation-order rounding — the one
-    mode whose statistics are NOT bit-identical to the reduce-based ones,
-    by construction."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_batchnorm_eval_backward_matches_closed_form_in_float64(dtype):
+    """In eval the running statistics are constants: y is affine in x, and
+    autodiff gives dx = dy·γ·inv, dγ = Σ dy·x̂, dβ = Σ dy (float64 NumPy)."""
     c = 12
     spec = ops.BatchNorm(c)
-    params, state = spec.init()
-    rs = np.random.RandomState(7)
-    params["gamma"] = jnp.asarray(rs.uniform(0.5, 1.5, c).astype(np.float32))
-    params["beta"] = jnp.asarray(rs.uniform(-0.5, 0.5, c).astype(np.float32))
-    x = jnp.asarray(rs.normal(1.0, 2.0, (8, 7, 7, c)).astype(np.float32))
+    rs = np.random.RandomState(4)
+    params = {"gamma": jnp.asarray(rs.uniform(0.5, 1.5, c).astype(np.float32)),
+              "beta": jnp.asarray(rs.uniform(-0.5, 0.5, c).astype(np.float32))}
+    state = {"mean": jnp.asarray(rs.normal(1.0, 0.3, c).astype(np.float32)),
+             "var": jnp.asarray(rs.uniform(2.0, 5.0, c).astype(np.float32))}
+    x = jnp.asarray(rs.normal(1.0, 2.0, (8, 7, 7, c)).astype(np.float32)).astype(dtype)
+    dy = jnp.asarray(rs.normal(0, 1, (8, 7, 7, c)).astype(np.float32)).astype(dtype)
 
-    y_ref, st_ref = spec.apply(params, state, x, train=True, mode="folded")
-    y_dot, st_dot = spec.apply(params, state, x, train=True, mode="sdot")
-    np.testing.assert_allclose(np.asarray(y_dot), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
-    for k in ("mean", "var"):
-        np.testing.assert_allclose(np.asarray(st_dot[k]), np.asarray(st_ref[k]), rtol=1e-5, atol=1e-6)
+    def loss(p, xx):
+        y, same = spec.apply(p, state, xx, train=False)
+        assert same is state
+        return jnp.sum(y.astype(jnp.float32) * dy.astype(jnp.float32))
 
-    w = jnp.asarray(rs.normal(0, 1, (8, 7, 7, c)).astype(np.float32))
-
-    def loss(p, xx, mode):
-        y, _ = spec.apply(p, state, xx, train=True, mode=mode)
-        return jnp.sum(y * w)
-
-    (g_ref, gx_ref) = jax.grad(loss, argnums=(0, 1))(params, x, "folded")
-    (g_dot, gx_dot) = jax.grad(loss, argnums=(0, 1))(params, x, "sdot")
-    np.testing.assert_allclose(np.asarray(gx_dot), np.asarray(gx_ref), rtol=1e-4, atol=1e-5)
-    for k in ("gamma", "beta"):
-        np.testing.assert_allclose(np.asarray(g_dot[k]), np.asarray(g_ref[k]), rtol=1e-4, atol=1e-5)
-
-    # bf16 activations (the real training dtype): the dot's bf16 products
-    # are exact in the f32 accumulator, so stats stay at f32-rounding
-    # distance even from bf16 inputs
-    xb = x.astype(jnp.bfloat16)
-    _, st_b16 = spec.apply(params, state, xb, train=True, mode="sdot")
-    _, st_ref16 = spec.apply(params, state, xb, train=True, mode="folded")
-    for k in ("mean", "var"):
-        np.testing.assert_allclose(np.asarray(st_b16[k]), np.asarray(st_ref16[k]), rtol=1e-5, atol=1e-6)
-
-    # eval mode uses running stats: sdot is folded exactly
-    y_eval_dot, _ = spec.apply(params, st_dot, x, train=False, mode="sdot")
-    y_eval_folded, _ = spec.apply(params, st_dot, x, train=False, mode="folded")
-    np.testing.assert_array_equal(np.asarray(y_eval_dot), np.asarray(y_eval_folded))
+    g, gx = jax.grad(loss, argnums=(0, 1))(params, x)
+    x64, dy64 = np.asarray(x.astype(jnp.float32), np.float64), np.asarray(dy.astype(jnp.float32), np.float64)
+    inv = 1.0 / np.sqrt(np.asarray(state["var"], np.float64) + spec.eps)
+    x_hat = (x64 - np.asarray(state["mean"], np.float64)) * inv
+    np.testing.assert_allclose(np.asarray(gx, np.float64), dy64 * np.asarray(params["gamma"], np.float64) * inv,
+                               rtol=1e-5 if dtype == jnp.float32 else 2.0 ** -7, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(g["gamma"]), (dy64 * x_hat).sum(axis=(0, 1, 2)), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(g["beta"]), dy64.sum(axis=(0, 1, 2)), rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("mode", ["exact", "folded", "compute", "fused_vjp", "sdot", "compute_sdot"])
-def test_syncbn_equals_full_batch_bn(mode):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_syncbn_equals_full_batch_bn(dtype):
     """psum-of-moments SyncBN over 8 shards == BN over the unsharded batch
-    (SURVEY.md §4.2) — the apex-SyncBatchNorm parity contract, in every
-    bn_mode normalize variant."""
+    (SURVEY.md §4.2) — the apex-SyncBatchNorm parity contract, in float32 and
+    in the training dtype (the moments are f32 sums either way; y within one
+    bf16 ulp)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
 
     c = 4
     spec = ops.BatchNorm(c)
     params, state = spec.init()
-    x = jax.random.normal(jax.random.PRNGKey(0), (16, 3, 3, c))
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 3, 3, c)).astype(dtype)
 
-    y_ref, st_ref = spec.apply(params, state, x, train=True, mode=mode)
+    y_ref, st_ref = spec.apply(params, state, x, train=True)
 
     mesh = Mesh(np.array(jax.devices()[:8]), ("data",))
 
     def shard_fn(p, s, xx):
-        return spec.apply(p, s, xx, train=True, axis_name="data", mode=mode)
+        return spec.apply(p, s, xx, train=True, axis_name="data")
 
     y, st = jax.jit(
         jax.shard_map(
@@ -355,13 +221,13 @@ def test_syncbn_equals_full_batch_bn(mode):
             mesh=mesh,
             in_specs=(P(), P(), P("data")),
             out_specs=(P("data"), P()),
-            # matches every production shard_map (parallel/dp.py): the
-            # fused_vjp custom backward has no replication rule, and old-jax
-            # check_rep=True rejects it outright (NotImplementedError)
+            # matches every production shard_map (parallel/dp.py)
             check_vma=False,
         )
     )(params, state, x)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    assert y.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref, np.float32), rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(st["mean"]), np.asarray(st_ref["mean"]), rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(st["var"]), np.asarray(st_ref["var"]), rtol=1e-5, atol=1e-6)
 
@@ -381,42 +247,46 @@ def _conv_bn_case(cin, cexp, dtype, seed=0):
     x = jnp.asarray(rs.normal(0.3, 1.0, (8, 6, 6, cin)).astype(np.float32)).astype(dtype)
     ct = jnp.asarray(rs.normal(0, 1.0, (8, 6, 6, cexp)).astype(np.float32))
 
-    def paired(cp, bp, xx, mode, axis_name=None, ct=ct):
+    def paired(cp, bp, xx, axis_name=None, ct=ct):
         y, st = layers.conv_bn(conv, bn, cp, bp, bn_state, xx, train=True, axis_name=axis_name,
-                               compute_dtype=dtype, bn_mode=mode)
+                               compute_dtype=dtype)
         return jnp.sum(y.astype(jnp.float32) * ct), (y, st)
 
-    def unpaired(cp, bp, xx, mode, axis_name=None, ct=ct):
+    def unpaired(cp, bp, xx, axis_name=None, ct=ct):
         e = conv.apply(cp, xx, compute_dtype=dtype)
-        y, st = bn.apply(bp, bn_state, e, train=True, axis_name=axis_name, mode=mode)
+        y, st = bn.apply(bp, bn_state, e, train=True, axis_name=axis_name)
         return jnp.sum(y.astype(jnp.float32) * ct), (y, st)
 
     return conv, bn, conv_params, bn_params, x, ct, paired, unpaired
 
 
-@pytest.mark.parametrize("mode", ["exact", "folded", "fused_vjp"])
-@pytest.mark.parametrize("cin,cexp", [(16, 64), (40, 240)])
+# every (in, out) the pair meets in the two benchmark networks: the expand convs of
+# apps/mobilenet_v3_large.yml and apps/efficientnet_b0.yml and their heads (160 -> 960, 320 -> 1280)
+BENCHMARK_PAIR_SITES = [(16, 64), (24, 72), (40, 120), (40, 240), (80, 200), (80, 184), (80, 480), (112, 672),
+                        (160, 960), (16, 96), (24, 144), (192, 1152), (320, 1280)]
+
+
+@pytest.mark.parametrize("cin,cexp", BENCHMARK_PAIR_SITES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
-def test_conv_bn_pair_matches_autodiff(dtype, cin, cexp, mode):
+def test_conv_bn_pair_matches_autodiff(dtype, cin, cexp):
     """conv_bn() on a widening 1x1 conv in training is the custom-VJP pair
     whose backward never reads the conv's output: forward values and running
-    stats bit-equal to Conv2D then BatchNorm in the same bn_mode; dX, dW,
-    dgamma, dbeta equal to jax.vjp of plain Conv2D + BatchNorm(mode="exact"),
-    to 1e-5 of the largest entry in float32 and one bf16 ulp of it (2^-7) in
-    bfloat16: the float32 reference of the changed arithmetic."""
+    stats bit-equal to Conv2D then BatchNorm; dX, dW, dgamma, dbeta equal to
+    jax.vjp of plain Conv2D + BatchNorm, to 1e-5 of the largest entry in float32 and one bf16 ulp of it (2^-7) in
+    bfloat16: the float32 reference of the changed arithmetic. At the widths
+    the benchmark's networks have, on a small image."""
     from yet_another_mobilenet_series_tpu.ops import layers
 
     conv, _, conv_params, bn_params, x, _, paired, unpaired = _conv_bn_case(cin, cexp, dtype)
-    assert layers.conv_bn_pairs(conv, train=True, bn_mode=mode)
-    grad = lambda f, m: jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(conv_params, bn_params, x, m)
-    (_, (y, st)), (g_conv, g_bn, g_x) = grad(paired, mode)
-    (_, (y_same, st_same)), _ = grad(unpaired, mode)
+    assert layers.conv_bn_pairs(conv, train=True)
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(conv_params, bn_params, x)
+    (_, (y, st)), (g_conv, g_bn, g_x) = grad(paired)
+    (_, (y_same, st_same)), (r_conv, r_bn, r_x) = grad(unpaired)
     assert y.dtype == dtype
     np.testing.assert_array_equal(np.asarray(y, np.float32), np.asarray(y_same, np.float32))
     for k in ("mean", "var"):
         np.testing.assert_array_equal(np.asarray(st[k]), np.asarray(st_same[k]))
 
-    _, (r_conv, r_bn, r_x) = grad(unpaired, "exact")
     tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
     for name, got, want in (("dX", g_x, r_x), ("dW", g_conv["w"], r_conv["w"]),
                             ("dgamma", g_bn["gamma"], r_bn["gamma"]), ("dbeta", g_bn["beta"], r_bn["beta"])):
@@ -433,7 +303,7 @@ def test_conv_bn_pair_in_bfloat16_is_no_further_from_float32_than_autodiff():
     _, _, cp, bp, x32, _, _, ref = cases[jnp.float32]
     _, _, _, _, x16, _, paired, unpaired = cases[jnp.bfloat16]
     x32 = x16.astype(jnp.float32)  # the same input values on both sides
-    grad = lambda f, xx: jax.grad(lambda *a: f(*a, "exact")[0], argnums=(0, 2))(cp, bp, xx)
+    grad = lambda f, xx: jax.grad(lambda *a: f(*a)[0], argnums=(0, 2))(cp, bp, xx)
     (w32, dx32), (wp, dxp), (wa, dxa) = grad(ref, x32), grad(paired, x16), grad(unpaired, x16)
     err = lambda a, b: float(np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32)).max()
                              / np.abs(np.asarray(b, np.float32)).max())
@@ -442,10 +312,14 @@ def test_conv_bn_pair_in_bfloat16_is_no_further_from_float32_than_autodiff():
 
 
 def test_conv_bn_pair_rejects_stat_cotangents():
-    """Like fused_vjp: a loss that differentiates the pair's batch statistics
-    fails where it is traced, and works on the unpaired path."""
+    """The pair's closed-form backward DISCARDS the cotangents of its mean/var
+    outputs by contract (they feed only the running statistics, which the
+    training loss never differentiates). symbolic_zeros lets it see a real
+    one: a loss term that reads the batch statistics fails LOUDLY where it is
+    traced instead of training with zero stat-gradients, and works on the
+    unpaired path, which is plain autodiff."""
     _, _, conv_params, bn_params, x, _, paired, unpaired = _conv_bn_case(8, 24, jnp.float32)
-    stat_loss = lambda f: jax.grad(lambda cp: jnp.sum(f(cp, bn_params, x, "exact")[1][1]["mean"]))(conv_params)
+    stat_loss = lambda f: jax.grad(lambda cp: jnp.sum(f(cp, bn_params, x)[1][1]["mean"]))(conv_params)
     with pytest.raises(TypeError, match="conv \\+ BatchNorm pair.*cotangents"):
         stat_loss(paired)
     assert np.all(np.isfinite(np.asarray(stat_loss(unpaired)["w"])))
@@ -453,13 +327,7 @@ def test_conv_bn_pair_rejects_stat_cotangents():
 
 @pytest.mark.parametrize("conv, kw, expect", [
     (ops.Conv2D(16, 64, 1), {}, True),
-    (ops.Conv2D(16, 64, 1), {"bn_mode": "folded"}, True),
-    (ops.Conv2D(16, 64, 1), {"bn_mode": "fused_vjp"}, True),
     (ops.Conv2D(16, 64, 1), {"train": False}, False),  # eval, export, serving: the plain path
-    (ops.Conv2D(16, 64, 1), {"bn_mode": "compute"}, False),
-    (ops.Conv2D(16, 64, 1), {"bn_mode": "sdot"}, False),
-    (ops.Conv2D(16, 64, 1), {"bn_mode": "compute_sdot"}, False),
-    (ops.Conv2D(16, 64, 1), {"conv1x1_dot": True}, False),
     (ops.Conv2D(64, 64, 1), {}, False),  # a pruned block shrunk to its input width: nothing to win
     (ops.Conv2D(64, 16, 1), {}, False),  # the project conv's shape
     (ops.Conv2D(3, 16, 3, 2), {}, False),  # the stem
@@ -470,7 +338,7 @@ def test_conv_bn_pair_rejects_stat_cotangents():
 def test_conv_bn_pair_engages_by_what_the_site_is(conv, kw, expect):
     from yet_another_mobilenet_series_tpu.ops import layers
 
-    kw = {"train": True, "bn_mode": "exact", **kw}
+    kw = {"train": True, **kw}
     assert layers.conv_bn_pairs(conv, **kw) is expect
     # and conv_bn() takes the custom-VJP pair exactly there
     bn = ops.BatchNorm(conv.out_channels)
@@ -483,7 +351,15 @@ def test_conv_bn_pair_engages_by_what_the_site_is(conv, kw, expect):
 def test_conv_bn_pair_sharded_grad_contract_matches_syncbn_autodiff():
     """The pair under shard_map on 4 devices (parallel/dp.py's contract,
     check_vma=False as there): per device, dgamma/dbeta/dW are LOCAL partials
-    and dX is complete, each equal to autodiff of Conv2D + SyncBN 'exact'."""
+    of the LOCAL loss and dX is complete, each equal to autodiff of Conv2D +
+    SyncBN: the convention train/steps.py's grad pmean (and ZeRO's
+    psum_scatter) assumes. A psum'd dgamma/dbeta inside the custom backward
+    would pass a globally-normalised comparison and train the BN affine
+    parameters at device_count x the gradient through the real step, so the
+    seam is compared device by device. check_vma=False because, under the
+    vma semantics, the cotangent of a replicated parameter is psum'd OUTSIDE
+    a custom_vjp's view: the pair is contract-correct only where every
+    production shard_map of this codebase already is."""
     from jax.sharding import Mesh, PartitionSpec as P
 
     _, _, conv_params, bn_params, x, ct, paired, unpaired = _conv_bn_case(8, 24, jnp.float32, seed=5)
@@ -491,7 +367,7 @@ def test_conv_bn_pair_sharded_grad_contract_matches_syncbn_autodiff():
 
     def per_device_grads(fn):
         def body(cp, bp, xx, cc):
-            (g_conv, g_bn, g_x) = jax.grad(lambda *a: fn(*a, "exact", "data", cc)[0], argnums=(0, 1, 2))(cp, bp, xx)
+            (g_conv, g_bn, g_x) = jax.grad(lambda *a: fn(*a, "data", cc)[0], argnums=(0, 1, 2))(cp, bp, xx)
             return jax.tree.map(lambda v: v[None], (g_conv, g_bn)), g_x
 
         return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P("data"), P("data")),

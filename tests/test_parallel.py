@@ -50,41 +50,40 @@ def setup():
     return cfg, net, lr_fn, opt, ts, batch
 
 
-def test_dp_step_bn_modes_agree(setup):
-    """Execution variants (bn_mode, conv1x1_dot) must not change the training
-    math: one 8-device DP step under each produces the same updated params
-    (within fp re-association) and the same grad_norm — the steps.py pmean
-    seam that a psum'd custom backward would break with device_count× BN
-    affine grads."""
+@pytest.mark.parametrize("sync_bn", [True, False], ids=["syncbn", "per_replica_bn"])
+def test_dp_step_is_the_same_update_with_and_without_the_conv_bn_pair(setup, monkeypatch, sync_bn):
+    """The conv + BN pair (ops/layers.py) must not change the training math:
+    one 8-device DP step with it, as the step is built, and one with every
+    site on plain autodiff produce the same updated params (within fp
+    re-association) and the same grad_norm — the steps.py pmean seam that a
+    psum'd custom backward would break with device_count× BN affine grads.
+    With dist.sync_bn off the pair runs without an axis name inside the same
+    shard_map: its sums are the replica's own."""
     import dataclasses as dc
 
+    from yet_another_mobilenet_series_tpu.ops import layers
+
     cfg, net, lr_fn, opt, _, batch = setup
+    cfg = dc.replace(cfg, dist=dc.replace(cfg.dist, sync_bn=sync_bn))
+    assert net.conv_bn_pair_sites()[0] > 0
     m = mesh_lib.make_mesh(8)
     b = mesh_lib.shard_batch(batch, m)
-    variants = {
-        "exact": {"bn_mode": "exact"},
-        "folded": {"bn_mode": "folded"},
-        "fused_vjp": {"bn_mode": "fused_vjp"},
-        "exact+dot": {"bn_mode": "exact", "conv1x1_dot": True},
-        "sdot": {"bn_mode": "sdot"},
-    }
     results = {}
-    for name, over in variants.items():
-        cfg_m = dc.replace(cfg, train=dc.replace(cfg.train, **over))
-        ts = mesh_lib.replicate(steps.init_train_state(net, cfg_m, opt, jax.random.PRNGKey(0)), m)
-        step = dp.make_dp_train_step(net, cfg_m, opt, lr_fn, m)
+    for name in ("paired", "unpaired"):
+        if name == "unpaired":  # the site decides where the step is traced, which is its first call
+            monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
+        ts = mesh_lib.replicate(steps.init_train_state(net, cfg, opt, jax.random.PRNGKey(0)), m)
+        step = dp.make_dp_train_step(net, cfg, opt, lr_fn, m)
         ts, met = step(ts, b, jax.random.PRNGKey(7))
         results[name] = (jax.device_get(ts.params), float(met["grad_norm"]), float(met["loss"]))
-    p_ref, gn_ref, loss_ref = results["exact"]
-    for mode in ("folded", "fused_vjp", "exact+dot", "sdot"):
-        p, gn, loss = results[mode]
-        np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
-        np.testing.assert_allclose(gn, gn_ref, rtol=1e-4)
-        # post-RMSProp params: rsqrt(nu) amplifies reduction-order rounding
-        # where grads are tiny, so the param bound is looser than the
-        # grad-level contract test's (test_ops.py, rtol=1e-4 per device)
-        for a, c in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-3, atol=1e-5)
+    (p_ref, gn_ref, loss_ref), (p, gn, loss) = results["unpaired"], results["paired"]
+    np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
+    np.testing.assert_allclose(gn, gn_ref, rtol=1e-4)
+    # post-RMSProp params: rsqrt(nu) amplifies reduction-order rounding
+    # where grads are tiny, so the param bound is looser than the
+    # grad-level contract test's (test_ops.py, rtol=1e-4 per device)
+    for a, c in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-3, atol=1e-5)
 
 
 def test_dp_step_equals_single_device_large_batch(setup):
@@ -193,9 +192,9 @@ def test_mesh_validation():
 
 def test_check_vma_contract():
     """Every production shard_map must pass check_vma=False explicitly
-    (ADVICE r3 #2): bn_mode='fused_vjp' returns LOCAL partial dgamma/dbeta
-    by contract (ops/layers.py _bn_train_fused_bwd), which is only the
-    gradient autodiff produces under check_vma=False maps. Anyone flipping
+    (ADVICE r3 #2): the conv + BN pair's backward returns LOCAL partial
+    dgamma/dbeta/dW by contract (ops/layers.py _bn_grad_sums), which is only
+    the gradient autodiff produces under check_vma=False maps. Anyone flipping
     a site to check_vma=True (or dropping the kwarg, inheriting a future
     default) must revisit that VJP — this test makes the coupling fail
     loudly instead of silently rescaling BN affine grads."""
@@ -217,92 +216,7 @@ def test_check_vma_contract():
             kw = {k.arg: k.value for k in call.keywords}
             assert "check_vma" in kw, (
                 f"{module.__name__}:{call.lineno}: shard_map without an explicit "
-                "check_vma kwarg (the fused_vjp grad contract requires False)")
+                "check_vma kwarg (the conv + BN pair's grad contract requires False)")
             assert isinstance(kw["check_vma"], ast.Constant) and kw["check_vma"].value is False, (
                 f"{module.__name__}:{call.lineno}: check_vma is not the literal False — "
-                "revisit ops/layers.py _bn_train_fused_bwd before changing this")
-
-
-
-
-def _assert_single_equals_grouped(cfg, net, lr_fn, opt, ts0, *, batch_seed0,
-                                  n_batches, k, metric_keys):
-    """Run n_batches through k-per-dispatch grouped steps and through single
-    dispatches (same batches/order, same per-step rng fold via ts.step) and
-    assert params + metrics agree at the XLA fusion-boundary tolerance
-    (~1e-7 rel: one k-step program fuses ACROSS steps; bit-identity is NOT
-    the contract, unlike remat). Returns the grouped final state."""
-    m = mesh_lib.make_mesh(8)
-    rng = jax.random.PRNGKey(9)
-    batches = [
-        mesh_lib.shard_batch({
-            "image": np.asarray(jax.random.normal(jax.random.PRNGKey(batch_seed0 + i), (16, 16, 16, 3))),
-            "label": np.asarray((jnp.arange(16) + i) % 8),
-        }, m)
-        for i in range(n_batches)
-    ]
-    step = dp.make_dp_train_step(net, cfg, opt, lr_fn, m)
-
-    # independent copies per path: the steps donate, and on fake CPU devices
-    # replication can alias the source buffers (see the fixture note)
-    ts_single = mesh_lib.replicate(jax.tree.map(jnp.copy, ts0), m)
-    single_metrics = []
-    for b in batches:
-        ts_single, met = step(ts_single, b, rng)
-        single_metrics.append(met)
-    params_single = jax.device_get(ts_single.params)
-
-    grouped = dp.make_grouped_train_step(step, k)
-    ts_grp = mesh_lib.replicate(jax.tree.map(jnp.copy, ts0), m)
-    grouped_metrics = []
-    for i in range(0, n_batches, k):
-        ts_grp, mets = grouped(ts_grp, tuple(batches[i:i + k]), rng)
-        grouped_metrics += mets
-    params_grp = jax.device_get(ts_grp.params)
-
-    # atol: measured 3.2e-6 max abs divergence under jax 0.4.37's CPU XLA
-    # (cross-step fusion reorders f32 reductions, then RMSProp's rsqrt
-    # amplifies); a real bug (wrong batch order / rng fold) shows ~1e-2
-    for a, b in zip(jax.tree.leaves(params_single), jax.tree.leaves(params_grp)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=5e-6)
-    # rtol: grad_norm is a global reduction over every param, the most
-    # rounding-sensitive scalar; measured 1.4e-5 rel drift by step 3 under
-    # jax 0.4.37's CPU XLA (a real divergence shows >=1e-2)
-    for i, (ms, mg) in enumerate(zip(single_metrics, grouped_metrics)):
-        for key in metric_keys:
-            np.testing.assert_allclose(float(ms[key]), float(mg[key]),
-                                       rtol=1e-4, err_msg=f"step {i} {key}")
-    return ts_grp
-
-def test_grouped_step_equals_single_steps(setup):
-    """steps_per_dispatch semantics: k steps in ONE jit dispatch
-    (dp.make_grouped_train_step) equal k single dispatches — same batches
-    in the same order, same per-step rng fold (via ts.step) — up to XLA
-    fusion-boundary rounding: compiling k steps as one program lets XLA
-    fuse ACROSS steps, so f32 reduction orders differ at ~1e-7 rel
-    (measured; bit-identity is NOT the contract, unlike remat)."""
-    cfg, net, lr_fn, opt, ts0, _ = setup
-    ts_grp = _assert_single_equals_grouped(
-        cfg, net, lr_fn, opt, ts0, batch_seed0=10, n_batches=4, k=2,
-        metric_keys=("loss", "grad_norm", "top1", "lr"))
-    assert int(ts_grp.step) == 4
-
-    with pytest.raises(ValueError, match="k >= 2"):
-        dp.make_grouped_train_step(lambda ts, b, r: (ts, {}), 1)
-
-
-@pytest.mark.slow  # ~60 s: two 8-device program builds (fast-gate budget)
-def test_grouped_step_equals_single_steps_with_mixup(setup):
-    """Composition pin: in-step Mixup/CutMix adds per-step rng draws inside
-    the loss; grouped dispatch must reproduce the SAME mix decisions as k
-    single dispatches (the mix key folds ts.step, which advances inside the
-    grouped program)."""
-    import dataclasses as dc
-
-    cfg, net, lr_fn, opt, ts0, _ = setup
-    cfg = dc.replace(cfg, optim=dc.replace(cfg.optim, mixup_alpha=0.2, cutmix_alpha=1.0))
-    # loss depends on the drawn lam/permutation: agreement at fusion
-    # tolerance proves the grouped program drew the SAME mixes
-    _assert_single_equals_grouped(
-        cfg, net, lr_fn, opt, ts0, batch_seed0=20, n_batches=2, k=2,
-        metric_keys=("loss", "grad_norm"))
+                "revisit ops/layers.py _conv_bn_pair_bwd before changing this")

@@ -231,35 +231,34 @@ def _tiny_cfg(**over):
     return config_from_dict(d)
 
 
-@pytest.mark.parametrize("policy,bn_mode", [
-    ("full", "exact"),
-    ("save_conv", "exact"),
-    # the composed round-3 stack: custom-VJP BN recomputed under the
-    # save-conv checkpoint policy must still be a pure scheduling change
-    ("save_conv", "fused_vjp"),
-])
-def test_remat_step_equals_plain_step(policy, bn_mode):
-    """train.remat (both policies) must be a pure memory/recompute trade:
-    the updated params after one step are BIT-IDENTICAL to the non-remat
-    step's on CPU f32 (jax.checkpoint changes scheduling, not math).
-    save_conv keeps the MXU outputs and recomputes the BN/act chains (the
-    round-3 attack on the BN activation round-trips, ops/layers.py conv_out
-    landmark)."""
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_device", "dp4_syncbn"])
+def test_remat_step_equals_plain_step(chips):
+    """train.remat must be a pure memory/recompute trade: the updated params
+    after one step are BIT-IDENTICAL to the non-remat step's on CPU f32
+    (jax.checkpoint changes scheduling, not math), the conv + BN pair
+    recomputed under it included; on a mesh its psums are recomputed too
+    (what __graft_entry__.py's second step runs on the chip)."""
+    from yet_another_mobilenet_series_tpu.parallel import dp, mesh as mesh_lib
+
     batch = {
         "image": jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16, 3)),
         "label": jnp.arange(8) % 4,
     }
     rng = jax.random.PRNGKey(42)
     results = []
-    for remat_over in ({}, {"remat": True, "remat_policy": policy}):
-        cfg = _tiny_cfg(train={"compute_dtype": "float32", "bn_mode": bn_mode, **remat_over})
+    for remat_over in ({}, {"remat": True}):
+        cfg = _tiny_cfg(train={"compute_dtype": "float32", **remat_over})
         net = get_model(cfg.model, image_size=16)
         lr_fn = schedules.make_lr_schedule(cfg.schedule, 8, 1, 100)
         params, _ = net.init(jax.random.PRNGKey(0))
         opt = optim.make_optimizer(cfg.optim, lr_fn, params)
         ts = steps.init_train_state(net, cfg, opt, jax.random.PRNGKey(0))
-        step_fn = jax.jit(steps.make_train_step(net, cfg, opt, lr_fn))
-        ts, metrics = step_fn(ts, batch, rng)
+        if chips == 1:
+            step_fn, b = jax.jit(steps.make_train_step(net, cfg, opt, lr_fn)), batch
+        else:
+            mesh = mesh_lib.make_mesh(chips)
+            step_fn, ts, b = dp.make_dp_train_step(net, cfg, opt, lr_fn, mesh), mesh_lib.replicate(ts, mesh), mesh_lib.shard_batch(batch, mesh)
+        ts, metrics = step_fn(ts, b, rng)
         results.append((ts, metrics))
     (ts_plain, met_plain), (ts_remat, met_remat) = results
     assert float(met_plain["loss"]) == float(met_remat["loss"])
@@ -268,16 +267,15 @@ def test_remat_step_equals_plain_step(policy, bn_mode):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _loss_and_grads(net, params, state, batch, bn_mode, masks=None):
+def _loss_and_grads(net, params, state, batch, masks=None):
     def loss(p):
-        logits, new_state = net.apply(p, state, batch["image"], train=True, bn_mode=bn_mode, masks=masks)
+        logits, new_state = net.apply(p, state, batch["image"], train=True, masks=masks)
         return jnp.mean(losses.cross_entropy_label_smooth(logits, batch["label"], 0.1)), new_state
 
     return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
 
 
-@pytest.mark.parametrize("bn_mode", ["exact", "folded", "fused_vjp"])
-def test_conv_bn_pair_leaves_loss_and_every_gradient_leaf_as_they_were(monkeypatch, bn_mode):
+def test_conv_bn_pair_leaves_loss_and_every_gradient_leaf_as_they_were(monkeypatch):
     """The two-block net at float32, its expand convs and head lowered through
     ops/layers.py's conv + BN pair (what a train step does) against the same
     net with the pair switched off in the test: same loss, same BN state,
@@ -287,12 +285,12 @@ def test_conv_bn_pair_leaves_loss_and_every_gradient_leaf_as_they_were(monkeypat
 
     cfg = _tiny_cfg()
     net = get_model(cfg.model, image_size=16)
-    assert net.conv_bn_pair_sites(bn_mode=bn_mode, conv1x1_dot=False) == (3, 5)  # two expands + the head; + two projects
+    assert net.conv_bn_pair_sites() == (3, 5)  # two expands + the head; + two projects
     params, state = net.init(jax.random.PRNGKey(0))
     batch = {"image": jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16, 3)), "label": jnp.arange(8) % 4}
-    (loss_pair, state_pair), grads_pair = _loss_and_grads(net, params, state, batch, bn_mode)
+    (loss_pair, state_pair), grads_pair = _loss_and_grads(net, params, state, batch)
     monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
-    (loss_plain, state_plain), grads_plain = _loss_and_grads(net, params, state, batch, bn_mode)
+    (loss_plain, state_plain), grads_plain = _loss_and_grads(net, params, state, batch)
     assert float(loss_pair) == float(loss_plain)
     for a, b in zip(jax.tree.leaves(state_pair), jax.tree.leaves(state_plain)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -307,55 +305,82 @@ def test_conv_bn_pair_leaves_loss_and_every_gradient_leaf_as_they_were(monkeypat
 
 
 def test_train_step_reports_how_many_sites_the_pair_lowers():
-    """make_train_step sets the two registry gauges from the network and the
-    configured modes: train.conv_bn_pairs, train.conv_bn_pair_eligible."""
+    """make_train_step sets the two registry gauges from the network alone:
+    train.conv_bn_pairs, train.conv_bn_pair_eligible."""
     from yet_another_mobilenet_series_tpu.obs.registry import get_registry
 
-    for over, expect in (({}, 3.0), ({"bn_mode": "compute"}, 0.0), ({"conv1x1_dot": True}, 0.0)):
-        cfg = _tiny_cfg(train={"compute_dtype": "float32", **over})
-        net = get_model(cfg.model, image_size=16)
-        lr_fn = schedules.make_lr_schedule(cfg.schedule, 8, 1, 100)
-        params, _ = net.init(jax.random.PRNGKey(0))
-        steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn)
-        assert get_registry().gauge("train.conv_bn_pairs").value == expect
-        assert get_registry().gauge("train.conv_bn_pair_eligible").value == 5.0
-
-
-@pytest.mark.parametrize("arch, expect", [("mobilenet_v3_large", (15, 30)), ("efficientnet_b0", (16, 32))])
-def test_conv_bn_pair_sites_of_the_benchmarks_networks(arch, expect):
-    """14 of MobileNetV3-Large's 15 blocks (the first has no expand) and its
-    head; all 15 expanding blocks of EfficientNet-B0 and its head."""
-    from yet_another_mobilenet_series_tpu.config import ModelConfig
-
-    net = get_model(ModelConfig(arch=arch), 224)
-    assert net.conv_bn_pair_sites(bn_mode="exact", conv1x1_dot=False) == expect
-    assert net.conv_bn_pair_sites(bn_mode="sdot", conv1x1_dot=False) == (0, expect[1])
-
-
-def test_remat_policy_validated():
-    cfg = _tiny_cfg(train={"compute_dtype": "float32", "remat": True, "remat_policy": "nope"})
+    for gauge in ("train.conv_bn_pairs", "train.conv_bn_pair_eligible"):
+        get_registry().gauge(gauge).set(-1.0)
+    cfg = _tiny_cfg(train={"compute_dtype": "float32"})
     net = get_model(cfg.model, image_size=16)
     lr_fn = schedules.make_lr_schedule(cfg.schedule, 8, 1, 100)
     params, _ = net.init(jax.random.PRNGKey(0))
-    opt = optim.make_optimizer(cfg.optim, lr_fn, params)
-    with pytest.raises(ValueError, match="remat_policy"):
-        steps.make_train_step(net, cfg, opt, lr_fn)
+    steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn)
+    assert get_registry().gauge("train.conv_bn_pairs").value == 3.0
+    assert get_registry().gauge("train.conv_bn_pair_eligible").value == 5.0
+
+
+@pytest.mark.parametrize("arch, expect", [
+    ("mobilenet_v1", (0, 13)),  # 13 pointwise convs, none behind an expand
+    ("mobilenet_v2", (17, 34)),
+    ("mobilenet_v3_large", (15, 30)),
+    ("mobilenet_v3_small", (11, 22)),
+    ("mnasnet_a1", (16, 32)),
+    ("atomnas_supernet", (17, 34)),
+    ("atomnas_supernet_se", (17, 34)),
+    ("efficientnet_b0", (16, 32)),
+    ("efficientnet_lite0", (16, 32)),
+])
+def test_conv_bn_pair_sites_of_the_benchmarks_networks(arch, expect):
+    """The benchmark's two (14 of MobileNetV3-Large's 15 blocks, the first
+    having no expand, and its head; all 15 expanding blocks of
+    EfficientNet-B0 and its head) and the rest of the zoo: every expand conv
+    and head widens, every project conv is eligible and narrows."""
+    from yet_another_mobilenet_series_tpu.config import ModelConfig
+    from yet_another_mobilenet_series_tpu.models.zoo import ARCHS
+
+    assert arch in ARCHS and len(ARCHS) == 9
+    net = get_model(ModelConfig(arch=arch), 224)
+    assert net.conv_bn_pair_sites() == expect
+
+
+@pytest.mark.parametrize("kind, name, value", [
+    ("key", "bn_mode", "exact"), ("key", "conv1x1_dot", "1"), ("key", "remat_policy", "full"),
+    ("key", "steps_per_dispatch", "1"), ("key", "tuning_file", "1"),
+    ("yaml", "bn_mode", "fused_vjp"), ("yaml", "tuning_file", "/path/to/BENCH_TUNING.json"),
+])
+def test_removed_step_options_are_refused_with_what_is_valid(tmp_path, kind, name, value):
+    """PRs 30 and 31 took the step's forks out with no alias: a config that
+    still names one, even with the value that was its default, fails where it
+    is loaded (an override, or an app's YAML) with the section's valid keys."""
+    from yet_another_mobilenet_series_tpu.config import parse_cli
+
+    if kind == "yaml":
+        app = tmp_path / "old_app.yml"
+        app.write_text(f"train:\n  batch_size: 8\n  {name}: {value}\n")
+        argv = [f"app:{app}"]
+    else:
+        argv = [f"train.{name}={value}"]
+    with pytest.raises(KeyError, match=rf"unknown config key\(s\) \['{name}'\] in section 'train'; valid: .*'batch_size'.*'remat'") as e:
+        parse_cli(argv)
+    assert not any(f"'{gone}'" in str(e.value).split("valid:")[1]
+                   for gone in ("bn_mode", "conv1x1_dot", "remat_policy", "steps_per_dispatch", "tuning_file"))
 
 
 @pytest.mark.slow
-def test_bn_variants_converge_identically():
-    """300 training steps under each bn_mode track the exact-mode loss
-    trajectory (single device, f32) with bounded divergence — the
-    training-dynamics half of the bn_mode adoption rule's top-1-parity
-    argument for `compute` (VERDICT r3 #5; the eval-forward half is
-    test_acceptance_mbv2.py::test_full_scale_bn_mode_prediction_agreement).
+def test_conv_bn_pair_converges_as_plain_autodiff_does(monkeypatch):
+    """300 training steps with the conv + BN pair, as the step is built,
+    track the loss trajectory of the same step with every site on plain
+    autodiff (single device, f32) with bounded divergence: the forward is
+    the same and the closed-form backward a re-association of the same sums.
 
     Raw losses cannot stay close for hundreds of steps: benign ~1e-7
     re-association differences compound chaotically through RMSProp's rsqrt
     (~0.5% rel by step 20, observed). The long-horizon guarantee is "same
-    optimization", asserted as (a) every mode converges to the same
-    overfit plateau band, and (b) end-state train-batch predictions match
-    exact's exactly."""
+    optimization", asserted as (a) both converge to the same overfit
+    plateau band, and (b) end-state train-batch predictions match exactly."""
+    from yet_another_mobilenet_series_tpu.ops import layers
+
     batch = {
         "image": jax.random.normal(jax.random.PRNGKey(1), (8, 16, 16, 3)),
         "label": jnp.arange(8) % 4,
@@ -363,8 +388,10 @@ def test_bn_variants_converge_identically():
     rng = jax.random.PRNGKey(42)
     n_steps, tail = 300, 50
     traces, end_preds = {}, {}
-    for mode in ("exact", "folded", "compute", "fused_vjp", "sdot", "compute_sdot"):
-        cfg = _tiny_cfg(train={"compute_dtype": "float32", "bn_mode": mode})
+    for mode in ("paired", "unpaired"):
+        if mode == "unpaired":  # the site decides where the step is traced, which is its first call
+            monkeypatch.setattr(layers, "conv_bn_pairs", lambda *a, **kw: False)
+        cfg = _tiny_cfg(train={"compute_dtype": "float32"})
         net = get_model(cfg.model, image_size=16)
         lr_fn = schedules.make_lr_schedule(cfg.schedule, 8, 1, 100)
         params, _ = net.init(jax.random.PRNGKey(0))
@@ -378,17 +405,17 @@ def test_bn_variants_converge_identically():
         traces[mode] = np.asarray(losses)
         logits, _ = net.apply(ts.params, ts.state, batch["image"], train=False)
         end_preds[mode] = np.asarray(jnp.argmax(logits, -1))
-    for mode in ("folded", "fused_vjp", "compute", "sdot", "compute_sdot"):
-        # short horizon: trajectories are still numerically locked
-        np.testing.assert_allclose(traces[mode][:8], traces["exact"][:8], rtol=1e-3, atol=1e-4)
-        # long horizon: same plateau (mean over the last `tail` steps) ...
-        exact_tail = traces["exact"][-tail:].mean()
-        mode_tail = traces[mode][-tail:].mean()
-        assert abs(mode_tail - exact_tail) <= max(0.05, 0.15 * exact_tail), (
-            mode, mode_tail, exact_tail)
-        # ... and the same learned classification of the train batch
-        np.testing.assert_array_equal(end_preds[mode], end_preds["exact"], err_msg=mode)
-    # and training actually overfit in every mode (4 classes, 8 samples)
+    mode = "paired"
+    # short horizon: trajectories are still numerically locked
+    np.testing.assert_allclose(traces[mode][:8], traces["unpaired"][:8], rtol=1e-3, atol=1e-4)
+    # long horizon: same plateau (mean over the last `tail` steps) ...
+    exact_tail = traces["unpaired"][-tail:].mean()
+    mode_tail = traces[mode][-tail:].mean()
+    assert abs(mode_tail - exact_tail) <= max(0.05, 0.15 * exact_tail), (
+        mode, mode_tail, exact_tail)
+    # ... and the same learned classification of the train batch
+    np.testing.assert_array_equal(end_preds[mode], end_preds["unpaired"], err_msg=mode)
+    # and training actually overfit on both paths (4 classes, 8 samples)
     assert all(t[-tail:].mean() < t[0] * 0.5 for t in traces.values())
 
 
@@ -424,16 +451,6 @@ def test_train_step_overfits_tiny_batch():
     assert jax.tree.structure(ts.ema_params) == jax.tree.structure(ts.params)
     diffs = jax.tree.map(lambda a, b: float(jnp.abs(a - b).max()), ts.ema_params, ts.params)
     assert max(jax.tree.leaves(diffs)) > 0
-
-
-def test_eval_step_validates_bn_mode():
-    """ADVICE r4 #4: eval pins bn_mode='exact' internally, but a misspelled
-    train.bn_mode must still fail fast in an eval-only run — before this,
-    the typo surfaced only if a train step was ever built."""
-    cfg = _tiny_cfg(train={"compute_dtype": "float32", "bn_mode": "exactt"})
-    net = get_model(cfg.model, image_size=16)
-    with pytest.raises(ValueError, match="bn_mode"):
-        steps.make_eval_step(net, cfg)
 
 
 def test_eval_step_counts_and_padding():

@@ -6,9 +6,9 @@
   (utils/compile_cache.py);
 - one process per chip: the fleet supervisor reaches its first spawn without
   a JAX backend, and refuses more replicas than the host has chips;
-- nothing passes without the device: cli.serve fails when requests failed,
-  bench.py without a chip prints no images/sec/chip, an unknown device has
-  no peak.
+- nothing passes without the device: cli.serve fails when requests failed
+  (that the benchmark without a chip exits 3 and an unknown device has no
+  peak is tests/benchmark_tests/'s).
 
 The file sorts last on purpose: the tier-1 gate counts passing dots inside a
 time limit the suite already strains, so tests added with PR 22 run after
@@ -90,7 +90,6 @@ def probes(tmp_path_factory):
     py = sys.executable
     cmds = {
         "chip_smoke": ([py, os.path.join(REPO, "chip_smoke.py")], _env(), REPO),
-        "bench": ([py, os.path.join(REPO, "bench.py")], _env(), REPO),
         "supervisor": ([py, "-c", _SUPERVISOR_PROBE, str(tmp)], _env(), "/"),
         "cache_a": ([py, "-c", _CACHE_PROBE], _env(**off_cpu, **no_cache), "/"),
         "cache_b": ([py, "-c", _CACHE_PROBE], _env(**off_cpu, **no_cache), str(tmp / "elsewhere")),
@@ -184,7 +183,7 @@ def test_compile_cache_is_placed_one_way(probes):
     assert got["cache_cpu"][:2] == (None, None)
     # set in exactly one place (the code git tracks, not stray copies)
     code = [os.path.join(REPO, d) for d in (
-        "yet_another_mobilenet_series_tpu", "scripts", "bench.py", "chip_smoke.py",
+        "yet_another_mobilenet_series_tpu", "scripts", "chip_smoke.py",
         "__graft_entry__.py")]
     hits = subprocess.run(
         ["grep", "-rlE", r"jax_compilation_cache_dir\"|compilation_cache\.set_cache_dir",
@@ -278,28 +277,3 @@ def test_cli_serve_fails_when_the_engine_raises_on_every_request(tmp_path, monke
     # the post-mortem still landed (the healthy path, counts intact, is
     # tests/test_serve.py's round trip)
     assert os.path.exists(tmp_path / "obs_registry.json")
-
-
-def test_bench_without_a_chip_exits_nonzero_and_prints_no_device_metric(probes):
-    rc, out, err = probes("bench")
-    assert rc != 0
-    assert "no TPU" in err
-    assert "images/sec/chip" not in out and out.strip() == ""
-
-
-def test_unknown_device_kind_has_no_peak():
-    bench = _load("bench.py", "bench_mod")
-    assert bench.peak_flops_for("TPU v5 lite") == 197e12
-    with pytest.raises(KeyError, match="PEAK_FLOPS_BY_KIND"):
-        bench.peak_flops_for("TPU v99")
-
-
-@pytest.mark.slow
-def test_bench_cpu_smoke_reports_no_rate():
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py"), "--cpu"],
-                       capture_output=True, text=True, timeout=900, env=_env(), cwd=REPO)
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["metric"] == "bench_cpu_control_flow_smoke" and out["value"] is None
-    assert out["loss_finite"] and out["platform"] == "cpu"
-    assert not {"unit", "mfu", "mfu_fwd_only", "ms_per_step"} & set(out)

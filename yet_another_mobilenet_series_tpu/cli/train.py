@@ -97,9 +97,7 @@ class Trainer:
         )
         self.eval_step = dp.make_dp_eval_step(net, cfg, mesh)
         # the complete per-cadence prune event (reached check + adaptive rho
-        # + mask update) as ONE device program — shared verbatim between the
-        # single-step dispatch path and the grouped program, so
-        # steps_per_dispatch>1 no longer has to be forced off under pruning
+        # + mask update) as ONE device program
         self.prune_stop_step = int(cfg.prune.stop_epoch_frac * cfg.train.epochs * self.steps_per_epoch)
         self.prune_event = (
             jax.jit(masking.make_prune_event(net, cfg.prune, self.prune_stop_step))
@@ -399,15 +397,6 @@ def run(cfg: Config) -> dict:
     import dataclasses as dc
 
     compile_cache.configure()  # before the first compile (config only: no backend touch)
-    tuning_lines: list[str] = []
-    if cfg.train.tuning_file:
-        # before ANY backend touch (jax.distributed / make_mesh): a 'flags'
-        # entry lands in XLA_FLAGS/LIBTPU_INIT_ARGS, read once at backend
-        # init. Malformed file = hard error: the user explicitly pointed the
-        # run at it (unlike bench.py, where tuning is an aux artifact).
-        from ..train import tuning as tuning_lib
-
-        cfg, tuning_lines = tuning_lib.apply_tuning_file(cfg)
     if cfg.dist.multihost:
         # multi-host rendezvous: the reference's torch.distributed env://
         # init; on TPU pods the coordinator/process env is auto-discovered.
@@ -420,8 +409,6 @@ def run(cfg: Config) -> dict:
     log = Logger(cfg.train.log_dir, enabled=is_coord, tensorboard=bool(cfg.train.log_dir))
     mesh = mesh_lib.make_mesh(cfg.dist.num_devices)
     log.log(f"devices: {mesh.size} ({jax.devices()[0].platform}), hosts: {jax.process_count()}")
-    for line in tuning_lines:  # provenance of measured-winner overrides
-        log.log(line)
 
     # ---- runtime telemetry (obs/, docs/OBSERVABILITY.md) ----
     # registry snapshots ride into every scalars row; the span tracer and
@@ -645,28 +632,6 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
     remat_cad = StepCadence(cfg.prune.remat_epochs, spe, host_step)
     best_ckpt: CheckpointManager | None = None  # created on first new-best eval
 
-    # multi-step dispatch (train.steps_per_dispatch): k steps per jit call,
-    # amortizing the per-step host-dispatch latency bench_bn's
-    # --dispatch-probe measures. Pruning composes since round 5: the prune
-    # event runs in-device after every unrolled sub-step (its own step gate
-    # keeps the cadence identical to single dispatches). Only the profiler
-    # window still needs step-granular host control (start/stop_trace are
-    # host calls at exact step indices) and forces k=1 with a warning —
-    # the obs span tracer has no such constraint: its spans time the host
-    # side of each dispatch, grouped or not.
-    k_dispatch = max(1, cfg.train.steps_per_dispatch)
-    if k_dispatch > 1 and cfg.train.profile_start_step:
-        log.log("WARNING: steps_per_dispatch>1 is incompatible with the profiler "
-                "window; forcing 1")
-        k_dispatch = 1
-
-    def build_grouped():
-        if k_dispatch < 2:
-            return None
-        return dp.make_grouped_train_step(trainer.train_step, k_dispatch,
-                                          event_fn=trainer.prune_event)
-
-    grouped_step = build_grouped()
     # device-cost accounting fires once per compiled step program: on the
     # first dispatch, and again after a rematerialize rebuild (new shapes =>
     # new executable => new cost)
@@ -681,138 +646,120 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                 if preempt.requested:
                     preempted = True
                     break
-                if grouped_step is not None and epoch_steps - steps_done >= k_dispatch:
-                    with tracer.span("data/next", "data", batches=k_dispatch):
-                        bs = tuple(next(train_iter) for _ in range(k_dispatch))
-                    t_dispatch0 = time.perf_counter()
-                    with tracer.span("dispatch/grouped_step", "dispatch", steps=k_dispatch):
-                        ts, metric_list = grouped_step(ts, bs, rng)
-                    cost_batch = bs[0]
-                else:
-                    with tracer.span("data/next", "data"):
-                        b = next(train_iter)  # already on-mesh (prefetch_to_mesh)
-                    t_dispatch0 = time.perf_counter()
-                    with tracer.span("dispatch/train_step", "dispatch"):
-                        ts, metrics = trainer.train_step(ts, b, rng)
-                    metric_list = [metrics]
-                    cost_batch = b
+                with tracer.span("data/next", "data"):
+                    b = next(train_iter)  # already on-mesh (prefetch_to_mesh)
+                t_dispatch0 = time.perf_counter()
+                with tracer.span("dispatch/train_step", "dispatch"):
+                    ts, metrics = trainer.train_step(ts, b, rng)
                 if not cost_recorded:
                     cost_recorded = True
                     _record_step_cost(
-                        trainer, ts, cost_batch, rng, reg, tracer, log,
+                        trainer, ts, b, rng, reg, tracer, log,
                         time.perf_counter() - t_dispatch0,
-                        scope_table_dir=(cfg.train.log_dir + "/trace"
-                                         if cfg.train.profile_start_step and k_dispatch == 1 else ""))
-                steps_done += len(metric_list)
-                # per-sub-step host processing: metrics entries are lazy
-                # device arrays; nothing below syncs unless a cadence fires
-                for metrics in metric_list:
-                    # host-side counter: int(ts.step) would sync the host
-                    # with the device every step and stall async dispatch
-                    host_step += 1
-                    step_i = host_step
-                    metric_log.update(metrics, batch_images=cfg.train.batch_size)
+                        scope_table_dir=cfg.train.log_dir + "/trace" if cfg.train.profile_start_step else "")
+                steps_done += 1
+                # the metrics entries are lazy device arrays: nothing below
+                # syncs unless a cadence fires. The counter is the host's own:
+                # int(ts.step) would sync the host with the device every step
+                # and stall async dispatch
+                host_step += 1
+                step_i = host_step
+                metric_log.update(metrics, batch_images=cfg.train.batch_size)
+                if guard is not None:
+                    guard.observe(step_i, metrics)  # lazy stash; no sync
+                if watchdog is not None:
+                    watchdog.arm(step_i)
+
+                if cfg.train.profile_start_step and is_coord:
+                    if step_i == cfg.train.profile_start_step:
+                        # stop is finally-guaranteed (YAMT013): the close
+                        # below runs in a finally, and the loop's outer
+                        # finally flushes a window still open on ANY exit
+                        jax.profiler.start_trace(cfg.train.log_dir + "/trace")
+                        trace_active = True
+                    elif trace_active and step_i >= cfg.train.profile_start_step + cfg.train.profile_num_steps:
+                        try:
+                            # barrier before closing the trace: dispatch is
+                            # async, so without a device->host read of a
+                            # value that depends on the last step the window
+                            # would close while that step is still running
+                            jax.device_get(metrics["loss"])
+                        finally:
+                            # a failed barrier sync must still close the
+                            # window HERE (the old code left it running
+                            # until the outer finally, capturing the whole
+                            # unwind into the trace)
+                            jax.profiler.stop_trace()
+                            trace_active = False
+                        log.log(f"profiler trace captured to {cfg.train.log_dir}/trace")
+
+                if (
+                    trainer.prune_event is not None
+                    and step_i % cfg.prune.mask_interval == 0
+                    and step_i <= trainer.prune_stop_step
+                ):
+                    # the whole event (reached-target check via in-jit
+                    # effective MACs, adaptive-rho feedback — SURVEY.md
+                    # §2 #11, conditional mask update) runs on device;
+                    # the host gate above only skips the off-cadence
+                    # dispatches (the event's own step gate is true
+                    # exactly when this condition is)
+                    with tracer.span("prune/mask_event", "prune", step=step_i):
+                        masks, rho_mult = trainer.prune_event(
+                            ts.params, ts.masks, ts.rho_mult, ts.step)
+                        ts = ts.replace(masks=masks, rho_mult=rho_mult)
+
+                if step_i % cfg.train.log_every == 0:
+                    # the log-boundary host sync: snapshot float()s every
+                    # pending metric (blocks on the last dispatched step)
+                    with tracer.span("sync/log_metrics", "sync", step=step_i):
+                        snap = metric_log.snapshot_and_reset(num_chips=trainer.mesh.size)
+                    reg.gauge("train.step").set(step_i)
+                    if isinstance(trainer.net, TokenModel):
+                        # a sequence is this loop's "image"; the expert layer's counters
+                        # (ops/lm.py) are step scalars like any other
+                        reg.gauge("train.tokens_per_s").set(
+                            snap.get("images_per_sec", 0.0) * trainer.net.lm.seq_len)
+                        for name in ("moe_assignments_here", "moe_load_max_over_mean", "moe_dropped"):
+                            if name in snap:
+                                reg.gauge("train." + name).set(snap[name])
+                    if cfg.prune.enable:
+                        snap["effective_macs"] = masking.mask_summary(trainer.net, ts.masks)["effective_macs"]
+                        if cfg.prune.rho_schedule == "adaptive":
+                            # adaptation lives on device now; one host
+                            # sync per log boundary, not per event
+                            with tracer.span("sync/rho_mult", "sync"):
+                                snap["rho_mult"] = float(jax.device_get(ts.rho_mult))
+                            reg.counter("train.forced_host_syncs").inc()
+                    # (decode failures now flow through the registry: the
+                    # native loader registers a data.decode_failures pull
+                    # gauge that every scalars row snapshots)
+                    log.log(format_metrics(f"step {step_i}:", snap))
+                    log.scalars(step_i, snap, "train/")
                     if guard is not None:
-                        guard.observe(step_i, metrics)  # lazy stash; no sync
-                    if watchdog is not None:
-                        watchdog.arm(step_i)
-
-                    if cfg.train.profile_start_step and is_coord:
-                        if step_i == cfg.train.profile_start_step:
-                            # stop is finally-guaranteed (YAMT013): the close
-                            # below runs in a finally, and the loop's outer
-                            # finally flushes a window still open on ANY exit
-                            jax.profiler.start_trace(cfg.train.log_dir + "/trace")
-                            trace_active = True
-                        elif trace_active and step_i >= cfg.train.profile_start_step + cfg.train.profile_num_steps:
-                            try:
-                                # barrier before closing the trace: dispatch is
-                                # async, so without a device->host read of a
-                                # value that depends on the last step the window
-                                # would close while that step is still running
-                                jax.device_get(metrics["loss"])
-                            finally:
-                                # a failed barrier sync must still close the
-                                # window HERE (the old code left it running
-                                # until the outer finally, capturing the whole
-                                # unwind into the trace)
-                                jax.profiler.stop_trace()
-                                trace_active = False
-                            log.log(f"profiler trace captured to {cfg.train.log_dir}/trace")
-
-                    if (
-                        len(metric_list) == 1
-                        and trainer.prune_event is not None
-                        and step_i % cfg.prune.mask_interval == 0
-                        and step_i <= trainer.prune_stop_step
-                    ):
-                        # the whole event (reached-target check via in-jit
-                        # effective MACs, adaptive-rho feedback — SURVEY.md
-                        # §2 #11, conditional mask update) runs on device;
-                        # the host gate above only skips the off-cadence
-                        # dispatches (the event's own step gate is true
-                        # exactly when this condition is). Inside a grouped
-                        # dispatch (len(metric_list) == k > 1) the event
-                        # already ran in-device after every sub-step — but
-                        # an epoch-TAIL step dispatched singly (fewer than k
-                        # steps left) has no in-device event and must take
-                        # this host path even when grouping is on.
-                        with tracer.span("prune/mask_event", "prune", step=step_i):
-                            masks, rho_mult = trainer.prune_event(
-                                ts.params, ts.masks, ts.rho_mult, ts.step)
-                            ts = ts.replace(masks=masks, rho_mult=rho_mult)
-
-                    if step_i % cfg.train.log_every == 0:
-                        # the log-boundary host sync: snapshot float()s every
-                        # pending metric (blocks on the last dispatched step)
-                        with tracer.span("sync/log_metrics", "sync", step=step_i):
-                            snap = metric_log.snapshot_and_reset(num_chips=trainer.mesh.size)
-                        reg.gauge("train.step").set(step_i)
-                        if isinstance(trainer.net, TokenModel):
-                            # a sequence is this loop's "image"; the expert layer's counters
-                            # (ops/lm.py) are step scalars like any other
-                            reg.gauge("train.tokens_per_s").set(
-                                snap.get("images_per_sec", 0.0) * trainer.net.lm.seq_len)
-                            for name in ("moe_assignments_here", "moe_load_max_over_mean", "moe_dropped"):
-                                if name in snap:
-                                    reg.gauge("train." + name).set(snap[name])
-                        if cfg.prune.enable:
-                            snap["effective_macs"] = masking.mask_summary(trainer.net, ts.masks)["effective_macs"]
-                            if cfg.prune.rho_schedule == "adaptive":
-                                # adaptation lives on device now; one host
-                                # sync per log boundary, not per event
-                                with tracer.span("sync/rho_mult", "sync"):
-                                    snap["rho_mult"] = float(jax.device_get(ts.rho_mult))
-                                reg.counter("train.forced_host_syncs").inc()
-                        # (decode failures now flow through the registry: the
-                        # native loader registers a data.decode_failures pull
-                        # gauge that every scalars row snapshots)
-                        log.log(format_metrics(f"step {step_i}:", snap))
-                        log.scalars(step_i, snap, "train/")
-                        if guard is not None:
-                            # the guard already rolled back any non-finite
-                            # step on device; here it counts the skips and
-                            # enforces the budget (train/guard.py) — may
-                            # raise TrainHealthError with train_health.json
-                            guard.check(step_i)
-                        elif snap.get("finite", 1.0) < 1.0:
-                            log.error("non-finite loss detected; aborting")
-                            raise FloatingPointError("non-finite loss")
-                    if cfg.train.check_finite_every and step_i % cfg.train.check_finite_every == 0:
-                        # forced host sync — a debug guard, off by default
-                        with tracer.span("sync/finite_check", "sync", step=step_i):
-                            finite = float(metrics["finite"])
-                        reg.counter("train.forced_host_syncs").inc()
-                        if finite < 1.0:
-                            log.error(f"non-finite loss at step {step_i}")
-                            raise FloatingPointError("non-finite loss")
-                    if cfg.train.param_checksum_every and step_i % cfg.train.param_checksum_every == 0:
-                        with tracer.span("sync/replica_checksum", "sync", step=step_i):
-                            div = float(trainer.sync_check(ts.params))
-                        reg.counter("train.forced_host_syncs").inc()
-                        if div != 0.0:
-                            log.error(f"replica divergence {div} at step {step_i}")
-                            raise RuntimeError("replica divergence")
+                        # the guard already rolled back any non-finite
+                        # step on device; here it counts the skips and
+                        # enforces the budget (train/guard.py) — may
+                        # raise TrainHealthError with train_health.json
+                        guard.check(step_i)
+                    elif snap.get("finite", 1.0) < 1.0:
+                        log.error("non-finite loss detected; aborting")
+                        raise FloatingPointError("non-finite loss")
+                if cfg.train.check_finite_every and step_i % cfg.train.check_finite_every == 0:
+                    # forced host sync — a debug guard, off by default
+                    with tracer.span("sync/finite_check", "sync", step=step_i):
+                        finite = float(metrics["finite"])
+                    reg.counter("train.forced_host_syncs").inc()
+                    if finite < 1.0:
+                        log.error(f"non-finite loss at step {step_i}")
+                        raise FloatingPointError("non-finite loss")
+                if cfg.train.param_checksum_every and step_i % cfg.train.param_checksum_every == 0:
+                    with tracer.span("sync/replica_checksum", "sync", step=step_i):
+                        div = float(trainer.sync_check(ts.params))
+                    reg.counter("train.forced_host_syncs").inc()
+                    if div != 0.0:
+                        log.error(f"replica divergence {div} at step {step_i}")
+                        raise RuntimeError("replica divergence")
             if preempted:
                 epoch = host_step / spe  # exact mid-epoch position
                 log.log(f"preemption ({preempt.reason}): stopping at step {host_step} "
@@ -827,13 +774,7 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                 with tracer.span("rebuild/rematerialize", "rebuild", step=host_step):
                     trainer, ts = _maybe_rematerialize(trainer, ts, log)
                 if trainer is not old_trainer:
-                    # shapes (and the prune event's cost table) changed —
-                    # the grouped program must be rebuilt against the new
-                    # trainer; identity check avoids a gratuitous retrace
-                    # when nothing died
                     reg.counter("train.rebuilds").inc()
-                    with tracer.span("rebuild/grouped_step", "rebuild"):
-                        grouped_step = build_grouped()
                     cost_recorded = not is_coord  # new executable: re-account its cost
                 if watchdog is not None:
                     watchdog.arm(host_step, phase="rematerialize")

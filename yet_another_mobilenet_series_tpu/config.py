@@ -369,21 +369,6 @@ class TrainConfig:
     # jax.checkpoint the forward pass: recompute activations in backward to
     # trade FLOPs for HBM (enables larger per-chip batches)
     remat: bool = False
-    # remat flavor: "full" recomputes everything from the inputs; "save_conv"
-    # saves the conv (MXU) outputs and recomputes only the BN/act elementwise
-    # chains — targets the BN activation round-trips without re-running convs
-    remat_policy: str = "full"
-    # BatchNorm normalize expression: "exact" (f32, reference semantics),
-    # "folded" (precomputed f32 scale/bias FMA), "compute" (FMA in the
-    # compute dtype), "fused_vjp" (folded forward + closed-form custom
-    # backward with pinned bf16 residuals). Statistics are identical f32 in
-    # every mode; this knob targets the 52% BN-reduction share of the
-    # pre-PR-1 TPU trace (ROADMAP.md's table). See ops/layers.py BatchNorm.apply.
-    bn_mode: str = "exact"
-    # lower 1x1 ungrouped convs as explicit matmuls so their weight grads
-    # are guaranteed MXU dots — targets the 25.3% multiply_add_fusion
-    # weight-grad share of the round-2 trace (ops/layers.py Conv2D.apply)
-    conv1x1_dot: bool = False
     log_every: int = 100
     eval_every_epochs: float = 1.0
     checkpoint_every_epochs: float = 1.0
@@ -405,24 +390,6 @@ class TrainConfig:
     # for profile_num_steps steps; trace lands in log_dir/trace. 0 = off.
     profile_start_step: int = 0
     profile_num_steps: int = 5
-    # >1: run this many train steps per host dispatch (one jit call of k
-    # unrolled steps) to amortize per-step host-dispatch latency —
-    # adopt when bench_bn's --dispatch-probe shows a real tax. Same data
-    # order/RNG/resume accounting as single dispatches; numerics agree to
-    # XLA cross-step fusion rounding ~1e-7 (parallel/dp.py
-    # make_grouped_train_step). Composes with pruning (the prune event runs
-    # in-device after every unrolled sub-step, nas/masking.make_prune_event);
-    # only the profiler window (host start/stop_trace at exact steps) still
-    # forces 1 with a logged warning.
-    steps_per_dispatch: int = 1
-    # path to a BENCH_TUNING.json-format file (train/tuning.py): its
-    # step-config keys (bn_mode,
-    # remat, remat_policy, conv1x1_dot, steps_per_dispatch) and XLA flags
-    # override this config at startup with provenance logged — measured
-    # winners reach production runs without hand-editing YAML
-    # (train/tuning.py; eval accuracy is immune: eval always runs exact BN
-    # + stock conv lowering). "" = off.
-    tuning_file: str = ""
     # step health guard + train-side chaos injection sub-blocks
     guard: GuardConfig = field(default_factory=GuardConfig)
     faults: TrainFaultsConfig = field(default_factory=TrainFaultsConfig)
@@ -435,9 +402,8 @@ class ObsConfig:
     counters); tracing and the watchdog are opt-in knobs."""
 
     # coordinator-only span tracer; Chrome-trace JSON lands in
-    # log_dir/obs_trace.json at run end (or on crash). Composes with
-    # train.steps_per_dispatch > 1 — spans time the HOST side of dispatches,
-    # unlike the jax.profiler window which forces k=1.
+    # log_dir/obs_trace.json at run end (or on crash). Spans time the HOST
+    # side of dispatches; the jax.profiler window times the device.
     trace: bool = False
     # completed spans kept in the ring buffer (oldest evicted); one span is
     # a ~100-byte tuple, so the default retains the last few thousand events

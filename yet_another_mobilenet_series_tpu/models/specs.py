@@ -94,12 +94,12 @@ class Network:
         params["classifier"] = self.classifier.init(keys[-1])
         return params, state
 
-    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+    def conv_bn_pair_sites(self) -> tuple[int, int]:
         """(sites a train step lowers through ops/layers.py's conv + BN pair,
         1x1 conv + BN sites in the network): what `train.conv_bn_pairs` and
         `train.conv_bn_pair_eligible` report."""
         parts = [self.stem, *self.blocks] + ([self.head] if self.head is not None else [])
-        counts = [p.conv_bn_pair_sites(bn_mode=bn_mode, conv1x1_dot=conv1x1_dot) for p in parts]
+        counts = [p.conv_bn_pair_sites() for p in parts]
         return sum(c[0] for c in counts), sum(c[1] for c in counts)
 
     def apply(
@@ -113,8 +113,6 @@ class Network:
         compute_dtype=None,
         masks: Mapping[int, Any] | None = None,
         rng=None,
-        bn_mode: str = "exact",
-        conv1x1_dot: bool = False,
     ):
         import jax
         import jax.numpy as jnp
@@ -129,7 +127,6 @@ class Network:
         h = x
         h, new_state["stem"] = self.stem.apply(
             params["stem"], state["stem"], h, train=train, axis_name=axis_name, compute_dtype=compute_dtype,
-            bn_mode=bn_mode,
         )
         nbs: dict = {}
         # Per-block stochastic-depth streams fold the block index into the
@@ -151,15 +148,12 @@ class Network:
                 axis_name=axis_name,
                 compute_dtype=compute_dtype,
                 mask=mask,
-                bn_mode=bn_mode,
-                conv1x1_dot=conv1x1_dot,
                 rng=block_rng,
             )
         new_state["blocks"] = nbs
         if self.head is not None:
             h, new_state["head"] = self.head.apply(
                 params["head"], state["head"], h, train=train, axis_name=axis_name, compute_dtype=compute_dtype,
-                bn_mode=bn_mode, conv1x1_dot=conv1x1_dot,
             )
         h = global_avg_pool(h)  # (N, C)
         if self.feature is not None:

@@ -66,14 +66,9 @@ def make_prune_event(net: Network, cfg: PruneConfig, stop_step: int):
     reached-target check, adaptive-rho feedback, and the conditional mask
     update — of (params, masks, rho_mult, step) -> (masks, rho_mult).
 
-    Until round 5 the reached/rho half lived host-side in cli/train.py,
-    which forced steps_per_dispatch=1 under pruning (VERDICT r4 weak #3 /
-    next #4): the longest runs — AtomNAS search — could not amortize a
-    measured dispatch tax. Moving the event in-device makes the single-step
-    and grouped paths share the identical program: the CLI dispatches it at
-    the mask cadence, and dp.make_grouped_train_step inlines it after every
-    unrolled sub-step, where the same (step % interval == 0) & (step <=
-    stop) gate it carries makes off-cadence sub-steps a no-op.
+    The whole event runs on the device: the CLI dispatches it at the mask
+    cadence, and the (step % interval == 0) & (step <= stop) gate it carries
+    makes a dispatch off the cadence or past the stop a no-op.
 
     The reached check uses the in-jit linear form of
     utils/profiling.masked_macs (exact: every atom's expand/dw/SE/project

@@ -10,9 +10,8 @@ on in production:
   ``Logger.scalars`` row under an ``obs/`` prefix.
 - ``trace``: a ring-buffered span tracer (context-manager API, monotonic
   clocks, no host<->device syncs on the hot path) emitting
-  Chrome-trace/Perfetto JSON. Unlike the ``jax.profiler`` window it composes
-  with ``train.steps_per_dispatch > 1``: spans measure HOST time around
-  dispatches, so grouping stays on.
+  Chrome-trace/Perfetto JSON. Unlike the ``jax.profiler`` window it can stay
+  on for a whole run: spans measure HOST time around dispatches.
 - ``watchdog``: a heartbeat thread armed per train step; if no step (or
   eval/checkpoint progress event) lands within a configurable deadline it
   dumps ``hang_report.json`` — open spans, last completed step, registry

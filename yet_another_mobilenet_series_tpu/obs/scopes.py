@@ -47,11 +47,11 @@ import jax
 SCOPES = (
     "input",        # train/steps.py: cast / uint8 normalize of the batch, mixup and cutmix
     "conv_dw",      # ops/layers.py Conv2D: depthwise (groups == channels), the VPU convs
-    "conv_pw",      # Conv2D: 1x1 ungrouped, the MXU matmuls (as conv or as dot)
+    "conv_pw",      # Conv2D: 1x1 ungrouped, the MXU matmuls
     "conv_full",    # Conv2D: the stem and any dense or grouped k x k
     "dense",        # Dense: feature layer and classifier
-    "bn_stats",     # BatchNorm: batch moments (every BN_MODES variant), running-stat update,
-                    # and in the backward the dgamma/dbeta reductions of `fused_vjp`
+    "bn_stats",     # BatchNorm: batch moments, running-stat update, and in the
+                    # conv + BN pair's backward the dgamma/dbeta reductions
     "bn_apply",     # BatchNorm: the normalize, and its backward's elementwise pass
     "act",          # ops/activations.py: every non-identity activation
     "se",           # ops/blocks.py SqueezeExcite, whole (see the nesting rule)

@@ -2,9 +2,9 @@
 
 Coordinator-only, host-side, and deliberately dumber than ``jax.profiler``:
 spans measure HOST wall time (monotonic ``perf_counter_ns``) around the
-things the profiler window cannot see without forcing
-``steps_per_dispatch=1`` — data fetch, step dispatch, the log-boundary
-``float()`` sync, prune events, eval, checkpoint saves, Trainer rebuilds.
+things a short profiler window does not cover over a whole run: data
+fetch, step dispatch, the log-boundary ``float()`` sync, prune events,
+eval, checkpoint saves, Trainer rebuilds.
 Because a dispatch span closes when the host call RETURNS (async dispatch,
 no device sync), tracing adds no host<->device round trips: an input-bound
 step shows a fat ``data/next`` span, a dispatch-bound one a fat

@@ -54,10 +54,9 @@ class ConvBNAct:
     def bn(self) -> BatchNorm:
         return BatchNorm(self.out_channels, self.bn_momentum, self.bn_eps)
 
-    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+    def conv_bn_pair_sites(self) -> tuple[int, int]:
         """(sites a train step lowers through the conv + BN pair, 1x1 conv + BN sites)."""
-        return (int(conv_bn_pairs(self.conv, train=True, bn_mode=bn_mode, conv1x1_dot=conv1x1_dot)),
-                int(is_conv1x1_bn_site(self.conv)))
+        return int(conv_bn_pairs(self.conv, train=True)), int(is_conv1x1_bn_site(self.conv))
 
     def init(self, key):
         params = {"conv": self.conv.init(key)}
@@ -65,10 +64,9 @@ class ConvBNAct:
         params["bn"] = bn_p
         return params, {"bn": bn_s}
 
-    def apply(self, params, state, x, *, train, axis_name=None, compute_dtype=jnp.float32, bn_mode="exact",
-              conv1x1_dot=False):
+    def apply(self, params, state, x, *, train, axis_name=None, compute_dtype=jnp.float32):
         y, bn_s = conv_bn(self.conv, self.bn, params["conv"], params["bn"], state["bn"], x, train=train,
-                          axis_name=axis_name, compute_dtype=compute_dtype, bn_mode=bn_mode, conv1x1_dot=conv1x1_dot)
+                          axis_name=axis_name, compute_dtype=compute_dtype)
         y = get_activation(self.active_fn)(y)
         return y, {"bn": bn_s}
 
@@ -182,12 +180,11 @@ class InvertedResidual:
     def _project_conv(self) -> Conv2D:
         return Conv2D(self.expanded_channels, self.out_channels, 1)
 
-    def conv_bn_pair_sites(self, *, bn_mode: str, conv1x1_dot: bool) -> tuple[int, int]:
+    def conv_bn_pair_sites(self) -> tuple[int, int]:
         """(sites a train step lowers through the conv + BN pair, 1x1 conv + BN
         sites). Only the expand conv goes through conv_bn(): the project conv's
         INPUT is the wide tensor, so there the pair has nothing to shed."""
-        expand = self.has_expand and conv_bn_pairs(self._expand_conv, train=True, bn_mode=bn_mode,
-                                                   conv1x1_dot=conv1x1_dot)
+        expand = self.has_expand and conv_bn_pairs(self._expand_conv, train=True)
         return int(expand), int(self.has_expand) + 1
 
     def _branches(self):
@@ -228,8 +225,6 @@ class InvertedResidual:
         axis_name: str | None = None,
         compute_dtype=jnp.float32,
         mask: Array | None = None,
-        bn_mode: str = "exact",
-        conv1x1_dot: bool = False,
         rng: Array | None = None,
     ):
         """mask: optional (expanded_channels,) multiplier zeroing dead atoms.
@@ -245,7 +240,6 @@ class InvertedResidual:
             h, new_state["expand_bn"] = conv_bn(
                 self._expand_conv, self._bn(self.expanded_channels), params["expand"], params["expand_bn"],
                 state["expand_bn"], h, train=train, axis_name=axis_name, compute_dtype=compute_dtype,
-                bn_mode=bn_mode, conv1x1_dot=conv1x1_dot,
             )
             h = act(h)
         branches = []
@@ -256,7 +250,7 @@ class InvertedResidual:
             )
         h = branches[0] if len(branches) == 1 else jnp.concatenate(branches, axis=-1)
         h, new_state["dw_bn"] = self._bn(self.expanded_channels).apply(
-            params["dw_bn"], state["dw_bn"], h, train=train, axis_name=axis_name, mode=bn_mode
+            params["dw_bn"], state["dw_bn"], h, train=train, axis_name=axis_name
         )
         h = act(h)
         if mask is not None:
@@ -266,9 +260,9 @@ class InvertedResidual:
             h = SqueezeExcite(self.expanded_channels, self.se_channels, self.se_inner_act, self.se_gate_fn).apply(
                 params["se"], h, compute_dtype=compute_dtype
             )
-        h = self._project_conv.apply(params["project"], h, compute_dtype=compute_dtype, as_dot=conv1x1_dot)
+        h = self._project_conv.apply(params["project"], h, compute_dtype=compute_dtype)
         h, new_state["project_bn"] = self._bn(self.out_channels).apply(
-            params["project_bn"], state["project_bn"], h, train=train, axis_name=axis_name, mode=bn_mode
+            params["project_bn"], state["project_bn"], h, train=train, axis_name=axis_name
         )
         h = get_activation(self.project_act)(h)
         if self.has_residual:

@@ -88,52 +88,15 @@ def make_dp_train_step(
         mesh=mesh,
         in_specs=(ts_spec, P(DATA_AXIS), P()),
         out_specs=(ts_spec, P()),
-        # check_vma=False is LOAD-BEARING for bn_mode='fused_vjp': its
-        # closed-form backward returns LOCAL partial dgamma/dbeta that the
-        # step's pmean/psum_scatter combines (ops/layers.py
-        # _bn_train_fused_bwd contract). Flipping to check_vma=True changes
-        # shard_map's replication semantics — revisit that VJP first
+        # check_vma=False is LOAD-BEARING for the conv + BatchNorm pair: its
+        # closed-form backward returns LOCAL partial dgamma/dbeta/dW that the
+        # step's pmean/psum_scatter combines (ops/layers.py _bn_grad_sums
+        # contract). Flipping to check_vma=True changes shard_map's
+        # replication semantics — revisit that VJP first
         # (pinned by tests/test_parallel.py::test_check_vma_contract).
         check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0,))
-
-
-def make_grouped_train_step(step_fn, k: int, event_fn=None):
-    """ONE host dispatch running ``k`` sequential train steps: the jitted
-    step inlines under trace, so the program is k unrolled step graphs
-    back-to-back. Amortizes the per-step host-dispatch latency that
-    bench_bn's --dispatch-probe measures (0.32 ms a step before PR 1,
-    ROADMAP.md's table) without any
-    batch-stacking copy — each prefetched on-mesh batch is consumed in
-    place, so data order, RNG folding (per-step via ts.step), and resume
-    accounting are IDENTICAL to k single dispatches. Numerics agree to XLA
-    fusion-boundary rounding (~1e-7 rel, measured: compiling k steps as one
-    program lets XLA fuse across steps — NOT bit-identical, unlike remat;
-    tests/test_parallel.py::test_grouped_step_equals_single_steps).
-
-    event_fn (nas/masking.make_prune_event): applied after EVERY unrolled
-    sub-step; its own (step % interval) & (step <= stop) gate makes
-    off-cadence sub-steps a no-op, so AtomNAS search runs grouped with the
-    mask/rho cadence identical to k single dispatches (VERDICT r4 next #4;
-    tests/test_nas.py::test_grouped_search_step_equals_singles).
-
-    Returns grouped(ts, (b_0..b_{k-1}), rng) -> (ts, [metrics_0..]).
-    Compile time scales with k (unrolled); intended for small k (2-8)."""
-    if k < 2:
-        raise ValueError(f"grouped step needs k >= 2, got {k}")
-
-    def grouped(ts: TrainState, batches, rng):
-        out = []
-        for b in batches:
-            ts, metrics = step_fn(ts, b, rng)
-            if event_fn is not None:
-                masks, rho_mult = event_fn(ts.params, ts.masks, ts.rho_mult, ts.step)
-                ts = ts.replace(masks=masks, rho_mult=rho_mult)
-            out.append(metrics)
-        return ts, out
-
-    return jax.jit(grouped, donate_argnums=(0,))
 
 
 def make_dp_eval_step(net: Network, cfg: Config, mesh: Mesh):

@@ -114,7 +114,7 @@ def init_opt_state(optimizer: optax.GradientTransformation, params, mesh: Mesh):
         in_specs=(P(),),
         out_specs=specs,
         # check_vma=False everywhere in parallel/: see the contract note at
-        # dp.py make_dp_train_step (fused_vjp local-partial grads) — pinned
+        # dp.py make_dp_train_step (the conv + BN pair's local-partial grads) — pinned
         # by tests/test_parallel.py::test_check_vma_contract
         check_vma=False,
     )

@@ -23,7 +23,6 @@ from ..models.lm import TokenModel
 from ..models.specs import Network
 from ..obs.registry import get_registry
 from ..obs.scopes import scope
-from ..ops.layers import BN_MODES
 from .ema import ema_update
 from .losses import cross_entropy_label_smooth, topk_correct
 
@@ -76,12 +75,6 @@ def init_train_state(
 
 def _dtype(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16}[name]
-
-
-def _check_bn_mode(cfg: Config):
-    """Fail at step-build time, not first-trace time deep inside jit."""
-    if cfg.train.bn_mode not in BN_MODES:
-        raise ValueError(f"unknown train.bn_mode {cfg.train.bn_mode!r} (valid: {BN_MODES})")
 
 
 def _input_normalizer(cfg: Config):
@@ -193,31 +186,17 @@ def _image_loss(net: Network, cfg: Config, axis_name: str | None, penalty_fn):
             compute_dtype=compute_dtype,
             masks=imasks,
             rng=rng,
-            bn_mode=cfg.train.bn_mode,
-            conv1x1_dot=cfg.train.conv1x1_dot,
         )
 
-    if cfg.train.remat_policy not in ("full", "save_conv"):
-        # validated even with remat off, so a config typo can't lie dormant
-        # until someone flips remat on
-        raise ValueError(f"unknown train.remat_policy {cfg.train.remat_policy!r}")
-    _check_bn_mode(cfg)
     # how often ops/layers.py's conv + BN pair engages in this step: decided
-    # there from each site's shape and these two modes, reported here
-    pairs, eligible = net.conv_bn_pair_sites(bn_mode=cfg.train.bn_mode, conv1x1_dot=cfg.train.conv1x1_dot)
+    # there from each site's shape alone, reported here
+    pairs, eligible = net.conv_bn_pair_sites()
     get_registry().gauge("train.conv_bn_pairs").set(pairs)
     get_registry().gauge("train.conv_bn_pair_eligible").set(eligible)
     if cfg.train.remat:
         # recompute activations during backward: HBM for FLOPs
         # (jax.checkpoint; SURVEY.md §0 HBM-bandwidth note)
-        if cfg.train.remat_policy == "full":
-            forward = jax.checkpoint(forward)
-        else:
-            # save_conv: keep the MXU results, recompute the BN/act chains
-            # (the conv_out landmark in ops/layers.py Conv2D.apply)
-            forward = jax.checkpoint(
-                forward, policy=jax.checkpoint_policies.save_only_these_names("conv_out")
-            )
+        forward = jax.checkpoint(forward)
 
     prep_input = _input_normalizer(cfg)
     mixer = make_batch_mixer(cfg)
@@ -373,14 +352,7 @@ def make_eval_step(net: Network | TokenModel, cfg: Config, *, axis_name: str | N
     """Returns eval_fn(params, state, batch, masks) -> summed metric counts
     {'top1','top5','n','loss_sum'} — allreduce-able AverageMeter counts
     (SURVEY.md §2 #13). Runs on EMA shadow weights when the caller passes
-    them (reference: eval-on-shadow, SURVEY.md §2 #8).
-
-    Perf knobs do NOT leak into the metric path (ADVICE r3 #3): eval always
-    normalizes with the reference-parity exact BN expression and the stock
-    conv lowering regardless of train.bn_mode/train.conv1x1_dot, so a tuned
-    training config can never perturb reported accuracy. (The bn_mode
-    perturbation itself is measured — on purpose, via net.apply directly —
-    by test_acceptance_mbv2.py::test_full_scale_bn_mode_prediction_agreement.)"""
+    them (reference: eval-on-shadow, SURVEY.md §2 #8)."""
     compute_dtype = _dtype(cfg.train.compute_dtype)
     if isinstance(net, TokenModel):
         # the main head's next-token counts, in the same four sums
@@ -392,11 +364,6 @@ def make_eval_step(net: Network | TokenModel, cfg: Config, *, axis_name: str | N
             return metrics
 
         return eval_tokens
-    # the value is ignored here (eval pins exact), but a misspelled
-    # train.bn_mode must still fail fast in an eval-only run rather than
-    # only when a train step is ever built (ADVICE r4 #4)
-    _check_bn_mode(cfg)
-
     prep_input = _input_normalizer(cfg)
 
     def eval_fn(params, state, batch, masks):
@@ -408,8 +375,6 @@ def make_eval_step(net: Network | TokenModel, cfg: Config, *, axis_name: str | N
             train=False,
             compute_dtype=compute_dtype,
             masks=imasks,
-            bn_mode="exact",
-            conv1x1_dot=False,
         )
         labels = batch["label"]
         # padded examples carry label -1: mask them out of every count

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — decided here and nowhere
 else (``jax_compilation_cache_dir`` is set in exactly this one place).
 
-Every entry point that compiles (cli/train.py, cli/serve.py, bench.py, the
+Every entry point that compiles (cli/train.py, cli/serve.py, the
 measuring scripts, chip_smoke.py) calls :func:`configure` before its first
 compile. The rule:
 
