@@ -272,8 +272,10 @@ def test_blocked_attention_and_its_backward_equal_plain_causal_attention(monkeyp
 def test_on_the_cpu_attention_lowers_to_the_loops_at_every_shape(setup):
     """The kernels are for a TPU lowering alone: at a shape they take, a CPU
     lowering holds the tile loops and no Mosaic call; and the toy token step's
-    lowered module is, byte for byte, what the commit before the kernels
-    lowered (digests taken at 61078ea, float32 and bfloat16)."""
+    lowered module is pinned byte for byte, float32 and bfloat16 (digests taken
+    at PR 32, whose layer checkpoint keeps attention's output and log-sum-exp:
+    the module of 61078ea, the commit before the kernels, less the backward's
+    second forward loops)."""
     import hashlib
 
     assert lm_attention.fuses(1024, 512, 128, 128, jnp.bfloat16)
@@ -282,9 +284,70 @@ def test_on_the_cpu_attention_lowers_to_the_loops_at_every_shape(setup):
                             (0, 1, 2))).lower(x, x, x).as_text()
     assert "stablehlo.while" in text and "tpu_custom_call" not in text
     net, params, state, tokens, _, _, _ = setup
-    for dtype, digest in ((jnp.float32, "e7f9acf7ecb146a8"), (jnp.bfloat16, "2d59df13c341d275")):
+    for dtype, digest in ((jnp.float32, "b6f613dc73fcefba"), (jnp.bfloat16, "933950a18e0716d0")):
         text = program.lower(net, params, state, tokens, dtype).as_text()
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def layer_checkpoint(patch, how):
+    """The token model's checkpoints as shipped (`kept`), with the policy taken
+    off (`plain`: a layer keeps its input alone, as before PR 32), or gone
+    (`none`: every intermediate is kept)."""
+    real = jax.checkpoint
+    if how != "kept":
+        patch.setattr(jax, "checkpoint", (lambda fn, **kw: real(fn)) if how == "plain" else (lambda fn, **kw: fn))
+
+
+def attention_runs(jaxpr, found=None):
+    """How often the gradient's jaxpr holds each half of the tile loops: the
+    forward's key loop is the one `while` with the row maximum's
+    `optimization_barrier` in its body, the backward's the one without."""
+    from jax._src.core import jaxprs_in_params as inner
+
+    def holds(jaxpr, primitive):
+        return any(e.primitive.name == primitive or any(holds(j, primitive) for j in inner(e.params)) for e in jaxpr.eqns)
+
+    found = {"fwd": 0, "bwd": 0} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            found["fwd" if any(holds(j, "optimization_barrier") for j in inner(eqn.params)) else "bwd"] += 1
+        else:
+            for j in inner(eqn.params):
+                attention_runs(j, found)
+    return found
+
+
+@pytest.mark.parametrize("how, forwards_a_site", [("kept", 1), ("plain", 2), ("none", 1)])
+def test_the_gradient_runs_attentions_forward_once_a_site(setup, monkeypatch, how, forwards_a_site):
+    """The layer checkpoint keeps attention's output and log-sum-exp by name
+    (`ops.ATTN_OUT_NAME`, `ops.ATTN_LSE_NAME`), so the backward's second run
+    of a layer holds no attention forward: one forward and one backward a site
+    in the gradient's jaxpr, where a plain `jax.checkpoint` (the parent's)
+    holds two forwards."""
+    net, params, state, tokens, _, _, _ = setup
+    sites = net.attention_sites(jnp.float32)[0]
+    layer_checkpoint(monkeypatch, how)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: net.loss(p, state, {"tokens": tokens})[0]))(params)
+    assert attention_runs(jaxpr.jaxpr) == {"fwd": forwards_a_site * sites, "bwd": sites}
+
+
+@pytest.mark.parametrize("how", ["plain", "none"])
+def test_what_the_layer_checkpoint_keeps_changes_no_number(setup, monkeypatch, how):
+    """Loss, scalars, new state and every gradient leaf of the step as shipped
+    equal, to float rounding, those of the same loss with the checkpoint's
+    policy taken off and with no checkpoint at all: the kept `out` and `lse`
+    are what the second run made."""
+    net, params, state, tokens, _, _, _ = setup
+
+    def step(p):
+        return jax.value_and_grad(lambda p_: net.loss(p_, state, {"tokens": tokens}), has_aux=True)(p)
+
+    (loss, aux), grads = jax.jit(step)(params)
+    layer_checkpoint(monkeypatch, how)
+    (want_loss, want_aux), want = jax.jit(lambda p: step(p))(params)  # a new function: `step` itself is traced already
+    assert abs(float(loss) - float(want_loss)) < 1e-6
+    assert worst_leaf(aux, want_aux) < 1e-6
+    assert worst_leaf(grads, want) < 1e-6
 
 
 @pytest.mark.parametrize("shape, dtype, expect", [
@@ -306,7 +369,8 @@ def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypat
     """make_train_step sets train.attn_sites / train.attn_fused_sites from the
     model's shapes and the platform the step is lowered for: 6 / 0 for the
     cell's model on the CPU, 6 / 6 for a TPU mesh, 4 / 0 for the toy model
-    anywhere."""
+    anywhere; and, third, train.attn_kept_sites, the layers whose attention
+    output and log-sum-exp the layer checkpoint keeps: all, on every platform."""
     import os
 
     from yet_another_mobilenet_series_tpu.config import load_config
@@ -321,15 +385,16 @@ def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypat
     def gauges(net, **kw):
         params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
         steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn, **kw)
-        return get_registry().gauge("train.attn_sites").value, get_registry().gauge("train.attn_fused_sites").value
+        return tuple(get_registry().gauge(name).value
+                     for name in ("train.attn_sites", "train.attn_fused_sites", "train.attn_kept_sites"))
 
     monkeypatch.setattr(ops, "ATTN_BLOCK", 512)  # the tile as shipped, which this file's fixture shrinks
     cell = get_model(cfg.model)
     assert cfg.train.compute_dtype == "bfloat16" and cell.attention_sites(jnp.bfloat16) == (6, 6)
-    assert gauges(cell) == (6.0, 0.0)
-    assert gauges(cell, platform="cpu") == (6.0, 0.0)
-    assert gauges(cell, platform="tpu") == (6.0, 6.0)
-    assert gauges(model(), platform="tpu") == (4.0, 0.0)
+    assert gauges(cell) == (6.0, 0.0, 6.0)
+    assert gauges(cell, platform="cpu") == (6.0, 0.0, 6.0)
+    assert gauges(cell, platform="tpu") == (6.0, 6.0, 6.0)
+    assert gauges(model(), platform="tpu") == (4.0, 0.0, 4.0)
 
 
 _FRESH_PROCESS = """

@@ -196,3 +196,43 @@ def test_no_tile_of_scores_and_no_float32_dq_reaches_hbm(attention_hlo):
     float32_whole = [n for n, (out, _, _, op) in instructions.items() if scopes.scope_of(op)[0] == "attn_core"
                      and any(o.startswith("f32[") and elements(o) >= whole for o in out)]
     assert not float32_whole, float32_whole
+
+
+@pytest.fixture(scope="module")
+def token_step_hlo(one_chip):
+    """(attention sites, compiled text) of the gradient of `TokenModel.loss`
+    for a SMALL token model whose attention the kernels take (bfloat16, one
+    512-row tile a sequence, head dims 128 / 128): 1 dense + 1 expert layer +
+    the MTP module, each under its layer checkpoint (~10 s)."""
+    from yet_another_mobilenet_series_tpu.config import LMConfig, ModelConfig
+    from yet_another_mobilenet_series_tpu.models import get_model
+
+    lm = LMConfig(hidden_size=256, num_hidden_layers=2, first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=64,
+                  kv_lora_rank=64, qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128, intermediate_size=512,
+                  moe_intermediate_size=128, n_routed_experts=16, num_experts_per_tok=2, expert_shares=8, seq_len=512)
+    net = get_model(ModelConfig(arch="glm4_moe_lite", num_classes=512, lm=lm))
+    sites, fitting = net.attention_sites(jnp.bfloat16)
+    assert sites == fitting == 3
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params, state = jax.tree.map(on_chip, jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0))))
+    tokens = jax.ShapeDtypeStruct((2, lm.seq_len + 2), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(lambda p, s, t: net.loss(p, s, {"tokens": t}, compute_dtype=jnp.bfloat16)[0])).lower(
+        params, state, tokens).compile()
+    return sites, compiled.as_text()
+
+
+def test_the_token_step_runs_the_attention_forward_kernel_once_a_layer(token_step_hlo):
+    """The compiled gradient of a token model's loss holds exactly
+    `attention_sites` forward kernels and as many backward kernels, all under
+    `attn_core`, every forward in the forward pass: the layer checkpoint keeps
+    attention's output and log-sum-exp by name (models/lm.py, PR 32), so the
+    backward's second run of a layer makes q, k, v again and not the forward.
+    Under the parent's plain `jax.checkpoint` the same step holds TWICE the
+    forward count (3 under `jvp`, 3 more under `transpose(jvp)`)."""
+    sites, text = token_step_hlo
+    instructions, _ = _entry_instructions(text)
+    kernels = sorted((n.split(".")[0], *scopes.scope_of(op)) for n, (_, opcode, _, op) in instructions.items()
+                     if opcode == "custom-call" and n.startswith("causal_attention"))
+    assert kernels == ([("causal_attention_bwd", "attn_core", "bwd")] * sites
+                       + [("causal_attention_fwd", "attn_core", "fwd")] * sites)
+    assert len(re.findall(r"^\s*%?causal_attention_[\w.]+ = .* custom-call\(", text, re.M)) == 2 * sites  # none outside ENTRY

@@ -215,9 +215,15 @@ class TokenModel:
             # (float32 rows, then the cast: a frequent token's gradient is summed in float32)
             emb = params["embed"][tokens[:, :seq + 1]].astype(compute_dtype)
 
+        # Across the step a layer keeps its input and, by name, its attention's output and row log-sum-exp
+        # (B, H, S, Dv in the compute dtype; B, H, S float32). The backward runs the rest of the layer again
+        # (norms, MLA projections, RoPE, the q/k joins, MLP, the expert layer), but not the attention forward:
+        # its custom_vjp needs from that second run q, k, v alone.
+        kept = jax.checkpoint_policies.save_only_these_names(ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME)
+
         def run(block, x, p, bias):
             fn = lambda x_, p_, b_: self._block(block, p_, b_, x_, cos, sin)  # noqa: E731
-            return jax.checkpoint(fn)(x, p, bias)  # a layer keeps its input; the backward recomputes the rest
+            return jax.checkpoint(fn, policy=kept)(x, p, bias)
 
         new_state, selected, per_block = {}, {}, []
 

@@ -66,6 +66,10 @@ def apply_rope(x: Array, cos: Array, sin: Array) -> Array:
 
 # Query rows, and key rows, of one tile of scores: what causal_attention holds at once.
 ATTN_BLOCK = 512
+# The names (jax.ad_checkpoint.checkpoint_name) of what attention's backward keeps beside its operands: a
+# jax.checkpoint around a layer that saves these two (models/lm.py) does not run the forward a second time.
+ATTN_OUT_NAME = "attn_out"
+ATTN_LSE_NAME = "attn_lse"
 
 
 def _tile_scores(q, k, first_q, first_k, scale):
@@ -106,9 +110,15 @@ def _by_lowering(kernels, loops, scale, block, *operands):
 
 def _blocked_attention_fwd(q, k, v, scale, block):
     """Kept for the backward pass: the output and each row's log-sum-exp,
-    never a tile."""
+    never a tile. Both carry a name, whichever lowering made them: under a
+    `jax.checkpoint` that saves `ATTN_OUT_NAME` and `ATTN_LSE_NAME` they are
+    what the layer holds across the step, and the backward's second run of the
+    layer makes `q`, `k`, `v` again (cheap projections) and not this forward."""
+    from jax.ad_checkpoint import checkpoint_name  # not an attribute of `jax`; an alias module of what `import jax` loaded
+
     with scope("attn_core"):
         out, lse = _by_lowering(lm_attention.attention_fwd, loops_fwd, scale, block, q, k, v)
+        out, lse = checkpoint_name(out, ATTN_OUT_NAME), checkpoint_name(lse, ATTN_LSE_NAME)
         return out, (q, k, v, out, lse)
 
 

@@ -9,6 +9,13 @@ before their matmuls; kept for the backward `q`, `k`, `v`, `out`, `lse`, never
 a tile. What differs is where a tile of scores lives: in VMEM, from `QK^T` to
 the last matmul that reads it.
 
+**What a layer keeps ACROSS the step** is less than what the backward kernel
+reads: `ops/lm.py` names `out` and `lse`, and the layer checkpoint of
+`models/lm.py` saves those two names beside the layer's input. `q`, `k`, `v`
+are made again in the backward's second run of the layer (the MLA projections,
+RoPE and the joins, with the norms, the MLP and the expert layer); the forward
+kernel is not run again, since nothing else of its results is wanted.
+
 **This module imports no Pallas.** It holds what the dispatch and the gauges
 need (the predicate, the layout, the two entry points); the kernels are
 `ops/lm_attention_kernels.py`, imported INSIDE `attention_fwd/attention_bwd`,

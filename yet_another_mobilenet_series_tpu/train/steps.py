@@ -248,6 +248,9 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     sites, fitting = net.attention_sites(compute_dtype)
     get_registry().gauge("train.attn_sites").set(sites)
     get_registry().gauge("train.attn_fused_sites").set(fitting if (platform or jax.default_backend()) == "tpu" else 0)
+    # every block is one attention layer under one layer checkpoint, which keeps that attention's output and row
+    # log-sum-exp by name whatever the lowering (models/lm.py `forward`): all of them, on every platform
+    get_registry().gauge("train.attn_kept_sites").set(sites)
 
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
         return net.loss(params, state, batch, compute_dtype=compute_dtype, axis_name=axis_name)
