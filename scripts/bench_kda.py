@@ -1,0 +1,168 @@
+#!/usr/bin/env python3
+"""One Kimi Delta Attention layer's pieces on the chip, at the token cell's
+shapes (1 x 16,384 tokens, 32 heads of 128, bfloat16): what each part of
+ops/lm_kda.py costs alone, forward and forward + backward, and how the core's
+time moves with its three module constants. What chose them (PERF.md, PR 33).
+
+    python scripts/bench_kda.py [--iters 5] [--chunks 64,128] [--subs 16] [--groups 8]
+
+Measures on a TPU or exits 3. Prints one JSON line a piece: ms a call (host
+clock around `iters` calls ending in a sync).
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path.insert(0, REPO)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax import lax  # noqa: E402
+
+from yet_another_mobilenet_series_tpu.ops import lm_kda  # noqa: E402
+
+B, S, H, D, HIDDEN = 1, 16384, 32, 128, 2304  # kimilinear_train_1x16k's KDA layer
+
+
+def timed(fn, args, iters):
+    jax.block_until_ready(fn(*args))  # compiles
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / iters * 1e3
+
+
+def say(name, ms, **more):
+    print(json.dumps({"piece": name, "ms": round(ms, 3), **more}), flush=True)
+
+
+def total(tree):
+    return sum(jnp.sum(x.astype(jnp.float32)) for x in jax.tree.leaves(tree))
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--iters", type=int, default=5)
+    ap.add_argument("--chunks", default=str(lm_kda.KDA_CHUNK))
+    ap.add_argument("--subs", default=str(lm_kda.KDA_SUBCHUNK))
+    ap.add_argument("--groups", default=str(lm_kda.KDA_HEAD_GROUP))
+    args = ap.parse_args()
+    if jax.devices()[0].platform != "tpu":
+        print(f"bench_kda: no TPU (platform {jax.devices()[0].platform!r}): this script measures on the chip", file=sys.stderr)
+        return 3
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    q, k, v = (jax.random.normal(key, (B, S, H, D), jnp.float32) for key in ks[:3])
+    q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5).astype(jnp.bfloat16)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(jnp.bfloat16)
+    v = v.astype(jnp.bfloat16)
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, S, H, D), minval=jnp.log(1e-3), maxval=jnp.log(1.6)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    core_args = (q, k, v, g, beta)
+    it = args.iters
+
+    for chunk in map(int, args.chunks.split(",")):
+        for sub in map(int, args.subs.split(",")):
+            for group in map(int, args.groups.split(",")):
+                lm_kda.KDA_CHUNK, lm_kda.KDA_SUBCHUNK, lm_kda.KDA_HEAD_GROUP = chunk, sub, group
+                tag = {"chunk": chunk, "sub": sub, "heads_at_once": group}
+                say("kda_core fwd", timed(jax.jit(lambda *a: lm_kda.kda_core(*a)[0]), core_args, it), **tag)
+                grad = jax.jit(jax.grad(lambda *a: total(lm_kda.kda_core(*a)[0]), argnums=(0, 1, 2, 3, 4)))
+                say("kda_core fwd+bwd", timed(grad, core_args, it), **tag)
+                # the in-chunk work of ONE head group, and its backward
+                n = S // chunk
+
+                def one_group(x):  # (B, S, H, ...) -> (B, h, N, C, ...)
+                    return jnp.moveaxis(x[:, :, :group].reshape(B, n, chunk, group, *x.shape[3:]), 3, 1)
+
+                grouped = tuple(jax.jit(one_group)(x) for x in (q, k, v, g, beta[..., None]))
+                say("in-chunk operands, one head group, fwd", timed(jax.jit(lambda *a: lm_kda._chunk_operands(*a)[0]), grouped, it), **tag,
+                    groups=H // group)
+                say("in-chunk operands, one head group, fwd+bwd",
+                    timed(jax.jit(jax.grad(lambda *a: total(lm_kda._chunk_operands(*a)[0]), argnums=(0, 1, 2, 3, 4))), grouped, it),
+                    **tag, groups=H // group)
+                # pieces of it
+                kf, gf = grouped[1].astype(jnp.float32), jnp.cumsum(grouped[3], axis=-2)
+                say("decayed scores (A and B), one head group, fwd",
+                    timed(jax.jit(lambda q_, k_, g_: lm_kda._decayed_scores(q_, k_, g_, min(sub, chunk), jnp.bfloat16)),
+                          (grouped[0].astype(jnp.float32), kf, gf), it), **tag)
+                a = jax.random.normal(ks[5], (B, group, n, chunk, chunk)) * 0.1
+                rhs = jax.random.normal(ks[6], (B, group, n, chunk, 2 * D))
+                solve = jax.jit(lambda a_, r_: lax.linalg.triangular_solve(jnp.eye(chunk) + jnp.tril(a_, -1), r_, left_side=True,
+                                                                            lower=True, unit_diagonal=True))
+                say("triangular solve, one head group, fwd", timed(solve, (a, rhs), it), **tag)
+                # the scan over chunks, all heads
+                operands = jax.jit(lambda *a_: tuple(jnp.moveaxis(x, 2, 0) for x in lm_kda._chunk_operands(*a_)[0]))(
+                    *(jnp.moveaxis(x.reshape(B, n, chunk, H, *x.shape[3:]), 3, 1) for x in (q, k, v, g, beta[..., None])))
+                say("scan over chunks, all heads, fwd", timed(jax.jit(lm_kda._state_scan), operands, it), **tag, steps=n)
+                say("scan over chunks, all heads, fwd+bwd",
+                    timed(jax.jit(jax.grad(lambda *a_: total(lm_kda._state_scan(*a_)), argnums=(0, 1, 2, 3, 4, 5))), operands, it),
+                    **tag, steps=n)
+
+    # the rest of the mixer
+    z = jax.random.normal(ks[5], (B, S, H * D), jnp.bfloat16)
+    w = jax.random.normal(ks[6], (4, H * D), jnp.float32) * 0.02
+    say("short conv, one of three, fwd", timed(jax.jit(lm_kda.short_conv), (z, w), it))
+    say("short conv, one of three, fwd+bwd", timed(jax.jit(jax.grad(lambda z_, w_: total(lm_kda.short_conv(z_, w_)), argnums=(0, 1))),
+                                                   (z, w), it))
+
+    def conv_op(z_, w_):  # the same convolution as ONE depthwise lax convolution, for comparison
+        out = lax.conv_general_dilated(z_, w_.astype(z_.dtype)[:, None, :], (1,), [(w_.shape[0] - 1, 0)],
+                                       dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=z_.shape[-1])
+        return jax.nn.silu(out.astype(jnp.float32)).astype(z_.dtype)
+
+    say("short conv as lax.conv_general_dilated, fwd", timed(jax.jit(conv_op), (z, w), it))
+    say("short conv as lax.conv_general_dilated, fwd+bwd",
+        timed(jax.jit(jax.grad(lambda z_, w_: total(conv_op(z_, w_)), argnums=(0, 1))), (z, w), it))
+    def conv_bf16_pad(z_, w_):  # the padding in the operand's dtype, the taps' sum in float32
+        taps, seq = w_.shape[0], z_.shape[1]
+        padded = jnp.pad(z_, ((0, 0), (taps - 1, 0), (0, 0)))
+        return jax.nn.silu(sum(padded[:, i:i + seq].astype(jnp.float32) * w_[i] for i in range(taps))).astype(z_.dtype)
+
+    def conv_roll(z_, w_):  # rotations along the sequence, the rows that wrapped around zeroed
+        taps, seq = w_.shape[0], z_.shape[1]
+        z32 = z_.astype(jnp.float32)
+        row = lax.broadcasted_iota(jnp.int32, (1, seq, 1), 1)
+        acc = z32 * w_[taps - 1]
+        for back in range(1, taps):
+            acc = acc + jnp.where(row >= back, jnp.roll(z32, back, axis=1), 0.0) * w_[taps - 1 - back]
+        return jax.nn.silu(acc).astype(z_.dtype)
+
+    for name, fn in (("bf16 pad", conv_bf16_pad), ("roll", conv_roll)):
+        say(f"short conv, {name}, fwd", timed(jax.jit(fn), (z, w), it))
+        say(f"short conv, {name}, fwd+bwd", timed(jax.jit(jax.grad(lambda z_, w_, fn=fn: total(fn(z_, w_)), argnums=(0, 1))), (z, w), it))
+
+    # the cell's ONE latent-attention layer: head dims 192 / 128 in the tile loops, and zero-padded to 256 / 128 in the kernels
+    from yet_another_mobilenet_series_tpu.ops import lm as ops
+
+    aq, ak = (jax.random.normal(key, (B, S, H, 192), jnp.bfloat16) for key in ks[:2])
+    av = jax.random.normal(ks[2], (B, S, H, 128), jnp.bfloat16)
+
+    def attention(pad):
+        def fn(q_, k_, v_):
+            if pad:
+                q_, k_ = (jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 64))) for x in (q_, k_))
+            return ops.causal_attention(q_, k_, v_, scale=192 ** -0.5)
+        return fn
+
+    for name, fn in (("loops 192/128", attention(False)), ("kernels, q k padded to 256", attention(True))):
+        try:
+            say(f"latent attention core, {name}, fwd", timed(jax.jit(fn), (aq, ak, av), it))
+            say(f"latent attention core, {name}, fwd+bwd",
+                timed(jax.jit(jax.grad(lambda *a, fn=fn: total(fn(*a)), argnums=(0, 1, 2))), (aq, ak, av), it))
+        except Exception as e:  # noqa: BLE001 - a kernel the compiler refuses is a finding, not a crash
+            say(f"latent attention core, {name}: refused", -1.0, error=str(e)[:400])
+    say("l2 norm, one of two, fwd+bwd",
+        timed(jax.jit(jax.grad(lambda x: total(lm_kda.l2_normalise(x)))), (q,), it))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,8 +1,11 @@
-"""The token-model family (models/lm.py, ops/lm.py: GLM-4.7-Flash's
-`glm4_moe_lite`) against its plain float32 reference (models/lm_reference.py)
-at a toy size on the CPU: hidden 64, 4 heads, 16 experts in 8 shares of 2,
-top-2, a vocabulary slice of 32, 1 dense + 2 expert layers + the MTP module,
-2 x 32 tokens.
+"""The token-model family (models/lm.py, ops/lm.py, ops/lm_kda.py) against its
+plain float32 reference (models/lm_reference.py) at a toy size on the CPU.
+GLM-4.7-Flash's `glm4_moe_lite`: hidden 64, 4 heads, 16 experts in 8 shares of
+2, top-2, a vocabulary slice of 32, 1 dense + 2 expert layers + the MTP
+module, 2 x 32 tokens. Kimi-Linear's `kimi_linear` (`KIMI`, the `kimi`
+cases): 5 layers in its pattern (KDA, KDA, KDA, MLA, KDA: 4 heads of 8), MLA
+without RoPE and without a low-rank q, 32 experts in 4 shares of 8, top-4, no
+MTP.
 """
 
 import dataclasses
@@ -13,39 +16,50 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from yet_another_mobilenet_series_tpu.config import LMConfig, ModelConfig
+from yet_another_mobilenet_series_tpu.config import LinearAttnConfig, LMConfig, ModelConfig
 from yet_another_mobilenet_series_tpu.models import get_model, lm_reference as ref
 from yet_another_mobilenet_series_tpu.models.serialize import network_from_dict, network_to_dict
 from yet_another_mobilenet_series_tpu.ops import lm as ops
-from yet_another_mobilenet_series_tpu.ops import lm_attention
+from yet_another_mobilenet_series_tpu.ops import lm_attention, lm_kda
 
 LM = LMConfig(hidden_size=64, num_hidden_layers=3, first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=24,
               kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4, v_head_dim=16, intermediate_size=160,
               moe_intermediate_size=48, n_routed_experts=16, num_experts_per_tok=2, expert_shares=8,
               expert_share_index=1, seq_len=32, init_std=0.1)
 VOCAB = 32
+# published layers 1-5 of kimi_linear's pattern (the lists run on, as the source's do: a cut reads its first few)
+KIMI = LMConfig(hidden_size=64, num_hidden_layers=5, first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=None,
+                kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, intermediate_size=160,
+                moe_intermediate_size=48, n_routed_experts=32, num_experts_per_tok=4, routed_scaling_factor=2.446,
+                num_nextn_predict_layers=0, mla_use_nope=True, expert_shares=4, expert_share_index=1, seq_len=32,
+                init_std=0.1, linear_attn_config=LinearAttnConfig(
+                    kda_layers=(1, 2, 3, 5, 6, 7), full_attn_layers=(4, 8), head_dim=8, num_heads=4))
+FAMILIES = ["glm", "kimi"]
 
 
 @pytest.fixture(scope="module", autouse=True)
 def small_blocks():
-    """32 tokens in tiles of 8 x 8 and the loss in blocks of 16, so that every
-    test of this file goes through several tiles and blocks as 8,192 do."""
+    """32 tokens in tiles of 8 x 8, the loss in blocks of 16 and KDA in chunks
+    of 8 with sub-blocks of 4, so that every test of this file goes through
+    several tiles, blocks and chunks as 8,192 and 16,384 do."""
     from yet_another_mobilenet_series_tpu.models import lm
 
     patch = pytest.MonkeyPatch()
     patch.setattr(ops, "ATTN_BLOCK", 8)
     patch.setattr(lm, "LOSS_BLOCK", 16)
+    patch.setattr(lm_kda, "KDA_CHUNK", 8)
+    patch.setattr(lm_kda, "KDA_SUBCHUNK", 4)
     yield
     patch.undo()
 
 
 def model(lm=LM):
-    return get_model(ModelConfig(arch="glm4_moe_lite", num_classes=VOCAB, lm=lm))
+    arch = "kimi_linear" if lm.linear_attn_config.kda_layers else "glm4_moe_lite"
+    return get_model(ModelConfig(arch=arch, num_classes=VOCAB, lm=lm))
 
 
-@pytest.fixture(scope="module")
-def setup():
-    net = model()
+def _setup(LM):
+    net = model(LM)
     params, state = net.init(jax.random.PRNGKey(0))
     # a router bias that matters: selection and weights must read different things
     state = jax.tree.map(lambda b: 0.3 * jax.random.normal(jax.random.PRNGKey(5), b.shape), state)
@@ -53,6 +67,20 @@ def setup():
     (ref_loss, aux), ref_grads = jax.jit(lambda p, s, t: ref.loss_and_grads(p, s, t, ref.dims_of(LM)))(
         params, state, tokens)
     return net, params, state, tokens, ref_loss, aux, ref_grads
+
+
+@pytest.fixture(scope="module")
+def setup():
+    return _setup(LM)
+
+
+@pytest.fixture(scope="module")
+def kimi_setup():
+    return _setup(KIMI)
+
+
+def family_setup(request, family):
+    return request.getfixturevalue("setup" if family == "glm" else "kimi_setup")
 
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
@@ -67,12 +95,14 @@ def worst_leaf(got, want):
         lambda a, b: float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30)), got, want)))
 
 
-def test_loss_and_every_gradient_leaf_equal_the_reference_in_float32(setup):
-    net, params, state, tokens, ref_loss, aux, ref_grads = setup
+@pytest.mark.parametrize("family", FAMILIES)
+def test_loss_and_every_gradient_leaf_equal_the_reference_in_float32(request, family):
+    net, params, state, tokens, ref_loss, aux, ref_grads = family_setup(request, family)
     (loss, (new_state, scalars)), grads = program(net, params, state, tokens)
     assert abs(float(loss) - float(ref_loss)) < 1e-5
     assert abs(float(scalars["ce"]) - float(aux["ce"])) < 1e-5
-    assert abs(float(scalars["ce_mtp"]) - float(aux["ce_mtp"])) < 1e-5
+    assert abs(float(scalars.get("ce_mtp", 0.0)) - float(aux["ce_mtp"])) < 1e-5
+    assert ("ce_mtp" in scalars) == (family == "glm") and ("kda_min_chunk_log_decay" in scalars) == (family == "kimi")
     assert jax.tree.structure(grads) == jax.tree.structure(ref_grads)
     assert worst_leaf(grads, ref_grads) < 2e-5
     assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree.leaves(grads))  # nothing is cut off from the loss
@@ -95,17 +125,23 @@ def test_logits_equal_the_reference(setup):
     np.testing.assert_allclose(logits, aux["logits"], atol=2e-5)
 
 
-def test_bfloat16_is_within_its_tolerance_and_a_lower_precision_is_not(setup):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_bfloat16_is_within_its_tolerance_and_a_lower_precision_is_not(request, family):
     """bfloat16 compute against the float32 reference: loss within 2e-3
-    relative, gradient norms by group within 1%, and within 10% for the router
-    and expert groups (of 64 tokens, one whose two best scores are a rounding
-    apart goes to another expert). The same step with every weight rounded to
-    float8_e4m3fn (the nearest precision below) must NOT pass."""
-    net, params, state, tokens, ref_loss, _, ref_grads = setup
+    relative, gradient norms by group within 1% (a KDA mixer's group among
+    them), and within 10% for the router and expert groups (of 64 tokens, one
+    whose two best scores are a rounding apart goes to another expert). The
+    same step with every weight rounded to float8_e4m3fn (the nearest
+    precision below) must NOT pass."""
+    net, params, state, tokens, ref_loss, _, ref_grads = family_setup(request, family)
     want = {**net.grad_scalars(ref_grads), "loss": ref_loss}
 
+    # kimi's five layers are deeper than GLM's three, and a KDA layer's in-chunk solve hands bfloat16's rounding on:
+    # its groups read up to 7% and 11% here (GLM's 0.6% and 6%), float8's 19% and 43%
+    groups, routed = (1e-2, 0.1) if family == "glm" else (8e-2, 0.15)
+
     def limit(name):
-        return 2e-3 if name == "loss" else 0.1 if name.endswith(("/router", "/experts")) else 1e-2
+        return 2e-3 if name == "loss" else routed if name.endswith(("/router", "/experts")) else groups
 
     def passes(p):
         (loss, _), grads = program(net, p, state, tokens, jnp.bfloat16)
@@ -116,30 +152,68 @@ def test_bfloat16_is_within_its_tolerance_and_a_lower_precision_is_not(setup):
     assert not passes(jax.tree.map(lambda w: w.astype(jnp.float8_e4m3fn).astype(jnp.float32), params))
 
 
-def test_the_parts_all_shares_compute_add_up_to_the_uncut_layer(setup):
-    """One expert layer: the routed part of each of the 8 shares, summed, plus
-    the shared expert counted ONCE, is the uncut reference layer."""
-    net, params, state, _, _, _, _ = setup
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_parts_all_shares_compute_add_up_to_the_uncut_layer(request, family):
+    """One expert layer: the routed part of each of the shares (8 of 2 experts,
+    top-2, for GLM; 4 of 8, top-4, for kimi), summed, plus the shared expert
+    counted ONCE, is the uncut reference layer."""
+    net, params, state, _, _, _, _ = family_setup(request, family)
+    c = net.lm
     p, bias = params["layer_1"], state["layer_1"]["router_bias"]
-    x = jax.random.normal(jax.random.PRNGKey(3), (2, LM.seq_len, LM.hidden_size))
-    whole = jax.random.normal(jax.random.PRNGKey(4), (16, LM.hidden_size, LM.moe_intermediate_size)) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, c.seq_len, c.hidden_size))
+    whole = jax.random.normal(jax.random.PRNGKey(4), (c.n_routed_experts, c.hidden_size, c.moe_intermediate_size)) * 0.1
     experts = {"gate": whole, "up": whole[::-1] * 0.5, "down": jnp.swapaxes(whole, 1, 2) * 0.7}
     routed = jnp.zeros_like(x)
     loads = []
-    for share in range(8):
-        held = {k: v[2 * share:2 * share + 2] for k, v in experts.items()}
-        y, load, counters, _ = ops.expert_layer({**p, "experts": held}, bias, x, top_k=2, scaling=1.8, held=2,
-                                                share_index=share)
+    n = net.experts_held
+    for share in range(c.expert_shares):
+        held = {k: v[n * share:n * share + n] for k, v in experts.items()}
+        y, load, counters, _ = ops.expert_layer({**p, "experts": held}, bias, x, top_k=c.num_experts_per_tok,
+                                                scaling=c.routed_scaling_factor, held=n, share_index=share)
         routed, loads = routed + y, loads + [counters["assignments_here"]]
         assert float(counters["dropped"]) == 0.0
     got = ops.gated_mlp(p["shared"], x) + routed
-    uncut = {**ref.dims_of(LM), "expert_shares": 1, "expert_share_index": 0}
+    uncut = {**ref.dims_of(c), "expert_shares": 1, "expert_share_index": 0}
     for row in range(2):
         want_routed, want_load = ref.experts({**p, "experts": experts}, bias, x[row], uncut)
         want = ref.gated_mlp(p["shared"]["gate"], p["shared"]["up"], p["shared"]["down"], x[row]) + want_routed
         np.testing.assert_allclose(got[row], want, atol=2e-5)
     # every assignment lands in exactly one share
-    assert sum(float(n) for n in loads) == 2 * LM.seq_len * 2 == float(jnp.sum(load))
+    assert sum(float(n) for n in loads) == 2 * c.seq_len * c.num_experts_per_tok == float(jnp.sum(load))
+
+
+def test_all_shares_of_a_kimi_model_add_up_to_the_uncut_model(kimi_setup):
+    """The WHOLE model, not one layer: with the other three shares' routed
+    parts handed in where they would arrive (the shared expert and the mixers
+    counted once, because each share runs them on the same tokens), layer by
+    layer, the hidden state that leaves every block is the uncut reference
+    block's."""
+    net, params, state, tokens, _, _, _ = kimi_setup
+    c = net.lm
+    whole = {name: {k: jax.random.normal(jax.random.PRNGKey(7 + i), (c.n_routed_experts, *v.shape[1:])) * 0.1
+                    for k, v in params[name]["experts"].items()}
+             for i, name in enumerate(net.block_names) if name in state}
+    uncut = {**ref.dims_of(c), "expert_shares": 1, "expert_share_index": 0}
+    n = net.experts_held
+    x = params["embed"][tokens[:, :c.seq_len]]
+    for name in net.block_names:
+        p = params[name]
+        mixed, _ = net._mixed(p, x, None, None)
+        y = ops.rms_norm(mixed, p["mlp_norm"], c.rms_norm_eps)
+        if name in state:
+            fed = mixed + ops.gated_mlp(p["shared"], y)
+            for share in range(c.expert_shares):
+                held = {k: v[n * share:n * share + n] for k, v in whole[name].items()}
+                fed = fed + ops.expert_layer({**p, "experts": held}, state[name]["router_bias"], y,
+                                             top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor, held=n,
+                                             share_index=share)[0]
+            want = jnp.stack([ref.block({**p, "experts": whole[name]}, state[name]["router_bias"], row, uncut, False)[0]
+                              for row in x])
+        else:
+            fed = mixed + ops.gated_mlp(p["mlp"], y)
+            want = jnp.stack([ref.block(p, None, row, uncut, True)[0] for row in x])
+        np.testing.assert_allclose(fed, want, atol=5e-5)
+        x = want
 
 
 def test_every_token_routed_to_one_held_expert_is_computed(setup):
@@ -317,27 +391,50 @@ def attention_runs(jaxpr, found=None):
     return found
 
 
+def kda_scans(jaxpr, found=None):
+    """How often the gradient's jaxpr holds the KDA core's scan over chunks,
+    forward and reverse: the `scan`s whose ONE carry is a state a head,
+    (B, H, key, value). (The head loss's scans carry three sums or a weight's
+    gradient; the in-chunk work's map over head groups carries nothing.)"""
+    from jax._src.core import jaxprs_in_params as inner
+
+    found = {"fwd": 0, "bwd": 0} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["num_carry"] == 1 and len(
+                eqn.outvars[0].aval.shape) == 4 and eqn.outvars[0].aval.shape[-1] == eqn.outvars[0].aval.shape[-2]:
+            found["bwd" if eqn.params["reverse"] else "fwd"] += 1
+        else:
+            for j in inner(eqn.params):
+                kda_scans(j, found)
+    return found
+
+
+@pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("how, forwards_a_site", [("kept", 1), ("plain", 2), ("none", 1)])
-def test_the_gradient_runs_attentions_forward_once_a_site(setup, monkeypatch, how, forwards_a_site):
+def test_the_gradient_runs_each_mixers_forward_once_a_site(request, monkeypatch, family, how, forwards_a_site):
     """The layer checkpoint keeps attention's output and log-sum-exp by name
-    (`ops.ATTN_OUT_NAME`, `ops.ATTN_LSE_NAME`), so the backward's second run
-    of a layer holds no attention forward: one forward and one backward a site
-    in the gradient's jaxpr, where a plain `jax.checkpoint` (the parent's)
-    holds two forwards."""
-    net, params, state, tokens, _, _, _ = setup
+    (`ops.ATTN_OUT_NAME`, `ops.ATTN_LSE_NAME`) and the KDA scan's output and
+    chunk-boundary states (`lm_kda.KDA_OUT_NAME`, `KDA_STATES_NAME`), so the
+    backward's second run of a layer holds neither forward: one forward and
+    one backward a site in the gradient's jaxpr, where a plain
+    `jax.checkpoint` holds two forwards."""
+    net, params, state, tokens, _, _, _ = family_setup(request, family)
     sites = net.attention_sites(jnp.float32)[0]
+    assert (sites, net.kda_sites) == ((4, 0) if family == "glm" else (1, 4))
     layer_checkpoint(monkeypatch, how)
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: net.loss(p, state, {"tokens": tokens})[0]))(params)
     assert attention_runs(jaxpr.jaxpr) == {"fwd": forwards_a_site * sites, "bwd": sites}
+    assert kda_scans(jaxpr.jaxpr) == {"fwd": forwards_a_site * net.kda_sites, "bwd": net.kda_sites}
 
 
+@pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("how", ["plain", "none"])
-def test_what_the_layer_checkpoint_keeps_changes_no_number(setup, monkeypatch, how):
+def test_what_the_layer_checkpoint_keeps_changes_no_number(request, monkeypatch, family, how):
     """Loss, scalars, new state and every gradient leaf of the step as shipped
     equal, to float rounding, those of the same loss with the checkpoint's
     policy taken off and with no checkpoint at all: the kept `out` and `lse`
-    are what the second run made."""
-    net, params, state, tokens, _, _, _ = setup
+    (and a KDA scan's output and states) are what the second run made."""
+    net, params, state, tokens, _, _, _ = family_setup(request, family)
 
     def step(p):
         return jax.value_and_grad(lambda p_: net.loss(p_, state, {"tokens": tokens}), has_aux=True)(p)
@@ -347,7 +444,9 @@ def test_what_the_layer_checkpoint_keeps_changes_no_number(setup, monkeypatch, h
     (want_loss, want_aux), want = jax.jit(lambda p: step(p))(params)  # a new function: `step` itself is traced already
     assert abs(float(loss) - float(want_loss)) < 1e-6
     assert worst_leaf(aux, want_aux) < 1e-6
-    assert worst_leaf(grads, want) < 1e-6
+    # kimi's five layers, four of them with a triangular solve: XLA fuses the three variants' float32 sums
+    # differently (1.5e-6 and 8.1e-6 read; program against reference reads 1.3e-5)
+    assert worst_leaf(grads, want) < (1e-6 if family == "glm" else 2e-5)
 
 
 @pytest.mark.parametrize("shape, dtype, expect", [
@@ -363,6 +462,37 @@ def test_what_the_layer_checkpoint_keeps_changes_no_number(setup, monkeypatch, h
 def test_which_attention_calls_the_kernels_take(shape, dtype, expect):
     """`shape` is (sequence, block, qk head dim, v head dim)."""
     assert lm_attention.fuses(*shape, dtype) is expect
+
+
+@pytest.mark.parametrize("shape, dtype, handed", [
+    ((16384, 512, 192, 128), jnp.bfloat16, 256),  # kimi_linear's one latent-attention layer: 128 + 64 filled to 256
+    ((8192, 512, 256, 256), jnp.bfloat16, 256),  # GLM's: fits as it is
+    ((16384, 512, 192, 128), jnp.float32, 192),  # no filling makes float32 fit: the loops, unfilled
+    ((32768, 512, 192, 128), jnp.bfloat16, 192),  # nor a sequence too long
+    ((32, 8, 12, 16), jnp.float32, 12),  # this file's toy heads
+], ids=str)
+def test_q_and_k_are_filled_with_zero_channels_only_where_that_makes_the_kernels_fit(shape, dtype, handed):
+    assert lm_attention.fitting_qk_dim(*shape, dtype) == handed
+
+
+def test_zero_filled_channels_change_no_number(monkeypatch):
+    """`causal_attention` at a shape whose q/k head dim it fills (192 -> 256,
+    one 256-row tile, bfloat16) against the same call with the filling
+    switched off: output and the three gradients bit for bit on the CPU (the
+    loops either way: a zero channel adds 0.0 to every score)."""
+    monkeypatch.setattr(ops, "ATTN_BLOCK", 256)
+    q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 256, 2, 192), jnp.bfloat16) for i in (0, 1))
+    v = jax.random.normal(jax.random.PRNGKey(2), (1, 256, 2, 128), jnp.bfloat16)
+    assert lm_attention.fitting_qk_dim(256, 256, 192, 128, jnp.bfloat16) == 256
+
+    def run():
+        fn = lambda *a: ops.causal_attention(*a, scale=192 ** -0.5)  # noqa: E731
+        return jax.jit(lambda *a: (fn(*a), jax.grad(lambda *b: jnp.sum(fn(*b).astype(jnp.float32) ** 2), (0, 1, 2))(*a)))(q, k, v)
+
+    filled = run()
+    monkeypatch.setattr(lm_attention, "fitting_qk_dim", lambda seq, block, qk, v_, dtype: qk)
+    plain = run()
+    assert all(a.shape == b.shape and bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(filled), jax.tree.leaves(plain)))
 
 
 def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypatch):
@@ -395,6 +525,13 @@ def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypat
     assert gauges(cell, platform="cpu") == (6.0, 0.0, 6.0)
     assert gauges(cell, platform="tpu") == (6.0, 6.0, 6.0)
     assert gauges(model(), platform="tpu") == (4.0, 0.0, 4.0)
+    assert tuple(get_registry().gauge(name).value for name in ("train.kda_sites", "train.kda_kept_sites")) == (0.0, 0.0)
+    # kimi_linear's cell: ONE latent-attention layer, whose (192, 128) head dims the kernels take with q and k
+    # filled to 256, and four KDA layers, each keeping its scan's output and states
+    kimi = get_model(load_config(app.replace("glm_4_7_flash_ep8_share", "kimi_linear_48b_ep32_share")).model)
+    assert kimi.attention_sites(jnp.bfloat16) == (1, 1) and kimi.kda_sites == 4
+    assert gauges(kimi, platform="tpu") == (1.0, 1.0, 1.0) and gauges(kimi, platform="cpu") == (1.0, 0.0, 1.0)
+    assert tuple(get_registry().gauge(name).value for name in ("train.kda_sites", "train.kda_kept_sites")) == (4.0, 4.0)
 
 
 _FRESH_PROCESS = """
@@ -420,6 +557,19 @@ if sys.argv[1] == "cnn_step":  # one toy CNN train step, built as every runner b
     batch = {"image": jnp.ones((4, 32, 32, 3), jnp.float32), "label": jnp.arange(4, dtype=jnp.int32)}
     ts, metrics = step(ts, batch, jax.random.PRNGKey(1))
     ran = bool(jnp.isfinite(metrics["loss"])) and int(ts.step) == 1
+elif sys.argv[1] == "kda_step":  # a toy kimi_linear step, KDA and the (unfused) latent attention traced
+    cfg = parse_cli(["app:" + sys.argv[2], "model.num_classes=64", "model.lm.hidden_size=32", "model.lm.num_attention_heads=2",
+                     "model.lm.kv_lora_rank=8", "model.lm.qk_nope_head_dim=8", "model.lm.qk_rope_head_dim=4",
+                     "model.lm.v_head_dim=8", "model.lm.linear_attn_config.head_dim=8", "model.lm.linear_attn_config.num_heads=2",
+                     "model.lm.intermediate_size=64", "model.lm.moe_intermediate_size=16", "model.lm.n_routed_experts=64",
+                     "model.lm.seq_len=16", "train.batch_size=1", "dist.num_devices=1"])
+    net = get_model(cfg.model)
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 1, 10, 1)
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0])
+    step = dp.make_dp_train_step(net, cfg, optimizer, lr_fn, mesh_lib.make_mesh(1))
+    ts = jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0)))
+    step.lower(ts, {"tokens": jax.ShapeDtypeStruct((1, 18), jnp.int32)}, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    ran = net.kda_sites == 4
 else:  # the predicate and the gauges' count first, which must not need Pallas; then a fitting site traced
     cfg = parse_cli(["app:" + sys.argv[2]])
     fits = lm_attention.fuses(512, 256, 128, 128, jnp.bfloat16) and get_model(cfg.model).attention_sites(jnp.bfloat16) == (6, 6)
@@ -432,7 +582,8 @@ print(json.dumps({"ran": ran, "pallas": pallas()}))
 
 
 @pytest.mark.parametrize("what, app, pays", [("cnn_step", "mobilenet_v3_large.yml", False),
-                                             ("fitting_site", "glm_4_7_flash_ep8_share.yml", True)])
+                                             ("fitting_site", "glm_4_7_flash_ep8_share.yml", True),
+                                             ("kda_step", "kimi_linear_48b_ep32_share.yml", False)])
 def test_pallas_is_imported_where_a_fused_attention_site_is_traced_and_nowhere_else(what, app, pays):
     """A fresh process that imports what every runner imports (train.steps,
     parallel.dp, models.lm, ops.lm, ops.lm_attention) and builds and runs a
@@ -515,3 +666,72 @@ def test_the_published_widths_give_the_parameter_count_of_the_cut():
                        "yet_another_mobilenet_series_tpu", "apps", "glm_4_7_flash_ep8_share.yml")
     net = get_model(load_config(app).model)
     assert net.param_count() == 706_518_528 and net.experts_held == 8 and net.vocab == 19_360
+
+
+def test_the_pattern_puts_latent_attention_exactly_where_full_attn_layers_says():
+    """Layers are numbered from 1, as `linear_attn_config` numbers them; a
+    cut reads the first `num_hidden_layers` entries of the published lists."""
+    net = model(KIMI)
+    assert [net.mixer(b) for b in net.block_names] == ["kda", "kda", "kda", "attn", "kda"]
+    assert net.blocks_mixing_by("attn") == ("layer_3",) and net.kda_sites == 4
+    shapes = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
+    assert ["kda" in shapes[b] for b in net.block_names] == [True, True, True, False, True]
+    assert set(shapes["layer_3"]["attn"]) == {"q", "kv_a", "kv_norm", "kv_b", "o"}  # one q projection, no q norm
+    assert shapes["layer_3"]["attn"]["q"].shape == (64, 4 * 12)
+    eight = model(dataclasses.replace(KIMI, num_hidden_layers=8))
+    assert [eight.mixer(b) for b in eight.block_names] == ["kda"] * 3 + ["attn"] + ["kda"] * 3 + ["attn"]
+    assert network_from_dict(network_to_dict(net)) == net
+    # GLM: no pattern, every block (the MTP module's too) is latent attention
+    assert [model().mixer(b) for b in model().block_names] == ["attn"] * 4
+
+
+@pytest.mark.parametrize("change, complaint", [
+    ({"kda_layers": (1, 2, 3, 4, 5)}, "in both"),
+    ({"kda_layers": (1, 2, 5), "full_attn_layers": (4,)}, r"layers \[3\] are in neither"),
+    ({"full_attn_layers": ()}, r"layers \[4\] are in neither"),
+])
+def test_validate_refuses_a_pattern_that_overlaps_or_leaves_a_layer_out(change, complaint):
+    pattern = dataclasses.replace(KIMI.linear_attn_config, **change)
+    with pytest.raises(ValueError, match=complaint):
+        model(dataclasses.replace(KIMI, linear_attn_config=pattern))
+
+
+def test_validate_refuses_an_odd_rope_dim_only_where_a_layer_mixes_by_latent_attention():
+    with pytest.raises(ValueError, match="qk_rope_head_dim must be even.*layer_3"):
+        model(dataclasses.replace(KIMI, qk_rope_head_dim=3))
+    all_kda = LinearAttnConfig(kda_layers=(1, 2, 3, 4, 5), head_dim=8, num_heads=4)
+    model(dataclasses.replace(KIMI, qk_rope_head_dim=3, linear_attn_config=all_kda))  # nothing reads it
+
+
+def test_latent_attention_without_rope_and_without_a_low_rank_q_equals_the_reference(kimi_setup):
+    """`mla_use_nope` + `q_lora_rank: null`: ops.mla_attention with `cos`
+    None and `p["q"]` against the reference's expanded attention; and RoPE, if
+    it were applied there, would be seen."""
+    net, params, _, _, _, _, _ = kimi_setup
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, KIMI.seq_len, KIMI.hidden_size))
+    kw = dict(heads=4, nope=8, rope=4, v_dim=8, kv_rank=16, eps=1e-5)
+    got = ops.mla_attention(params["layer_3"]["attn"], x, None, None, **kw)
+    for row in range(2):
+        np.testing.assert_allclose(got[row], ref.mla(params["layer_3"]["attn"], x[row], ref.dims_of(KIMI)), atol=2e-5)
+    cos, sin = ops.rope_tables(KIMI.seq_len, 4, 10000.0)
+    turned = ops.mla_attention(params["layer_3"]["attn"], x, cos, sin, **kw)
+    assert float(jnp.max(jnp.abs(turned - got))) > 1e-3
+
+
+def test_the_published_widths_of_kimi_linear_give_the_parameter_count_of_the_cut_and_of_the_source():
+    from yet_another_mobilenet_series_tpu.config import load_config
+    import os
+
+    app = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "yet_another_mobilenet_series_tpu", "apps", "kimi_linear_48b_ep32_share.yml")
+    cfg = load_config(app).model
+    net = get_model(cfg)
+    assert net.param_count() == 602_433_408 and net.experts_held == 8 and net.vocab == 20_480
+    shapes = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
+    count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))  # noqa: E731
+    assert (count(shapes["layer_0"]["kda"]), count(shapes["layer_3"]["attn"])) == (39_514_272, 29_114_880)
+    # uncut: all 27 layers, all 256 experts, all 163,840 vocabulary rows
+    uncut = dataclasses.replace(cfg, num_classes=163_840, lm=dataclasses.replace(cfg.lm, num_hidden_layers=27, expert_shares=1))
+    whole = get_model(uncut)
+    assert len(whole.blocks_mixing_by("kda")) == 20 and len(whole.blocks_mixing_by("attn")) == 7
+    assert whole.param_count() == 49_122_675_072

@@ -59,3 +59,40 @@ def test_token_batches_are_seeded_zipf_and_resume_where_they_left():
     assert ids.min() >= 0 and ids.max() < 500
     assert 1.7 < counts[0] / counts[1] < 2.3 and counts[0] > 5 * counts[9]  # p(id) ~ 1 / (id + 1)
     assert len(list(pipeline.token_batches(cfg, 8, 500, seed=0, num_batches=3))) == 3
+
+
+KIMI_APP = os.path.join(REPO, "yet_another_mobilenet_series_tpu", "apps", "kimi_linear_48b_ep32_share.yml")
+KIMI_TOY = ["model.num_classes=256", "model.lm.hidden_size=64", "model.lm.num_attention_heads=4",
+            "model.lm.kv_lora_rank=16", "model.lm.qk_nope_head_dim=8", "model.lm.qk_rope_head_dim=4",
+            "model.lm.v_head_dim=8", "model.lm.linear_attn_config.head_dim=8", "model.lm.linear_attn_config.num_heads=4",
+            "model.lm.intermediate_size=160", "model.lm.moe_intermediate_size=48", "model.lm.n_routed_experts=64",
+            "model.lm.num_experts_per_tok=4", "model.lm.seq_len=32"]
+
+
+def test_three_steps_of_kimi_linear_through_cli_train(tmp_path):
+    """The second arch through the same entry point: apps/kimi_linear_48b_ep32_share.yml
+    at a toy size, its five layers in the published pattern (KDA, KDA, KDA,
+    MLA, KDA), one head, the KDA gauge at the log boundary, a checkpoint that
+    restores as the same TokenModel (its `linear_attn_config` with it)."""
+    log_dir = str(tmp_path / "log")
+    final = cli_train.main([f"app:{KIMI_APP}", *KIMI_TOY, "data.fake_train_size=3", "train.epochs=1",
+                            "train.log_every=1", f"train.log_dir={log_dir}", "dist.num_devices=1"])
+    assert final["epoch"] == 1.0 and final["eval_n"] == 2 * 32 and np.isfinite(final["eval_loss"])
+    with open(os.path.join(log_dir, "metrics.jsonl")) as f:
+        rows = [json.loads(line) for line in f if '"train/' in line]
+    assert len(rows) == 3
+    last = rows[-1]
+    assert last["train/moe_dropped"] == 0.0 and abs(last["train/ce"] - np.log(256)) < 0.2
+    assert "train/ce_mtp" not in last and abs(last["train/loss"] - last["train/ce"]) < 1e-6
+    assert last["train/kda_min_chunk_log_decay"] < 0.0
+    assert {"train/gnorm/layer_0/kda", "train/gnorm/layer_3/attn", "train/gnorm/layer_4/kda"} <= set(last)
+    assert "train/gnorm/layer_3/kda" not in last and "train/gnorm/layer_0/attn" not in last
+    with open(os.path.join(log_dir, "obs_registry.json")) as f:
+        registry = json.load(f)
+    assert (registry["train.kda_sites"], registry["train.kda_kept_sites"], registry["train.attn_sites"]) == (4.0, 4.0, 1.0)
+    assert registry["train.kda_min_chunk_log_decay"] < 0.0 and registry["train.tokens_per_s"] > 0
+    mgr = CheckpointManager(log_dir + "/ckpt")
+    step, net, _ = mgr.restore_spec()
+    mgr.close()
+    assert step == 3 and isinstance(net, TokenModel) and net.arch == "kimi_linear" and net.experts_held == 2
+    assert [net.mixer(b) for b in net.block_names] == ["kda", "kda", "kda", "attn", "kda"]

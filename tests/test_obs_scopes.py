@@ -32,6 +32,7 @@ TOY = ("model.block_specs=[{exp: 16, c: 16, n: 1, s: 2, k: 3, act: relu}, "
 
 TOKEN_SCOPES = {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "moe_router", "moe_dispatch",
                 "moe_experts", "moe_combine", "mtp_merge", "lm_head"}
+KDA_SCOPES = {"kda_proj", "kda_conv", "kda_gate", "kda_core", "kda_norm"}
 
 
 def lowered_step(*overrides, chips: int = 1):
@@ -108,7 +109,7 @@ def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
     seen = {scope for scope, _ in scopes.scope_table(toy_text).values()}
     # everything on the list except the collectives (one chip), AtomNAS (no
     # masks, no penalty), the guard (off) and the token models' scopes
-    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES
+    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES - KDA_SCOPES
     assert expect <= seen, f"missing: {sorted(expect - seen)}"
     assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
 
@@ -342,6 +343,54 @@ def test_token_model_scopes_resolve():
     assert {("attn_core", "fwd"), ("attn_core", "bwd")} <= seen
 
 
+def token_step(arch, lm, dtype="float32"):
+    """The toy token step of tests/test_lm.py's sizes, lowered as every runner builds it."""
+    from test_lm import VOCAB
+
+    from yet_another_mobilenet_series_tpu.config import ModelConfig, config_from_dict
+
+    cfg = config_from_dict({"optim": {"optimizer": "adamw"}, "ema": {"enable": False},
+                            "train": {"compute_dtype": dtype, "batch_size": 2}})
+    net = get_model(ModelConfig(arch=arch, num_classes=VOCAB, lm=lm))
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 2, 10, 1)
+    params_example, _ = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, params_example)
+    step = dp.make_dp_train_step(net, cfg, optimizer, lr_fn, mesh_lib.make_mesh(1), params_example=params_example)
+    ts = jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, lm.seq_len + 2), jnp.int32)}
+    return step.lower(ts, batch, jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+
+@pytest.mark.parametrize("dtype, digest", [("float32", "793e81ba1884fd98"), ("bfloat16", "9c566b9bf47247b4")])
+def test_the_glm_step_is_the_program_it_was_before_kimi_linear(dtype, digest):
+    """models/lm.py and ops/lm.py serve two archs since PR 33 (a mixer chosen
+    by a pattern, `mla_attention` with two options, a block in two halves).
+    The toy `glm4_moe_lite` step's lowered module (StableHLO text) is pinned
+    by digest: both are what 15ed7fc, PR 33's parent, lowers (taken there and
+    here with `token_step("glm4_moe_lite", test_lm.LM, dtype).as_text()`,
+    the tile and loss blocks as shipped; jax 0.9.0). A change that means to
+    alter GLM's step takes a new digest, and says so."""
+    import hashlib
+
+    from test_lm import LM
+
+    text = token_step("glm4_moe_lite", LM, dtype).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_kimi_linear_scopes_resolve():
+    """`kimi_linear`'s step on the CPU: every KDA scope is in its table, the
+    core's scan and its hand-written backward under both phases, beside the
+    family's scopes that this arch has (no `rope`, no `mtp_merge`)."""
+    from test_lm import KIMI
+
+    text = token_step("kimi_linear", KIMI).compile().as_text()
+    seen = set(scopes.scope_table(text).values())
+    assert (TOKEN_SCOPES - {"rope", "mtp_merge"}) | KDA_SCOPES | {"loss", "optim", "residual"} <= {scope for scope, _ in seen}
+    assert not {"rope", "mtp_merge"} & {scope for scope, _ in seen}
+    assert {(name, phase) for name in ("kda_core", "kda_proj", "kda_conv", "attn_core") for phase in ("fwd", "bwd")} <= seen
+
+
 def test_taxonomy_version_is_pinned_to_the_scope_sites():
     """The compile cache's key carries TAXONOMY_VERSION (utils/compile_cache.py)
     because JAX leaves metadata out of it: a scope added, renamed or moved
@@ -356,11 +405,11 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
                     found = re.findall(r'\bscope\((?:(?:self|conv)\.scope_name|"(\w+)")\)', f.read())
                 if found:
                     sites[os.path.relpath(os.path.join(root, name), pkg)] = sorted(found)
-    assert (scopes.TAXONOMY_VERSION, sites) == (4, {
+    assert (scopes.TAXONOMY_VERSION, sites) == (5, {
         # versions 3 and 4: the token-model family's scopes (PR 27; 3 was its first draft, whose
         # executables may still sit in a chip machine's cache)
-        "models/lm.py": ["embed", "lm_head", "loss", "loss", "moe_combine", "moe_router", "mtp_merge", "residual",
-                         "residual", "residual", "rope"],
+        "models/lm.py": ["embed", "kda_gate", "lm_head", "loss", "loss", "moe_combine", "moe_router", "mtp_merge",
+                         "residual", "residual", "residual", "rope"],
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
@@ -369,6 +418,8 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
                       "moe_dispatch", "moe_experts", "moe_router", "norm", "rope"],
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name
         # in a compiled cell moved, so no bump
+        # version 5: Kimi Delta Attention's scopes (PR 33)
+        "ops/lm_kda.py": ["kda_conv", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj", "kda_proj"],
         "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],

@@ -175,6 +175,30 @@ def test_attention_is_two_mosaic_kernels_under_its_scope(attention_hlo):
     assert not re.search(r"\bwhile\(", attention_hlo)
 
 
+def test_the_192_channel_site_takes_the_kernels_with_q_and_k_filled_to_256(one_chip):
+    """kimi_linear's latent-attention layer at the cell's shape (1 x 16,384
+    tokens, 32 heads of 128 + 64 / 128): `causal_attention` fills q and k with
+    zero channels to 256, and Mosaic compiles both kernels there: a whole
+    sequence of one head is 8 MiB, exactly `RESIDENT_BYTES`."""
+    from yet_another_mobilenet_series_tpu.ops import lm
+
+    seq, heads = 16384, 32
+    assert lm.lm_attention.fitting_qk_dim(seq, lm.ATTN_BLOCK, 192, 128, jnp.bfloat16) == 256
+
+    def loss(q, k, v, ct):
+        return jnp.sum(lm.causal_attention(q, k, v, scale=192 ** -0.5).astype(jnp.float32) * ct)
+
+    qk = jax.ShapeDtypeStruct((1, seq, heads, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, seq, heads, 128), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        qk, qk, v, jax.ShapeDtypeStruct(v.shape, jnp.float32, sharding=one_chip)).compile().as_text()
+    instructions, _ = _entry_instructions(text)
+    kernels = sorted(n.split(".")[0] for n, (_, opcode, _, _) in instructions.items() if opcode == "custom-call")
+    assert kernels == ["causal_attention_bwd", "causal_attention_fwd"] and not re.search(r"\bwhile\(", text)
+    (dq,) = [out for n, (out, _, _, _) in instructions.items() if n.startswith("causal_attention_bwd")]
+    assert dq[0] == f"bf16[1,{heads * 256},{seq}]"  # the kernels' own width; the filling's gradient is cut off after
+
+
 def test_no_tile_of_scores_and_no_float32_dq_reaches_hbm(attention_hlo):
     """What the tile loops paid for: no buffer of a tile's shape in any
     dtype (`f32[2,20,512,512]`, or the kernel's own 512 x 512 block), no

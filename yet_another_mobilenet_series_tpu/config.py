@@ -102,12 +102,27 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
+class LinearAttnConfig:
+    """``model.lm.linear_attn_config``, as ``kimi_linear``'s ``config.json``
+    has it: which layers mix by Kimi Delta Attention (ops/lm_kda.py) and which
+    by latent attention, NUMBERED FROM 1 as the source numbers them, and the
+    KDA heads. Layers beyond ``num_hidden_layers`` (a cut holds the first few)
+    are not read. Empty lists: every layer is latent attention."""
+
+    kda_layers: Sequence[int] = ()
+    full_attn_layers: Sequence[int] = ()
+    head_dim: int = 128  # of q, k and v alike: a head's state is head_dim x head_dim
+    num_heads: int = 32
+    short_conv_kernel_size: int = 4
+
+
+@dataclass(frozen=True)
 class LMConfig:
-    """Shapes of a token model (``model.arch: glm4_moe_lite``; models/lm.py),
-    under the keys of the published ``config.json``. The defaults are
-    GLM-4.7-Flash's widths. ``model.num_classes`` is the number of vocabulary
-    rows held here (embedding, head, token ids and the loss are over that
-    slice).
+    """Shapes of a token model (token family: ``glm4_moe_lite``,
+    ``kimi_linear``; models/lm.py), under the keys of the published
+    ``config.json``. The defaults are GLM-4.7-Flash's widths.
+    ``model.num_classes`` is the number of vocabulary rows held here
+    (embedding, head, token ids and the loss are over that slice).
 
     The model is ONE SHARE of an expert-parallel deployment: the router keeps
     its published width ``n_routed_experts`` and its ``num_experts_per_tok``;
@@ -120,7 +135,8 @@ class LMConfig:
     num_hidden_layers: int = 5
     first_k_dense_replace: int = 1
     num_attention_heads: int = 20
-    q_lora_rank: int = 768
+    # None: q is ONE projection of the hidden state (no low-rank pair, no q norm)
+    q_lora_rank: int | None = 768
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
@@ -134,6 +150,9 @@ class LMConfig:
     num_nextn_predict_layers: int = 1  # 0 or 1 multi-token-prediction module
     rms_norm_eps: float = 1e-5
     rope_theta: float = 1e6
+    # true: latent attention rotates nothing; the `qk_rope_head_dim` channels stay, unrotated
+    mla_use_nope: bool = False
+    linear_attn_config: LinearAttnConfig = field(default_factory=lambda: LinearAttnConfig())
     expert_shares: int = 8
     expert_share_index: int = 0
     # tokens of one sequence; a batch row carries seq_len + 2 ids (the next
@@ -1141,6 +1160,7 @@ def _build(dc_type, data: Mapping[str, Any], path: str = ""):
 
 _SECTION_TYPES = {
     "ModelConfig": ModelConfig,
+    "LinearAttnConfig": LinearAttnConfig,
     "LMConfig": LMConfig,
     "DataConfig": DataConfig,
     "OptimConfig": OptimConfig,

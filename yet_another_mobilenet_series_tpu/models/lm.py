@@ -1,10 +1,16 @@
-"""The token-model family beside `specs.Network`: `glm4_moe_lite`
-(GLM-4.7-Flash): token embedding, dense blocks then expert blocks of
-multi-head latent attention, one multi-token-prediction module, an untied
-head, cross-entropy over the vocabulary slice held here.
+"""The token-model family beside `specs.Network` (token family:
+`glm4_moe_lite`, `kimi_linear`): token embedding, dense blocks then expert
+blocks, each behind a token MIXER, an untied head, cross-entropy over the
+vocabulary slice held here. `glm4_moe_lite` (GLM-4.7-Flash) mixes by
+multi-head latent attention in every block and has one
+multi-token-prediction module; `kimi_linear` (Kimi-Linear-48B-A3B) mixes by
+Kimi Delta Attention (ops/lm_kda.py) in the layers its
+`linear_attn_config.kda_layers` names and by latent attention without
+rotation and without a low-rank q in its `full_attn_layers`, and has no MTP.
+An arch is a name: what a block holds is read from `config.LMConfig` alone.
 
 A `TokenModel` is one SHARE of an expert-parallel deployment (config.LMConfig):
-attention is whole, each expert layer holds `experts_held` of the
+the mixers are whole, each expert layer holds `experts_held` of the
 `n_routed_experts` the router scores, embedding and head hold `vocab` rows.
 On one chip the share runs without an exchange, and computes exactly its own
 part: ops/lm.py `expert_layer`.
@@ -21,6 +27,7 @@ equations is models/lm_reference.py, which shares no function with this file.
 from __future__ import annotations
 
 import dataclasses
+import math
 import zlib
 from typing import Any
 
@@ -31,9 +38,12 @@ from jax import lax
 from ..config import LMConfig, ModelConfig
 from ..obs.scopes import scope
 from ..ops import lm as ops
-from ..ops import lm_attention
+from ..ops import lm_attention, lm_kda
 
-LM_ARCHS = ("glm4_moe_lite",)
+LM_ARCHS = ("glm4_moe_lite", "kimi_linear")
+# What a layer's checkpoint keeps, by name, beside its input: attention's output and row log-sum-exp, the
+# KDA scan's output and chunk-boundary states: what the two cores' backwards read, so neither forward runs twice.
+KEPT_NAMES = (ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME, lm_kda.KDA_OUT_NAME, lm_kda.KDA_STATES_NAME)
 # Tokens whose logits over the vocabulary slice are held at once (each such block is a jax.checkpoint).
 LOSS_BLOCK = 2048
 
@@ -66,28 +76,51 @@ class TokenModel:
     def is_dense(self, block: str) -> bool:
         return block != "mtp" and int(block.split("_")[1]) < self.lm.first_k_dense_replace
 
+    def mixer(self, block: str) -> str:
+        """`kda` where `linear_attn_config.kda_layers` names the block's
+        layer (numbered from 1, as the source numbers them), else `attn`."""
+        kda_layers = self.lm.linear_attn_config.kda_layers
+        return "kda" if block != "mtp" and int(block.split("_")[1]) + 1 in kda_layers else "attn"
+
+    def blocks_mixing_by(self, kind: str) -> tuple[str, ...]:
+        return tuple(b for b in self.block_names if self.mixer(b) == kind)
+
     def validate(self) -> None:
         c = self.lm
+        kda, full = set(c.linear_attn_config.kda_layers), set(c.linear_attn_config.full_attn_layers)
+        if kda & full:
+            raise ValueError(f"layers {sorted(kda & full)} are in both kda_layers and full_attn_layers")
+        unassigned = set(range(1, c.num_hidden_layers + 1)) - kda - full
+        if (kda or full) and unassigned:
+            raise ValueError(f"layers {sorted(unassigned)} are in neither kda_layers nor full_attn_layers")
         if c.n_routed_experts % c.expert_shares or not 0 <= c.expert_share_index < c.expert_shares:
             raise ValueError(f"{c.n_routed_experts} experts do not divide into {c.expert_shares} shares "
                              f"with a share of index {c.expert_share_index}")
         if c.num_nextn_predict_layers not in (0, 1):
             raise ValueError("num_nextn_predict_layers is 0 or 1")
-        if c.qk_rope_head_dim % 2:
-            raise ValueError("qk_rope_head_dim must be even")
+        if c.qk_rope_head_dim % 2 and self.blocks_mixing_by("attn"):
+            raise ValueError(f"qk_rope_head_dim must be even where a layer mixes by latent attention "
+                             f"({', '.join(self.blocks_mixing_by('attn'))})")
         if not 0 < c.first_k_dense_replace <= c.num_hidden_layers:
             raise ValueError("first_k_dense_replace must be in [1, num_hidden_layers]")
 
     def attention_sites(self, compute_dtype) -> tuple[int, int]:
-        """(attention layers, those whose shapes ops/lm_attention.py's fused
-        kernels take): what `train.attn_sites` reports and, where the step is
-        lowered for a TPU, `train.attn_fused_sites` (train/steps.py). The
-        predicate is the one `ops.causal_attention` dispatches on."""
+        """(latent-attention layers, those whose shapes ops/lm_attention.py's
+        fused kernels take): what `train.attn_sites` reports and, where the
+        step is lowered for a TPU, `train.attn_fused_sites` (train/steps.py).
+        The predicate is the one `ops.causal_attention` dispatches on. KDA
+        layers are `kda_sites`."""
         c = self.lm
-        sites = len(self.block_names)
-        fits = lm_attention.fuses(c.seq_len, min(ops.ATTN_BLOCK, c.seq_len), c.qk_nope_head_dim + c.qk_rope_head_dim,
-                                  c.v_head_dim, compute_dtype)
+        sites = len(self.blocks_mixing_by("attn"))
+        block = min(ops.ATTN_BLOCK, c.seq_len)
+        wide = lm_attention.fitting_qk_dim(c.seq_len, block, c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim,
+                                           compute_dtype)  # q and k as `ops.causal_attention` hands them on
+        fits = lm_attention.fuses(c.seq_len, block, wide, c.v_head_dim, compute_dtype)
         return sites, sites if fits else 0
+
+    @property
+    def kda_sites(self) -> int:
+        return len(self.blocks_mixing_by("kda"))
 
     def param_count(self) -> int:
         shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0))[0])
@@ -106,19 +139,21 @@ class TokenModel:
             return {"gate": w(name + "g", *lead, h, width), "up": w(name + "u", *lead, h, width),
                     "down": w(name + "d", *lead, width, h)}
 
-        p = {
-            "attn_norm": jnp.ones((h,), jnp.float32),
-            "mlp_norm": jnp.ones((h,), jnp.float32),
-            "attn": {
-                "q_a": w("q_a", h, c.q_lora_rank),
-                "q_norm": jnp.ones((c.q_lora_rank,), jnp.float32),
-                "q_b": w("q_b", c.q_lora_rank, heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)),
+        p = {"attn_norm": jnp.ones((h,), jnp.float32), "mlp_norm": jnp.ones((h,), jnp.float32)}
+        if self.mixer(block) == "kda":
+            p["kda"] = self._init_kda(key, w)
+        else:
+            q_width = heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)
+            q = ({"q": w("q", h, q_width)} if c.q_lora_rank is None else
+                 {"q_a": w("q_a", h, c.q_lora_rank), "q_norm": jnp.ones((c.q_lora_rank,), jnp.float32),
+                  "q_b": w("q_b", c.q_lora_rank, q_width)})
+            p["attn"] = {
+                **q,
                 "kv_a": w("kv_a", h, c.kv_lora_rank + c.qk_rope_head_dim),
                 "kv_norm": jnp.ones((c.kv_lora_rank,), jnp.float32),
                 "kv_b": w("kv_b", c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim)),
                 "o": w("o", heads * c.v_head_dim, h),
-            },
-        }
+            }
         if self.is_dense(block):
             p["mlp"] = mlp("mlp", c.intermediate_size)
         else:
@@ -127,9 +162,31 @@ class TokenModel:
             p["experts"] = mlp("exp", c.moe_intermediate_size, self.experts_held)
         return p
 
+    def _init_kda(self, key, w) -> dict:
+        """One KDA mixer's parameters (ops/lm_kda.py `kda_attention`). Beside
+        the N(0, init_std) matrices and filters: `A_log` = ln(U[1, 16]) a head,
+        `dt_bias` = softplus^-1(dt) with dt log-uniform in [1e-3, 1e-1] a
+        channel (the decay a fresh layer starts from: e^{-A dt} a position)."""
+        c = self.lm
+        la = c.linear_attn_config
+        h, wide = c.hidden_size, la.num_heads * la.head_dim
+        rate = jax.random.uniform(jax.random.fold_in(key, _key("A_log")), (la.num_heads,), jnp.float32, 1.0, 16.0)
+        dt = jnp.exp(jax.random.uniform(jax.random.fold_in(key, _key("dt_bias")), (wide,), jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        return {
+            "q": w("kda_q", h, wide), "k": w("kda_k", h, wide), "v": w("kda_v", h, wide),
+            **{"conv_" + n: w("conv_" + n, la.short_conv_kernel_size, wide) for n in ("q", "k", "v")},
+            "f_a": w("f_a", h, la.head_dim), "f_b": w("f_b", la.head_dim, wide),
+            "A_log": jnp.log(rate), "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "b": w("kda_b", h, la.num_heads),
+            "g_a": w("g_a", h, la.head_dim), "g_b": w("g_b", la.head_dim, wide),
+            "o_norm": jnp.ones((la.head_dim,), jnp.float32), "o": w("kda_o", wide, h),
+        }
+
     def init(self, key) -> tuple[dict, dict]:
-        """(params, state): float32 weights ~ N(0, init_std), norm gains 1;
-        state = each expert block's router bias, zeros."""
+        """(params, state): float32 weights ~ N(0, init_std), norm gains 1 (a
+        KDA mixer's decay parameters: `_init_kda`); state = each expert
+        block's router bias, zeros."""
         self.validate()
         c = self.lm
         h = c.hidden_size
@@ -151,14 +208,27 @@ class TokenModel:
 
     # ---- forward ----------------------------------------------------------
 
-    def _block(self, block: str, p: dict, bias, x, cos, sin):
+    def _mixed(self, p: dict, x, cos, sin):
+        """A block's first half, x + Mixer(norm(x)): (x, its KDA mixer's most
+        negative in-chunk log decay or None)."""
         c = self.lm
-        a = ops.mla_attention(
-            p["attn"], ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps), cos, sin,
-            heads=c.num_attention_heads, nope=c.qk_nope_head_dim, rope=c.qk_rope_head_dim,
-            v_dim=c.v_head_dim, kv_rank=c.kv_lora_rank, eps=c.rms_norm_eps)
+        normed = ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps)
+        lowest = None
+        if "kda" in p:
+            la = c.linear_attn_config
+            a, lowest = lm_kda.kda_attention(p["kda"], normed, heads=la.num_heads, head_dim=la.head_dim,
+                                             eps=c.rms_norm_eps)
+        else:
+            a = ops.mla_attention(
+                p["attn"], normed, cos, sin,
+                heads=c.num_attention_heads, nope=c.qk_nope_head_dim, rope=c.qk_rope_head_dim,
+                v_dim=c.v_head_dim, kv_rank=c.kv_lora_rank, eps=c.rms_norm_eps)
         with scope("residual"):
-            x = x + a
+            return x + a, lowest
+
+    def _fed(self, block: str, p: dict, bias, x):
+        """A block's second half, x + FFN(norm(x)): (x, what its expert layer reports or None)."""
+        c = self.lm
         y = ops.rms_norm(x, p["mlp_norm"], c.rms_norm_eps)
         if self.is_dense(block):
             out = ops.gated_mlp(p["mlp"], y)
@@ -208,8 +278,10 @@ class TokenModel:
         seq = tokens.shape[1] - 2
         if seq != c.seq_len:
             raise ValueError(f"a batch row holds {tokens.shape[1]} ids, model.lm.seq_len + 2 = {c.seq_len + 2} expected")
-        with scope("rope"):
-            cos, sin = ops.rope_tables(seq, c.qk_rope_head_dim, c.rope_theta)
+        cos = sin = None
+        if not c.mla_use_nope:
+            with scope("rope"):
+                cos, sin = ops.rope_tables(seq, c.qk_rope_head_dim, c.rope_theta)
         with scope("embed"):
             # one gather for both heads' inputs: positions 0..seq of every row
             # (float32 rows, then the cast: a frequent token's gradient is summed in float32)
@@ -218,18 +290,24 @@ class TokenModel:
         # Across the step a layer keeps its input and, by name, its attention's output and row log-sum-exp
         # (B, H, S, Dv in the compute dtype; B, H, S float32). The backward runs the rest of the layer again
         # (norms, MLA projections, RoPE, the q/k joins, MLP, the expert layer), but not the attention forward:
-        # its custom_vjp needs from that second run q, k, v alone.
-        kept = jax.checkpoint_policies.save_only_these_names(ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME)
+        # its custom_vjp needs from that second run q, k, v alone. A KDA layer likewise keeps its scan's output
+        # and chunk-boundary states, and its second run makes the in-chunk matrices again, not the scan.
+        kept = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
         def run(block, x, p, bias):
-            fn = lambda x_, p_, b_: self._block(block, p_, b_, x_, cos, sin)  # noqa: E731
+            def fn(x_, p_, b_):
+                x_, lowest = self._mixed(p_, x_, cos, sin)
+                return (*self._fed(block, p_, b_, x_), lowest)
+
             return jax.checkpoint(fn, policy=kept)(x, p, bias)
 
-        new_state, selected, per_block = {}, {}, []
+        new_state, selected, per_block, lowest_by_block = {}, {}, [], []
 
         def through(block, x):
             bias = state[block]["router_bias"] if block in state else None
-            x, routed = run(block, x, params[block], bias)
+            x, routed, lowest = run(block, x, params[block], bias)
+            if lowest is not None:
+                lowest_by_block.append(lowest)
             if routed is not None:
                 load, counters, selected[block] = routed
                 with scope("moe_router"):
@@ -263,6 +341,10 @@ class TokenModel:
                 "moe_dropped": sum(b["dropped"] for b in per_block),
                 "moe_load_max_over_mean": jnp.max(jnp.stack([b["load_max_over_mean"] for b in per_block])),
             } if per_block else {}
+        if lowest_by_block:
+            with scope("kda_gate"):
+                lowest = jnp.min(jnp.stack(lowest_by_block))
+                counters["kda_min_chunk_log_decay"] = lowest if axis_name is None else lax.pmin(lowest, axis_name)
         return heads, new_state, counters, selected
 
     def loss(self, params, state, batch, *, compute_dtype=jnp.float32, axis_name: str | None = None):
@@ -292,8 +374,9 @@ class TokenModel:
 
     def grad_scalars(self, grads: dict) -> dict:
         """Gradient norms by group, as step scalars: embedding, head, `W_eh`,
-        and per block its attention, router, held experts, shared or dense
-        MLP and norm gains. What the benchmark holds against the reference."""
+        and per block its mixer (`attn` or `kda`), router, held experts,
+        shared or dense MLP and norm gains. What the benchmark holds against
+        the reference."""
         def norm(tree):
             return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(tree)))
 
@@ -301,7 +384,7 @@ class TokenModel:
                "gnorm/final_norm": norm(grads["final_norm"])}
         for block in self.block_names:
             g = grads[block]
-            for name in ("attn", "mlp", "router", "shared", "experts", "eh_proj"):
+            for name in ("attn", "kda", "mlp", "router", "shared", "experts", "eh_proj"):
                 if name in g:
                     out[f"gnorm/{block}/{name}"] = norm(g[name])
             out[f"gnorm/{block}/norms"] = norm([v for k, v in g.items() if k.endswith("norm")])

@@ -1,14 +1,19 @@
-"""The plain reference of the `glm4_moe_lite` family (GLM-4.7-Flash): forward,
-loss and, through `jax.grad`, gradients, in straightforward `jax.numpy`,
-float32, under `jax.default_matmul_precision("highest")`.
+"""The plain reference of the token family (`glm4_moe_lite`: GLM-4.7-Flash;
+`kimi_linear`: Kimi-Linear-48B-A3B): forward, loss and, through `jax.grad`,
+gradients, in straightforward `jax.numpy`, float32, under
+`jax.default_matmul_precision("highest")`.
 
 It follows the published layer equations and shares no function with the
 program (models/lm.py, ops/lm.py). Where the program is clever this is not:
 keys and values are expanded per head, with the one shared `k_rope` head
 repeated; the causal mask is a dense S x S array; the held experts are a
 Python loop in which every expert sees every token under a 0/1 mask; nothing
-is sorted, blocked or recomputed. It reads the program's parameter tree
-(`TokenModel.init`) and a plain dict of sizes (:func:`dims_of`).
+is sorted, blocked or recomputed; Kimi Delta Attention is its recurrence,
+token by token (a `lax.scan` over the positions, never a chunk), its short
+convolution four shifted multiplies. It reads the program's parameter tree
+(`TokenModel.init`: a block with a `kda` group mixes by KDA, one with `attn`
+by latent attention; `attn` with `q` has no low-rank q) and a plain dict of
+sizes (:func:`dims_of`).
 
 Given a share (`expert_shares`, `expert_share_index`) it leaves out the same
 absent experts as the program; with `expert_shares=1` it is the uncut layer.
@@ -16,7 +21,8 @@ absent experts as the program; with `expert_shares=1` it is the uncut layer.
 Departures from the published description: none in the equations. Not in
 `config.json`, and therefore assumed (the configuration's file lists them):
 the RoPE pairing (channel i with i + d/2), the MTP loss weight, the bias
-update rate.
+update rate; for `kimi_linear` what KDA's description leaves to the code
+(:func:`kda` names each).
 """
 
 from __future__ import annotations
@@ -29,7 +35,7 @@ import jax.numpy as jnp
 DIM_KEYS = ("hidden_size", "num_hidden_layers", "first_k_dense_replace", "num_attention_heads", "kv_lora_rank",
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "n_routed_experts", "num_experts_per_tok",
             "routed_scaling_factor", "num_nextn_predict_layers", "rms_norm_eps", "rope_theta", "expert_shares",
-            "expert_share_index", "mtp_loss_weight", "router_bias_rate")
+            "expert_share_index", "mtp_loss_weight", "router_bias_rate", "mla_use_nope")
 
 
 def dims_of(lm_config) -> dict:
@@ -52,17 +58,22 @@ def rope(x, theta):
 
 
 def mla(p, x, d):
-    """One sequence x (S, h) through multi-head latent attention."""
+    """One sequence x (S, h) through multi-head latent attention. `q` in `p`:
+    q is one projection (no low-rank pair); `mla_use_nope`: nothing is rotated."""
     seq = x.shape[0]
     heads, nope, rope_d, v_d = (d["num_attention_heads"], d["qk_nope_head_dim"], d["qk_rope_head_dim"],
                                 d["v_head_dim"])
-    c_q = rms_norm(x @ p["q_a"], p["q_norm"], d["rms_norm_eps"])
-    q = (c_q @ p["q_b"]).reshape(seq, heads, nope + rope_d)
+    turn = (lambda t: t) if d["mla_use_nope"] else (lambda t: rope(t, d["rope_theta"]))
+    if "q" in p:
+        q = (x @ p["q"]).reshape(seq, heads, nope + rope_d)
+    else:
+        c_q = rms_norm(x @ p["q_a"], p["q_norm"], d["rms_norm_eps"])
+        q = (c_q @ p["q_b"]).reshape(seq, heads, nope + rope_d)
     kv_a = x @ p["kv_a"]
     c_kv = rms_norm(kv_a[:, :d["kv_lora_rank"]], p["kv_norm"], d["rms_norm_eps"])
     kv = (c_kv @ p["kv_b"]).reshape(seq, heads, nope + v_d)
-    q_rope = rope(q[..., nope:], d["rope_theta"])
-    k_rope = rope(kv_a[:, None, d["kv_lora_rank"]:], d["rope_theta"])  # one head
+    q_rope = turn(q[..., nope:])
+    k_rope = turn(kv_a[:, None, d["kv_lora_rank"]:])  # one head
     k_rope = jnp.repeat(k_rope, heads, axis=1)  # ... shared by all
     k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)  # per-head keys (S, heads, nope + rope)
     q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
@@ -71,6 +82,47 @@ def mla(p, x, d):
     probs = jax.nn.softmax(jnp.where(mask[None], scores, -jnp.inf), axis=-1)
     out = jnp.einsum("hqk,khd->qhd", probs, kv[..., nope:])
     return out.reshape(seq, heads * v_d) @ p["o"]
+
+
+def short_conv(z, w):
+    """z (S, D), one filter of `taps` weights a channel, w (taps, D): SiLU(sum_i
+    w_i z_{t - (taps-1) + i}), positions before the document's first read 0;
+    `taps` shifted multiplies. ASSUMED: no bias, SiLU after the sum."""
+    taps = w.shape[0]
+    total = jnp.zeros_like(z)
+    for i in range(taps):
+        back = taps - 1 - i
+        total = total + w[i] * jnp.concatenate([jnp.zeros_like(z[:back]), z[:z.shape[0] - back]], axis=0)
+    return jax.nn.silu(total)
+
+
+def kda(p, x, d):
+    """One sequence x (S, h) through Kimi Delta Attention, as the RECURRENCE,
+    a token at a time: S'_t = Diag(alpha_t) S_{t-1}; S_t = S'_t + beta_t k_t
+    (v_t - S'_t^T k_t)^T; o_t = S_t^T q_t / sqrt(head_dim). ASSUMED (not in
+    `config.json`): the low-rank width of the decay and output gates is the
+    head dim (read off `f_a`, `g_a`); L2 norm with eps 1e-6 INSIDE the root;
+    the output norm's gain is shared by the heads; sigmoid on the output gate."""
+    seq = x.shape[0]
+    heads = p["A_log"].shape[0]
+    width = p["q"].shape[1] // heads
+    by_head = lambda z: z.reshape(seq, heads, width)  # noqa: E731
+    q, k, v = (by_head(short_conv(x @ p[n], p["conv_" + n])) for n in ("q", "k", "v"))
+    q = q / jnp.sqrt(jnp.sum(q * q, axis=-1, keepdims=True) + 1e-6)
+    k = k / jnp.sqrt(jnp.sum(k * k, axis=-1, keepdims=True) + 1e-6)
+    log_decay = -jnp.exp(p["A_log"])[:, None] * jax.nn.softplus(by_head(x @ p["f_a"] @ p["f_b"] + p["dt_bias"]))
+    beta = jax.nn.sigmoid(x @ p["b"])  # (S, heads)
+
+    def token(state, xs):  # state (heads, key, value)
+        q_t, k_t, v_t, g_t, b_t = xs
+        state = jnp.exp(g_t)[:, :, None] * state
+        read = jnp.einsum("hkv,hk->hv", state, k_t)
+        state = state + b_t[:, None, None] * k_t[:, :, None] * (v_t - read)[:, None, :]
+        return state, jnp.einsum("hkv,hk->hv", state, q_t) / math.sqrt(width)
+
+    _, out = jax.lax.scan(token, jnp.zeros((heads, width, width), x.dtype), (q, k, v, log_decay, beta))
+    out = rms_norm(out, p["o_norm"], d["rms_norm_eps"]) * jax.nn.sigmoid(by_head(x @ p["g_a"] @ p["g_b"]))
+    return out.reshape(seq, heads * width) @ p["o"]
 
 
 def gated_mlp(gate, up, down, x):
@@ -95,7 +147,8 @@ def experts(p, bias, x, d):
 
 
 def block(p, bias, x, d, dense):
-    x = x + mla(p["attn"], rms_norm(x, p["attn_norm"], d["rms_norm_eps"]), d)
+    mixed = rms_norm(x, p["attn_norm"], d["rms_norm_eps"])
+    x = x + (kda(p["kda"], mixed, d) if "kda" in p else mla(p["attn"], mixed, d))
     y = rms_norm(x, p["mlp_norm"], d["rms_norm_eps"])
     if dense:
         return x + gated_mlp(p["mlp"]["gate"], p["mlp"]["up"], p["mlp"]["down"], y), None

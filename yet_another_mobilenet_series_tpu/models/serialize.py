@@ -14,7 +14,7 @@ from typing import Any
 
 import dataclasses
 
-from ..config import LMConfig
+from ..config import LinearAttnConfig, LMConfig
 from ..ops.blocks import ConvBNAct, InvertedResidual
 from ..ops.layers import Dense
 from .lm import TokenModel
@@ -97,7 +97,11 @@ def network_from_dict(d: dict[str, Any]) -> Network | TokenModel:
     if d.get("schema") not in (1, _SCHEMA_VERSION):
         raise ValueError(f"unsupported network schema {d.get('schema')!r}")
     if "token_model" in d:
-        return TokenModel(arch=d["token_model"], vocab=d["vocab"], lm=LMConfig(**d["lm"]))
+        lm = dict(d["lm"])
+        pattern = lm.pop("linear_attn_config", {})  # absent in a spec written before the token family had one
+        pattern = {k: tuple(v) if isinstance(v, list) else v for k, v in pattern.items()}
+        return TokenModel(arch=d["token_model"], vocab=d["vocab"],
+                          lm=LMConfig(**lm, linear_attn_config=LinearAttnConfig(**pattern)))
 
     def _blk(bd):
         bd = dict(bd)

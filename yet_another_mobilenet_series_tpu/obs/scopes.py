@@ -71,6 +71,12 @@ SCOPES = (
     "moe_experts",  # the held experts' grouped matmuls (lax.ragged_dot) and their SiLU gate
     "moe_combine",  # un-sort, weight and sum over the selected experts; the layer's counters
     "mtp_merge",    # the MTP module's W_eh over [norm(h) ; norm(emb)]
+    # Kimi Delta Attention (ops/lm_kda.py):
+    "kda_proj",     # its seven projections: q, k, v, the decay gate's and the output gate's low-rank pairs, beta, o
+    "kda_conv",     # the short causal depthwise convolutions on q, k, v and their SiLU
+    "kda_gate",     # the log decay of every key channel, the write strength beta; the step's lowest chunk decay
+    "kda_core",     # the chunked gated delta rule: in-chunk decayed scores, the triangular solve, the scan over chunks
+    "kda_norm",     # L2 norm of q and k, the sigmoid-gated RMSNorm of the output
     "lm_head",      # the output head over the vocabulary slice, a block of tokens at a time
     "loss",         # label-smoothed CE and the step's reported scalars (top-1, lr, their pmean)
     "nas_penalty",  # AtomNAS FLOPs-weighted BN-gamma L1
@@ -86,7 +92,7 @@ SCOPES = (
 # filled before a scope was added, renamed or moved hands back an executable
 # with the old names in it. BUMP IT with any such change;
 # tests/test_obs_scopes.py pins it to the list of scope sites.
-TAXONOMY_VERSION = 4
+TAXONOMY_VERSION = 5
 UNSCOPED = "unscoped"
 PHASES = ("fwd", "bwd", "-")
 

@@ -201,37 +201,51 @@ def causal_attention(q: Array, k: Array, v: Array, *, scale: float, block: int |
     """Causal softmax(q k^T * scale) v, float32 softmax, a tile of `block`
     query rows by `block` key rows at a time and never one kept, forward and
     backward (the backward recomputes each tile): in the fused TPU kernels of
-    ops/lm_attention.py where the shapes fit them and the step is lowered for
-    a TPU, else in the loops over tiles. q, k (B, S, H, D); v (B, S, H, Dv)
-    -> (B, S, H, Dv)."""
+    ops/lm_attention.py where the shapes fit them (q and k filled with zero
+    channels to a multiple of 128 where that is all they lack:
+    `lm_attention.fitting_qk_dim`) and the step is lowered for a TPU, else in
+    the loops over tiles. q, k (B, S, H, D); v (B, S, H, Dv) -> (B, S, H, Dv)."""
     seq = q.shape[1]
     block = min(block or ATTN_BLOCK, seq)
     if seq % block:
         raise ValueError(f"sequence length {seq} is not a multiple of the attention block {block}")
     with scope("attn_core"):
+        wide = lm_attention.fitting_qk_dim(seq, block, q.shape[-1], v.shape[-1], q.dtype)
+        if wide != q.shape[-1]:  # zero channels, so that the kernels take the call (192 -> 256): exact
+            q, k = (jnp.pad(x, [(0, 0)] * 3 + [(0, wide - x.shape[-1])]) for x in (q, k))
         q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))  # heads lead: batch and head are the tiles' batch axes
         return jnp.swapaxes(_blocked_attention(q, k, v, scale, block), 1, 2)
 
 
-def mla_attention(p: dict, x: Array, cos: Array, sin: Array, *, heads: int, nope: int, rope: int,
+def mla_attention(p: dict, x: Array, cos: Array | None, sin: Array | None, *, heads: int, nope: int, rope: int,
                   v_dim: int, kv_rank: int, eps: float) -> Array:
-    """Multi-head latent attention (DeepSeek-V2's MLA, as glm4_moe_lite has
-    it), training form: the latents are expanded to per-head keys and values.
-    `k_rope` is ONE head, shared by all `heads`. x (B, S, h) -> (B, S, h)."""
+    """Multi-head latent attention (DeepSeek-V2's MLA, as glm4_moe_lite and
+    kimi_linear have it), training form: the latents are expanded to per-head
+    keys and values. `k_rope` is ONE head, shared by all `heads`. Two options,
+    read from what is handed in: q through a low-rank pair with its norm
+    (`p` holds `q_a`, `q_norm`, `q_b`) or one projection (`p` holds `q`); and
+    with `cos` None nothing is rotated: the `rope` channels stay as they are
+    projected (`mla_use_nope`). x (B, S, h) -> (B, S, h)."""
     cd = x.dtype
     b, s, _ = x.shape
+    low_rank_q = "q_a" in p
     with scope("attn_proj"):
-        c_q = x @ p["q_a"].astype(cd)
+        c_q = x @ p["q_a"].astype(cd) if low_rank_q else None
         kv_a = x @ p["kv_a"].astype(cd)
-    c_q = rms_norm(c_q, p["q_norm"], eps)
+    if low_rank_q:
+        c_q = rms_norm(c_q, p["q_norm"], eps)
     c_kv = rms_norm(kv_a[..., :kv_rank], p["kv_norm"], eps)
     with scope("attn_proj"):
-        q = (c_q @ p["q_b"].astype(cd)).reshape(b, s, heads, nope + rope)
+        q = ((c_q @ p["q_b"].astype(cd)) if low_rank_q else (x @ p["q"].astype(cd))).reshape(b, s, heads, nope + rope)
         kv = (c_kv @ p["kv_b"].astype(cd)).reshape(b, s, heads, nope + v_dim)
-    q_rope = apply_rope(q[..., nope:], cos, sin)
-    k_rope = apply_rope(kv_a[..., None, kv_rank:], cos, sin)
+    if cos is not None:
+        q_rope = apply_rope(q[..., nope:], cos, sin)
+        k_rope = apply_rope(kv_a[..., None, kv_rank:], cos, sin)
+    else:
+        k_rope = kv_a[..., None, kv_rank:]
     with scope("attn_core"):
-        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+        if cos is not None:
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
         k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (b, s, heads, rope))], axis=-1)
     out = causal_attention(q, k, kv[..., nope:], scale=(nope + rope) ** -0.5)
     with scope("attn_proj"):

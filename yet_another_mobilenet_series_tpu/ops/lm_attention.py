@@ -57,6 +57,16 @@ def fuses(seq: int, block: int, qk_dim: int, v_dim: int, dtype) -> bool:
             and jnp.dtype(dtype) == jnp.bfloat16 and seq * max(qk_dim, v_dim) * 2 <= RESIDENT_BYTES)
 
 
+def fitting_qk_dim(seq: int, block: int, qk_dim: int, v_dim: int, dtype) -> int:
+    """The q/k head dim the kernels are handed: `qk_dim` itself, or, where
+    that alone keeps them from taking the call, `qk_dim` filled with ZERO
+    channels to the next multiple of the 128 lanes (192 -> 256). Exact: a zero
+    channel adds 0 to every score, and its gradient is cut off with the
+    filling. A fit that needs more than that takes the loops, unfilled."""
+    filled = -(-qk_dim // 128) * 128
+    return filled if fuses(seq, block, filled, v_dim, dtype) else qk_dim
+
+
 def features_lead(x):
     """(B, H, S, D) -> (B, H * D, S), how the kernels take their operands: a
     bitcast where XLA holds the projections' results with the sequence in

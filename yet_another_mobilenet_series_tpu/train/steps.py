@@ -242,15 +242,18 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     compute_dtype = _dtype(cfg.train.compute_dtype)
     if cfg.prune.enable or cfg.optim.mixup_alpha or cfg.optim.cutmix_alpha:
         raise ValueError(f"model.arch {net.arch!r} is a token model: prune.enable, mixup and cutmix are image-only")
-    # how many attention layers this step lowers through ops/lm_attention.py's fused kernels. A
+    # how many latent-attention layers this step lowers through ops/lm_attention.py's fused kernels. A
     # PREDICTION of the lowering, not a reading of it: ops/lm.py decides from each call's shapes (the
     # same predicate) and the platform the step is in fact lowered for; here `platform` stands for that
     sites, fitting = net.attention_sites(compute_dtype)
     get_registry().gauge("train.attn_sites").set(sites)
     get_registry().gauge("train.attn_fused_sites").set(fitting if (platform or jax.default_backend()) == "tpu" else 0)
-    # every block is one attention layer under one layer checkpoint, which keeps that attention's output and row
-    # log-sum-exp by name whatever the lowering (models/lm.py `forward`): all of them, on every platform
+    # every block is one mixer under one layer checkpoint, which keeps, by name and whatever the lowering, an
+    # attention's output and row log-sum-exp, a KDA scan's output and states (models/lm.py `forward`): all of
+    # them, on every platform
     get_registry().gauge("train.attn_kept_sites").set(sites)
+    get_registry().gauge("train.kda_sites").set(net.kda_sites)
+    get_registry().gauge("train.kda_kept_sites").set(net.kda_sites)
 
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
         return net.loss(params, state, batch, compute_dtype=compute_dtype, axis_name=axis_name)
