@@ -253,24 +253,109 @@ def test_selection_reads_scores_plus_bias_weights_read_scores_and_the_bias_has_n
     assert set(new_state) == {"layer_1", "layer_2", "mtp"}
 
 
-def test_dropped_counts_the_rows_the_grouped_matmul_did_not_write(setup, monkeypatch):
+@pytest.mark.parametrize("branch, to_held, planted", [("fallback", 10.0, 50), ("bounded", 0.0, 3)])
+def test_dropped_counts_the_rows_the_grouped_matmul_did_not_write(setup, monkeypatch, branch, to_held, planted):
     """`dropped` is read from what `lax.ragged_dot` wrote, not from the ids: a
     grouped matmul that leaves out the last rows of every group (a capacity,
-    planted here) is counted, assignment by assignment."""
+    planted here) is counted, assignment by assignment, in the full-length
+    branch (every token to the two held experts: 64 rows a group) as in the
+    bounded one (a flat bias: 12 rows in the two groups)."""
     net, params, _, _, _, _, _ = setup
     p = params["layer_1"]
-    bias = jnp.full((16,), -10.0).at[2:4].set(10.0)  # every token to the two held experts: 64 rows a group
+    bias = jnp.full((16,), -to_held).at[2:4].set(to_held)
     x = jax.random.normal(jax.random.PRNGKey(7), (2, LM.seq_len, LM.hidden_size))
     real = jax.lax.ragged_dot
 
     def with_capacity(rows, weights, group_sizes, **kwargs):
-        return real(rows, weights, jnp.minimum(group_sizes, 50), **kwargs)  # the groups no longer cover their rows
+        return real(rows, weights, jnp.minimum(group_sizes, planted), **kwargs)  # the groups no longer cover their rows
 
     layer = lambda: ops.expert_layer(p, bias, x, top_k=2, scaling=1.8, held=2, share_index=1)  # noqa: E731
-    assert float(layer()[2]["dropped"]) == 0.0
-    monkeypatch.setattr(ops.lax, "ragged_dot", with_capacity)
     counters = layer()[2]
-    assert float(counters["assignments_here"]) == 128 and float(counters["dropped"]) > 0
+    assert float(counters["dropped"]) == 0.0 and float(counters["bounded"]) == (branch == "bounded")
+    monkeypatch.setattr(ops.lax, "ragged_dot", with_capacity)
+    jax.clear_caches()  # the layer's two halves are jitted: one trace serves every site of these shapes
+    try:
+        counters = layer()[2]
+    finally:
+        jax.clear_caches()  # and must not serve the planted capacity to the next test
+    assert float(counters["assignments_here"]) == (128 if branch == "fallback" else 12) and float(counters["dropped"]) > 0
+
+
+@pytest.mark.parametrize("assignments, held, n_experts, rows", [
+    (16384 * 4, 8, 64, 16384),    # glm47flash_train_2x8k: a quarter of the assignments
+    (16384 * 8, 8, 256, 8192),    # kimilinear_train_1x16k: a sixteenth
+    (128, 2, 16, 64), (256, 8, 32, 128),  # this file's two models: 32 rows, up to a whole tile of 64; 128
+    (24, 2, 16, 64), (48, 2, 64, 64),     # tests/benchmark_tests' 12-token models: not fewer rows than there are, so one body
+    (128, 8, 16, 128), (128, 16, 16, 256),  # a share of half the experts, the uncut layer: the same
+])
+def test_the_capacity_is_twice_the_shares_expected_rows_read_from_the_shapes(assignments, held, n_experts, rows):
+    assert ops.capacity_rows(assignments, held, n_experts) == rows
+    # the branches a site traces: the bounded one only where it is the shorter
+    assert len(ops._branches(2, rows, assignments)) == (2 if rows < assignments else 1)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_bounded_branch_equals_the_full_length_one(request, monkeypatch, family):
+    """One expert layer at the toy shapes (capacity 64 of 128 assignment rows
+    for GLM's, 128 of 256 for kimi's): the branch over `capacity_rows` rows,
+    which the layer takes here, against the same layer with only the
+    full-length branch traced: the value and every gradient (x, router, held
+    experts), and the counters."""
+    net, params, state, _, _, _, _ = family_setup(request, family)
+    c = net.lm
+    p, bias = params["layer_1"], state["layer_1"]["router_bias"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, c.seq_len, c.hidden_size))
+    ct = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+    def run():
+        def fn(p_, x_):
+            y, _, counters, _ = ops.expert_layer(p_, bias, x_, top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
+                                                 held=net.experts_held, share_index=c.expert_share_index)
+            return jnp.sum(y * ct), (y, counters)
+        (_, (y, counters)), grads = jax.jit(jax.value_and_grad(fn, argnums=(0, 1), has_aux=True))(
+            {k: p[k] for k in ("router", "experts")}, x)
+        return y, grads, {k: float(v) for k, v in counters.items()}
+
+    y, grads, counters = run()
+    monkeypatch.setattr(ops, "capacity_rows", lambda assignments, held, n_experts: assignments)
+    full_y, full_grads, full_counters = run()
+    assert counters.pop("bounded") == 1.0 and full_counters.pop("bounded") == 0.0
+    assert counters == full_counters and counters["dropped"] == 0.0 and 0 < counters["assignments_here"]
+    np.testing.assert_allclose(y, full_y, atol=1e-6)
+    assert worst_leaf(grads, full_grads) < 1e-5
+    assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree.leaves(grads))
+
+
+@pytest.mark.parametrize("held_rows, bounded", [(64, 1.0), (65, 0.0)])
+def test_a_held_count_of_the_capacity_is_bounded_and_one_more_falls_back(setup, held_rows, bounded):
+    """Capacity 64 (2 x 64 tokens, top-2: 256 assignments, 2 of 16 experts
+    held). A router that sends exactly `held_rows` tokens to a held expert
+    (and to one held elsewhere) and every other token to two experts held
+    elsewhere: 64 rows take the bounded branch, 65 the full-length one; both
+    drop nothing, give the reference's result and its gradients."""
+    net, params, _, _, _, _, _ = setup
+    assert ops.capacity_rows(2 * 64 * 2, 2, 16) == 64
+    router = jnp.zeros((LM.hidden_size, 16)).at[0, 2].set(10.0).at[1, 7].set(10.0).at[2, 5].set(10.0).at[3, 6].set(10.0)
+    p = {**params["layer_1"], "router": router}
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(7), (2, 64, LM.hidden_size)).at[..., :4].set(0.0)
+    to_held = (jax.random.permutation(jax.random.PRNGKey(8), 2 * 64) < held_rows).reshape(2, 64)
+    x = x.at[..., 0:2].set(jnp.where(to_held[..., None], 1.0, 0.0)).at[..., 2:4].set(jnp.where(to_held[..., None], 0.0, 1.0))
+    bias = jnp.zeros((16,))
+
+    def fn(experts, x_):
+        y, load, counters, _ = ops.expert_layer({**p, "experts": experts}, bias, x_, top_k=2, scaling=1.8, held=2, share_index=1)
+        return jnp.sum(jnp.square(y)), (y, load, counters)
+
+    (_, (y, load, counters)), grads = jax.value_and_grad(fn, argnums=(0, 1), has_aux=True)(p["experts"], x)
+    assert float(counters["bounded"]) == bounded and float(counters["dropped"]) == 0.0
+    assert float(counters["assignments_here"]) == held_rows and load.tolist()[2:4] == [held_rows, 0]
+
+    def reference(experts, x_):
+        return jnp.stack([ref.experts({**p, "experts": experts}, bias, row, ref.dims_of(LM))[0] for row in x_])
+
+    np.testing.assert_allclose(y, reference(p["experts"], x), atol=2e-5)
+    want = jax.grad(lambda e, x_: jnp.sum(jnp.square(reference(e, x_))), argnums=(0, 1))(p["experts"], x)
+    assert worst_leaf(grads, want) < 2e-5
 
 
 def as_lowered_for_a_tpu(patch):
@@ -347,9 +432,11 @@ def test_on_the_cpu_attention_lowers_to_the_loops_at_every_shape(setup):
     """The kernels are for a TPU lowering alone: at a shape they take, a CPU
     lowering holds the tile loops and no Mosaic call; and the toy token step's
     lowered module is pinned byte for byte, float32 and bfloat16 (digests taken
-    at PR 32, whose layer checkpoint keeps attention's output and log-sum-exp:
-    the module of 61078ea, the commit before the kernels, less the backward's
-    second forward loops)."""
+    at PR 34, whose expert layers are each one `lax.cond` between the rows held
+    and every assignment's, forward and backward; b6f613dc73fcefba /
+    933950a18e0716d0 from PR 32, whose layer checkpoint keeps attention's
+    output and log-sum-exp, to PR 33: the module of 61078ea, the commit before
+    the kernels, less the backward's second forward loops)."""
     import hashlib
 
     assert lm_attention.fuses(1024, 512, 128, 128, jnp.bfloat16)
@@ -358,7 +445,7 @@ def test_on_the_cpu_attention_lowers_to_the_loops_at_every_shape(setup):
                             (0, 1, 2))).lower(x, x, x).as_text()
     assert "stablehlo.while" in text and "tpu_custom_call" not in text
     net, params, state, tokens, _, _, _ = setup
-    for dtype, digest in ((jnp.float32, "b6f613dc73fcefba"), (jnp.bfloat16, "933950a18e0716d0")):
+    for dtype, digest in ((jnp.float32, "dbc40fdaadfbcd5e"), (jnp.bfloat16, "002fcb076a545b6a")):
         text = program.lower(net, params, state, tokens, dtype).as_text()
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
@@ -532,6 +619,44 @@ def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypat
     assert kimi.attention_sites(jnp.bfloat16) == (1, 1) and kimi.kda_sites == 4
     assert gauges(kimi, platform="tpu") == (1.0, 1.0, 1.0) and gauges(kimi, platform="cpu") == (1.0, 0.0, 1.0)
     assert tuple(get_registry().gauge(name).value for name in ("train.kda_sites", "train.kda_kept_sites")) == (4.0, 4.0)
+
+
+@pytest.mark.parametrize("family, sites, rows, cell, cell_sites, cell_rows", [
+    ("glm", 3, 64, "glm_4_7_flash_ep8_share", 5, 16384), ("kimi", 4, 128, "kimi_linear_48b_ep32_share", 4, 8192)])
+def test_train_step_reports_its_expert_sites_their_capacity_and_how_many_ran_bounded(request, family, sites, rows, cell,
+                                                                                     cell_sites, cell_rows):
+    """Beside `train.attn_sites`: `train.moe_sites` where the step is built
+    (the toy GLM's two expert layers and MTP block, kimi's four; 5 and 4 for
+    the cells' models), `train.moe_capacity_rows` where it is traced, from the
+    batch one replica sees (64 of 128 assignment rows, 128 of 256; 16,384 of
+    65,536 and 8,192 of 131,072 at the cells' batches), and the step scalar
+    `moe_bounded_sites`: every site of a step whose held rows fit."""
+    import os
+
+    from yet_another_mobilenet_series_tpu.config import load_config
+    from yet_another_mobilenet_series_tpu.obs.registry import get_registry
+    from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
+
+    net, params, state, tokens, _, _, _ = family_setup(request, family)
+    app = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                       "yet_another_mobilenet_series_tpu", "apps", cell + ".yml")
+    cfg = load_config(app)
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 2, 10, 1)
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, params)
+    gauge = lambda name: get_registry().gauge(name).value  # noqa: E731
+    get_registry().gauge("train.moe_capacity_rows").set(-1.0)
+    step = steps.make_train_step(net, cfg, optimizer, lr_fn)
+    assert (gauge("train.moe_sites"), gauge("train.moe_capacity_rows")) == (sites, -1.0)
+    ts = jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0)))
+    _, metrics = jax.eval_shape(step, ts, {"tokens": tokens}, jax.random.PRNGKey(1))
+    assert gauge("train.moe_capacity_rows") == rows == net.expert_capacity_rows(tokens.shape[0])
+    assert "moe_bounded_sites" in metrics and "moe_dropped" in metrics
+    (_, (_, scalars)), _ = program(net, params, state, tokens)
+    assert float(scalars["moe_bounded_sites"]) == sites and float(scalars["moe_dropped"]) == 0.0
+    published = get_model(cfg.model)
+    steps.make_train_step(published, cfg, optimizer, lr_fn)
+    batch = {"glm": 2, "kimi": 1}[family]  # the cells' sequences a step
+    assert (gauge("train.moe_sites"), published.expert_capacity_rows(batch)) == (cell_sites, cell_rows)
 
 
 _FRESH_PROCESS = """

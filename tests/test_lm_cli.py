@@ -34,6 +34,7 @@ def test_three_steps_through_cli_train(tmp_path):
     assert len(rows) == 3
     last = rows[-1]
     assert last["train/moe_dropped"] == 0.0 and last["train/moe_assignments_here"] > 0
+    assert [row["train/moe_bounded_sites"] for row in rows] == [3.0] * 3  # every expert site, every step: the held rows fit
     assert abs(last["train/ce"] - np.log(256)) < 0.2 and abs(last["train/ce_mtp"] - np.log(256)) < 0.2
     assert last["train/loss"] == last["train/ce"] + 0.3 * last["train/ce_mtp"] or abs(
         last["train/loss"] - last["train/ce"] - 0.3 * last["train/ce_mtp"]) < 1e-5
@@ -42,6 +43,7 @@ def test_three_steps_through_cli_train(tmp_path):
         registry = json.load(f)
     assert registry["train.moe_dropped"] == 0.0 and registry["train.moe_load_max_over_mean"] >= 1.0
     assert registry["train.tokens_per_s"] > 0 and registry["train.moe_assignments_here"] > 0
+    assert (registry["train.moe_bounded_sites"], registry["train.moe_sites"], registry["train.moe_capacity_rows"]) == (3.0, 3.0, 64.0)
     mgr = CheckpointManager(log_dir + "/ckpt")
     step, net, _ = mgr.restore_spec()
     mgr.close()

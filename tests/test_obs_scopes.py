@@ -361,15 +361,19 @@ def token_step(arch, lm, dtype="float32"):
     return step.lower(ts, batch, jax.ShapeDtypeStruct((2,), jnp.uint32))
 
 
-@pytest.mark.parametrize("dtype, digest", [("float32", "793e81ba1884fd98"), ("bfloat16", "9c566b9bf47247b4")])
+@pytest.mark.parametrize("dtype, digest", [("float32", "f096f8949bb69b6a"), ("bfloat16", "9756a3708728f7be")])
 def test_the_glm_step_is_the_program_it_was_before_kimi_linear(dtype, digest):
     """models/lm.py and ops/lm.py serve two archs since PR 33 (a mixer chosen
     by a pattern, `mla_attention` with two options, a block in two halves).
     The toy `glm4_moe_lite` step's lowered module (StableHLO text) is pinned
-    by digest: both are what 15ed7fc, PR 33's parent, lowers (taken there and
-    here with `token_step("glm4_moe_lite", test_lm.LM, dtype).as_text()`,
-    the tile and loss blocks as shipped; jax 0.9.0). A change that means to
-    alter GLM's step takes a new digest, and says so."""
+    by digest (taken with `token_step("glm4_moe_lite", test_lm.LM,
+    dtype).as_text()`, the tile and loss blocks as shipped; jax 0.9.0). From
+    15ed7fc, PR 33's parent, through PR 33 it was 793e81ba1884fd98 /
+    9c566b9bf47247b4. PR 34 ALTERED IT ON PURPOSE: every expert layer is one
+    `lax.cond` between a branch over `capacity_rows` rows and the full-length
+    body, forward and backward (ops/lm.py `_held_experts`), and the step
+    reports `moe_bounded_sites`. A change that means to alter GLM's step
+    takes a new digest, and says so."""
     import hashlib
 
     from test_lm import LM
@@ -414,8 +418,11 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
         # version 2: the conv + BN pair's forward and custom backward (PR 26)
+        # PR 34 added a `moe_combine` and a `moe_dispatch` site twice over (the expert layer's two branches) in a step
+        # whose program changed with them, so its cache key moved anyway, and no other step holds them: no bump
         "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "mlp", "moe_combine",
-                      "moe_dispatch", "moe_experts", "moe_router", "norm", "rope"],
+                      "moe_combine", "moe_combine", "moe_dispatch", "moe_dispatch", "moe_dispatch", "moe_experts", "moe_router", "norm",
+                      "rope"],
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name
         # in a compiled cell moved, so no bump
         # version 5: Kimi Delta Attention's scopes (PR 33)

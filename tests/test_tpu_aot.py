@@ -260,3 +260,47 @@ def test_the_token_step_runs_the_attention_forward_kernel_once_a_layer(token_ste
     assert kernels == ([("causal_attention_bwd", "attn_core", "bwd")] * sites
                        + [("causal_attention_fwd", "attn_core", "fwd")] * sites)
     assert len(re.findall(r"^\s*%?causal_attention_[\w.]+ = .* custom-call\(", text, re.M)) == 2 * sites  # none outside ENTRY
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation: its instruction lines}."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s*->.*\{\s*$", line)
+        if head is not None:
+            name = head.group(1)
+            found[name] = []
+        elif name is not None:
+            found[name].append(line)
+    return found
+
+
+def test_an_expert_site_is_one_conditional_each_way_and_its_bounded_branch_holds_one_gather_of_all_rows(token_step_hlo):
+    """The small step's two expert sites (an expert layer and the MTP block;
+    2 x 512 tokens, top-2 of 16 experts, 2 held: 2,048 assignment rows of
+    width 256, capacity 512) compile to ONE `conditional` a site in the
+    forward pass and one in the backward (the layer checkpoint's second run
+    needs neither branch: the backward's own `cond` differentiates the branch
+    it takes), on `held rows <= capacity`. The bounded branch holds exactly
+    one tensor of every assignment's row by the width, the gather that sums
+    the held rows into token rows (forward: the experts' output; backward: the
+    held rows' gradient), where the full-length branch holds five or more."""
+    _, text = token_step_hlo
+    computations = _computations(text)
+    assignments, width = 2 * 512 * 2, 256
+    sites = re.findall(r"\sconditional\(.*branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}", text)
+    assert len(sites) == 2 * 2
+
+    def full_length(computation):
+        """Instructions of the branch's own computation whose output is a whole (assignments, width), reshapes apart."""
+        found = []
+        for line in computations[computation]:
+            m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+([\w\-]+)\(", line)
+            if m is not None and m.group(3) not in ("reshape", "bitcast", "get-tuple-element"):
+                found += [m.group(1) for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(2))
+                          if np.prod([int(d) for d in dims.split(",")]) >= assignments * width]
+        return found
+
+    for every_row, held_rows in sites:  # index 0 is the predicate's False
+        assert len(full_length(held_rows)) == 1, (held_rows, full_length(held_rows))
+        assert len(full_length(every_row)) >= 5, (every_row, full_length(every_row))

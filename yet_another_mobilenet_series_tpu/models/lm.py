@@ -122,6 +122,17 @@ class TokenModel:
     def kda_sites(self) -> int:
         return len(self.blocks_mixing_by("kda"))
 
+    @property
+    def expert_sites(self) -> int:
+        """The expert layers (`train.moe_sites`): what `moe_bounded_sites` reads on a
+        step whose every site's held assignments fit `expert_capacity_rows`."""
+        return sum(not self.is_dense(block) for block in self.block_names)
+
+    def expert_capacity_rows(self, sequences: int) -> int:
+        """`ops.capacity_rows` of every expert layer for a batch of `sequences` on one replica."""
+        c = self.lm
+        return ops.capacity_rows(sequences * c.seq_len * c.num_experts_per_tok, self.experts_held, c.n_routed_experts)
+
     def param_count(self) -> int:
         shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0))[0])
         return sum(int(x.size) for x in jax.tree.leaves(shapes))
@@ -339,6 +350,7 @@ class TokenModel:
             counters = {
                 "moe_assignments_here": sum(b["assignments_here"] for b in per_block),
                 "moe_dropped": sum(b["dropped"] for b in per_block),
+                "moe_bounded_sites": sum(b["bounded"] for b in per_block),
                 "moe_load_max_over_mean": jnp.max(jnp.stack([b["load_max_over_mean"] for b in per_block])),
             } if per_block else {}
         if lowest_by_block:

@@ -20,8 +20,11 @@ Two things keep a long sequence inside a chip's memory:
 - :func:`expert_layer` sorts the (token, expert) assignments so that those of
   the experts HELD HERE come first, grouped by expert, and multiplies them
   with `lax.ragged_dot` (a grouped matmul that skips the rows outside its
-  groups). Every assignment to a held expert is computed whatever the load:
-  nothing is dropped and there is no capacity. Assignments to experts that
+  groups). It gathers, multiplies and sums back `capacity_rows` rows, twice
+  what the share expects of the batch, a number the shapes give; ONE
+  `lax.cond` a site takes the same body over EVERY assignment row on a step
+  whose held assignments do not fit. So every assignment to a held expert is
+  computed whatever the load: nothing is dropped. Assignments to experts that
   other shares hold are left out; nothing stands in for them.
 """
 
@@ -260,23 +263,45 @@ def gated_mlp(p: dict, x: Array) -> Array:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of(x: Array, order: Array, inverse: Array, repeat: int) -> Array:
-    """`jnp.repeat(x, repeat, axis=0)[order]` for a PERMUTATION `order` of the
-    repeated rows, with inverse `inverse`, without holding the repeated array.
-    The cotangent is a gather by the inverse and a sum over each row's
-    copies, where autodiff would scatter-add."""
+def _rows_of(x: Array, order: Array, at: Array, repeat: int) -> Array:
+    """`jnp.repeat(x, repeat, axis=0)[order]` without holding the repeated
+    array, for `order` a permutation of the repeated rows or its first rows,
+    and `at[i]` where repeated row i went (`len(order)` for one that is not
+    among them). The cotangent is :func:`_sum_of_rows`, a gather, where
+    autodiff would scatter-add."""
     return x[order // repeat]
 
 
-def _rows_of_fwd(x, order, inverse, repeat):
-    return x[order // repeat], inverse
+def _rows_of_fwd(x, order, at, repeat):
+    return x[order // repeat], (order, at)
 
 
-def _rows_of_bwd(repeat, inverse, g):
-    return jnp.sum(g[inverse].reshape(-1, repeat, g.shape[-1]), axis=1), None, None
+def _rows_of_bwd(repeat, kept, g):
+    return _sum_of_rows(g, *kept, repeat), None, None
 
 
 _rows_of.defvjp(_rows_of_fwd, _rows_of_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_of_rows(x: Array, order: Array, at: Array, repeat: int) -> Array:
+    """The transpose of :func:`_rows_of`: out[t] = the sum of the rows
+    `x[at[i]]` over the `repeat` copies i of t, a copy that has no row (`at[i]`
+    = `len(x)`) counting zero; summed in float32 and rounded once. The
+    cotangent is `_rows_of`."""
+    rows = x.at[at].get(mode="fill", fill_value=0).reshape(-1, repeat, x.shape[-1])
+    return jnp.sum(rows.astype(jnp.float32), axis=1).astype(x.dtype)
+
+
+def _sum_of_rows_fwd(x, order, at, repeat):
+    return _sum_of_rows(x, order, at, repeat), (order, at)
+
+
+def _sum_of_rows_bwd(repeat, kept, g):
+    return _rows_of(g, *kept, repeat), None, None
+
+
+_sum_of_rows.defvjp(_sum_of_rows_fwd, _sum_of_rows_bwd)
 
 
 @jax.custom_vjp
@@ -314,6 +339,144 @@ def route(router_w: Array, bias: Array, x: Array, *, top_k: int, scaling: float)
         return ids, weights, load
 
 
+# Rows the capacity is a multiple of. Coarser than a sublane tile on purpose: a bounded branch
+# shorter than this saves nothing worth a second body, and at a dozen tokens (where
+# tests/benchmark_tests count every `ragged_dot` the forward traces) a site stays ONE body.
+CAPACITY_TILE = 64
+
+
+def capacity_rows(assignments: int, held: int, n_experts: int) -> int:
+    """The rows an expert layer's bounded branch works on: twice what a share
+    holding `held` of `n_experts` experts expects of `assignments` (tokens x
+    top_k), to a whole `CAPACITY_TILE`. Read from the shapes; nothing sets it."""
+    return -(-2 * assignments * held // (CAPACITY_TILE * n_experts)) * CAPACITY_TILE
+
+
+def _grouped_mlp(experts: dict, rows: Array, group_sizes: Array) -> Array:
+    """down(silu(gate row) * up row), each row by its group's expert; the rows
+    past the groups are not written."""
+    cd = rows.dtype
+    with scope("moe_experts"):
+        hidden = (jax.nn.silu(lax.ragged_dot(rows, experts["gate"].astype(cd), group_sizes))
+                  * lax.ragged_dot(rows, experts["up"].astype(cd), group_sizes))
+        return lax.ragged_dot(hidden, experts["down"].astype(cd), group_sizes)
+
+
+def _not_written(out: Array, count: Array) -> Array:
+    """How many of the `count` held assignments were not computed, counted from
+    what the grouped matmul WROTE, not from the ids: an assignment to a held
+    expert whose row came back all zero. `out` is zero wherever no held
+    assignment's row is."""
+    written = jnp.any(lax.stop_gradient(out) != 0, axis=-1)
+    return count.astype(jnp.float32) - jnp.sum(written.astype(jnp.float32))
+
+
+def _every_row(top_k: int, xf, weights, experts, order, inverse, group_sizes):
+    """The held assignments through their experts and back into token rows, at
+    full length: every assignment's row is gathered, goes through the grouped
+    matmuls (which skip the rows past the groups) and is un-sorted, whatever
+    the share holds. -> (y (tokens, h), the held assignments not computed)."""
+    cd = xf.dtype
+    tokens, h = xf.shape
+    count = jnp.sum(group_sizes)
+    with scope("moe_dispatch"):
+        in_group = (jnp.arange(order.shape[0]) < count)[:, None]
+        # rows outside the groups are never written by the grouped matmul, in
+        # either pass: a select keeps what they hold out of both
+        rows = jnp.where(in_group, _rows_of(xf, order, inverse, top_k), jnp.zeros((), cd))
+    out = _grouped_mlp(experts, rows, group_sizes)
+    with scope("moe_combine"):
+        out = jnp.where(in_group, out, jnp.zeros((), cd))
+        out = _unsort(out, order, inverse).reshape(tokens, top_k, h)
+        return jnp.sum(out * weights[..., None].astype(cd), axis=1), _not_written(out, count)
+
+
+def _held_rows(capacity: int, top_k: int, xf, weights, experts, order, inverse, group_sizes):
+    """The same, working on the first `capacity` rows of the sorted order,
+    which must hold every held assignment: those rows alone are gathered,
+    multiplied, weighted (in float32) and summed back into their tokens' rows
+    (in float32, rounded once)."""
+    cd = xf.dtype
+    count = jnp.sum(group_sizes)
+    with scope("moe_dispatch"):
+        first = order[:capacity]
+        live = (jnp.arange(capacity) < count)[:, None]
+        # where an assignment's row is; `capacity`, which is no row, for one that another share holds
+        at = jnp.where(inverse < count, inverse, capacity)
+        rows = jnp.where(live, _rows_of(xf, first, at, top_k), jnp.zeros((), cd))
+    out = _grouped_mlp(experts, rows, group_sizes)
+    with scope("moe_combine"):
+        out = jnp.where(live, out, jnp.zeros((), cd))
+        weight = _rows_of(weights.reshape(-1, 1), first, at, 1)
+        y = _sum_of_rows((out.astype(jnp.float32) * weight).astype(cd), first, at, top_k)
+        return y, _not_written(out, count)
+
+
+def _branches(top_k: int, capacity: int, assignments: int) -> list:
+    """A site's bodies, indexed by the predicate `held rows <= capacity`:
+    `_every_row`, and `_held_rows` where it is the shorter. A share holding
+    half the experts or more (the uncut layer) has the first alone."""
+    every_row = functools.partial(_every_row, top_k)
+    return [every_row, functools.partial(_held_rows, capacity, top_k)] if capacity < assignments else [every_row]
+
+
+def _one_of(branches: list, fits: Array, *operands):
+    """`branches[fits]` of `operands`: ONE `lax.cond`, which runs one branch."""
+    return lax.cond(fits, *branches[::-1], *operands) if len(branches) > 1 else branches[0](*operands)
+
+
+# Both halves are jitted so that a model's sites, which share their shapes, share ONE trace of the
+# two branches (and of their derivatives): traced a site at a time they cost a token cell ~2.5 s of set-up.
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _held_experts_forward(top_k: int, capacity: int, xf, weights, experts, order, inverse, group_sizes):
+    branches = _branches(top_k, capacity, order.shape[0])
+    fits = jnp.sum(group_sizes) <= capacity
+    y, dropped = _one_of(branches, fits, xf, weights, experts, order, inverse, group_sizes)
+    return y, dropped, (fits & (len(branches) > 1)).astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1), inline=True)
+def _held_experts_backward(top_k: int, capacity: int, xf, weights, experts, order, inverse, group_sizes, g_y):
+    def pulled_back(branch):
+        def fn(xf, weights, experts, order, inverse, group_sizes, g_y):
+            _, pull = jax.vjp(lambda *d: branch(*d, order, inverse, group_sizes)[0], xf, weights, experts)
+            return pull(g_y)
+        return fn
+
+    branches = [pulled_back(b) for b in _branches(top_k, capacity, order.shape[0])]
+    return _one_of(branches, jnp.sum(group_sizes) <= capacity, xf, weights, experts, order, inverse, group_sizes, g_y)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(top_k: int, capacity: int, xf, weights, experts, order, inverse, group_sizes):
+    """:func:`_held_rows` where this step's held assignments fit in `capacity`
+    rows, else :func:`_every_row`, which drops nothing whatever the load: one
+    `lax.cond` forward and one backward. -> (y, the held assignments not
+    computed, 1.0 where the bounded branch ran). The backward differentiates
+    the branch it takes INSIDE its own `cond`: differentiating through a
+    `cond` hands every branch's residuals across, the untaken one's as zeros
+    at full length."""
+    return _held_experts_forward(top_k, capacity, xf, weights, experts, order, inverse, group_sizes)
+
+
+def _held_experts_fwd(top_k, capacity, *operands):
+    return _held_experts_forward(top_k, capacity, *operands), operands
+
+
+def _held_experts_bwd(top_k, capacity, operands, g):
+    g_xf, g_weights, g_experts = _held_experts_backward(top_k, capacity, *operands, g[0])
+    # ONE of the `cond`'s outputs behind a barrier. Left free, XLA:TPU's conditional code motion sinks what reads the
+    # experts' gradients (the square sums of the clip and of the reported norms) into both branches of every site, and
+    # kimi_linear's executable quadruples (0.11 -> 0.40 GiB of code: +2.4 s to read it from the compile cache at every
+    # warm start). One output is enough to stop that; all three would cost GLM's step 0.65 GiB of temporaries, this one
+    # costs it 16 MiB (compiles for the described chip and chip runs, PERF.md, PR 34).
+    g_experts = {**g_experts, "down": lax.optimization_barrier(g_experts["down"])}
+    return g_xf, g_weights, g_experts, None, None, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def expert_layer(p: dict, bias: Array, x: Array, *, top_k: int, scaling: float, held: int,
                  share_index: int):
     """The routed experts of ONE expert-parallel share: routes every token
@@ -324,7 +487,6 @@ def expert_layer(p: dict, bias: Array, x: Array, *, top_k: int, scaling: float, 
     here. x (B, S, h) -> (y (B, S, h), load over all experts (E,), counters,
     the selected expert ids (B * S, top_k)).
     """
-    cd = x.dtype
     b, s, h = x.shape
     tokens = b * s
     xf = x.reshape(tokens, h)
@@ -337,27 +499,14 @@ def expert_layer(p: dict, bias: Array, x: Array, *, top_k: int, scaling: float, 
         order = jnp.argsort(jnp.where(here, flat - first, held), stable=True)
         inverse = jnp.argsort(order)
         group_sizes = load[first:first + held].astype(jnp.int32)
-        in_group = (jnp.arange(flat.shape[0]) < jnp.sum(group_sizes))[:, None]
-        rows = _rows_of(xf, order, inverse, top_k)
-        # rows outside the groups are never written by the grouped matmul, in
-        # either pass: a select keeps what they hold out of both
-        rows = jnp.where(in_group, rows, jnp.zeros((), cd))
-    with scope("moe_experts"):
-        e = p["experts"]
-        hidden = (jax.nn.silu(lax.ragged_dot(rows, e["gate"].astype(cd), group_sizes))
-                  * lax.ragged_dot(rows, e["up"].astype(cd), group_sizes))
-        out = lax.ragged_dot(hidden, e["down"].astype(cd), group_sizes)
+    y, dropped, bounded = _held_experts(top_k, capacity_rows(tokens * top_k, held, load.shape[0]),
+                                        xf, weights, p["experts"], order, inverse, group_sizes)
     with scope("moe_combine"):
-        out = jnp.where(in_group, out, jnp.zeros((), cd))
-        out = _unsort(out, order, inverse).reshape(tokens, top_k, h)
-        y = jnp.sum(out * weights[..., None].astype(cd), axis=1)
-        # counted from what the grouped matmul WROTE, not from the ids: an assignment to a
-        # held expert whose row came back all zero was not computed
-        written = jnp.any(lax.stop_gradient(out) != 0, axis=-1)
         held_load = group_sizes.astype(jnp.float32)
         counters = {
             "assignments_here": jnp.sum(here.astype(jnp.float32)),
-            "dropped": jnp.sum((here.reshape(tokens, top_k) & ~written).astype(jnp.float32)),
+            "dropped": dropped,
+            "bounded": bounded,
             "load_max_over_mean": jnp.max(held_load) / jnp.maximum(jnp.mean(held_load), 1.0),
         }
     return y.reshape(b, s, h), load, counters, ids

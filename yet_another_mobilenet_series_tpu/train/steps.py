@@ -254,8 +254,12 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     get_registry().gauge("train.attn_kept_sites").set(sites)
     get_registry().gauge("train.kda_sites").set(net.kda_sites)
     get_registry().gauge("train.kda_kept_sites").set(net.kda_sites)
+    # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
+    get_registry().gauge("train.moe_sites").set(net.expert_sites)
 
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
+        # read where the step is traced, from one replica's batch: the rows of a site's bounded branch
+        get_registry().gauge("train.moe_capacity_rows").set(net.expert_capacity_rows(batch["tokens"].shape[0]))
         return net.loss(params, state, batch, compute_dtype=compute_dtype, axis_name=axis_name)
 
     def report(aux, batch, grads):
