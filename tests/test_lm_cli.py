@@ -98,3 +98,42 @@ def test_three_steps_of_kimi_linear_through_cli_train(tmp_path):
     mgr.close()
     assert step == 3 and isinstance(net, TokenModel) and net.arch == "kimi_linear" and net.experts_held == 2
     assert [net.mixer(b) for b in net.block_names] == ["kda", "kda", "kda", "attn", "kda"]
+
+
+OURO_APP = os.path.join(REPO, "yet_another_mobilenet_series_tpu", "apps", "ouro_2_6b_depth8.yml")
+OURO_TOY = ["model.num_classes=256", "model.lm.hidden_size=64", "model.lm.num_hidden_layers=2",
+            "model.lm.first_k_dense_replace=2", "model.lm.num_attention_heads=4", "model.lm.num_key_value_heads=4",
+            "model.lm.head_dim=16", "model.lm.intermediate_size=160", "model.lm.seq_len=32"]
+
+
+def test_three_steps_of_the_looped_arch_through_cli_train(tmp_path, capsys):
+    """The third arch through the same entry point: apps/ouro_2_6b_depth8.yml
+    at a toy size, 2 layers run 4 times a step, the banner of a model without
+    an expert layer, the loop's gauges and exit statistics at the log boundary,
+    eval on the last loop step's head, a checkpoint that restores as the same
+    TokenModel."""
+    log_dir = str(tmp_path / "log")
+    final = cli_train.main([f"app:{OURO_APP}", *OURO_TOY, "data.fake_train_size=3", "train.epochs=1",
+                            "train.log_every=1", f"train.log_dir={log_dir}", "dist.num_devices=1"])
+    assert final["epoch"] == 1.0 and final["eval_n"] == 2 * 32 and np.isfinite(final["eval_loss"])
+    banner = [line for line in capsys.readouterr().out.splitlines() if "model ouro" in line]
+    assert banner and "no expert layer; 2 layers run 4 times a step; 256 vocabulary rows" in banner[0]
+    assert "experts a layer" not in banner[0]
+    with open(os.path.join(log_dir, "metrics.jsonl")) as f:
+        rows = [json.loads(line) for line in f if '"train/' in line]
+    assert len(rows) == 3
+    last = rows[-1]
+    assert all(abs(last[f"train/ce_step_{r}"] - np.log(256)) < 0.2 for r in (1, 2, 3, 4))
+    assert abs(last["train/loss"] - (last["train/ce"] - 0.1 * last["train/exit_entropy"])) < 1e-5
+    assert 1.8 < last["train/expected_exit_step"] < 1.95 and "train/gnorm/exit_gate" in last
+    assert not [k for k in last if k.startswith("train/moe_") or k.endswith("ce_mtp")]
+    with open(os.path.join(log_dir, "obs_registry.json")) as f:
+        registry = json.load(f)
+    assert (registry["train.loop_steps"], registry["train.layer_applications"], registry["train.attn_sites"],
+            registry["train.moe_sites"], registry["train.moe_capacity_rows"]) == (4.0, 8.0, 2.0, 0.0, 0.0)
+    assert registry["train.expected_exit_step"] == last["train/expected_exit_step"] and registry["train.tokens_per_s"] > 0
+    assert 0.1 < registry["train.exit_p_last"] < 0.15 and 1.15 < registry["train.exit_entropy"] < 1.25
+    mgr = CheckpointManager(log_dir + "/ckpt")
+    step, net, _ = mgr.restore_spec()
+    mgr.close()
+    assert step == 3 and isinstance(net, TokenModel) and net.arch == "ouro" and net.loop_steps == 4

@@ -33,6 +33,7 @@ TOY = ("model.block_specs=[{exp: 16, c: 16, n: 1, s: 2, k: 3, act: relu}, "
 TOKEN_SCOPES = {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "moe_router", "moe_dispatch",
                 "moe_experts", "moe_combine", "mtp_merge", "lm_head"}
 KDA_SCOPES = {"kda_proj", "kda_conv", "kda_gate", "kda_core", "kda_norm"}
+LOOP_SCOPES = {"exit_gate"}  # a looped model's (`ouro`)
 
 
 def lowered_step(*overrides, chips: int = 1):
@@ -109,7 +110,8 @@ def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
     seen = {scope for scope, _ in scopes.scope_table(toy_text).values()}
     # everything on the list except the collectives (one chip), AtomNAS (no
     # masks, no penalty), the guard (off) and the token models' scopes
-    expect = set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES - KDA_SCOPES
+    expect = (set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES - KDA_SCOPES
+              - LOOP_SCOPES)
     assert expect <= seen, f"missing: {sorted(expect - seen)}"
     assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
 
@@ -395,6 +397,23 @@ def test_kimi_linear_scopes_resolve():
     assert {(name, phase) for name in ("kda_core", "kda_proj", "kda_conv", "attn_core") for phase in ("fwd", "bwd")} <= seen
 
 
+def test_a_looped_models_scopes_resolve_and_the_exit_gate_in_both_phases():
+    """`ouro`'s step on the CPU: the exit gate's scope is in its table forward
+    AND backward (the gate learns through the exit distribution), beside the
+    family's scopes that this arch has (`rope` on the whole head; no expert
+    layer, no `mtp_merge`), the sandwich's norms and residual adds, and the
+    attention core under both phases."""
+    from test_lm_ouro import OURO
+
+    text = token_step("ouro", OURO).compile().as_text()
+    seen = set(scopes.scope_table(text).values())
+    names = {scope for scope, _ in seen}
+    assert {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "lm_head", "exit_gate", "loss", "optim",
+            "residual"} <= names
+    assert not {"moe_router", "moe_dispatch", "moe_experts", "moe_combine", "mtp_merge"} & names and not KDA_SCOPES & names
+    assert {(name, phase) for name in ("exit_gate", "attn_core", "norm", "residual") for phase in ("fwd", "bwd")} <= seen
+
+
 def test_taxonomy_version_is_pinned_to_the_scope_sites():
     """The compile cache's key carries TAXONOMY_VERSION (utils/compile_cache.py)
     because JAX leaves metadata out of it: a scope added, renamed or moved
@@ -412,15 +431,20 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
     assert (scopes.TAXONOMY_VERSION, sites) == (5, {
         # versions 3 and 4: the token-model family's scopes (PR 27; 3 was its first draft, whose
         # executables may still sit in a chip machine's cache)
-        "models/lm.py": ["embed", "kda_gate", "lm_head", "loss", "loss", "moe_combine", "moe_router", "mtp_merge",
-                         "residual", "residual", "residual", "rope"],
+        # PR 35 added `exit_gate` (two sites), two `residual` sites, an `embed` and a `rope` site in models/lm.py and
+        # two `attn_proj` sites in ops/lm.py, all of them in the step of a NEW arch (`ouro`), whose cache key is new
+        # anyway; no older step holds one of them: no bump (a bump would cost every cell one cold start)
+        "models/lm.py": ["embed", "embed", "exit_gate", "exit_gate", "kda_gate", "lm_head", "loss", "loss", "moe_combine",
+                         "moe_router", "mtp_merge", "residual", "residual", "residual", "residual", "residual", "rope",
+                         "rope"],
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
         # version 2: the conv + BN pair's forward and custom backward (PR 26)
         # PR 34 added a `moe_combine` and a `moe_dispatch` site twice over (the expert layer's two branches) in a step
         # whose program changed with them, so its cache key moved anyway, and no other step holds them: no bump
-        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "mlp", "moe_combine",
+        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "attn_proj",
+                      "attn_proj", "mlp", "moe_combine",
                       "moe_combine", "moe_combine", "moe_dispatch", "moe_dispatch", "moe_dispatch", "moe_experts", "moe_router", "norm",
                       "rope"],
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name

@@ -304,3 +304,59 @@ def test_an_expert_site_is_one_conditional_each_way_and_its_bounded_branch_holds
     for every_row, held_rows in sites:  # index 0 is the predicate's False
         assert len(full_length(held_rows)) == 1, (held_rows, full_length(held_rows))
         assert len(full_length(every_row)) >= 5, (every_row, full_length(every_row))
+
+
+# The looped arch at its cell's size (ouro26b_train_1x8k): apps/ouro_2_6b_depth8.yml as shipped, 1 x 8,192 tokens
+OURO_APP = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "yet_another_mobilenet_series_tpu",
+                        "apps", "ouro_2_6b_depth8.yml")
+HBM_GIB = 15.75  # what a v5e chip's 16 GB leave a program
+
+
+@pytest.fixture(scope="module")
+def ouro_step(one_chip):
+    """(model, compiled train step) of `ouro_2_6b_depth8` at the PUBLISHED widths
+    for the described chip, built as every runner builds it (`parallel/dp.py`
+    on a one-device mesh): 612 M parameters, 8 layers run 4 times (~1 min)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from yet_another_mobilenet_series_tpu.config import parse_cli
+    from yet_another_mobilenet_series_tpu.models import get_model
+    from yet_another_mobilenet_series_tpu.parallel import dp, mesh as mesh_lib
+    from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
+
+    cfg = parse_cli([f"app:{OURO_APP}", "dist.num_devices=1"])
+    net = get_model(cfg.model)
+    mesh = Mesh(np.asarray([next(iter(one_chip.device_set))]), (mesh_lib.DATA_AXIS,))
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 1, 1000, cfg.train.epochs)
+    params_example, _ = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))
+    optimizer = optim.make_optimizer(cfg.optim, lr_fn, params_example)
+    step = dp.make_dp_train_step(net, cfg, optimizer, lr_fn, mesh, params_example=params_example)
+    replicated = NamedSharding(mesh, P())
+    ts = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated),
+                      jax.eval_shape(lambda: steps.init_train_state(net, cfg, optimizer, jax.random.PRNGKey(0))))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, cfg.model.lm.seq_len + 2), jnp.int32,
+                                            sharding=NamedSharding(mesh, P(mesh_lib.DATA_AXIS)))}
+    return net, step.lower(ts, batch, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=replicated)).compile()
+
+
+def test_the_looped_step_fuses_every_attention_site_and_runs_its_forward_once_an_application(ouro_step):
+    """All 8 layers' attention (head dims 128 / 128, 8,192 rows in tiles of
+    512) take the fused kernels, and the compiled step holds ONE forward kernel
+    and one backward kernel a layer APPLICATION (8 layers x 4 loop steps): the
+    layer checkpoint keeps `attn_out` and `attn_lse` in every application."""
+    net, compiled = ouro_step
+    assert net.param_count() == 612_438_017
+    assert net.attention_sites(jnp.bfloat16) == (8, 8) and net.layer_applications == 32
+    text = compiled.as_text()
+    for half in ("fwd", "bwd"):
+        assert len(re.findall(rf"^\s*%?causal_attention_{half}[\w.]* = .* custom-call\(", text, re.M)) == 32, half
+
+
+def test_the_looped_step_fits_the_chip_at_eight_layers(ouro_step):
+    """Arguments (weights and both Adam moments: 6.84 GiB; the step donates
+    them) plus the program's temporaries (gradients, 32 applications' kept
+    tensors, one loss block) stay under a v5e's 15.75 GiB."""
+    m = ouro_step[1].memory_analysis()
+    peak = m.argument_size_in_bytes + m.temp_size_in_bytes + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert 6.8 < m.argument_size_in_bytes / 2**30 < 6.9
+    assert 12.0 < peak / 2**30 < HBM_GIB - 0.5, peak / 2**30

@@ -454,10 +454,11 @@ def run(cfg: Config) -> dict:
 def _run_impl(cfg: Config, log: Logger, mesh, is_coord: bool, tracer, watchdog) -> dict:
     net = get_model(cfg.model, cfg.data.image_size)
     if isinstance(net, TokenModel):
+        held = (f"{net.experts_held} of {net.lm.n_routed_experts} experts a layer, share {net.lm.expert_share_index} of "
+                f"{net.lm.expert_shares}" if net.expert_sites else
+                f"no expert layer; {net.lm.num_hidden_layers} layers run {net.loop_steps} times a step")
         log.log(f"model {net.arch}: {net.param_count()/1e6:.2f}M params held here "
-                f"({net.experts_held} of {net.lm.n_routed_experts} experts a layer, share "
-                f"{net.lm.expert_share_index} of {net.lm.expert_shares}; {net.vocab} vocabulary rows; "
-                f"{net.lm.seq_len} tokens a sequence)")
+                f"({held}; {net.vocab} vocabulary rows; {net.lm.seq_len} tokens a sequence)")
     else:
         prof = profile_network(net)
         arch_name = cfg.model.network_spec or f"{cfg.model.arch} x{cfg.model.width_mult}"
@@ -717,11 +718,11 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                     reg.gauge("train.step").set(step_i)
                     if isinstance(trainer.net, TokenModel):
                         # a sequence is this loop's "image"; the expert layer's counters
-                        # (ops/lm.py) are step scalars like any other
+                        # (ops/lm.py) and a looped model's exit statistics are step scalars like any other
                         reg.gauge("train.tokens_per_s").set(
                             snap.get("images_per_sec", 0.0) * trainer.net.lm.seq_len)
                         for name in ("moe_assignments_here", "moe_load_max_over_mean", "moe_dropped", "moe_bounded_sites",
-                                     "kda_min_chunk_log_decay"):
+                                     "kda_min_chunk_log_decay", "expected_exit_step", "exit_p_last", "exit_entropy"):
                             if name in snap:
                                 reg.gauge("train." + name).set(snap[name])
                     if cfg.prune.enable:

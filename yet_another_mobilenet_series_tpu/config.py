@@ -119,16 +119,19 @@ class LinearAttnConfig:
 @dataclass(frozen=True)
 class LMConfig:
     """Shapes of a token model (token family: ``glm4_moe_lite``,
-    ``kimi_linear``; models/lm.py), under the keys of the published
+    ``kimi_linear``, ``ouro``; models/lm.py), under the keys of the published
     ``config.json``. The defaults are GLM-4.7-Flash's widths.
     ``model.num_classes`` is the number of vocabulary rows held here
     (embedding, head, token ids and the loss are over that slice).
 
-    The model is ONE SHARE of an expert-parallel deployment: the router keeps
-    its published width ``n_routed_experts`` and its ``num_experts_per_tok``;
-    this share holds ``n_routed_experts / expert_shares`` experts of every
-    expert layer, those of index ``expert_share_index``, and computes their
-    part of the result. What the absent experts would add is left out."""
+    A model WITH expert layers (``glm4_moe_lite``, ``kimi_linear``) is ONE
+    SHARE of an expert-parallel deployment: the router keeps its published
+    width ``n_routed_experts`` and its ``num_experts_per_tok``; this share
+    holds ``n_routed_experts / expert_shares`` experts of every expert layer,
+    those of index ``expert_share_index``, and computes their part of the
+    result. What the absent experts would add is left out. A model without
+    one (``ouro``: ``first_k_dense_replace`` = every layer) is whole, layer by
+    layer: it reads none of the expert, latent or MTP keys."""
 
     hidden_size: int = 2048
     # dense + expert layers held here (the MTP module is counted apart)
@@ -162,6 +165,14 @@ class LMConfig:
     # the router's selection bias moves by rate * sign(mean load - load) a step
     router_bias_rate: float = 1e-3
     init_std: float = 0.02
+    # `ouro` alone reads the four below. Plain multi-head attention: heads of `head_dim` channels, all of them
+    # rotated; as many key/value heads as query heads (None = that many; any other count is refused)
+    num_key_value_heads: int | None = None
+    head_dim: int | None = None
+    # the layer stack runs this many times a step with the SAME weights; head, loss and exit gate after every run
+    total_ut_steps: int = 1
+    # beta: the loss is E_exit[CE] - beta * H(exit distribution)
+    exit_entropy_weight: float = 0.1
 
 
 @dataclass(frozen=True)

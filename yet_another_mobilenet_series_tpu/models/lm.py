@@ -1,26 +1,39 @@
 """The token-model family beside `specs.Network` (token family:
-`glm4_moe_lite`, `kimi_linear`): token embedding, dense blocks then expert
-blocks, each behind a token MIXER, an untied head, cross-entropy over the
-vocabulary slice held here. `glm4_moe_lite` (GLM-4.7-Flash) mixes by
+`glm4_moe_lite`, `kimi_linear`, `ouro`): token embedding, dense blocks then
+expert blocks, each behind a token MIXER, an untied head, cross-entropy over
+the vocabulary slice held here. `glm4_moe_lite` (GLM-4.7-Flash) mixes by
 multi-head latent attention in every block and has one
 multi-token-prediction module; `kimi_linear` (Kimi-Linear-48B-A3B) mixes by
 Kimi Delta Attention (ops/lm_kda.py) in the layers its
 `linear_attn_config.kda_layers` names and by latent attention without
 rotation and without a low-rank q in its `full_attn_layers`, and has no MTP.
-An arch is a name: what a block holds is read from `config.LMConfig` alone.
+What a block of those two holds is read from `config.LMConfig` alone.
 
-A `TokenModel` is one SHARE of an expert-parallel deployment (config.LMConfig):
-the mixers are whole, each expert layer holds `experts_held` of the
-`n_routed_experts` the router scores, embedding and head hold `vocab` rows.
-On one chip the share runs without an exchange, and computes exactly its own
-part: ops/lm.py `expert_layer`.
+`ouro` (Ouro-2.6B, a LOOPED model) is the arch whose name says more than its
+sizes: every block mixes by plain multi-head attention with the whole head
+rotated and a dense MLP, each sub-layer between TWO RMSNorms (one before, one
+on its output, before the residual add); the stack of `num_hidden_layers`
+blocks runs `total_ut_steps` times a step with the SAME weights, the final
+norm inside the loop; head, cross-entropy and an exit gate follow every run,
+and the loss is the cross-entropy's expectation over a learned exit
+distribution less `exit_entropy_weight` times that distribution's entropy
+(`TokenModel._looped`, `_expected_loss`). Not on this path: the early exit at
+inference (`early_exit_threshold`), a key/value cache a (loop step, layer),
+and training the gate alone against a frozen model.
+
+A `TokenModel` with expert layers is one SHARE of an expert-parallel
+deployment (config.LMConfig): the mixers are whole, each expert layer holds
+`experts_held` of the `n_routed_experts` the router scores, embedding and head
+hold `vocab` rows. On one chip the share runs without an exchange, and
+computes exactly its own part: ops/lm.py `expert_layer`. `ouro` has no expert
+layer: what is held of it is whole.
 
 The train step takes the family through three methods where it takes a
 `Network` through `apply` (train/steps.py): `init`, `loss` (tokens in,
 `(loss, (new_state, scalars))` out) and `eval_counts`. The only state is the
 router's selection bias of each expert layer, which the forward moves once a
 step from the step's own counts: non-gradient state, carried where a CNN
-carries its BatchNorm statistics. The plain float32 reference of the same
+carries its BatchNorm statistics (`ouro` has none: `{}`). The plain float32 reference of the same
 equations is models/lm_reference.py, which shares no function with this file.
 """
 
@@ -40,7 +53,7 @@ from ..obs.scopes import scope
 from ..ops import lm as ops
 from ..ops import lm_attention, lm_kda
 
-LM_ARCHS = ("glm4_moe_lite", "kimi_linear")
+LM_ARCHS = ("glm4_moe_lite", "kimi_linear", "ouro")
 # What a layer's checkpoint keeps, by name, beside its input: attention's output and row log-sum-exp, the
 # KDA scan's output and chunk-boundary states: what the two cores' backwards read, so neither forward runs twice.
 KEPT_NAMES = (ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME, lm_kda.KDA_OUT_NAME, lm_kda.KDA_STATES_NAME)
@@ -73,6 +86,20 @@ class TokenModel:
         names = tuple(f"layer_{i}" for i in range(self.lm.num_hidden_layers))
         return names + (("mtp",) if self.lm.num_nextn_predict_layers else ())
 
+    @property
+    def looped(self) -> bool:
+        return self.arch == "ouro"
+
+    @property
+    def loop_steps(self) -> int:
+        """How often a step runs the layer stack (`train.loop_steps`): `total_ut_steps` of a looped model, else 1."""
+        return self.lm.total_ut_steps if self.looped else 1
+
+    @property
+    def layer_applications(self) -> int:
+        """Blocks run a step (`train.layer_applications`): what the layer checkpoints' kept tensors are counted by."""
+        return len(self.block_names) * self.loop_steps
+
     def is_dense(self, block: str) -> bool:
         return block != "mtp" and int(block.split("_")[1]) < self.lm.first_k_dense_replace
 
@@ -102,20 +129,35 @@ class TokenModel:
             raise ValueError(f"qk_rope_head_dim must be even where a layer mixes by latent attention "
                              f"({', '.join(self.blocks_mixing_by('attn'))})")
         if not 0 < c.first_k_dense_replace <= c.num_hidden_layers:
-            raise ValueError("first_k_dense_replace must be in [1, num_hidden_layers]")
+            raise ValueError("first_k_dense_replace (the leading layers whose MLP is dense: every layer of a model "
+                             "without expert layers) must be in [1, num_hidden_layers]")
+        if self.looped:
+            if c.num_key_value_heads not in (None, c.num_attention_heads):
+                raise ValueError(f"arch ouro is plain multi-head attention: num_key_value_heads {c.num_key_value_heads} "
+                                 f"is not num_attention_heads {c.num_attention_heads}, and grouped heads are not guessed")
+            if not c.head_dim or c.head_dim % 2:
+                raise ValueError(f"arch ouro rotates the whole head: head_dim must be even, not {c.head_dim}")
+            if c.first_k_dense_replace != c.num_hidden_layers or c.num_nextn_predict_layers or kda or full:
+                raise ValueError("arch ouro has no expert layer, no MTP module and no layer pattern: "
+                                 "first_k_dense_replace = num_hidden_layers, num_nextn_predict_layers = 0, "
+                                 "linear_attn_config empty")
+            if c.total_ut_steps < 1:
+                raise ValueError("total_ut_steps must be at least 1")
 
     def attention_sites(self, compute_dtype) -> tuple[int, int]:
-        """(latent-attention layers, those whose shapes ops/lm_attention.py's
-        fused kernels take): what `train.attn_sites` reports and, where the
-        step is lowered for a TPU, `train.attn_fused_sites` (train/steps.py).
-        The predicate is the one `ops.causal_attention` dispatches on. KDA
-        layers are `kda_sites`."""
+        """(layers that mix by softmax attention, latent or `ouro`'s plain
+        multi-head, those whose shapes ops/lm_attention.py's fused kernels
+        take): what `train.attn_sites` reports and, where the step is lowered
+        for a TPU, `train.attn_fused_sites` (train/steps.py). The predicate is
+        the one `ops.causal_attention` dispatches on. A site is a LAYER: a
+        looped model calls each `loop_steps` times a step
+        (`layer_applications`). KDA layers are `kda_sites`."""
         c = self.lm
         sites = len(self.blocks_mixing_by("attn"))
         block = min(ops.ATTN_BLOCK, c.seq_len)
-        wide = lm_attention.fitting_qk_dim(c.seq_len, block, c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim,
-                                           compute_dtype)  # q and k as `ops.causal_attention` hands them on
-        fits = lm_attention.fuses(c.seq_len, block, wide, c.v_head_dim, compute_dtype)
+        qk_dim, v_dim = (c.head_dim, c.head_dim) if self.looped else (c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim)
+        wide = lm_attention.fitting_qk_dim(c.seq_len, block, qk_dim, v_dim, compute_dtype)  # as `ops.causal_attention` hands q, k on
+        fits = lm_attention.fuses(c.seq_len, block, wide, v_dim, compute_dtype)
         return sites, sites if fits else 0
 
     @property
@@ -131,6 +173,8 @@ class TokenModel:
     def expert_capacity_rows(self, sequences: int) -> int:
         """`ops.capacity_rows` of every expert layer for a batch of `sequences` on one replica."""
         c = self.lm
+        if not self.expert_sites:
+            return 0
         return ops.capacity_rows(sequences * c.seq_len * c.num_experts_per_tok, self.experts_held, c.n_routed_experts)
 
     def param_count(self) -> int:
@@ -151,6 +195,12 @@ class TokenModel:
                     "down": w(name + "d", *lead, width, h)}
 
         p = {"attn_norm": jnp.ones((h,), jnp.float32), "mlp_norm": jnp.ones((h,), jnp.float32)}
+        if self.looped:  # a sandwich block: a second gain a sub-layer, on its output; q, k, v, o whole
+            wide = heads * c.head_dim
+            p.update(attn_out_norm=jnp.ones((h,), jnp.float32), mlp_out_norm=jnp.ones((h,), jnp.float32),
+                     attn={"q": w("q", h, wide), "k": w("k", h, wide), "v": w("v", h, wide), "o": w("o", wide, h)},
+                     mlp=mlp("mlp", c.intermediate_size))
+            return p
         if self.mixer(block) == "kda":
             p["kda"] = self._init_kda(key, w)
         else:
@@ -196,8 +246,9 @@ class TokenModel:
 
     def init(self, key) -> tuple[dict, dict]:
         """(params, state): float32 weights ~ N(0, init_std), norm gains 1 (a
-        KDA mixer's decay parameters: `_init_kda`); state = each expert
-        block's router bias, zeros."""
+        KDA mixer's decay parameters: `_init_kda`; a looped model's exit gate:
+        zeros, so that a fresh gate exits with probability 1/2 after every
+        step); state = each expert block's router bias, zeros."""
         self.validate()
         c = self.lm
         h = c.hidden_size
@@ -208,6 +259,8 @@ class TokenModel:
         }
         for i, block in enumerate(self.block_names):
             params[block] = self._init_block(jax.random.fold_in(key, 1000 + i), block)
+        if self.looped:
+            params["exit_gate"] = {"w": jnp.zeros((h,), jnp.float32), "b": jnp.zeros((), jnp.float32)}
         if c.num_nextn_predict_layers:
             params["mtp"].update(
                 eh_proj=c.init_std * jax.random.normal(jax.random.fold_in(key, _key("eh")), (2 * h, h)),
@@ -252,10 +305,17 @@ class TokenModel:
         with scope("residual"):
             return x + shared + routed, (load, counters, ids)
 
-    def _head_loss(self, head_w, hidden, targets):
+    def _seq_of(self, tokens) -> int:
+        if tokens.shape[1] != self.lm.seq_len + 2:
+            raise ValueError(f"a batch row holds {tokens.shape[1]} ids, model.lm.seq_len + 2 = {self.lm.seq_len + 2} expected")
+        return self.lm.seq_len
+
+    def _head_loss(self, head_w, hidden, targets, per_token: bool = False):
         """Summed cross-entropy, and how many targets rank first and among the
         first five, over a (tokens, h) block: float32 logits over the slice,
-        never more than `LOSS_BLOCK` tokens of them at once."""
+        never more than `LOSS_BLOCK` tokens of them at once. `per_token`: and
+        every token's cross-entropy, (tokens,), which a looped model weights
+        by its exit distribution."""
         tokens = hidden.shape[0]
         block = min(LOSS_BLOCK, tokens)
         if tokens % block:
@@ -271,12 +331,81 @@ class TokenModel:
                 above = jnp.sum(lax.stop_gradient(logits) > lax.stop_gradient(own)[:, None], axis=-1)
                 out = jnp.stack([jnp.sum(nll), jnp.sum(above < 1).astype(jnp.float32),
                                  jnp.sum(above < 5).astype(jnp.float32)])
-            return carry + out, None
+            return carry + out, (nll if per_token else None)
 
         chunk = jax.checkpoint(chunk)
         xs = (hidden.reshape(tokens // block, block, -1), targets.reshape(tokens // block, block))
-        totals, _ = lax.scan(chunk, jnp.zeros((3,), jnp.float32), xs)
-        return totals
+        totals, nll = lax.scan(chunk, jnp.zeros((3,), jnp.float32), xs)
+        return (totals, nll.reshape(tokens)) if per_token else totals
+
+    def _sandwich(self, p: dict, x, cos, sin):
+        """A looped model's block: y = x + N(Attn(N(x))), then y + N(MLP(N(y))), four gains."""
+        c = self.lm
+        a = ops.mha_attention(p["attn"], ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps), cos, sin,
+                              heads=c.num_attention_heads, head_dim=c.head_dim)
+        a = ops.rms_norm(a, p["attn_out_norm"], c.rms_norm_eps)
+        with scope("residual"):
+            x = x + a
+        m = ops.rms_norm(ops.gated_mlp(p["mlp"], ops.rms_norm(x, p["mlp_norm"], c.rms_norm_eps)),
+                         p["mlp_out_norm"], c.rms_norm_eps)
+        with scope("residual"):
+            return x + m
+
+    def _looped(self, params, tokens, compute_dtype):
+        """A looped model's forward: tokens (B, seq_len + 2; the last id is not
+        read) -> (head totals (R, 3), every token's cross-entropy (R, B * S),
+        every token's exit-gate logit (R, B * S)), one row a loop step. Step r
+        reads step r - 1's output AFTER the final norm, which is inside the
+        loop; positions, and so the rotation, are the same in every step. The
+        loop is written out, R x layers blocks in one program: as ONE
+        `lax.scan` body it compiles in 20 s where this takes 51, steps 0.13%
+        slower and holds 1.5 GiB more (the weight gradients' sums ride in the
+        scan's carry), which at the cell's size passes the chip's memory as
+        the benchmark sums it (PERF.md, PR 35)."""
+        c = self.lm
+        seq = self._seq_of(tokens)
+        with scope("rope"):
+            cos, sin = ops.rope_tables(seq, c.head_dim, c.rope_theta)
+        with scope("embed"):
+            x = params["embed"][tokens[:, :seq]].astype(compute_dtype)
+        targets = tokens[:, 1:seq + 1].reshape(-1)
+        # a layer's checkpoint keeps its input and, by name, attention's output and row log-sum-exp (`forward`
+        # says why): here once an APPLICATION, `loop_steps` times a layer
+        block = jax.checkpoint(lambda x_, p_: self._sandwich(p_, x_, cos, sin),
+                               policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+        gate = params["exit_gate"]
+
+        by_step = []
+        for _ in range(c.total_ut_steps):
+            for name in self.block_names:
+                x = block(x, params[name])
+            x = ops.rms_norm(x, params["final_norm"], c.rms_norm_eps)
+            flat = x.reshape(-1, c.hidden_size)
+            totals, nll = self._head_loss(params["head"], flat, targets, per_token=True)
+            with scope("exit_gate"):  # the last step's is computed and not read (`_expected_loss`)
+                logit = jnp.dot(flat.astype(jnp.float32), gate["w"], precision=lax.Precision.HIGHEST) + gate["b"]
+            by_step.append((totals, nll, logit))
+        return tuple(jnp.stack(rows) for rows in zip(*by_step))
+
+    def _expected_loss(self, nll, logits):
+        """(loss a token's mean, scalars) from every step's cross-entropy and
+        gate logit, (R, tokens) each, in float32: the exit distribution p_1 =
+        g_1, p_r = g_r prod_{j<r}(1 - g_j), p_R = prod_{j<R}(1 - g_j) (g =
+        sigmoid(logit); the last step's gate is not read), the loss sum_r p_r
+        CE_r - exit_entropy_weight * H(p)."""
+        with scope("exit_gate"):
+            stay = jnp.cumsum(jax.nn.log_sigmoid(-logits[:-1]), axis=0)  # ln prod_{j<=r}(1 - g_j), r < R
+            reached = jnp.concatenate([jnp.zeros_like(logits[:1]), stay])  # ln P(step r is reached), r = 1..R
+            log_p = jnp.concatenate([jax.nn.log_sigmoid(logits[:-1]) + reached[:-1], reached[-1:]])
+            p = jnp.exp(log_p)
+            entropy = -jnp.sum(p * log_p, axis=0)
+            steps = jnp.arange(1, p.shape[0] + 1, dtype=jnp.float32)[:, None]
+            expected = jnp.sum(p * nll, axis=0)
+            loss = jnp.mean(expected - self.lm.exit_entropy_weight * entropy)
+            scalars = {"ce": jnp.mean(expected), "exit_p_last": jnp.mean(p[-1]), "exit_entropy": jnp.mean(entropy),
+                       "expected_exit_step": jnp.mean(jnp.sum(steps * p, axis=0)),
+                       **{f"ce_step_{r + 1}": jnp.mean(nll[r]) for r in range(p.shape[0])}}
+        return loss, scalars
 
     def forward(self, params, state, tokens, *, compute_dtype=jnp.float32, axis_name: str | None = None):
         """tokens (B, seq_len + 2) -> (per-head totals {head: [nll sum, top-1,
@@ -286,9 +415,9 @@ class TokenModel:
         each router bias after the sign rule's one move. selected: each
         expert block's chosen expert ids, (B * seq_len, top_k)."""
         c = self.lm
-        seq = tokens.shape[1] - 2
-        if seq != c.seq_len:
-            raise ValueError(f"a batch row holds {tokens.shape[1]} ids, model.lm.seq_len + 2 = {c.seq_len + 2} expected")
+        seq = self._seq_of(tokens)
+        if self.looped:  # the LAST loop step's head: every step runs (`early_exit_threshold` 1)
+            return {"main": self._looped(params, tokens, compute_dtype)[0][-1]}, {}, {}, {}
         cos = sin = None
         if not c.mla_use_nope:
             with scope("rope"):
@@ -362,8 +491,13 @@ class TokenModel:
     def loss(self, params, state, batch, *, compute_dtype=jnp.float32, axis_name: str | None = None):
         """The train step's loss: `(loss, (new_state, scalars))`, loss =
         CE_main + mtp_loss_weight * CE_mtp, each the mean over this shard's
-        tokens."""
+        tokens; a looped model's: `_expected_loss`, with `ce` the expected
+        cross-entropy, `ce_step_<r>` each step's and `top1` the last step's."""
         tokens = batch["tokens"]
+        if self.looped:
+            totals, nll, logits = self._looped(params, tokens, compute_dtype)
+            loss, scalars = self._expected_loss(nll, logits)
+            return loss, ({}, {**scalars, "top1": totals[-1, 1] / nll.shape[1]})
         heads, new_state, counters, _ = self.forward(params, state, tokens, compute_dtype=compute_dtype,
                                                      axis_name=axis_name)
         with scope("loss"):
@@ -386,14 +520,16 @@ class TokenModel:
 
     def grad_scalars(self, grads: dict) -> dict:
         """Gradient norms by group, as step scalars: embedding, head, `W_eh`,
-        and per block its mixer (`attn` or `kda`), router, held experts,
-        shared or dense MLP and norm gains. What the benchmark holds against
-        the reference."""
+        a looped model's exit gate, and per block its mixer (`attn` or `kda`),
+        router, held experts, shared or dense MLP and norm gains (a sandwich
+        block's four). What the benchmark holds against the reference."""
         def norm(tree):
             return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(tree)))
 
         out = {"gnorm/embed": norm(grads["embed"]), "gnorm/head": norm(grads["head"]),
                "gnorm/final_norm": norm(grads["final_norm"])}
+        if "exit_gate" in grads:
+            out["gnorm/exit_gate"] = norm(grads["exit_gate"])
         for block in self.block_names:
             g = grads[block]
             for name in ("attn", "kda", "mlp", "router", "shared", "experts", "eh_proj"):

@@ -1,6 +1,7 @@
 """The plain reference of the token family (`glm4_moe_lite`: GLM-4.7-Flash;
-`kimi_linear`: Kimi-Linear-48B-A3B): forward, loss and, through `jax.grad`,
-gradients, in straightforward `jax.numpy`, float32, under
+`kimi_linear`: Kimi-Linear-48B-A3B; `ouro`: Ouro-2.6B, the `ouro_*`
+functions at the end): forward, loss and, through `jax.grad`, gradients, in
+straightforward `jax.numpy`, float32, under
 `jax.default_matmul_precision("highest")`.
 
 It follows the published layer equations and shares no function with the
@@ -22,7 +23,7 @@ Departures from the published description: none in the equations. Not in
 `config.json`, and therefore assumed (the configuration's file lists them):
 the RoPE pairing (channel i with i + d/2), the MTP loss weight, the bias
 update rate; for `kimi_linear` what KDA's description leaves to the code
-(:func:`kda` names each).
+(:func:`kda` names each); for `ouro` what :func:`ouro_loss_and_aux` names.
 """
 
 from __future__ import annotations
@@ -210,3 +211,95 @@ def loss_and_aux(params, state, tokens, d):
 def loss_and_grads(params, state, tokens, d):
     """((loss, aux), gradients of the loss by parameter)."""
     return jax.value_and_grad(loss_and_aux, has_aux=True)(params, state, tokens, d)
+
+
+# ---- `ouro`: a looped model (Ouro-2.6B; "Scaling Latent Reasoning via Looped Language Models") ----------------
+
+OURO_DIM_KEYS = ("hidden_size", "num_hidden_layers", "num_attention_heads", "head_dim", "rms_norm_eps", "rope_theta",
+                 "total_ut_steps", "exit_entropy_weight")
+
+
+def ouro_dims_of(lm_config) -> dict:
+    return {k: getattr(lm_config, k) for k in OURO_DIM_KEYS}
+
+
+def ouro_attention(p, x, d):
+    """One sequence x (S, h) through plain multi-head attention: q, k, v three
+    projections, every channel of q and k rotated, a dense S x S causal mask."""
+    seq = x.shape[0]
+    heads, width = d["num_attention_heads"], d["head_dim"]
+    q, k, v = ((x @ p[n]).reshape(seq, heads, width) for n in ("q", "k", "v"))
+    q, k = rope(q, d["rope_theta"]), rope(k, d["rope_theta"])
+    scores = jnp.einsum("qhd,khd->hqk", q, k) / math.sqrt(width)
+    mask = jnp.tril(jnp.ones((seq, seq), bool))
+    probs = jax.nn.softmax(jnp.where(mask[None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", probs, v).reshape(seq, heads * width) @ p["o"]
+
+
+def ouro_block(p, y, d):
+    """The sandwich: a norm before AND after each sub-layer, four gains."""
+    eps = d["rms_norm_eps"]
+    y = y + rms_norm(ouro_attention(p["attn"], rms_norm(y, p["attn_norm"], eps), d), p["attn_out_norm"], eps)
+    m = p["mlp"]
+    return y + rms_norm(gated_mlp(m["gate"], m["up"], m["down"], rms_norm(y, p["mlp_norm"], eps)), p["mlp_out_norm"], eps)
+
+
+def ouro_exit_distribution(gates):
+    """gates: the R exit probabilities g_r a token, a list of (S,) arrays ->
+    [p_1..p_R]: p_1 = g_1, p_r = g_r prod_{j<r}(1 - g_j), p_R = prod_{j<R}(1 -
+    g_j); g_R is not read."""
+    p, reached = [], jnp.ones_like(gates[0])
+    for g in gates[:-1]:
+        p.append(g * reached)
+        reached = reached * (1.0 - g)
+    return p + [reached]
+
+
+def ouro_sequence(params, ids, d):
+    """One row of S + 2 ids (the last is not read) -> per loop step: logits
+    (S, V), the exit probability g_r (S,). The final norm is INSIDE the loop:
+    step r + 1 reads the normed state."""
+    seq = ids.shape[0] - 2
+    x = params["embed"][ids[:seq]]
+    logits, gates = [], []
+    for _ in range(d["total_ut_steps"]):  # the SAME parameters every time
+        for i in range(d["num_hidden_layers"]):
+            x = ouro_block(params[f"layer_{i}"], x, d)
+        x = rms_norm(x, params["final_norm"], d["rms_norm_eps"])
+        logits.append(x @ params["head"])
+        gates.append(jax.nn.sigmoid(x @ params["exit_gate"]["w"] + params["exit_gate"]["b"]))
+    return logits, gates
+
+
+def ouro_loss_and_aux(params, tokens, d):
+    """tokens (B, S + 2) -> (loss, {"ce_step": [R], "exit_entropy",
+    "exit_p_last", "expected_exit_step", "logits": (B, R, S, V)}): a token's loss is sum_r p_r CE(logits^r, next id) - beta H(p), the
+    step's its mean over all B * S tokens. ASSUMED (the configuration file
+    lists each; none is a key of `config.json`): the four norms' placement; the
+    final norm inside the loop; the gate one row of h weights and a bias; beta
+    0.1; no bias on a projection; plain multi-head attention."""
+    with jax.default_matmul_precision("highest"):
+        seq = tokens.shape[1] - 2
+        n = tokens.shape[0] * seq
+        steps = d["total_ut_steps"]
+        loss = entropy = p_last = expected_step = 0.0
+        ce_step = [0.0] * steps
+        all_logits = []
+        for ids in tokens:
+            logits, gates = ouro_sequence(params, ids, d)
+            p = ouro_exit_distribution(gates)
+            targets = ids[1:seq + 1]
+            nll = [jax.nn.logsumexp(z, axis=-1) - z[jnp.arange(seq), targets] for z in logits]
+            h = -sum(jnp.where(q > 0, q * jnp.log(jnp.where(q > 0, q, 1.0)), 0.0) for q in p)
+            loss = loss + jnp.sum(sum(q * c for q, c in zip(p, nll)) - d["exit_entropy_weight"] * h) / n
+            entropy, p_last = entropy + jnp.sum(h) / n, p_last + jnp.sum(p[-1]) / n
+            expected_step = expected_step + jnp.sum(sum((r + 1) * q for r, q in enumerate(p))) / n
+            ce_step = [total + jnp.sum(c) / n for total, c in zip(ce_step, nll)]
+            all_logits.append(jnp.stack(logits))
+        return loss, {"ce_step": ce_step, "exit_entropy": entropy, "exit_p_last": p_last,
+                      "expected_exit_step": expected_step, "logits": jnp.stack(all_logits)}
+
+
+def ouro_loss_and_grads(params, tokens, d):
+    """((loss, aux), gradients of the loss by parameter)."""
+    return jax.value_and_grad(ouro_loss_and_aux, has_aux=True)(params, tokens, d)

@@ -61,9 +61,10 @@ SCOPES = (
     "nas_mask",     # AtomNAS channel masks over the expanded channels
     # the token models' compute (ops/lm.py, models/lm.py):
     "embed",        # token embedding gather (both heads' inputs), and its scatter-add backward
-    "norm",         # RMSNorm, every one: pre-attention, pre-MLP, the latents', final, the MTP merge's
-    "rope",         # rotary tables and the rotation of q_rope and the shared k_rope
-    "attn_proj",    # MLA's matmuls: q_a, q_b, kv_a, kv_b, o
+    "norm",         # RMSNorm, every one: pre-attention, pre-MLP, the latents', final, the MTP merge's, and the
+                    # two that a sandwich block (`ouro`) puts AFTER attention and MLP
+    "rope",         # rotary tables and the rotation of q_rope and the shared k_rope (`ouro`: of all of q and k)
+    "attn_proj",    # MLA's matmuls: q_a, q_b, kv_a, kv_b, o; plain multi-head attention's: q, k, v, o
     "attn_core",    # scores, causal mask, float32 softmax, values: a query block at a time
     "mlp",          # SiLU-gated MLP: the dense layer's and every shared expert's
     "moe_router",   # gate matmul, sigmoid, top-k of scores + bias, weights, counts, the bias update
@@ -77,6 +78,8 @@ SCOPES = (
     "kda_gate",     # the log decay of every key channel, the write strength beta; the step's lowest chunk decay
     "kda_core",     # the chunked gated delta rule: in-chunk decayed scores, the triangular solve, the scan over chunks
     "kda_norm",     # L2 norm of q and k, the sigmoid-gated RMSNorm of the output
+    "exit_gate",    # a looped model's exit gate (`ouro`): its projection after every loop step, the exit
+                    # distribution over the steps, the expected loss and the entropy term
     "lm_head",      # the output head over the vocabulary slice, a block of tokens at a time
     "loss",         # label-smoothed CE and the step's reported scalars (top-1, lr, their pmean)
     "nas_penalty",  # AtomNAS FLOPs-weighted BN-gamma L1

@@ -1,6 +1,7 @@
 """Layers of the token-model family (models/lm.py): RMSNorm, rotary
-embedding, causal multi-head latent attention, the SiLU-gated MLP and the
-expert layer of one expert-parallel share.
+embedding, causal multi-head latent attention and plain multi-head attention
+over one tiled causal core, the SiLU-gated MLP and the expert layer of one
+expert-parallel share.
 
 Everything is plain `jax.numpy`/`lax` for XLA as it is, but attention on a
 TPU (below). Weights are float32 and cast to the compute dtype where they are
@@ -253,6 +254,20 @@ def mla_attention(p: dict, x: Array, cos: Array | None, sin: Array | None, *, he
     out = causal_attention(q, k, kv[..., nope:], scale=(nope + rope) ** -0.5)
     with scope("attn_proj"):
         return out.reshape(b, s, heads * v_dim) @ p["o"].astype(cd)
+
+
+def mha_attention(p: dict, x: Array, cos: Array, sin: Array, *, heads: int, head_dim: int) -> Array:
+    """Plain multi-head attention (`ouro`): q, k, v three projections of
+    `heads` x `head_dim` channels, ALL channels of q and k rotated, no bias, no
+    q/k norm; the causal core is :func:`causal_attention`, as for the latent
+    archs. x (B, S, h) -> (B, S, h)."""
+    cd = x.dtype
+    b, s, _ = x.shape
+    with scope("attn_proj"):
+        q, k, v = ((x @ p[name].astype(cd)).reshape(b, s, heads, head_dim) for name in ("q", "k", "v"))
+    out = causal_attention(apply_rope(q, cos, sin), apply_rope(k, cos, sin), v, scale=head_dim ** -0.5)
+    with scope("attn_proj"):
+        return out.reshape(b, s, heads * head_dim) @ p["o"].astype(cd)
 
 
 def gated_mlp(p: dict, x: Array) -> Array:

@@ -242,7 +242,7 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     compute_dtype = _dtype(cfg.train.compute_dtype)
     if cfg.prune.enable or cfg.optim.mixup_alpha or cfg.optim.cutmix_alpha:
         raise ValueError(f"model.arch {net.arch!r} is a token model: prune.enable, mixup and cutmix are image-only")
-    # how many latent-attention layers this step lowers through ops/lm_attention.py's fused kernels. A
+    # how many softmax-attention layers this step lowers through ops/lm_attention.py's fused kernels. A
     # PREDICTION of the lowering, not a reading of it: ops/lm.py decides from each call's shapes (the
     # same predicate) and the platform the step is in fact lowered for; here `platform` stands for that
     sites, fitting = net.attention_sites(compute_dtype)
@@ -256,6 +256,10 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     get_registry().gauge("train.kda_kept_sites").set(net.kda_sites)
     # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
     get_registry().gauge("train.moe_sites").set(net.expert_sites)
+    # a looped model runs its layers `loop_steps` times a step with the same weights: the sites above are LAYERS,
+    # the checkpoints' kept tensors are counted by applications (1 and the number of blocks for every other arch)
+    get_registry().gauge("train.loop_steps").set(net.loop_steps)
+    get_registry().gauge("train.layer_applications").set(net.layer_applications)
 
     def loss_fn(params, state, batch, masks, rho_mult, step, rng):
         # read where the step is traced, from one replica's batch: the rows of a site's bounded branch
