@@ -4,7 +4,11 @@ shapes (1 x 16,384 tokens, 32 heads of 128, bfloat16): what each part of
 ops/lm_kda.py costs alone, forward and forward + backward, and how the core's
 time moves with its three module constants. What chose them (PERF.md, PR 33).
 
-    python scripts/bench_kda.py [--iters 5] [--chunks 64,128] [--subs 16] [--groups 8]
+    python scripts/bench_kda.py [--iters 5] [--chunks 64,128] [--subs 16] [--groups 8] [--core-only]
+
+Beside the plain pieces it times the two fused kernels of ops/lm_kda_kernels.py
+(PR 36) wherever `lm_kda.fuses` takes the shape: the forward kernel, the
+kernel pair, and `kda_core` through each form (`--core-only` stops there).
 
 Measures on a TPU or exits 3. Prints one JSON line a piece: ms a call (host
 clock around `iters` calls ending in a sync).
@@ -54,6 +58,7 @@ def main() -> int:
     ap.add_argument("--chunks", default=str(lm_kda.KDA_CHUNK))
     ap.add_argument("--subs", default=str(lm_kda.KDA_SUBCHUNK))
     ap.add_argument("--groups", default=str(lm_kda.KDA_HEAD_GROUP))
+    ap.add_argument("--core-only", action="store_true", help="kda_core and its operands through both forms, nothing else")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print(f"bench_kda: no TPU (platform {jax.devices()[0].platform!r}): this script measures on the chip", file=sys.stderr)
@@ -73,9 +78,29 @@ def main() -> int:
             for group in map(int, args.groups.split(",")):
                 lm_kda.KDA_CHUNK, lm_kda.KDA_SUBCHUNK, lm_kda.KDA_HEAD_GROUP = chunk, sub, group
                 tag = {"chunk": chunk, "sub": sub, "heads_at_once": group}
-                say("kda_core fwd", timed(jax.jit(lambda *a: lm_kda.kda_core(*a)[0]), core_args, it), **tag)
-                grad = jax.jit(jax.grad(lambda *a: total(lm_kda.kda_core(*a)[0]), argnums=(0, 1, 2, 3, 4)))
-                say("kda_core fwd+bwd", timed(grad, core_args, it), **tag)
+                grads = lambda fn: jax.jit(jax.grad(lambda *a: total(fn(*a)), argnums=(0, 1, 2, 3, 4)))  # noqa: E731
+                fits = lm_kda.fuses(S, chunk, D, q.dtype)
+                takes = lm_kda.fuses
+                for form in ("plain", "fused kernels") if fits else ("plain",):
+                    # the dispatch reads `fuses` while it traces: the plain form at a shape the kernels take is that of a refusing predicate
+                    lm_kda.fuses = takes if form != "plain" else (lambda *a: False)
+                    core = lambda *a: lm_kda.kda_core(*a)[0]  # noqa: E731 - a new function a form: jit caches by the function
+                    say(f"kda_core, {form}, fwd", timed(jax.jit(core), core_args, it), **tag)
+                    say(f"kda_core, {form}, fwd+bwd", timed(grads(core), core_args, it), **tag)
+                lm_kda.fuses = takes
+                # what depends on a chunk alone, the whole layer: the plain form (head groups, regrouping copies and all) and the kernels
+                whole = (q, k, v, g, beta)
+                say("chunk operands, plain, all heads, fwd", timed(jax.jit(lambda *a: lm_kda._plain_operands(*a)[0]), whole, it), **tag)
+                say("chunk operands, plain, all heads, fwd+bwd", timed(grads(lambda *a: lm_kda._plain_operands(*a)[0]), whole, it), **tag)
+                if fits:
+                    say("chunk operands, forward kernel, fwd", timed(jax.jit(lambda *a: lm_kda.operands_fwd(*a)[0]), whole, it), **tag)
+                    say("chunk operands, kernel pair, fwd+bwd", timed(grads(lambda *a: lm_kda._fused_operands(*a)[0]), whole, it), **tag)
+                    # the two backwards alone, from the same cotangents: what decides whether the second kernel ships
+                    cts = jax.tree.map(lambda x: jnp.ones(x.shape, x.dtype), jax.eval_shape(lambda *a: lm_kda.operands_fwd(*a)[0], *whole))
+                    say("chunk operands, backward kernel alone", timed(jax.jit(lm_kda.operands_bwd), (*whole, cts), it), **tag)
+                    say("chunk operands, the plain form's vjp alone", timed(jax.jit(lm_kda._plain_operands_bwd), (*whole, cts), it), **tag)
+                if args.core_only:
+                    continue
                 # the in-chunk work of ONE head group, and its backward
                 n = S // chunk
 
@@ -106,6 +131,8 @@ def main() -> int:
                     timed(jax.jit(jax.grad(lambda *a_: total(lm_kda._state_scan(*a_)), argnums=(0, 1, 2, 3, 4, 5))), operands, it),
                     **tag, steps=n)
 
+    if args.core_only:
+        return 0
     # the rest of the mixer
     z = jax.random.normal(ks[5], (B, S, H * D), jnp.bfloat16)
     w = jax.random.normal(ks[6], (4, H * D), jnp.float32) * 0.02

@@ -32,20 +32,20 @@ def recurrence(q, k, v, g, beta):
         return jax.vmap(one)(q, k, v, g, beta)
 
 
-def operands(seq: int, hard: bool, seed: int = 0):
-    """q (scaled), k L2-normalised, v, log decays, write strengths. `hard`:
-    decays up to 12 a position (a chunk of 64 passes -88 many times over) and
-    `beta` exactly 0 at every third position, exactly 1 at every fifth."""
+def operands(seq: int, hard: bool, seed: int = 0, width: int = WIDTH, dtype=jnp.float32):
+    """q (scaled), k L2-normalised, v (in `dtype`), log decays, write strengths.
+    `hard`: decays up to 12 a position (a chunk of 64 passes -88 many times
+    over) and `beta` exactly 0 at every third position, exactly 1 at every fifth."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    shape = (2, seq, HEADS, WIDTH)
+    shape = (2, seq, HEADS, width)
     q, k, v = (jax.random.normal(key, shape) for key in ks[:3])
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * WIDTH ** -0.5
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * width ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     g = -jnp.exp(jax.random.uniform(ks[3], shape, minval=math.log(1e-3), maxval=math.log(12.0 if hard else 0.5)))
     beta = jax.nn.sigmoid(2 * jax.random.normal(ks[4], shape[:3]))
     if hard:
         beta = beta.at[:, ::3].set(0.0).at[:, 1::5].set(1.0)
-    return q, k, v, g, beta
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 
 def value_and_grads(fn, args, ct):
@@ -154,3 +154,187 @@ def test_the_mixer_equals_the_references_recurrence(monkeypatch):
     assert abs(float(got[0]) - float(want[0])) < 1e-4 * abs(float(want[0]))
     assert worst(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])) < 2e-5
     assert all(float(jnp.max(jnp.abs(g))) > 0 for g in jax.tree.leaves(got[1]))
+
+
+# ---- the fused kernels (ops/lm_kda_kernels.py, PR 36) in Pallas interpret mode, against the plain form --------------
+KERNEL_WIDTH = 128  # a head is a whole band of lanes, or the kernels do not take the site
+
+
+def kernel_operands(seq: int, hard: bool, seed: int = 0):
+    """`operands` at the kernels' shapes and dtypes: 2 sequences, 2 heads of 128, q, k, v in bfloat16."""
+    return operands(seq, hard, seed, width=KERNEL_WIDTH, dtype=jnp.bfloat16)
+
+
+def deviations(got, want):
+    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))) / (jnp.max(jnp.abs(b.astype(jnp.float32))) + 1e-30))
+            for a, b in zip(got, want)]
+
+
+def cotangents(like):
+    return tuple(jax.random.normal(jax.random.PRNGKey(7 + i), x.shape).astype(x.dtype) for i, x in enumerate(like))
+
+
+# the largest deviation allowed, against each result's largest entry: an ulp of bfloat16 at the top of its range is 2^-8 for the
+# results in the compute dtype (qg, b, w, kh; dq, dk, dv); the float32 ones (u0, gamma; dg, dbeta) inherit the bfloat16
+# operands of A and B, whose pairs inside a 16-row sub-block the plain form multiplies out in float32
+FWD_LIMITS = (1e-2, 1e-2, 1e-2, 2e-3, 1e-2, 1e-5)
+BWD_LIMITS = (1.5e-2, 1.5e-2, 1.5e-2, 1e-2, 2e-3)
+REGIMES = pytest.mark.parametrize("hard", [False, True], ids=["fresh_gates", "gates_that_underflow"])
+LENGTHS = pytest.mark.parametrize("seq", [64, 512], ids=["1_chunk", "8_chunks"])
+
+
+@REGIMES
+@LENGTHS
+def test_the_forward_kernel_makes_the_plain_forms_six_operands_and_its_lowest_decay(seq, hard):
+    """`operands_fwd` (one fused kernel, every intermediate in VMEM) against
+    `_plain_operands` (head groups, sub-blocks, XLA's triangular solve): qg, b,
+    w, u0, kh, gamma in the scan's chunk-leading shapes, and the most negative
+    in-chunk cumulative log decay, past -88 in the hard regime."""
+    args = kernel_operands(seq, hard)
+    assert lm_kda.fuses(seq, lm_kda.KDA_CHUNK, KERNEL_WIDTH, args[0].dtype)
+    got, lowest = jax.jit(lambda *a: lm_kda.operands_fwd(*a, interpret=True))(*args)
+    want, want_lowest = jax.jit(lm_kda._plain_operands)(*args)
+    assert [x.shape for x in got] == [x.shape for x in want] and [x.dtype for x in got] == [x.dtype for x in want]
+    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in got)
+    assert all(d < limit for d, limit in zip(deviations(got, want), FWD_LIMITS)), deviations(got, want)
+    assert (float(lowest) < -88.0) == hard and abs(float(lowest) - float(want_lowest)) < 1e-4 * abs(float(want_lowest))
+
+
+@REGIMES
+@LENGTHS
+def test_the_backward_kernel_equals_the_plain_forms_vjp(seq, hard):
+    """`operands_bwd` (the second kernel: A, B and the inverse made again in
+    VMEM) against `jax.vjp` of the plain form: dq, dk, dv, dg, dbeta from the
+    same cotangents of the six operands, finite where the decays underflow."""
+    args = kernel_operands(seq, hard, seed=1)
+    cts = cotangents(jax.eval_shape(lm_kda._plain_operands, *args)[0])
+    got = jax.jit(lambda *a: lm_kda.operands_bwd(*a, cts, interpret=True))(*args)
+    want = jax.jit(lambda *a: lm_kda._plain_operands_bwd(*a, cts))(*args)
+    assert [(x.shape, x.dtype) for x in got] == [(x.shape, x.dtype) for x in args]
+    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in got)
+    assert all(d < limit for d, limit in zip(deviations(got, want), BWD_LIMITS)), deviations(got, want)
+
+
+@REGIMES
+def test_a_clamped_gate_in_the_kernel_path_is_caught_where_the_decays_underflow(monkeypatch, hard):
+    """Plant what a form that multiplies `k e^G` by `k e^-G` would need: log
+    decays held above -88 / chunk, so that no in-chunk sum passes float32's
+    `exp`. At fresh gates (g >= -0.5) the clamp touches nothing and both
+    comparisons stand; at gates that underflow both fail."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    decay_sums = kernels._decay_sums
+    monkeypatch.setattr(kernels, "_decay_sums", lambda sums_ref, pattern, g, chunk: decay_sums(
+        sums_ref, pattern, jnp.maximum(g, -88.0 / lm_kda.KDA_CHUNK), chunk))
+    args = kernel_operands(128, hard, seed=2)
+    want, _ = jax.jit(lm_kda._plain_operands)(*args)
+    cts = cotangents(want)
+    got, _ = jax.jit(lambda *a: lm_kda.operands_fwd(*a, interpret=True))(*args)
+    grads = jax.jit(lambda *a: lm_kda.operands_bwd(*a, cts, interpret=True))(*args)
+    want_grads = jax.jit(lambda *a: lm_kda._plain_operands_bwd(*a, cts))(*args)
+    fwd_holds = all(d < limit for d, limit in zip(deviations(got, want), FWD_LIMITS))
+    bwd_holds = all(d < limit for d, limit in zip(deviations(grads, want_grads), BWD_LIMITS))
+    assert (fwd_holds, bwd_holds) == (not hard, not hard), (deviations(got, want), deviations(grads, want_grads))
+
+
+@pytest.mark.parametrize("seq, dtype, width, takes", [
+    (128, jnp.bfloat16, 128, True), (96, jnp.bfloat16, 128, False), (128, jnp.float32, 128, False), (128, jnp.bfloat16, 64, False)],
+    ids=["fits", "sequence_not_whole_chunks", "float32_operands", "head_dim_64"])
+def test_the_dispatch_takes_the_kernels_form_by_the_shapes_alone(monkeypatch, seq, dtype, width, takes):
+    """`kda_core` asks `fuses` (whole chunks, a head a whole number of 128-lane
+    bands, bfloat16) and nothing else; what does not fit takes the plain form,
+    head groups and all, with no `custom_vjp` and no platform switch around it."""
+    assert lm_kda.fuses(seq, lm_kda.KDA_CHUNK, width, dtype) == takes
+    asked = []
+    fused = lm_kda._fused_operands
+    monkeypatch.setattr(lm_kda, "_fused_operands", lambda *a: asked.append(a[0].shape) or fused(*a))
+    shape = (1, seq, HEADS, width)
+    q, k, v = (jax.ShapeDtypeStruct(shape, dtype) for _ in range(3))
+    jaxpr = jax.make_jaxpr(lm_kda.kda_core)(q, k, v, jax.ShapeDtypeStruct(shape, jnp.float32),
+                                            jax.ShapeDtypeStruct(shape[:3], jnp.float32))
+    assert bool(asked) == takes
+    assert ("platform_index" in str(jaxpr)) == takes
+
+
+def test_on_a_cpu_the_fitting_shape_runs_the_plain_form_and_its_vjp(monkeypatch):
+    """The kernels' shapes lowered for a CPU: `lax.platform_dependent` keeps
+    the plain form and, in the backward, the plain form's own vjp, so output
+    and every gradient equal those of a dispatch that refuses the shape."""
+    args = kernel_operands(128, True, seed=4)
+    ct = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    run = lambda: value_and_grads(lambda *a: lm_kda.kda_core(*a)[0].astype(jnp.float32), args, ct)  # noqa: E731
+    assert "pallas_call" not in str(jax.jit(lm_kda.kda_core).lower(*args).compile().as_text())
+    through_the_dispatch = run()
+    monkeypatch.setattr(lm_kda, "fuses", lambda *a: False)
+    plain = run()
+    assert all(bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(through_the_dispatch), jax.tree.leaves(plain)))
+
+
+def _step_gauges(app, overrides, platform):
+    from yet_another_mobilenet_series_tpu.config import parse_cli
+    from yet_another_mobilenet_series_tpu.models import get_model
+    from yet_another_mobilenet_series_tpu.obs.registry import get_registry
+    from yet_another_mobilenet_series_tpu.train import optim, schedules, steps
+
+    cfg = parse_cli([f"app:{app}", *overrides])
+    net = get_model(cfg.model)
+    lr_fn = schedules.make_lr_schedule(cfg.schedule, 2, 10, 1)
+    params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
+    steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn, platform=platform)
+    return tuple(get_registry().gauge(name).value for name in ("train.kda_sites", "train.kda_kept_sites", "train.kda_fused_sites"))
+
+
+@pytest.mark.parametrize("family, toy, cell", [("kimi", (4.0, 4.0, 0.0), (4.0, 4.0, 4.0)), ("glm", (0.0, 0.0, 0.0), (0.0, 0.0, 0.0)),
+                                               ("ouro", (0.0, 0.0, 0.0), (0.0, 0.0, 0.0))])
+def test_train_step_reports_how_many_kda_layers_the_kernels_take(family, toy, cell):
+    """`train.kda_fused_sites` beside `train.kda_sites` and `train.kda_kept_sites`,
+    set where the step is built, as `train.attn_fused_sites` is: the KDA layers
+    whose shapes `fuses` takes when the step is lowered for a TPU, else 0. The
+    toys (heads of 8 channels, float32) take the plain form anywhere; kimi's
+    cell's model reads 4 / 4 / 4 for a TPU and 4 / 4 / 0 for a CPU; GLM and
+    Ouro hold no KDA layer."""
+    import test_lm_cli as cli
+
+    app, overrides = {"kimi": (cli.KIMI_APP, cli.KIMI_TOY), "glm": (cli.APP, cli.TOY), "ouro": (cli.OURO_APP, cli.OURO_TOY)}[family]
+    assert _step_gauges(app, overrides, None) == _step_gauges(app, overrides, "tpu") == toy
+    assert _step_gauges(app, [], "tpu") == cell
+    assert _step_gauges(app, [], "cpu") == (*cell[:2], 0.0)
+
+
+_FRESH_PROCESS = """
+import json, sys
+import jax, jax.numpy as jnp
+from yet_another_mobilenet_series_tpu.models import lm
+from yet_another_mobilenet_series_tpu.ops import lm_kda
+from yet_another_mobilenet_series_tpu.train import steps
+
+def pallas():
+    return sorted(m for m in sys.modules if m.startswith(("jax.experimental.pallas", "jax._src.pallas")))
+
+shape = (1, 128, 2, 128)
+q = jax.ShapeDtypeStruct(shape, jnp.bfloat16 if sys.argv[1] == "fitting_site" else jnp.float32)
+fits = lm_kda.fuses(128, lm_kda.KDA_CHUNK, 128, q.dtype)
+before = pallas()
+jax.eval_shape(lm_kda.kda_core, q, q, q, jax.ShapeDtypeStruct(shape, jnp.float32), jax.ShapeDtypeStruct(shape[:3], jnp.float32))
+print(json.dumps({"fits": fits, "before": before, "pallas": pallas()}))
+"""
+
+
+@pytest.mark.parametrize("what, pays", [("plain_site", False), ("fitting_site", True)])
+def test_pallas_comes_in_where_a_fitting_kda_site_is_traced_and_nowhere_else(what, pays):
+    """A fresh process that imports `ops.lm_kda`, `models.lm` and `train.steps`
+    and asks the predicate has no `jax.experimental.pallas*` module, and none
+    after tracing a KDA site the kernels do not take; the `tpu` branch of a
+    fitting site, once traced, brings it in (PR 28 was refused for the 1.2-1.5
+    s of `setup_s` that import costs a cell that runs none of its code)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", _FRESH_PROCESS, what], cwd=repo, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    said = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert said["fits"] == pays and not said["before"]
+    assert {"jax.experimental.pallas", "jax.experimental.pallas.tpu"} <= set(said["pallas"]) if pays else not said["pallas"], said

@@ -450,7 +450,9 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name
         # in a compiled cell moved, so no bump
         # version 5: Kimi Delta Attention's scopes (PR 33)
-        "ops/lm_kda.py": ["kda_conv", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj", "kda_proj"],
+        # PR 36 added a second `kda_core` site (the fused in-chunk work's backward, a `custom_vjp`'s) in the one step whose
+        # program changed with it, so its cache key moved anyway, and no other step holds it: no bump
+        "ops/lm_kda.py": ["kda_conv", "kda_core", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj", "kda_proj"],
         "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],

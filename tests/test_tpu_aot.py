@@ -199,6 +199,41 @@ def test_the_192_channel_site_takes_the_kernels_with_q_and_k_filled_to_256(one_c
     assert dq[0] == f"bf16[1,{heads * 256},{seq}]"  # the kernels' own width; the filling's gradient is cut off after
 
 
+def test_a_kda_layer_at_the_cells_shape_is_two_mosaic_kernels_under_its_scope(one_chip, monkeypatch):
+    """kimi_linear's Kimi Delta Attention core at the cell's shape (1 x 16,384
+    tokens, 32 heads of 128, bfloat16), forward + backward, compiled for the
+    described chip from this CPU process: `lax.platform_dependent` takes the
+    kernels of ops/lm_kda_kernels.py and Mosaic compiles both; each custom call
+    keeps `kda_core` and its phase in its `op_name` and carries the kernel's
+    name (so `scope_table` resolves its device events as it does
+    `causal_attention_fwd.N`); the only loops left are the scan's two; and the
+    declared temporaries are not above the plain form's (head groups, a
+    `lax.map` and a solve loop; ~3.2 GiB against ~2.0)."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda
+
+    shape = (1, 16384, 32, 128)
+    assert lm_kda.fuses(shape[1], lm_kda.KDA_CHUNK, shape[3], jnp.bfloat16)
+    operand = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    rest = (jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip), jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip))
+
+    def compiled():
+        loss = lambda *a: jnp.sum(lm_kda.kda_core(*a)[0].astype(jnp.float32))  # noqa: E731
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4))).lower(operand, operand, operand, *rest).compile()
+
+    fused = compiled()
+    text = fused.as_text()
+    instructions, _ = _entry_instructions(text)
+    kernels = {n.split(".")[0]: scopes.scope_of(op) for n, (_, opcode, _, op) in instructions.items()
+               if opcode == "custom-call" and n.startswith("kda_operands")}  # the scan's buffers are custom calls too (`AllocateBuffer`)
+    assert kernels == {"kda_operands_fwd": ("kda_core", "fwd"), "kda_operands_bwd": ("kda_core", "bwd")}, kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(re.findall(r"\bwhile\(", text)) == 2  # `_state_scan` forward and backward: no head groups, no solve loop
+    monkeypatch.setattr(lm_kda, "fuses", lambda *a: False)
+    plain = compiled()
+    assert "tpu_custom_call" not in plain.as_text()
+    assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
+
+
 def test_no_tile_of_scores_and_no_float32_dq_reaches_hbm(attention_hlo):
     """What the tile loops paid for: no buffer of a tile's shape in any
     dtype (`f32[2,20,512,512]`, or the kernel's own 512 x 512 block), no

@@ -164,6 +164,15 @@ class TokenModel:
     def kda_sites(self) -> int:
         return len(self.blocks_mixing_by("kda"))
 
+    def kda_fitting_sites(self, compute_dtype) -> int:
+        """The KDA layers whose shapes ops/lm_kda_kernels.py's fused kernels
+        take, by the predicate `lm_kda.kda_core` dispatches on: what
+        `train.kda_fused_sites` reports where the step is lowered for a TPU
+        (train/steps.py)."""
+        la = self.lm.linear_attn_config
+        fits = lm_kda.fuses(self.lm.seq_len, min(lm_kda.KDA_CHUNK, self.lm.seq_len), la.head_dim, compute_dtype)
+        return self.kda_sites if fits else 0
+
     @property
     def expert_sites(self) -> int:
         """The expert layers (`train.moe_sites`): what `moe_bounded_sites` reads on a

@@ -2,7 +2,9 @@
 (models/lm.py): short causal convolutions on q, k, v; L2-normalised q and k; a
 decay gate of one value a KEY CHANNEL; a write strength a head; the gated
 delta rule over a `head_dim x head_dim` state a head; a sigmoid-gated RMSNorm
-on the way out. Plain `jax.numpy`/`lax`, one lowering for every platform.
+on the way out. Plain `jax.numpy`/`lax` on every platform, but for what
+depends on a chunk alone, which two fused TPU kernels make where the shapes
+fit them (below).
 
 The recurrence, a head at a time (state S, key x value, S_0 = 0)::
 
@@ -33,6 +35,25 @@ most 1, and their product is a matmul); a pair inside one sub-block is
 multiplied out channel by channel with `e^{G_r - G_j}` itself. Both are exact
 for any gate. `e^{G_r}` alone (the state's way into the chunk) only ever
 underflows, to the 0 that its true value rounds to.
+
+**Two lowerings of the in-chunk work** (PR 36). `_plain_operands` is the form
+above in `lax`, `KDA_HEAD_GROUP` heads at a time: every other platform's path,
+a short or ragged sequence's, and the kernels' oracle in the tests.
+`_fused_operands` is the same function as two Pallas/Mosaic kernels
+(ops/lm_kda_kernels.py) under one `custom_vjp`: a forward kernel that writes
+the scan's six operands from q, k, v, g, beta as `kda_attention` holds them,
+every intermediate in VMEM, and a backward kernel that makes A, B and the
+inverse again in VMEM and returns dq, dk, dv, dg, dbeta. `kda_core` takes it
+where `fuses` says the shapes fit (whole chunks, heads of whole 128-lane bands,
+bfloat16) AND the step is lowered for a TPU (`lax.platform_dependent`, as
+`ops/lm.py:_by_lowering` does for attention); no option chooses. There every
+pair of rows meets through a reference row, by halving (that module's
+docstring): exact for any gate, like the sub-block scheme here. This module
+imports no Pallas: the kernels' module comes in INSIDE `operands_fwd` /
+`operands_bwd`, so while the `tpu` branch of a fitting site is traced and at
+no other time (`models/lm.py` imports this module at module level, and every
+runner imports `train/steps.py`: PERF.md, PR 28). `train.kda_fused_sites`
+(train/steps.py) says how many KDA layers a step lowers that way.
 
 **What the backward keeps.** The scan is a `custom_vjp`: its backward walks
 the chunks in reverse with the cotangent of the state, from the scan's
@@ -245,42 +266,122 @@ def _chunk_operands(q: Array, k: Array, v: Array, g: Array, beta: Array):
             (kf * jnp.exp(last - big_g)).astype(cd), jnp.exp(last[..., 0, :])), jnp.min(big_g)
 
 
+def _plain_operands(q: Array, k: Array, v: Array, g: Array, beta: Array):
+    """`_chunk_operands` for a whole layer in plain `lax`: q, k, v (B, S, H, D),
+    g alike in float32, beta (B, S, H) float32 -> (`_state_scan`'s six operands,
+    chunk-leading (N, B, H, ...); the most negative in-chunk cumulative log decay).
+    Any length: the last chunk is filled with positions that neither decay nor
+    write (g = 0, beta = 0, k = 0).
+
+    The work goes `KDA_HEAD_GROUP` heads at a time, each group a
+    `jax.checkpoint` (its backward makes the group's matrices again): at
+    16,384 positions the float32 intermediates of all 32 heads at once, and
+    their cotangents, are several GiB."""
+    batch, seq, heads, _ = q.shape
+    chunk = min(KDA_CHUNK, seq)
+    fill = -seq % chunk
+    n = (seq + fill) // chunk
+    at_once = KDA_HEAD_GROUP if heads % KDA_HEAD_GROUP == 0 else heads
+    groups = heads // at_once
+
+    def grouped(x):  # (B, S, H, ...) -> (groups, B, h, N, C, ...)
+        x = jnp.pad(x, [(0, 0), (0, fill)] + [(0, 0)] * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape(batch, n, chunk, groups, at_once, *x.shape[3:]), (3, 4), (0, 2))
+
+    by_group = (grouped(q), grouped(k), grouped(v), grouped(g), grouped(beta[..., None]))
+    if groups == 1:
+        operands, lowest = jax.tree.map(lambda x: x[None], _chunk_operands(*(x[0] for x in by_group)))
+    else:
+        operands, lowest = lax.map(lambda xs: jax.checkpoint(_chunk_operands)(*xs), by_group)
+    # (groups, B, h, N, ...) -> (N, B, H, ...)
+    return tuple(jnp.moveaxis(x, (0, 3), (2, 0)).reshape(n, batch, heads, *x.shape[4:]) for x in operands), jnp.min(lowest)
+
+
+def _plain_operands_bwd(q, k, v, g, beta, cts):
+    """`_plain_operands`' own vjp (a head group at a time, its matrices made again)."""
+    made, vjp = jax.vjp(_plain_operands, q, k, v, g, beta)
+    return vjp((cts, jnp.zeros_like(made[1])))
+
+
+def fuses(seq: int, chunk: int, width: int, dtype) -> bool:
+    """Whether the kernels of ops/lm_kda_kernels.py take a KDA site of this
+    shape (the platform is the lowering's to decide, not this predicate's):
+    whole chunks (the sequence a multiple of the chunk), a chunk that halves
+    down to one row and fills bfloat16's 16-row tiles, a head a whole number
+    of 128-lane bands, bfloat16 operands (the kernels' tiles and their
+    matmuls' operands are laid out for it)."""
+    return (seq % chunk == 0 and chunk % 16 == 0 and chunk & (chunk - 1) == 0 and width % 128 == 0
+            and jnp.dtype(dtype) == jnp.bfloat16)
+
+
+def operands_fwd(q, k, v, g, beta, interpret: bool = False):
+    """`_plain_operands` as ONE fused kernel (ops/lm_kda_kernels.py): the same
+    arguments, the same results, every intermediate in VMEM. The operands go in
+    as `kda_attention` holds them, (B, S, H * D): a reshape, no copy."""
+    from . import lm_kda_kernels as kernels  # Pallas comes in HERE and nowhere earlier (module docstring)
+
+    batch, seq, heads, width = q.shape
+    flat = lambda x: x.reshape(batch, seq, heads * width)  # noqa: E731
+    *operands, gamma, lowest = kernels.fwd_call(flat(q), flat(k), flat(v), flat(g), beta, heads, min(KDA_CHUNK, seq), interpret)
+    return (*operands, gamma.reshape(*gamma.shape[:3], width)), jnp.min(lowest)
+
+
+def operands_bwd(q, k, v, g, beta, cts, interpret: bool = False):
+    """(dq, dk, dv, dg, dbeta) from the forward's arguments and the cotangents
+    of its six operands: the second kernel, which makes the chunk's matrices
+    again in VMEM."""
+    from . import lm_kda_kernels as kernels  # as in operands_fwd
+
+    batch, seq, heads, width = q.shape
+    flat = lambda x: x.reshape(batch, seq, heads * width)  # noqa: E731
+    *others, d_gamma = cts
+    grads = kernels.bwd_call(flat(q), flat(k), flat(v), flat(g), beta, (*others, d_gamma[..., None, :]), heads,
+                             min(KDA_CHUNK, seq), interpret)
+    return (*(x.reshape(q.shape) for x in grads[:4]), grads[4])
+
+
+@jax.custom_vjp
+def _fused_operands(q: Array, k: Array, v: Array, g: Array, beta: Array):
+    """`_plain_operands` at a shape the kernels take (`fuses`): the two fused
+    kernels where the step is lowered for a TPU, else the plain form and its
+    own vjp. `lax.platform_dependent` decides at lowering, so a compile for a
+    described chip from a CPU process takes the kernels and a CPU the plain form."""
+    return _fused_operands_fwd(q, k, v, g, beta)[0]
+
+
+def _fused_operands_fwd(q, k, v, g, beta):
+    return lax.platform_dependent(q, k, v, g, beta, tpu=operands_fwd, default=_plain_operands), (q, k, v, g, beta)
+
+
+def _fused_operands_bwd(kept, cts):
+    with scope("kda_core"):
+        return lax.platform_dependent(*kept, cts[0], tpu=operands_bwd, default=_plain_operands_bwd)
+
+
+_fused_operands.defvjp(_fused_operands_fwd, _fused_operands_bwd)
+
+
 def kda_core(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tuple[Array, Array]:
     """The gated delta rule in its chunked form (module docstring). q (already
     scaled), k, v (B, S, H, D) in the compute dtype; g (B, S, H, D) float32,
     the LOG decay of each key channel (<= 0); beta (B, S, H) float32 ->
     (o (B, S, H, D), the most negative in-chunk cumulative log decay met: a
-    float32 scalar, no gradient). Any length: the last chunk is filled with
-    positions that neither decay nor write (g = 0, beta = 0, k = 0).
+    float32 scalar, no gradient). Any length (`_plain_operands`).
 
-    The in-chunk work goes `KDA_HEAD_GROUP` heads at a time, each group a
-    `jax.checkpoint` (its backward makes the group's matrices again): at
-    16,384 positions the float32 intermediates of all 32 heads at once, and
-    their cotangents, are several GiB. The scan takes all heads together."""
+    What depends on a chunk alone is made by the fused kernels where the
+    shapes fit them and the lowering is a TPU's (`fuses`, `_fused_operands`),
+    else in plain `lax` a head group at a time; the scan takes all heads
+    together either way."""
     with scope("kda_core"):
         batch, seq, heads, width = q.shape
         chunk = min(KDA_CHUNK, seq)
         if chunk % min(KDA_SUBCHUNK, chunk):
             raise ValueError(f"a chunk of {chunk} positions is not a multiple of the sub-block {KDA_SUBCHUNK}")
-        fill = -seq % chunk
-        n = (seq + fill) // chunk
-        at_once = KDA_HEAD_GROUP if heads % KDA_HEAD_GROUP == 0 else heads
-        groups = heads // at_once
-
-        def grouped(x):  # (B, S, H, ...) -> (groups, B, h, N, C, ...)
-            x = jnp.pad(x, [(0, 0), (0, fill)] + [(0, 0)] * (x.ndim - 2))
-            return jnp.moveaxis(x.reshape(batch, n, chunk, groups, at_once, *x.shape[3:]), (3, 4), (0, 2))
-
-        by_group = (grouped(q), grouped(k), grouped(v), grouped(g.astype(jnp.float32)),
-                    grouped(beta.astype(jnp.float32)[..., None]))
-        if groups == 1:
-            operands, lowest = jax.tree.map(lambda x: x[None], _chunk_operands(*(x[0] for x in by_group)))
-        else:
-            operands, lowest = lax.map(lambda xs: jax.checkpoint(_chunk_operands)(*xs), by_group)
-        # (groups, B, h, N, ...) -> (N, B, H, ...)
-        out = _state_scan(*(jnp.moveaxis(x, (0, 3), (2, 0)).reshape(n, batch, heads, *x.shape[4:]) for x in operands))
-        out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(batch, n * chunk, heads, width)[:, :seq]
-        return out, lax.stop_gradient(jnp.min(lowest))
+        make = _fused_operands if fuses(seq, chunk, width, q.dtype) else _plain_operands
+        operands, lowest = make(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32))
+        out = _state_scan(*operands)  # (N, B, H, C, D)
+        out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(batch, -1, heads, width)[:, :seq]
+        return out, lax.stop_gradient(lowest)
 
 
 def kda_attention(p: dict, x: Array, *, heads: int, head_dim: int, eps: float) -> tuple[Array, Array]:

@@ -247,13 +247,17 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     # same predicate) and the platform the step is in fact lowered for; here `platform` stands for that
     sites, fitting = net.attention_sites(compute_dtype)
     get_registry().gauge("train.attn_sites").set(sites)
-    get_registry().gauge("train.attn_fused_sites").set(fitting if (platform or jax.default_backend()) == "tpu" else 0)
+    on_tpu = (platform or jax.default_backend()) == "tpu"
+    get_registry().gauge("train.attn_fused_sites").set(fitting if on_tpu else 0)
     # every block is one mixer under one layer checkpoint, which keeps, by name and whatever the lowering, an
     # attention's output and row log-sum-exp, a KDA scan's output and states (models/lm.py `forward`): all of
     # them, on every platform
     get_registry().gauge("train.attn_kept_sites").set(sites)
     get_registry().gauge("train.kda_sites").set(net.kda_sites)
     get_registry().gauge("train.kda_kept_sites").set(net.kda_sites)
+    # the KDA layers whose in-chunk work this step lowers through ops/lm_kda_kernels.py's two fused kernels: the same
+    # kind of prediction as `train.attn_fused_sites` (ops/lm_kda.py `fuses` on the shapes, `platform` for the lowering)
+    get_registry().gauge("train.kda_fused_sites").set(net.kda_fitting_sites(compute_dtype) if on_tpu else 0)
     # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
     get_registry().gauge("train.moe_sites").set(net.expert_sites)
     # a looped model runs its layers `loop_steps` times a step with the same weights: the sites above are LAYERS,
@@ -301,9 +305,11 @@ def make_train_step(
     mesh's, which parallel/dp.py hands in; the default backend's when left
     out). It moves nothing in the step: the gauges that PREDICT which
     lowering a platform-dependent piece takes read it
-    (`train.attn_fused_sites`). A step built without it and then compiled
-    ahead of time for another platform than the default backend's reports the
-    default backend's count.
+    (`train.attn_fused_sites`: the attention layers through the kernels of
+    ops/lm_attention.py; `train.kda_fused_sites`: the KDA layers whose
+    in-chunk work goes through those of ops/lm_kda.py). A step built without
+    it and then compiled ahead of time for another platform than the default
+    backend's reports the default backend's count.
     """
     if isinstance(net, TokenModel):
         loss_fn, report, bn_axis = _token_loss(net, cfg, axis_name, platform)
