@@ -222,7 +222,10 @@ def train_phase(size: Size, n_dev: int, work: str, clog, registry, on_tpu: bool,
         "loss_first_last": [round(losses[0], 4), round(losses[-1], 4)],
         "eval": {k: result[k] for k in ("eval_n", "eval_loss", "eval_top1")},
         "obs_compiles": last.get("obs/obs.compiles"),
-        "step_compiles": len(in_step), "step_compile_s": in_step[0]["s"],
+        "step_compiles": len(in_step), "step_compile_s": in_step[0]["compile_s"],
+        # the step's own split, and the whole phase's (the watch: trace, lowering, compile, the collector)
+        "step_trace_s": in_step[0]["trace_s"], "step_lower_s": in_step[0]["lower_s"],
+        **{k: comp[k] for k in ("trace_s", "lower_s", "gc_s")},
         "compiles_in_steady_windows": len(late),
         "batch_rows_per_device": rows_per_dev,
         "bytes_in_use_per_device": in_use,
@@ -234,7 +237,9 @@ def train_phase(size: Size, n_dev: int, work: str, clog, registry, on_tpu: bool,
         f"set-up {out['setup_s']} s (first window {out['first_window_s']} s; "
         f"{comp['compiles']} compiles {comp['compile_s']} s, cache {comp['cache_hits']} hit / "
         f"{comp['cache_misses']} miss), steady {out['steady_ms_per_step']} ms/step (smoke), "
-        f"step compiled once ({in_step[0]['s']} s), 0 compilations in steps "
+        f"step compiled once (trace {in_step[0]['trace_s']:.2f} s, lower {in_step[0]['lower_s']:.2f} s, "
+        f"compile {in_step[0]['compile_s']:.2f} s, cache {in_step[0]['cache']}; the phase's collector "
+        f"{comp['gc_s']} s), 0 compilations in steps "
         f"{2 * size.log_every}..{size.steps}, loss {losses[0]:.3f} -> {losses[-1]:.3f}, "
         f"eval n={result['eval_n']} loss {result['eval_loss']:.3f}, checkpoint at {size.steps}")
     say(f"train: per device rows {rows_per_dev} bytes_in_use {in_use}")

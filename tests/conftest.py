@@ -42,8 +42,15 @@ def an_older_cells_file_reads_the_manifest_as_its_pr_left_it(request, monkeypatc
     whole manifest), and no file of that directory may be edited by a later PR
     (the driver refuses a PR that touches a benchmark file). So the same cut
     is laid under a plain read too: inside such a module, `open` of
-    BENCHMARK.json yields the manifest cut back to the module's `CELL`. For
-    the newest cell's file that is the file as it stands."""
+    BENCHMARK.json yields the manifest cut back to the module's `CELL`.
+
+    `cut_back_to` drops later CELLS, not later METRICS: an every-cell metric
+    that a later PR appended (no `workloads` key, or a list of all the train
+    cells) would still reach a file that holds the exact set its cell reports
+    (test_ouro_cell.py). So the plain read also leaves out the `per_layer`
+    entries APPENDED after the cell's own: every entry that stands after the
+    last one which, in the cut, lists the cell ALONE (the last metric its PR
+    brought). An entry moved, removed or put before that one still shows."""
     module = request.module
     cell = getattr(module, "CELL", None)
     if not isinstance(cell, str) or os.path.dirname(getattr(module, "__file__", "")) != _BENCH_TESTS:
@@ -53,10 +60,15 @@ def an_older_cells_file_reads_the_manifest_as_its_pr_left_it(request, monkeypatc
     cut = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(cut)
 
+    def as_the_cells_pr_left_it(manifest):
+        view = cut.cut_back_to(manifest, cell)
+        own = [i for i, m in enumerate(view["per_layer"]) if m.get("workloads") == [cell]]
+        return {**view, "per_layer": view["per_layer"][:own[-1] + 1]} if own else view
+
     def cut_open(path, *args, **kwargs):
         if os.path.basename(str(path)) == "BENCHMARK.json" and not (set("wa+") & set(args[0] if args else kwargs.get("mode", "r"))):
             with open(path) as f:
-                return io.StringIO(json.dumps(cut.cut_back_to(json.load(f), cell)))
+                return io.StringIO(json.dumps(as_the_cells_pr_left_it(json.load(f))))
         return open(path, *args, **kwargs)
 
     monkeypatch.setattr(module, "open", cut_open, raising=False)

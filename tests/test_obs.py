@@ -465,7 +465,8 @@ def test_compile_watch_counts_compiles_and_names_the_open_span():
         obs_trace._TRACER = prev
     mine = [e for e in got["events"] if "only_compiled_here" in e["fun"]]
     assert len(mine) == 1 and got["compiles"] >= 1
-    assert mine[0]["open"] == ["dispatch/train_step"] and mine[0]["s"] >= 0
+    assert mine[0]["open"] == ["dispatch/train_step"] and mine[0]["compile_s"] >= 0
+    assert mine[0]["cache"] == "off" and mine[0]["trace_s"] > 0 and mine[0]["lower_s"] > 0  # the CPU has no cache
     assert {"train_step", "serve_requests"} <= set(mine[0])
     assert (got["cache_hits"], got["cache_misses"]) == (1, 1)
     assert after["jax.backend_compiles"] - before.get("jax.backend_compiles", 0) == got["compiles"]
@@ -476,6 +477,276 @@ def test_compile_watch_counts_compiles_and_names_the_open_span():
     marks = [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "compile/jit(only_compiled_here)"]
     assert len(marks) == 1 and marks[0]["ph"] == "i" and marks[0]["cat"] == "compile"
     assert marks[0]["args"]["open"] == ["dispatch/train_step"]
+
+
+@pytest.fixture
+def watch():
+    """The process's compile watch with an enabled tracer of the test's own,
+    put back afterwards: (watch, tracer)."""
+    from yet_another_mobilenet_series_tpu.obs.device import install_compile_watch
+    from yet_another_mobilenet_series_tpu.utils import compile_cache
+
+    compile_cache.configure()
+    prev = obs_trace.get_tracer()
+    tr = obs_trace.configure(enabled=True, ring_size=256)
+    yield install_compile_watch(), tr
+    obs_trace._TRACER = prev
+
+
+def test_compile_watch_counts_outermost_traces_and_their_seconds_once(watch):
+    """A jitted function traced inside another's trace reports too (jax's own
+    jnp helpers among them), and so do the traces a lowering rule makes: ONE
+    trace is counted for the program, and its seconds once, so that the
+    histogram's sum is wall time."""
+    import jax
+    import jax.monitoring
+    import jax.numpy as jnp
+
+    watch, tr = watch
+    reported = []
+    listener = lambda name, secs, **kw: reported.append(kw.get("fun_name")) if name == watch.TRACE else None  # noqa: E731
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        @jax.jit
+        def nested_inside(x):
+            return jnp.tanh(x) * 1.75
+
+        def traced_once_outermost(x):
+            return nested_inside(x) + jnp.where(x > 0, x, 0.5) + jnp.cumsum(x)  # cumsum: its lowering traces again
+
+        before = get_registry().snapshot()
+        mark = watch.mark()
+        t0 = time.perf_counter()
+        jax.block_until_ready(jax.jit(traced_once_outermost)(jnp.ones((4,))))
+        wall = time.perf_counter() - t0
+        got, after = watch.since(mark), get_registry().snapshot()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    mine = [n for n in reported if n in ("nested_inside", "traced_once_outermost")]
+    assert sorted(mine) == ["nested_inside", "traced_once_outermost"] and len(reported) > 4  # JAX reported the inner ones
+    # ...and the watch counted the programs, not the helpers: eager jnp calls made for the
+    # arguments are programs of their own, each with ONE trace
+    assert after["jax.traces"] - before["jax.traces"] == got["compiles"] < len(reported)
+    (event,) = [e for e in got["events"] if "traced_once_outermost" in e["fun"]]
+    seconds = after["jax.trace_seconds.sum"] - before["jax.trace_seconds.sum"]
+    assert 0 < event["trace_s"] <= seconds <= wall  # once: the nested traces' seconds are inside it
+    assert seconds + after["jax.lower_seconds.sum"] - before["jax.lower_seconds.sum"] <= wall
+    drawn = [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"].startswith("compile/trace:")]
+    assert "compile/trace:traced_once_outermost" in [e["name"] for e in drawn]
+    assert not [e for e in drawn if "nested_inside" in e["name"]] and all(e["ph"] == "X" for e in drawn)
+    assert [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "compile/lower:jit(traced_once_outermost)"]
+
+
+def test_compile_watch_draws_the_outermost_stretches_as_profiler_annotations(watch):
+    """With the tracer on, JAX's start mark opens a TraceAnnotation for the
+    outermost trace, lowering and compile, closed where the stretch ends: one
+    each a program, none for what is nested inside; none at all with it off."""
+    import jax
+    import jax.numpy as jnp
+
+    watch, tr = watch
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tr._annotate = Annotation
+
+    def annotated_program(x):
+        return jnp.tanh(x) * 0.375 + jnp.cumsum(x)
+
+    jax.block_until_ready(jax.jit(annotated_program)(jnp.ones((5,))))
+    mine = [(what, name) for what, name in log if "annotated_program" in name]
+    assert mine == [(what, name) for name in ("compile/trace:annotated_program", "compile/lower:jit(annotated_program)",
+                                              "compile/jit(annotated_program)") for what in ("enter", "exit")]
+    assert not [name for _, name in log if "tanh" in name or "cumsum" in name]  # nested: counted by nobody, drawn by nobody
+    assert watch._here.depth == 0 and watch._here.annotation is None
+    obs_trace.configure(enabled=False)
+    del log[:]
+    jax.block_until_ready(jax.jit(lambda x: annotated_program(x) * 2)(jnp.ones((5,))))
+    assert log == []
+
+
+def _as_jax_reports(kind, secs, fun_name, inside=()):
+    """One stretch exactly as jax's LogElapsedTimeContextManager records it:
+    a scalar where it starts, a duration and a time span where it ends."""
+    import jax.monitoring as mon
+
+    t = time.time()
+    mon.record_scalar(kind, t, fun_name=fun_name)
+    for args in inside:
+        args()
+    mon.record_event_duration_secs(kind, secs, fun_name=fun_name)
+    mon.record_event_time_span(kind, t, t + secs, fun_name=fun_name)
+
+
+def test_compile_watch_puts_lowering_and_cache_read_on_the_right_programs_event(watch):
+    """The CPU has no persistent cache, so JAX's events are recorded by hand,
+    as JAX orders them: two programs made at once on two threads, one read
+    from the cache and one compiled anew; a nested trace inside the first."""
+    import threading
+
+    import jax.monitoring as mon
+
+    watch, tr = watch
+    before = get_registry().snapshot()
+    mark = watch.mark()
+    turn = [threading.Event(), threading.Event()]
+
+    def other_thread():
+        turn[0].wait(10)
+        _as_jax_reports(watch.TRACE, 0.25, "cold_step")
+        _as_jax_reports(watch.LOWER, 0.125, "jit(cold_step)")
+        turn[1].set()
+        turn[0].wait(10)
+        _as_jax_reports(watch.COMPILE, 8.0, "jit(cold_step)", inside=[lambda: mon.record_event(watch.MISS)])
+
+    t = threading.Thread(target=other_thread)
+    t.start()
+    _as_jax_reports(watch.TRACE, 2.0, "warm_step",
+                    inside=[lambda: _as_jax_reports(watch.TRACE, 0.5, "_where")])
+    turn[0].set()
+    turn[1].wait(10)  # the other thread's trace and lowering land between this program's trace and lowering
+    _as_jax_reports(watch.LOWER, 0.5, "jit(warm_step)",
+                    inside=[lambda: _as_jax_reports(watch.TRACE, 0.0625, "_cumsum")])
+    _as_jax_reports(watch.COMPILE, 1.5, "jit(warm_step)", inside=[
+        lambda: mon.record_event(watch.HIT),
+        lambda: mon.record_event_duration_secs(watch.CACHE_READ, 1.25)])
+    turn[0].set()
+    t.join(10)
+    assert not t.is_alive()
+    got, after = watch.since(mark), get_registry().snapshot()
+    events = {e["fun"]: e for e in got["events"]}
+    warm, cold = events["jit(warm_step)"], events["jit(cold_step)"]
+    assert (warm["trace_s"], warm["lower_s"], warm["compile_s"], warm["cache"], warm["cache_read_s"]) \
+        == (2.0, 0.5, 1.5, "hit", 1.25)
+    assert (cold["trace_s"], cold["lower_s"], cold["compile_s"], cold["cache"], cold["cache_read_s"]) \
+        == (0.25, 0.125, 8.0, "miss", 0.0)
+    assert (got["compiles"], got["trace_s"], got["lower_s"], got["compile_s"], got["cache_read_s"]) \
+        == (2, 2.25, 0.62, 9.5, 1.25)
+    assert (got["cache_hits"], got["cache_misses"]) == (1, 1) and warm["t"] <= time.perf_counter()
+    moved = lambda key: after[key] - before.get(key, 0.0)  # noqa: E731
+    assert moved("jax.traces") == 2 and moved("jax.trace_seconds.sum") == 2.25  # `_where`, `_cumsum`: inside
+    assert moved("jax.lower_seconds.sum") == 0.625 and moved("jax.cache_read_seconds.sum") == 1.25
+    assert moved("jax.backend_compile_seconds.sum") == 9.5  # keeps its meaning: it contains the read
+    drawn = {e["name"]: e for e in tr.to_chrome_trace()["traceEvents"] if e.get("cat") == "compile"}
+    assert drawn["compile/trace:warm_step"]["dur"] == pytest.approx(2e6, rel=1e-3)
+    assert drawn["compile/lower:jit(cold_step)"]["ph"] == "X" and "compile/trace:_where" not in drawn
+    assert drawn["compile/jit(warm_step)"]["ph"] == "i" and drawn["compile/jit(warm_step)"]["args"]["cache"] == "hit"
+
+
+def test_compile_watch_a_lowering_whose_trace_jax_answered_from_memory_starts_its_own_program(watch):
+    """`jit(f).lower()` of a function traced before reports no trace; a trace
+    that nothing compiled (`eval_shape`) must not be handed to the next program."""
+    watch, _ = watch
+    mark = watch.mark()
+    _as_jax_reports(watch.TRACE, 4.0, "only_shapes_wanted")  # eval_shape: traced, never lowered
+    _as_jax_reports(watch.LOWER, 0.25, "jit(step)")
+    _as_jax_reports(watch.COMPILE, 0.5, "jit(step)")
+    (event,) = watch.since(mark)["events"]
+    assert (event["fun"], event["trace_s"], event["lower_s"], event["cache"]) == ("jit(step)", 0.0, 0.25, "off")
+
+
+def test_compile_watch_a_forced_collection_is_one_full_collection_and_one_gc_full_span(watch):
+    import gc
+
+    watch, tr = watch
+    reg = get_registry()
+    before = reg.snapshot()
+    t0 = time.perf_counter()
+    gc.collect()
+    t1 = time.perf_counter()
+    after = reg.snapshot()
+    assert after["host.gc_full_collections"] - before["host.gc_full_collections"] == 1
+    assert after["host.gc_collections"] - before["host.gc_collections"] == 1
+    pause = after["host.gc_pause_seconds"] - before["host.gc_pause_seconds"]
+    assert 0 < pause <= t1 - t0 and after["host.gc_max_pause_seconds"] >= pause > 0
+    spans = [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "gc/full"]
+    assert len(spans) == 1 and spans[0]["ph"] == "X" and spans[0]["cat"] == "gc" and "gc" in obs_trace.SPAN_CATEGORIES
+    assert spans[0]["dur"] == pytest.approx(pause * 1e6, rel=1e-6) and "collected" in spans[0]["args"]
+    # the ring keeps pauses over a millisecond, stamped on perf_counter where they started
+    if pause > watch.GC_RING_FLOOR_S:
+        assert watch.gc_max_pause_between(t0, t1) == pytest.approx(pause)
+    assert watch.gc_max_pause_between(t1, t1 + 1.0) == 0.0
+    gc.collect(0)  # a young collection is counted, not drawn
+    assert reg.gauge("host.gc_collections").value - after["host.gc_collections"] == 1
+    assert reg.gauge("host.gc_full_collections").value == after["host.gc_full_collections"]
+    assert len([e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "gc/full"]) == 1
+
+
+def test_compile_watch_a_collection_while_the_registrys_locks_are_held_does_not_hang(watch):
+    """A collection can start inside Histogram.observe or MetricsRegistry._get
+    while their lock is held: the collector's callback must not want any of
+    them (nor the watch's own, nor the tracer's open-span stack). Here the
+    locks are held by THIS thread while another one collects."""
+    import gc
+    import threading
+
+    watch, tr = watch
+    reg = get_registry()
+    held = [reg._lock, watch._lock, reg.histogram("jax.trace_seconds")._lock, reg.counter("jax.traces")._lock,
+            reg.counter("obs.misnested_spans")._lock]
+    full = watch.gc_collections[2]
+    done = threading.Event()
+
+    def collect():
+        gc.collect()
+        done.set()
+
+    t = threading.Thread(target=collect, daemon=True)
+    for lock in held:
+        lock.acquire()
+    try:
+        t.start()
+        finished = done.wait(20)
+    finally:
+        for lock in held:
+            lock.release()
+    t.join(20)
+    assert finished and not t.is_alive(), "the collector's callback waited for a lock that other code takes"
+    assert watch.gc_collections[2] == full + 1 and watch.gc_callback_errors == 0
+    assert [e for e in tr.to_chrome_trace()["traceEvents"] if e["name"] == "gc/full"]
+
+
+def test_compile_watch_collector_callback_swallows_its_own_errors(watch):
+    watch, _ = watch
+    errors, seconds = watch.gc_callback_errors, list(watch.gc_seconds)
+    watch._on_gc("start", {})  # no generation: not what the interpreter hands over
+    watch._on_gc("start", {"generation": 0})
+    watch._on_gc("stop", {"generation": 7, "collected": 0})  # no such generation
+    assert watch.gc_callback_errors == errors + 2 and watch.gc_seconds == seconds
+    watch._on_gc("stop", {"generation": 0, "collected": 0})  # a stop with no start is not a pause
+    assert watch.gc_seconds == seconds
+
+
+def test_install_compile_watch_twice_appends_one_callback_and_restores_the_gauges():
+    import gc
+
+    from yet_another_mobilenet_series_tpu.obs.device import CompileWatch, install_compile_watch
+
+    first = install_compile_watch()
+    assert install_compile_watch() is first
+    mine = [cb for cb in gc.callbacks if getattr(cb, "__self__", None).__class__ is CompileWatch]
+    assert len(mine) == 1 and mine[0].__self__ is first
+    # a registry that was emptied (tests do it) gets the pull gauges and the zeroes back
+    reg = get_registry()
+    saved = dict(reg._metrics)
+    try:
+        reg.reset()
+        install_compile_watch()
+        snap = reg.snapshot()
+    finally:
+        with reg._lock:
+            reg._metrics.update(saved)
+    assert snap["host.gc_collections"] == sum(first.gc_collections) > 0
+    assert snap["jax.cache_read_seconds.sum"] == 0.0 and snap["jax.traces"] == 0.0
 
 
 # ---------------------------------------------------------------------------

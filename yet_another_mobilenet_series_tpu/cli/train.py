@@ -495,11 +495,13 @@ def _run_impl(cfg: Config, log: Logger, mesh, is_coord: bool, tracer, watchdog) 
 
 
 def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger,
-                      first_dispatch_s: float, scope_table_dir: str = "") -> None:
+                      first_dispatch_s: float, watched: dict, scope_table_dir: str = "") -> None:
     """Device-cost accounting for the compiled train step (obs/device.py):
     the first dispatch's host wall time (≈ trace + compile under async
     dispatch — the run never blocks on device execution here) lands in
-    ``obs.compile_seconds``, and a one-time re-lower of the step records its
+    ``obs.compile_seconds``; ``watched`` is what the compile watch saw across
+    that dispatch (``CompileWatch.since``), and the log line says what the
+    time was made of. A one-time re-lower of the step records its
     cost_analysis FLOPs/bytes into the ``train_step`` cost gauges. Lowering
     traces but does NOT compile, so the one-off cost is seconds of host
     time per trainer build — amortized to noise over a run. Telemetry only:
@@ -526,10 +528,14 @@ def _record_step_cost(trainer: Trainer, ts, batch, rng, reg, tracer, log: Logger
         log.log(f"train step cost_analysis unavailable ({type(e).__name__}: {e})")
         return
     if cost.get("flops"):
+        read_from_cache = watched["cache_hits"] and not watched["cache_misses"]
+        backend = (f"cache read {watched['cache_read_s']:.2f}" if read_from_cache
+                   else f"compile {watched['compile_s']:.2f}")
         log.log(
             f"train step cost_analysis: {cost['flops'] / 1e9:.3f} GFLOP, "
             f"{cost.get('bytes', 0) / 1e6:.1f} MB accessed per step "
-            f"(first dispatch {first_dispatch_s:.1f}s ≈ trace+compile)"
+            f"(first dispatch {first_dispatch_s:.1f}s: trace {watched['trace_s']:.2f} + lower "
+            f"{watched['lower_s']:.2f} + {backend} s, the collector {watched['gc_s']:.2f} s of it)"
         )
     if scope_table_dir:
         try:
@@ -637,6 +643,7 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
     # first dispatch, and again after a rematerialize rebuild (new shapes =>
     # new executable => new cost)
     cost_recorded = not is_coord
+    compile_watch = obs_device.install_compile_watch()  # compile_cache.configure() put it in: this is the handle
 
     try:
         while epoch < total_epochs:
@@ -650,13 +657,14 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                 with tracer.span("data/next", "data"):
                     b = next(train_iter)  # already on-mesh (prefetch_to_mesh)
                 t_dispatch0 = time.perf_counter()
+                watch_mark = None if cost_recorded else compile_watch.mark()
                 with tracer.span("dispatch/train_step", "dispatch"):
                     ts, metrics = trainer.train_step(ts, b, rng)
                 if not cost_recorded:
                     cost_recorded = True
                     _record_step_cost(
                         trainer, ts, b, rng, reg, tracer, log,
-                        time.perf_counter() - t_dispatch0,
+                        time.perf_counter() - t_dispatch0, compile_watch.since(watch_mark),
                         scope_table_dir=cfg.train.log_dir + "/trace" if cfg.train.profile_start_step else "")
                 steps_done += 1
                 # the metrics entries are lazy device arrays: nothing below

@@ -62,6 +62,7 @@ is wrapped and a miss records nothing.
 from __future__ import annotations
 
 import collections
+import gc
 import os
 import threading
 import time
@@ -163,22 +164,65 @@ def compile_report() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the compile watch (jax.monitoring: costs nothing until something compiles)
+# the compile watch (jax.monitoring + gc.callbacks: the host's stops, timed
+# where they happen; costs nothing until something is traced, compiled or collected)
 # ---------------------------------------------------------------------------
 
 
-class CompileWatch:
-    """Every backend compile and persistent-cache hit or miss JAX reports,
-    as registry metrics and as a bounded list of events, each compile stamped
-    with where the program was: the host spans open on the compiling thread
-    at that moment, the last logged train step and the number of serving requests
-    accepted so far. Registry and tracer are fetched by call: an entry point
-    configures its tracer after the watch is installed. One per process
-    (:func:`install_compile_watch`)."""
+class _Here(threading.local):
+    """What the watch knows of the thread it was called on (JAX's events fire
+    on the thread that does the work). The class attributes are each thread's
+    first values."""
 
+    depth = 0  # trace, lowering and compile stretches open on this thread
+    t0_ns = 0  # with the tracer on: where the outermost of them started...
+    annotation = None  # ...and its TraceAnnotation, open until it ends
+    program = None  # the program being made here, until its compile arrives: {fun, trace_s, lower_s}
+    cache = "off"  # what the persistent cache answered for it: "hit" | "miss" | "off"
+    cache_read_s = 0.0
+
+
+class CompileWatch:
+    """The host's stops, as JAX and the interpreter report them. A program's
+    tracing, lowering, persistent-cache read and backend compile
+    (``jax.monitoring``) land in the registry and in a bounded list of events,
+    one a program, each stamped with where the program was: the host spans
+    open on the compiling thread at that moment, the last logged train step
+    and the number of serving requests accepted so far. The collector's
+    pauses (``gc.callbacks``) land in plain fields that the registry reads
+    through pull gauges. Registry and tracer are fetched by call: an entry
+    point configures its tracer after the watch is installed. One per process
+    (:func:`install_compile_watch`).
+
+    **Outermost traces only.** A jitted function traced inside another's trace
+    (jax's own ``jnp`` helpers: thousands in a token step) reports too, and
+    its seconds lie inside the outer one's; so do the traces a lowering rule
+    makes. JAX marks the START of each stretch with a scalar event
+    (``LogElapsedTimeContextManager.__enter__``), which is what makes
+    "outermost" decidable when a stretch ends: the watch keeps a depth a
+    thread, and a trace that ends at depth 0 is counted. The sum of
+    ``jax.trace_seconds`` is therefore wall time, and for a nested trace the
+    listeners do a comparison and return. With the tracer on, the same start
+    mark opens a ``jax.profiler.TraceAnnotation`` for the outermost stretch
+    (``compile/trace:<fun>``, ``compile/lower:<fun>``, ``compile/<fun>``), so
+    inside a profiler window a recompile lies over the idle gap it caused.
+
+    **The collector's callback takes no lock and touches no registry
+    object.** A collection can start wherever an object is allocated:
+    inside ``Histogram.observe`` or ``MetricsRegistry._get`` while their lock
+    is held, or in the middle of a span's ``_push``. A callback that asked for
+    such a lock would hang the process. Collections never overlap (the
+    interpreter runs one at a time), so the fields need no lock of their own."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
     COMPILE = "/jax/core/compile/backend_compile_duration"
+    CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
     HIT = "/jax/compilation_cache/cache_hits"
     MISS = "/jax/compilation_cache/cache_misses"
+    # the stretches whose start JAX marks, and what each is called where it is drawn
+    _DRAWN = {TRACE: "compile/trace:", LOWER: "compile/lower:", COMPILE: "compile/"}
+    GC_RING_FLOOR_S = 1e-3  # the ring keeps pauses longer than this
 
     def __init__(self, keep: int = 4096):
         import jax.monitoring as mon
@@ -187,17 +231,90 @@ class CompileWatch:
         self._events: collections.deque = collections.deque(maxlen=keep)
         self._lock = threading.Lock()
         self._seq = 0
+        self._here = _Here()
+        # the collector, by generation; written by _on_gc alone
+        self.gc_seconds = [0.0, 0.0, 0.0]
+        self.gc_collections = [0, 0, 0]
+        self.gc_max_pause = [0.0, 0.0, 0.0]
+        self.gc_callback_errors = 0
+        # (t, generation, seconds, collected) of the pauses over GC_RING_FLOOR_S;
+        # t is time.perf_counter() where the collection started
+        self.gc_pauses: collections.deque = collections.deque(maxlen=1024)
+        self._gc_t0_ns = 0
+        self._gc_annotation = None
+        mon.register_scalar_listener(self._on_start)
         mon.register_event_duration_secs_listener(self._on_duration)
         mon.register_event_listener(self._on_event)
 
-    def _on_duration(self, name, secs, fun_name="", **_):
-        if name != self.COMPILE:
+    # -- jax.monitoring -------------------------------------------------------
+
+    def _on_start(self, name, value, fun_name="", **_):
+        if name not in self._DRAWN:
             return
+        here = self._here
+        here.depth += 1
+        if here.depth == 1:
+            tracer = get_tracer()
+            if tracer.enabled:
+                # the outermost stretch is drawn, on the profiler's clock too
+                here.t0_ns = time.perf_counter_ns()
+                here.annotation = tracer.annotation(self._DRAWN[name] + fun_name)
+
+    def _ended(self, here: _Here, name: str, secs: float, fun_name: str) -> None:
+        """An outermost stretch that :meth:`_on_start` drew ends: its
+        annotation closes, and the trace and the lowering become complete
+        events in ``obs_trace.json`` (the compile is the instant that is there)."""
+        annotation, here.annotation = here.annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        t0_ns, here.t0_ns = here.t0_ns, 0
+        if t0_ns and name != self.COMPILE:
+            get_tracer().complete(self._DRAWN[name] + fun_name, "compile", t0_ns, int(secs * 1e9))
+
+    def _on_duration(self, name, secs, fun_name="", **_):
+        here = self._here
+        if name == self.CACHE_READ:
+            # fires on the compiling thread just before that program's COMPILE
+            get_registry().histogram("jax.cache_read_seconds").observe(secs)
+            here.cache_read_s = secs
+            return
+        if name not in self._DRAWN:
+            return
+        here.depth = max(here.depth - 1, 0)
+        if not here.depth:
+            self._ended(here, name, secs, fun_name)
+        elif name == self.TRACE:
+            return  # inside another trace or a lowering: its seconds are in that one's
+        if name == self.TRACE:
+            reg = get_registry()
+            reg.counter("jax.traces").inc()
+            reg.histogram("jax.trace_seconds").observe(secs)
+            here.program = {"fun": fun_name, "trace_s": secs, "lower_s": 0.0}
+        elif name == self.LOWER:
+            get_registry().histogram("jax.lower_seconds").observe(secs)
+            program = here.program
+            # the trace says `f`, the lowering and the compile `jit(f)`; a trace
+            # that JAX answered from memory reports nothing, and then the
+            # program starts here
+            if program is None or program["lower_s"] or program["fun"] not in fun_name:
+                program = here.program = {"trace_s": 0.0}
+            program.update(fun=fun_name, lower_s=secs)
+        else:
+            self._compiled(here, secs, fun_name)
+
+    def _compiled(self, here: _Here, secs: float, fun_name: str) -> None:
         reg, tracer = get_registry(), get_tracer()
         reg.counter("jax.backend_compiles").inc()
         reg.histogram("jax.backend_compile_seconds").observe(secs)
+        program, here.program = here.program, None
+        if program is None or program["fun"] not in fun_name:
+            program = {"trace_s": 0.0, "lower_s": 0.0}
         event = {
-            "s": round(secs, 3), "fun": fun_name,
+            "fun": fun_name,
+            "trace_s": round(program["trace_s"], 6), "lower_s": round(program["lower_s"], 6),
+            # contains the cache's read on a hit
+            "compile_s": round(secs, 6), "cache": here.cache, "cache_read_s": round(here.cache_read_s, 6),
+            "t": time.perf_counter(),
             # a jitted call compiles on the thread that made it, so this
             # thread's open spans are where the compile happened; another
             # thread's (a prefetch worker mid-fill) are not
@@ -205,6 +322,7 @@ class CompileWatch:
             "train_step": reg.gauge("train.step").value,
             "serve_requests": reg.counter("serve.requests").value,
         }
+        here.cache, here.cache_read_s = "off", 0.0
         with self._lock:
             self._seq += 1
             self._events.append((self._seq, event))
@@ -213,24 +331,69 @@ class CompileWatch:
     def _on_event(self, name, **_):
         if name == self.HIT:
             get_registry().counter("jax.cache_hits").inc()
+            self._here.cache = "hit"
         elif name == self.MISS:
             get_registry().counter("jax.cache_misses").inc()
+            self._here.cache = "miss"
 
-    def mark(self) -> tuple[int, float, float]:
+    # -- gc.callbacks ---------------------------------------------------------
+
+    def _on_gc(self, phase, info):
+        """NO lock, NO registry object (class docstring), and it never raises."""
+        try:
+            if phase == "start":
+                self._gc_t0_ns = time.perf_counter_ns()
+                if info["generation"] == 2:
+                    # a full collection is drawn, on the profiler's clock too;
+                    # the younger ones (tens a second) are counted
+                    self._gc_annotation = get_tracer().annotation("gc/full")
+            elif self._gc_t0_ns:
+                t0_ns, self._gc_t0_ns = self._gc_t0_ns, 0
+                dur_ns = time.perf_counter_ns() - t0_ns
+                annotation, self._gc_annotation = self._gc_annotation, None
+                gen, secs = info["generation"], dur_ns / 1e9
+                if annotation is not None:
+                    annotation.__exit__(None, None, None)
+                if gen == 2:
+                    get_tracer().complete("gc/full", "gc", t0_ns, dur_ns, {"collected": info["collected"]})
+                self.gc_seconds[gen] += secs
+                self.gc_collections[gen] += 1
+                if secs > self.gc_max_pause[gen]:
+                    self.gc_max_pause[gen] = secs
+                if secs > self.GC_RING_FLOOR_S:
+                    self.gc_pauses.append((t0_ns / 1e9, gen, secs, info["collected"]))
+        except Exception:  # noqa: BLE001 — telemetry inside the collector must never raise
+            self.gc_callback_errors += 1
+
+    def gc_pause_seconds(self) -> float:
+        return float(sum(self.gc_seconds))
+
+    def gc_max_pause_between(self, t0: float, t1: float) -> float:
+        """The longest pause that started in [t0, t1] (``time.perf_counter()``
+        seconds); 0.0 where none passed the ring's floor of a millisecond."""
+        return max((secs for t, _, secs, _ in list(self.gc_pauses) if t0 <= t <= t1), default=0.0)
+
+    # -- readout --------------------------------------------------------------
+
+    def mark(self) -> tuple:
         reg = get_registry()
         with self._lock:
             seq = self._seq
-        return seq, reg.counter("jax.cache_hits").value, reg.counter("jax.cache_misses").value
+        return (seq, reg.counter("jax.cache_hits").value, reg.counter("jax.cache_misses").value,
+                self.gc_pause_seconds())
 
     def since(self, mark) -> dict:
-        """What compiled and what the cache answered since :meth:`mark`:
-        {compiles, compile_s, cache_hits, cache_misses, events}."""
-        seq0, h0, m0 = mark
-        seq, hits, misses = self.mark()
+        """What was traced, lowered and compiled, what the cache answered and
+        what the collector took since :meth:`mark`: {compiles, trace_s,
+        lower_s, compile_s, cache_read_s, cache_hits, cache_misses, gc_s,
+        events}; an event is one program."""
+        seq0, h0, m0, gc0 = mark
+        seq, hits, misses, gc1 = self.mark()
         with self._lock:
             new = [e for n, e in self._events if n > seq0]
-        return {"compiles": seq - seq0, "compile_s": round(sum(e["s"] for e in new), 2),
-                "cache_hits": int(hits - h0), "cache_misses": int(misses - m0), "events": new}
+        total = {k: round(sum(e[k] for e in new), 2) for k in ("trace_s", "lower_s", "compile_s", "cache_read_s")}
+        return {"compiles": seq - seq0, **total, "cache_hits": int(hits - h0), "cache_misses": int(misses - m0),
+                "gc_s": round(gc1 - gc0, 3), "events": new}
 
 
 _COMPILE_WATCH: CompileWatch | None = None
@@ -239,12 +402,26 @@ _COMPILE_WATCH_LOCK = threading.Lock()
 
 def install_compile_watch() -> CompileWatch:
     """The process's compile watch, installed on first call (idempotent:
-    ``utils/compile_cache.configure()`` calls this from every entry point)."""
+    ``utils/compile_cache.configure()`` calls this from every entry point):
+    its three ``jax.monitoring`` listeners and its one ``gc.callbacks`` entry
+    go in once; the collector's pull gauges are set on every call, so a
+    registry that was reset gets them again."""
     global _COMPILE_WATCH
     with _COMPILE_WATCH_LOCK:
         if _COMPILE_WATCH is None:
             _COMPILE_WATCH = CompileWatch()
-        return _COMPILE_WATCH
+            gc.callbacks.append(_COMPILE_WATCH._on_gc)
+        watch = _COMPILE_WATCH
+    reg = get_registry()
+    # there from the start, so that "nothing was read from the cache" is a 0 and not a gap
+    reg.counter("jax.traces")
+    for name in ("jax.trace_seconds", "jax.lower_seconds", "jax.cache_read_seconds"):
+        reg.histogram(name)
+    reg.gauge("host.gc_pause_seconds").set_fn(watch.gc_pause_seconds)
+    reg.gauge("host.gc_collections").set_fn(lambda: sum(watch.gc_collections))
+    reg.gauge("host.gc_full_collections").set_fn(lambda: watch.gc_collections[2])
+    reg.gauge("host.gc_max_pause_seconds").set_fn(lambda: max(watch.gc_max_pause))
+    return watch
 
 
 # ---------------------------------------------------------------------------

@@ -69,9 +69,10 @@ from .registry import get_registry
 # `<cat>/<name>`, and that is how a reader of the profiler's xplane tells the
 # program's annotations from everything else on a host thread's line
 # (scripts/trace_ops.py). `fleet` spans carry cat "serve"; `compile` is the
-# compile watch's instants (obs/device.py).
+# compile watch's instants and its trace / lowering stretches, `gc` its
+# `gc/full` span, a full collection of the interpreter's (obs/device.py).
 SPAN_CATEGORIES = ("data", "dispatch", "sync", "prune", "eval", "ckpt", "rebuild", "serve",
-                   "fleet", "compile")
+                   "fleet", "compile", "gc")
 
 
 class _NullSpan:
@@ -103,11 +104,8 @@ class _Span:
     def __enter__(self):
         self.t0_ns = time.perf_counter_ns()
         self._tracer._push(self)
-        annotate = self._tracer._annotate or self._tracer._find_annotate()
-        if annotate is not None:
-            # the same stretch on the profiler's clock, on this thread's line
-            self._annotation = annotate(self.name)
-            self._annotation.__enter__()
+        # the same stretch on the profiler's clock, on this thread's line
+        self._annotation = self._tracer.annotation(self.name)
         return self
 
     def __exit__(self, *exc):
@@ -211,6 +209,27 @@ class SpanTracer:
         self._events.append(
             (ph, name, cat, time.perf_counter_ns(), 0, threading.get_ident(), args, ev_id)
         )
+
+    def complete(self, name: str, cat: str, t0_ns: int, dur_ns: int, args: dict | None = None) -> None:
+        """One FINISHED stretch, timed by the caller on ``perf_counter_ns``,
+        straight into the ring: no open-span stack, no registry, no lock. For
+        callers that learn of a stretch when it is over (the compile watch's
+        trace and lowering events) or that may run in the middle of another
+        span's ``_push`` or ``_pop`` (its collector callback)."""
+        if self.enabled:
+            self._events.append(("X", name, cat, t0_ns, dur_ns, threading.get_ident(), args, None))
+
+    def annotation(self, name: str):
+        """An ENTERED ``jax.profiler.TraceAnnotation`` for a stretch the caller
+        closes itself (``__exit__``) and records with :meth:`complete`; None
+        from a disabled tracer or in a process without jax. Lock-free, as
+        :meth:`complete` is."""
+        annotate = self._annotate or self._find_annotate()
+        if annotate is None:
+            return None
+        entered = annotate(name)
+        entered.__enter__()
+        return entered
 
     def instant(self, name: str, cat: str = "misc", **args) -> None:
         """One point in time on the calling thread's row (``ph: i``): the
