@@ -5,10 +5,15 @@ ops/lm_kda.py costs alone, forward and forward + backward, and how the core's
 time moves with its three module constants. What chose them (PERF.md, PR 33).
 
     python scripts/bench_kda.py [--iters 5] [--chunks 64,128] [--subs 16] [--groups 8] [--core-only]
+    python scripts/bench_kda.py --conv-only [--conv-tiles 512x512,1024x512]
 
 Beside the plain pieces it times the two fused kernels of ops/lm_kda_kernels.py
 (PR 36) wherever `lm_kda.fuses` takes the shape: the forward kernel, the
 kernel pair, and `kda_core` through each form (`--core-only` stops there).
+And the short convolution with its SiLU and, for q and k, the L2 norm of a
+head (`lm_kda.conv_and_norm`), plain and as the pair of conv kernels, at each
+tile of `--conv-tiles` (rows x lanes; `--conv-only` times nothing else), with
+the kernels' largest deviation from the plain form on the chip.
 
 Measures on a TPU or exits 3. Prints one JSON line a piece: ms a call (host
 clock around `iters` calls ending in a sync).
@@ -59,6 +64,8 @@ def main() -> int:
     ap.add_argument("--subs", default=str(lm_kda.KDA_SUBCHUNK))
     ap.add_argument("--groups", default=str(lm_kda.KDA_HEAD_GROUP))
     ap.add_argument("--core-only", action="store_true", help="kda_core and its operands through both forms, nothing else")
+    ap.add_argument("--conv-only", action="store_true", help="the short convolution and L2 norm through both forms, nothing else")
+    ap.add_argument("--conv-tiles", default="", help="rows x lanes of the conv kernels' tile, comma-separated (default: the module's)")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print(f"bench_kda: no TPU (platform {jax.devices()[0].platform!r}): this script measures on the chip", file=sys.stderr)
@@ -72,6 +79,8 @@ def main() -> int:
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
     core_args = (q, k, v, g, beta)
     it = args.iters
+    if args.conv_only:
+        return conv(args, ks, it)
 
     for chunk in map(int, args.chunks.split(",")):
         for sub in map(int, args.subs.split(",")):
@@ -188,6 +197,38 @@ def main() -> int:
             say(f"latent attention core, {name}: refused", -1.0, error=str(e)[:400])
     say("l2 norm, one of two, fwd+bwd",
         timed(jax.jit(jax.grad(lambda x: total(lm_kda.l2_normalise(x)))), (q,), it))
+    return conv(args, ks, it)
+
+
+def conv(args, ks, it) -> int:
+    """q's and v's short convolution (+ q's L2 norm), plain and through the conv kernels: what `kda_conv` costs a tensor."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    z = jax.random.normal(ks[5], (B, S, H * D), jnp.bfloat16)
+    w = jax.random.normal(ks[6], (4, H * D), jnp.float32) * 0.5
+    ct = jax.random.normal(ks[7], (B, S, H * D), jnp.bfloat16)
+    # the cotangent an argument, not a constant the executable would carry; the loss is linear in the output, so XLA
+    # drops the forward kernel here and "fwd+bwd" times the backward
+    grads = lambda fn: jax.jit(jax.grad(lambda z_, w_, c_: total(fn(z_, w_) * c_), argnums=(0, 1)))  # noqa: E731
+    deviation = lambda got, want: max(float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))  # noqa: E731
+                                            / jnp.max(jnp.abs(b.astype(jnp.float32)))) for a, b in zip(got, want))
+    tiles = [tuple(map(int, t.split("x"))) for t in args.conv_tiles.split(",") if t] or [(kernels.CONV_ROWS, kernels.CONV_LANES)]
+    for name, scale in (("q: conv + L2 norm", D ** -0.5), ("v: conv", None)):
+        plain = lambda z_, w_, scale=scale: lm_kda._plain_conv(z_, w_, D, scale)  # noqa: E731
+        say(f"{name}, plain, fwd", timed(jax.jit(plain), (z, w), it))
+        say(f"{name}, plain, fwd+bwd", timed(grads(plain), (z, w, ct), it))
+        want = (jax.jit(plain)(z, w), *grads(plain)(z, w, ct))
+        for rows, lanes in tiles:
+            kernels.CONV_ROWS, kernels.CONV_LANES = rows, lanes
+            jax.clear_caches()  # `lm_kda.conv_fwd` / `conv_bwd` are jitted: a trace of another tile would be reused
+            fused = lambda z_, w_, scale=scale: lm_kda._fused_conv(z_, w_, D, scale)  # noqa: E731 - a new function a tile
+            tag = {"rows": rows, "lanes": lanes}
+            say(f"{name}, conv kernels, fwd", timed(jax.jit(fused), (z, w), it), **tag)
+            say(f"{name}, conv kernels, fwd+bwd", timed(grads(fused), (z, w, ct), it), **tag)
+            say(f"{name}, backward kernel alone", timed(jax.jit(lambda z_, w_, c_, scale=scale: lm_kda.conv_bwd(z_, w_, c_, D, scale)),
+                                                       (z, w, ct), it), **tag)
+            print(json.dumps({"piece": f"{name}, conv kernels against plain", **tag,
+                              "deviation": deviation((jax.jit(fused)(z, w), *grads(fused)(z, w, ct)), want)}), flush=True)
     return 0
 
 

@@ -270,6 +270,127 @@ def test_on_a_cpu_the_fitting_shape_runs_the_plain_form_and_its_vjp(monkeypatch)
     assert all(bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(through_the_dispatch), jax.tree.leaves(plain)))
 
 
+# ---- the short convolutions' kernels (ops/lm_kda_kernels.py, `conv_*`) in Pallas interpret mode, against the plain form ----
+CONV_KINDS = pytest.mark.parametrize("scale", [KERNEL_WIDTH ** -0.5, 1.0, None], ids=["q_conv_and_l2", "k_conv_and_l2", "v_conv"])
+# (batch, positions, heads, head dim, rows of a tile, lanes of a tile): several row tiles, several lane blocks, a head of two
+# bands, a sequence shorter than the module's tile (one tile of 48 rows)
+CONV_SHAPES = pytest.mark.parametrize("batch, seq, heads, width, rows, lanes", [
+    (2, 96, 2, 128, 32, 128), (2, 64, 3, 128, 16, 384), (1, 64, 2, 256, 32, 512), (2, 48, 2, 128, 512, 512)],
+    ids=["3_row_tiles_2_lane_blocks", "4_row_tiles_of_16", "head_of_two_bands", "one_short_tile"])
+CONV_LIMITS = (1e-2, 1.5e-2, 5e-3)  # out, dz (bfloat16: ulp 2^-8 of the largest entry), dw (float32 sums of bfloat16 operands)
+
+
+@pytest.fixture
+def conv_kernels(monkeypatch):
+    """ops/lm_kda_kernels.py with some of its names set for one test: `conv_fwd` / `conv_bwd` are jitted, so a
+    trace made under the module's own names is dropped first, and the test's own traces after it."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    def setting(**names):
+        for name, value in names.items():
+            monkeypatch.setattr(kernels, name, value)
+        jax.clear_caches()
+
+    yield setting
+    jax.clear_caches()
+
+
+def conv_operands(batch, seq, heads, width, seed=0):
+    """z and the incoming cotangent (B, S, H * D) in bfloat16, a filter of 4 taps in float32 (as `kda_attention` holds them)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    z = jax.random.normal(ks[0], (batch, seq, heads * width)).astype(jnp.bfloat16)
+    w = jax.random.normal(ks[1], (4, heads * width)) * 0.5
+    return z, w, jax.random.normal(ks[2], z.shape).astype(jnp.bfloat16)
+
+
+def conv_deviations(width, scale, z, w, ct):
+    """(out, dz, dw) of the kernel pair against the plain form and its vjp, each as a share of the plain's largest entry."""
+    got = (jax.jit(lambda *a: lm_kda.conv_fwd(*a, width, scale, interpret=True))(z, w),
+           *jax.jit(lambda *a: lm_kda.conv_bwd(*a, width, scale, interpret=True))(z, w, ct))
+    want = (jax.jit(lambda *a: lm_kda._plain_conv(*a, width, scale))(z, w),
+            *jax.jit(lambda *a: lm_kda._plain_conv_bwd(*a, width, scale))(z, w, ct))
+    assert [(x.shape, x.dtype) for x in got] == [(x.shape, x.dtype) for x in want]
+    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in got)
+    return deviations(got, want)
+
+
+@CONV_KINDS
+@CONV_SHAPES
+def test_the_conv_kernels_equal_the_plain_convolution_norm_and_vjp(conv_kernels, scale, batch, seq, heads, width, rows, lanes):
+    """`conv_fwd` (ONE kernel: causal conv, SiLU and, for q and k, each head's
+    L2 norm) against `short_conv` then `l2_normalise`; `conv_bwd` (the second
+    kernel: dz and the tiles' float32 dw) against the plain form's vjp. Every
+    tile boundary is crossed both ways (the forward's history behind a tile,
+    the backward's cotangent ahead of it), and the plain form's zero history
+    before position 0 is the oracle's. Whole tiles only: `conv_fuses` takes no
+    sequence whose tiles are not all full."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    conv_kernels(CONV_ROWS=rows, CONV_LANES=lanes)
+    assert lm_kda.conv_fuses(seq, width, 4, jnp.bfloat16)
+    assert kernels.conv_cut(seq, heads * width, width) == (min(rows, seq), min(lanes, heads * width))
+    devs = conv_deviations(width, scale, *conv_operands(batch, seq, heads, width))
+    assert all(d < limit for d, limit in zip(devs, CONV_LIMITS)), devs
+
+
+def _reads_one_row_ahead(x, back, rows):
+    from jax.experimental.pallas import tpu as pltpu
+
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    return pltpu.roll(x, (back - 1) % x.shape[0], 0)[kernels.CONV_HALO:kernels.CONV_HALO + rows]
+
+
+def _drops_the_halo(ref, band, first_row):
+    x = jnp.zeros((ref.shape[0], band.stop - band.start), jnp.float32)
+    return x, first_row + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+
+
+@pytest.mark.parametrize("fault, planted", [("_behind", _reads_one_row_ahead), ("_history", _drops_the_halo)],
+                         ids=["reads_one_row_ahead", "drops_the_halo"])
+def test_a_conv_kernel_that_peeks_ahead_or_drops_its_halo_is_refused(conv_kernels, fault, planted):
+    """The comparison above refuses a forward that reads the position after
+    its own (not causal), and a pair that loses the rows a tile takes from
+    its neighbours (right inside every tile, wrong at every boundary)."""
+    conv_kernels(CONV_ROWS=32, CONV_LANES=128, **{fault: planted})
+    devs = conv_deviations(KERNEL_WIDTH, KERNEL_WIDTH ** -0.5, *conv_operands(2, 96, 2, KERNEL_WIDTH, seed=5))
+    assert not all(d < limit for d, limit in zip(devs, CONV_LIMITS)), devs
+
+
+@pytest.mark.parametrize("seq, dtype, width, takes", [
+    (128, jnp.bfloat16, 128, True), (120, jnp.bfloat16, 128, False), (128, jnp.float32, 128, False), (128, jnp.bfloat16, 64, False)],
+    ids=["fits", "sequence_not_whole_16_row_tiles", "float32_operands", "head_dim_64"])
+def test_the_conv_dispatch_takes_the_kernels_by_the_shapes_alone(monkeypatch, seq, dtype, width, takes):
+    """`conv_and_norm` asks `conv_fuses` (whole 16-row tiles, a head a whole
+    number of 128-lane bands, bfloat16, a filter whose history fits a halo)
+    and nothing else; what does not fit takes the plain form, with no
+    `custom_vjp` and no platform switch around it."""
+    assert lm_kda.conv_fuses(seq, width, 4, dtype) == takes
+    assert not lm_kda.conv_fuses(128, 128, lm_kda.CONV_HALO + 2, jnp.bfloat16)  # a history longer than one halo
+    asked = []
+    fused = lm_kda._fused_conv
+    monkeypatch.setattr(lm_kda, "_fused_conv", lambda *a: asked.append(a[0].shape) or fused(*a))
+    z = jax.ShapeDtypeStruct((1, seq, HEADS * width), dtype)
+    jaxpr = jax.make_jaxpr(lambda z_, w_: lm_kda.conv_and_norm(z_, w_, width, 0.5))(z, jax.ShapeDtypeStruct((4, HEADS * width), jnp.float32))
+    assert bool(asked) == takes
+    assert ("platform_index" in str(jaxpr)) == takes
+
+
+@pytest.mark.parametrize("scale", [0.5, None], ids=["q_or_k", "v"])
+def test_on_a_cpu_the_fitting_conv_runs_the_plain_form_and_its_vjp(monkeypatch, scale):
+    """The conv kernels' shapes lowered for a CPU: `lax.platform_dependent`
+    keeps the plain form and its own vjp, so output, dz and dw equal those of
+    a dispatch that refuses the shape, to the bit."""
+    z, w, ct = conv_operands(2, 64, HEADS, KERNEL_WIDTH, seed=6)
+    run = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda z_, w_: jnp.sum(lm_kda.conv_and_norm(z_, w_, KERNEL_WIDTH, scale).astype(jnp.float32) * ct), (0, 1)))(z, w)
+    assert "pallas_call" not in jax.jit(lambda *a: lm_kda.conv_and_norm(*a, KERNEL_WIDTH, scale)).lower(z, w).compile().as_text()
+    through_the_dispatch = run()
+    monkeypatch.setattr(lm_kda, "conv_fuses", lambda *a: False)
+    plain = run()
+    assert all(bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(through_the_dispatch), jax.tree.leaves(plain)))
+
+
 def _step_gauges(app, overrides, platform):
     from yet_another_mobilenet_series_tpu.config import parse_cli
     from yet_another_mobilenet_series_tpu.models import get_model
@@ -281,24 +402,27 @@ def _step_gauges(app, overrides, platform):
     lr_fn = schedules.make_lr_schedule(cfg.schedule, 2, 10, 1)
     params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
     steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn, platform=platform)
-    return tuple(get_registry().gauge(name).value for name in ("train.kda_sites", "train.kda_kept_sites", "train.kda_fused_sites"))
+    return tuple(get_registry().gauge(name).value
+                 for name in ("train.kda_sites", "train.kda_kept_sites", "train.kda_fused_sites", "train.kda_conv_fused_sites"))
 
 
-@pytest.mark.parametrize("family, toy, cell", [("kimi", (4.0, 4.0, 0.0), (4.0, 4.0, 4.0)), ("glm", (0.0, 0.0, 0.0), (0.0, 0.0, 0.0)),
-                                               ("ouro", (0.0, 0.0, 0.0), (0.0, 0.0, 0.0))])
+@pytest.mark.parametrize("family, toy, cell", [("kimi", (4.0, 4.0, 0.0, 0.0), (4.0, 4.0, 4.0, 4.0)),
+                                               ("glm", (0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0)),
+                                               ("ouro", (0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0))])
 def test_train_step_reports_how_many_kda_layers_the_kernels_take(family, toy, cell):
-    """`train.kda_fused_sites` beside `train.kda_sites` and `train.kda_kept_sites`,
-    set where the step is built, as `train.attn_fused_sites` is: the KDA layers
-    whose shapes `fuses` takes when the step is lowered for a TPU, else 0. The
-    toys (heads of 8 channels, float32) take the plain form anywhere; kimi's
-    cell's model reads 4 / 4 / 4 for a TPU and 4 / 4 / 0 for a CPU; GLM and
-    Ouro hold no KDA layer."""
+    """`train.kda_fused_sites` and `train.kda_conv_fused_sites` beside
+    `train.kda_sites` and `train.kda_kept_sites`, set where the step is built,
+    as `train.attn_fused_sites` is: the KDA layers whose shapes `fuses` (the
+    in-chunk work) / `conv_fuses` (the three short convolutions) take when the
+    step is lowered for a TPU, else 0. The toys (heads of 8 channels, float32)
+    take the plain forms anywhere; kimi's cell's model reads 4 / 4 / 4 / 4 for
+    a TPU and 4 / 4 / 0 / 0 for a CPU; GLM and Ouro hold no KDA layer."""
     import test_lm_cli as cli
 
     app, overrides = {"kimi": (cli.KIMI_APP, cli.KIMI_TOY), "glm": (cli.APP, cli.TOY), "ouro": (cli.OURO_APP, cli.OURO_TOY)}[family]
     assert _step_gauges(app, overrides, None) == _step_gauges(app, overrides, "tpu") == toy
     assert _step_gauges(app, [], "tpu") == cell
-    assert _step_gauges(app, [], "cpu") == (*cell[:2], 0.0)
+    assert _step_gauges(app, [], "cpu") == (*cell[:2], 0.0, 0.0)
 
 
 _FRESH_PROCESS = """
@@ -312,21 +436,28 @@ def pallas():
     return sorted(m for m in sys.modules if m.startswith(("jax.experimental.pallas", "jax._src.pallas")))
 
 shape = (1, 128, 2, 128)
-q = jax.ShapeDtypeStruct(shape, jnp.bfloat16 if sys.argv[1] == "fitting_site" else jnp.float32)
-fits = lm_kda.fuses(128, lm_kda.KDA_CHUNK, 128, q.dtype)
+q = jax.ShapeDtypeStruct(shape, jnp.bfloat16 if sys.argv[1].startswith("fitting") else jnp.float32)
 before = pallas()
-jax.eval_shape(lm_kda.kda_core, q, q, q, jax.ShapeDtypeStruct(shape, jnp.float32), jax.ShapeDtypeStruct(shape[:3], jnp.float32))
+if sys.argv[1].endswith("conv_site"):  # the short convolution and q's L2 norm
+    fits = lm_kda.conv_fuses(128, 128, 4, q.dtype)
+    jax.eval_shape(lambda z, w: lm_kda.conv_and_norm(z, w, 128, 0.5), jax.ShapeDtypeStruct((1, 128, 256), q.dtype),
+                   jax.ShapeDtypeStruct((4, 256), jnp.float32))
+else:
+    fits = lm_kda.fuses(128, lm_kda.KDA_CHUNK, 128, q.dtype)
+    jax.eval_shape(lm_kda.kda_core, q, q, q, jax.ShapeDtypeStruct(shape, jnp.float32), jax.ShapeDtypeStruct(shape[:3], jnp.float32))
 print(json.dumps({"fits": fits, "before": before, "pallas": pallas()}))
 """
 
 
-@pytest.mark.parametrize("what, pays", [("plain_site", False), ("fitting_site", True)])
+@pytest.mark.parametrize("what, pays", [("plain_site", False), ("fitting_site", True), ("plain_conv_site", False),
+                                        ("fitting_conv_site", True)])
 def test_pallas_comes_in_where_a_fitting_kda_site_is_traced_and_nowhere_else(what, pays):
     """A fresh process that imports `ops.lm_kda`, `models.lm` and `train.steps`
     and asks the predicate has no `jax.experimental.pallas*` module, and none
-    after tracing a KDA site the kernels do not take; the `tpu` branch of a
-    fitting site, once traced, brings it in (PR 28 was refused for the 1.2-1.5
-    s of `setup_s` that import costs a cell that runs none of its code)."""
+    after tracing a KDA core or short convolution the kernels do not take; the
+    `tpu` branch of a fitting site, once traced, brings it in (the import
+    costs 1.2-1.5 s of `setup_s`, which a cell that runs none of its code
+    must not pay)."""
     import json
     import os
     import subprocess

@@ -234,6 +234,41 @@ def test_a_kda_layer_at_the_cells_shape_is_two_mosaic_kernels_under_its_scope(on
     assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
 
 
+def test_a_kda_mixer_at_the_cells_shape_makes_q_k_v_in_the_conv_kernels_under_their_scope(one_chip, monkeypatch):
+    """A whole KDA mixer of kimi_linear's cell (1 x 16,384 tokens, hidden 2,304,
+    32 heads of 128; float32 weights, bfloat16 activations), its gradient
+    compiled for the described chip: each of q, k, v is ONE `kda_conv_fwd`
+    custom call under `("kda_conv", "fwd")` and one `kda_conv_bwd` under
+    `("kda_conv", "bwd")`, beside the in-chunk kernels under `kda_core`; and
+    the declared temporaries are not above those of the same mixer with the
+    plain convolution and norms (4.01 against 4.52 GiB when written)."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda
+
+    hidden, heads, width, seq = 2304, 32, 128, 16384
+    wide = heads * width
+    shapes = {"q": (hidden, wide), "k": (hidden, wide), "v": (hidden, wide), "conv_q": (4, wide), "conv_k": (4, wide),
+              "conv_v": (4, wide), "f_a": (hidden, width), "f_b": (width, wide), "A_log": (heads,), "dt_bias": (wide,),
+              "b": (hidden, heads), "g_a": (hidden, width), "g_b": (width, wide), "o_norm": (width,), "o": (wide, hidden)}
+    params = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip) for name, shape in shapes.items()}
+    x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16, sharding=one_chip)
+    assert lm_kda.conv_fuses(seq, width, 4, jnp.bfloat16)
+
+    def compiled():
+        loss = lambda p, x_: jnp.sum(lm_kda.kda_attention(p, x_, heads=heads, head_dim=width, eps=1e-5)[0].astype(jnp.float32))  # noqa: E731
+        return jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+
+    fused = compiled()
+    instructions, _ = _entry_instructions(fused.as_text())
+    kernels = sorted((n.split(".")[0], *scopes.scope_of(op)) for n, (_, opcode, _, op) in instructions.items()
+                     if opcode == "custom-call" and n.startswith("kda_"))
+    assert kernels == ([("kda_conv_bwd", "kda_conv", "bwd")] * 3 + [("kda_conv_fwd", "kda_conv", "fwd")] * 3
+                       + [("kda_operands_bwd", "kda_core", "bwd"), ("kda_operands_fwd", "kda_core", "fwd")]), kernels
+    monkeypatch.setattr(lm_kda, "conv_fuses", lambda *a: False)
+    plain = compiled()
+    assert "kda_conv_" not in plain.as_text()
+    assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
+
+
 def test_no_tile_of_scores_and_no_float32_dq_reaches_hbm(attention_hlo):
     """What the tile loops paid for: no buffer of a tile's shape in any
     dtype (`f32[2,20,512,512]`, or the kernel's own 512 x 512 block), no

@@ -173,6 +173,15 @@ class TokenModel:
         fits = lm_kda.fuses(self.lm.seq_len, min(lm_kda.KDA_CHUNK, self.lm.seq_len), la.head_dim, compute_dtype)
         return self.kda_sites if fits else 0
 
+    def kda_conv_fitting_sites(self, compute_dtype) -> int:
+        """The KDA layers whose three short convolutions (and q's and k's L2
+        norms) the conv kernels of ops/lm_kda_kernels.py take, by the predicate
+        `lm_kda.conv_and_norm` dispatches on: what `train.kda_conv_fused_sites`
+        reports where the step is lowered for a TPU (train/steps.py)."""
+        la = self.lm.linear_attn_config
+        fits = lm_kda.conv_fuses(self.lm.seq_len, la.head_dim, la.short_conv_kernel_size, compute_dtype)
+        return self.kda_sites if fits else 0
+
     @property
     def expert_sites(self) -> int:
         """The expert layers (`train.moe_sites`): what `moe_bounded_sites` reads on a

@@ -3,8 +3,8 @@
 decay gate of one value a KEY CHANNEL; a write strength a head; the gated
 delta rule over a `head_dim x head_dim` state a head; a sigmoid-gated RMSNorm
 on the way out. Plain `jax.numpy`/`lax` on every platform, but for what
-depends on a chunk alone, which two fused TPU kernels make where the shapes
-fit them (below).
+depends on a chunk alone and for the short convolutions with their norms,
+which fused TPU kernels make where the shapes fit them (below).
 
 The recurrence, a head at a time (state S, key x value, S_0 = 0)::
 
@@ -55,6 +55,19 @@ no other time (`models/lm.py` imports this module at module level, and every
 runner imports `train/steps.py`: PERF.md, PR 28). `train.kda_fused_sites`
 (train/steps.py) says how many KDA layers a step lowers that way.
 
+**The short convolutions: one kernel each way.** `conv_and_norm` makes q,
+k and v from their projections: `short_conv` (causal, SiLU) and for q and k
+`l2_normalise` over each head. Where `conv_fuses` takes the shapes (whole
+16-row tiles, heads of whole 128-lane bands, bfloat16) and the lowering is a
+TPU's, that is `_fused_conv`, a `custom_vjp` over two kernels of
+ops/lm_kda_kernels.py: the forward reads z once (with `CONV_HALO` rows of
+history behind each tile) and writes the result once, the float32 sum, SiLU
+and norm in VMEM; the backward reads z and the cotangent once (with their
+halos), makes the pre-activation and the norm's statistics again, and writes
+dz once and a float32 dw a tile. The backward keeps z and the filter alone.
+Its kernels come in inside `conv_fwd` / `conv_bwd`, as above;
+`train.kda_conv_fused_sites` says how many KDA layers a step lowers that way.
+
 **What the backward keeps.** The scan is a `custom_vjp`: its backward walks
 the chunks in reverse with the cotangent of the state, from the scan's
 operands and the state at each chunk's start; nothing else of the forward is
@@ -69,6 +82,8 @@ the decay `g`, its cumulative sums, every `exp`, the solve, the pseudo-values
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +103,8 @@ KDA_HEAD_GROUP = 8
 KDA_OUT_NAME = "kda_out"
 KDA_STATES_NAME = "kda_states"
 L2_EPS = 1e-6
+# Rows of a short-convolution kernel's halo (`conv_fuses`): one bfloat16 sublane tile of a neighbouring tile's rows.
+CONV_HALO = 16
 
 
 def short_conv(z: Array, w: Array) -> Array:
@@ -109,6 +126,94 @@ def l2_normalise(x: Array, scale: float = 1.0) -> Array:
     with scope("kda_norm"):
         x32 = x.astype(jnp.float32)
         return (x32 * lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + L2_EPS) * scale).astype(x.dtype)
+
+
+def _plain_conv(z: Array, w: Array, width: int, scale: float | None) -> Array:
+    """`short_conv`, then unless `scale` is None `l2_normalise` over each head
+    of `width` channels: z (B, S, H * width) -> the same shape."""
+    out = short_conv(z, w)
+    if scale is None:
+        return out
+    return l2_normalise(out.reshape(*z.shape[:2], -1, width), scale).reshape(z.shape)
+
+
+def _plain_conv_bwd(z, w, ct, width, scale):
+    """`_plain_conv`'s own vjp: (dz, dw)."""
+    return jax.vjp(functools.partial(_plain_conv, width=width, scale=scale), z, w)[1](ct)
+
+
+def conv_fuses(seq: int, width: int, taps: int, dtype) -> bool:
+    """Whether the short-convolution kernels of ops/lm_kda_kernels.py take a
+    site of this shape (the platform is the lowering's to decide): a sequence
+    of whole `CONV_HALO`-row tiles (bfloat16's sublane tile; a neighbour's
+    halo is one such tile), a filter whose history fits one, a head a whole
+    number of 128-lane bands, bfloat16 operands."""
+    return (seq % CONV_HALO == 0 and 1 <= taps <= CONV_HALO + 1 and width % 128 == 0
+            and jnp.dtype(dtype) == jnp.bfloat16)
+
+
+# Sites of one shape share one trace and one lowering of each form (the twelve convolutions of a step are three kinds).
+@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret"))
+def conv_fwd(z, w, width, scale, interpret: bool = False):
+    """`_plain_conv` as ONE fused kernel: z read once with a halo of the rows
+    before each tile, the float32 sum, SiLU and norm in VMEM, the result
+    written once."""
+    from . import lm_kda_kernels as kernels  # Pallas comes in HERE and nowhere earlier (module docstring)
+
+    return kernels.conv_fwd_call(z, w, width, scale, L2_EPS, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret"))
+def conv_bwd(z, w, ct, width, scale, interpret: bool = False):
+    """(dz, dw) from the forward's operands and the cotangent of its result:
+    the second kernel remakes the pre-activation and the norm's statistics in
+    VMEM; XLA sums its tiles' float32 dw."""
+    from . import lm_kda_kernels as kernels  # as in conv_fwd
+
+    dz, dw = kernels.conv_bwd_call(z, w, ct, width, scale, L2_EPS, interpret)
+    return dz, jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _fused_conv(z: Array, w: Array, width: int, scale: float | None) -> Array:
+    """`_plain_conv` at a shape the kernels take (`conv_fuses`): the two
+    kernels where the step is lowered for a TPU, else the plain form and its
+    own vjp (`lax.platform_dependent` decides at lowering). What the backward
+    keeps is z and w alone."""
+    return _fused_conv_fwd(z, w, width, scale)[0]
+
+
+# the other platforms' branches, shared by the sites as the kernels' are
+_shared_plain_conv = jax.jit(_plain_conv, static_argnames=("width", "scale"))
+_shared_plain_conv_bwd = jax.jit(_plain_conv_bwd, static_argnames=("width", "scale"))
+
+
+def _fused_conv_fwd(z, w, width, scale):
+    made = lax.platform_dependent(z, w, tpu=functools.partial(conv_fwd, width=width, scale=scale),
+                                  default=functools.partial(_shared_plain_conv, width=width, scale=scale))
+    return made, (z, w)
+
+
+def _fused_conv_bwd(width, scale, kept, ct):
+    with scope("kda_conv"):
+        return lax.platform_dependent(*kept, ct, tpu=functools.partial(conv_bwd, width=width, scale=scale),
+                                      default=functools.partial(_shared_plain_conv_bwd, width=width, scale=scale))
+
+
+_fused_conv.defvjp(_fused_conv_fwd, _fused_conv_bwd)
+
+
+def conv_and_norm(z: Array, w: Array, width: int, scale: float | None = None) -> Array:
+    """q, k or v of a KDA mixer from its projection: `short_conv` (causal,
+    SiLU) and, unless `scale` is None, `l2_normalise` over each head of
+    `width` channels, times `scale`. z (B, S, H * width), w (taps, H *
+    width) -> (B, S, H * width) in z's dtype. Where `conv_fuses` takes the
+    shapes and the lowering is a TPU's, one kernel each way (`_fused_conv`);
+    else the plain form."""
+    if conv_fuses(z.shape[1], width, w.shape[0], z.dtype):
+        with scope("kda_conv"):
+            return _fused_conv(z, w, width, scale)
+    return _plain_conv(z, w, width, scale)
 
 
 def _pairs_decay(big_g: Array) -> Array:
@@ -399,8 +504,8 @@ def kda_attention(p: dict, x: Array, *, heads: int, head_dim: int, eps: float) -
         write = x @ p["b"].astype(cd)
         gate = (x @ p["g_a"].astype(cd)) @ p["g_b"].astype(cd)
     by_head = lambda z: z.reshape(batch, seq, heads, head_dim)  # noqa: E731
-    q, k, v = (by_head(short_conv(z, p["conv_" + name])) for name, z in (("q", q), ("k", k), ("v", v)))
-    q, k = l2_normalise(q, head_dim ** -0.5), l2_normalise(k)
+    q, k, v = (by_head(conv_and_norm(z, p["conv_" + name], head_dim, scale))
+               for name, z, scale in (("q", q, head_dim ** -0.5), ("k", k, 1.0), ("v", v, None)))
     with scope("kda_gate"):
         g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
             by_head(decay.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)))

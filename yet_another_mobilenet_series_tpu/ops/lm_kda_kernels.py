@@ -1,6 +1,7 @@
-"""The two Pallas/Mosaic kernels behind `ops/lm_kda.py`'s in-chunk work, and
-the calls that build them. Imported only from inside
-`lm_kda.operands_fwd/operands_bwd`, that is while the `tpu` branch of a
+"""The Pallas/Mosaic kernels behind `ops/lm_kda.py`: two for the in-chunk
+work, two for the short convolutions (the end of this module), and the calls
+that build them. Imported only from inside `lm_kda.operands_fwd/operands_bwd`
+and `lm_kda.conv_fwd/conv_bwd`, that is while the `tpu` branch of a
 fitting KDA site is traced (or a test asks for interpret mode): the Pallas
 import costs 1.2-1.5 s on the chip's host, `train/steps.py` is imported by
 every process, and a step with no KDA layer must not pay it
@@ -64,6 +65,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .lm_kda import CONV_HALO
 
 # Chunks one program instance makes (fewer where the sequence has fewer): amortises the ~0.35 us a grid step costs.
 CHUNKS_AT_ONCE = 16
@@ -416,3 +419,124 @@ def bwd_call(q, k, v, g, beta, cts, heads: int, chunk: int, interpret: bool = Fa
         scratch_shapes=[pltpu.VMEM((blocks * tile, width), jnp.float32)] * 2,
         compiler_params=_params(), interpret=interpret, name="kda_operands_bwd",
     )(pattern, pattern_t, q, k, v, g, beta, *cts)
+
+
+# ---- the short convolutions (`lm_kda.conv_and_norm`): causal conv, SiLU and a head's L2 norm, one pass each way ----------
+# Memory-bound passes with little arithmetic, kept apart from the VPU-bound kernels above: one program instance is one tile,
+# each head a band of its lanes; rows are shifted by sublane rotations (`pltpu.roll`) of the tile stacked under its halo.
+# A tile is CONV_ROWS rows (positions) by CONV_LANES lanes (whole heads) of (B, S, H * D), the halo a neighbour's
+# CONV_HALO rows (lm_kda's): one bfloat16 sublane tile, behind the tile (the filter's history) and, in the backward, ahead.
+CONV_ROWS = 512
+CONV_LANES = 512
+
+
+def conv_cut(seq: int, features: int, width: int) -> tuple[int, int]:
+    """(rows, lanes) of a tile: the most rows up to `CONV_ROWS` in whole halo
+    tiles that divide the sequence, and the most whole heads of `width` lanes
+    up to `CONV_LANES` that divide the features (one head where a head is wider)."""
+    rows = max(r for r in range(CONV_HALO, min(CONV_ROWS, seq) + 1, CONV_HALO) if seq % r == 0)
+    lanes = max([m * width for m in range(1, CONV_LANES // width + 1) if features % (m * width) == 0] or [width])
+    return rows, lanes
+
+
+def _behind(x, back, rows):
+    """Rows [CONV_HALO - back, CONV_HALO - back + rows) of x (float32, the tile's
+    history in its first CONV_HALO rows): x rolled down by `back`, an aligned slice."""
+    return (pltpu.roll(x, back, 0) if back else x)[CONV_HALO:CONV_HALO + rows]
+
+
+def _history(ref, band, first_row):
+    """A halo block's band in float32 and each row's position (`first_row` the
+    first's): the caller zeroes what lies outside the sequence. A halo index
+    is clamped to the sequence, so those rows hold a real block's data."""
+    x = ref[:, band].astype(jnp.float32)
+    return x, first_row + lax.broadcasted_iota(jnp.int32, x.shape, 0)
+
+
+def _conv_fwd_kernel(w_ref, behind_ref, z_ref, out_ref, *, width, scale, eps):
+    rows, lanes = z_ref.shape
+    taps = w_ref.shape[0]
+    start = pl.program_id(1) * rows
+    for h in range(lanes // width):
+        band = slice(h * width, (h + 1) * width)
+        history, at = _history(behind_ref, band, start - CONV_HALO)
+        x = jnp.concatenate([jnp.where(at >= 0, history, 0.0), z_ref[:, band].astype(jnp.float32)], axis=0)
+        pre = sum(w_ref[pl.ds(i, 1), band] * _behind(x, taps - 1 - i, rows) for i in range(taps))
+        c = pre * jax.nn.sigmoid(pre)
+        if scale is not None:
+            c = c * (lax.rsqrt(jnp.sum(c * c, axis=1, keepdims=True) + eps) * scale)
+        out_ref[:, band] = c.astype(out_ref.dtype)
+
+
+def _conv_bwd_kernel(w_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, dz_ref, dw_ref, *, width, scale, eps, seq):
+    rows, lanes = z_ref.shape
+    taps = w_ref.shape[0]
+    start = pl.program_id(1) * rows
+    reach = rows + CONV_HALO  # the pre-activations remade: the tile's and the next CONV_HALO rows', whose taps reach back into it
+    for h in range(lanes // width):
+        band = slice(h * width, (h + 1) * width)
+        history, at = _history(behind_ref, band, start - CONV_HALO)
+        later, later_at = _history(ahead_ref, band, start + rows)
+        x = jnp.concatenate([jnp.where(at >= 0, history, 0.0), z_ref[:, band].astype(jnp.float32),
+                             jnp.where(later_at < seq, later, 0.0)], axis=0)
+        shifted = [_behind(x, taps - 1 - i, reach) for i in range(taps)]  # z_{t - (taps - 1) + i}, t from the tile's first row
+        pre = sum(w_ref[pl.ds(i, 1), band] * shifted[i] for i in range(taps))
+        gate = jax.nn.sigmoid(pre)
+        dy_later, dy_at = _history(dy_ahead_ref, band, start + rows)
+        dc = jnp.concatenate([dy_ref[:, band].astype(jnp.float32), jnp.where(dy_at < seq, dy_later, 0.0)], axis=0)
+        if scale is not None:  # y = s c r, r = (c . c + eps)^-1/2  =>  dc = s r dy - s r^3 c (dy . c)
+            c = pre * gate
+            r = lax.rsqrt(jnp.sum(c * c, axis=1, keepdims=True) + eps)
+            dc = (scale * r) * (dc - c * (r * r * jnp.sum(dc * c, axis=1, keepdims=True)))
+        d_pre = dc * (gate * (1.0 + pre * (1.0 - gate)))
+        # z_t feeds the pre-activation at t + (taps - 1) - i through tap i: rolled UP by that many rows
+        dz = sum(w_ref[pl.ds(i, 1), band] * (pltpu.roll(d_pre, reach - (taps - 1 - i), 0) if taps - 1 - i else d_pre)[:rows]
+                 for i in range(taps))
+        dz_ref[:, band] = dz.astype(dz_ref.dtype)
+        for i in range(taps):
+            dw_ref[pl.ds(i, 1), band] = jnp.sum(d_pre[:rows] * shifted[i][:rows], axis=0, keepdims=True)
+
+
+def _conv_specs(seq, features, width, taps):
+    rows, lanes = conv_cut(seq, features, width)
+    step, last = rows // CONV_HALO, seq // CONV_HALO - 1
+    tile = pl.BlockSpec((None, rows, lanes), lambda b, i, j: (b, i, j))
+    behind = pl.BlockSpec((None, CONV_HALO, lanes), lambda b, i, j: (b, jnp.maximum(i * step - 1, 0), j))
+    ahead = pl.BlockSpec((None, CONV_HALO, lanes), lambda b, i, j: (b, jnp.minimum((i + 1) * step, last), j))
+    taps_spec = pl.BlockSpec((taps, lanes), lambda b, i, j: (0, j))
+    return rows, lanes, tile, behind, ahead, taps_spec
+
+
+def _conv_params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel"), vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def conv_fwd_call(z, w, width: int, scale, eps: float, interpret: bool = False):
+    """z (B, S, H * D) in the compute dtype, w (taps, H * D) -> SiLU of the
+    causal convolution, each head of `width` lanes L2-normalised and times
+    `scale` unless `scale` is None; in z's dtype."""
+    batch, seq, features = z.shape
+    rows, lanes, tile, behind, _, taps_spec = _conv_specs(seq, features, width, w.shape[0])
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, width=width, scale=scale, eps=eps),
+        grid=(batch, seq // rows, features // lanes),
+        in_specs=[taps_spec, behind, tile], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
+        compiler_params=_conv_params(), interpret=interpret, name="kda_conv_fwd",
+    )(w.astype(jnp.float32), z, z)
+
+
+def conv_bwd_call(z, w, ct, width: int, scale, eps: float, interpret: bool = False):
+    """(dz in z's dtype, float32 dw of each tile (B, S / rows, taps, H * D)) from
+    the forward's operands and the cotangent of its result."""
+    batch, seq, features = z.shape
+    taps = w.shape[0]
+    rows, lanes, tile, behind, ahead, taps_spec = _conv_specs(seq, features, width, taps)
+    return pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, width=width, scale=scale, eps=eps, seq=seq),
+        grid=(batch, seq // rows, features // lanes),
+        in_specs=[taps_spec, behind, tile, ahead, tile, ahead],
+        out_specs=[tile, pl.BlockSpec((None, None, taps, lanes), lambda b, i, j: (b, i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct(z.shape, z.dtype), jax.ShapeDtypeStruct((batch, seq // rows, taps, features), jnp.float32)],
+        compiler_params=_conv_params(), interpret=interpret, name="kda_conv_bwd",
+    )(w.astype(jnp.float32), z, z, z, ct, ct)

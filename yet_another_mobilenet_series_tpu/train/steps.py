@@ -258,6 +258,8 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     # the KDA layers whose in-chunk work this step lowers through ops/lm_kda_kernels.py's two fused kernels: the same
     # kind of prediction as `train.attn_fused_sites` (ops/lm_kda.py `fuses` on the shapes, `platform` for the lowering)
     get_registry().gauge("train.kda_fused_sites").set(net.kda_fitting_sites(compute_dtype) if on_tpu else 0)
+    # and those whose three short convolutions (with q's and k's L2 norms) go through its two conv kernels (`conv_fuses`)
+    get_registry().gauge("train.kda_conv_fused_sites").set(net.kda_conv_fitting_sites(compute_dtype) if on_tpu else 0)
     # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
     get_registry().gauge("train.moe_sites").set(net.expert_sites)
     # a looped model runs its layers `loop_steps` times a step with the same weights: the sites above are LAYERS,
@@ -306,8 +308,9 @@ def make_train_step(
     out). It moves nothing in the step: the gauges that PREDICT which
     lowering a platform-dependent piece takes read it
     (`train.attn_fused_sites`: the attention layers through the kernels of
-    ops/lm_attention.py; `train.kda_fused_sites`: the KDA layers whose
-    in-chunk work goes through those of ops/lm_kda.py). A step built without
+    ops/lm_attention.py; `train.kda_fused_sites` / `train.kda_conv_fused_sites`:
+    the KDA layers whose in-chunk work / short convolutions go through those of
+    ops/lm_kda.py). A step built without
     it and then compiled ahead of time for another platform than the default
     backend's reports the default backend's count.
     """
