@@ -31,6 +31,9 @@ import json  # noqa: E402
 import pytest  # noqa: E402
 
 _BENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmark_tests")
+# Files of that directory that pin the manifest as their PR left it WITHOUT a `CELL` of their own (a PR that added
+# metrics to the cells there were, and no cell): the newest cell when each was written.
+PINNED_AT = {"test_host_watch_metrics.py": "ouro26b_train_1x8k"}
 
 
 @pytest.fixture(autouse=True)
@@ -50,10 +53,17 @@ def an_older_cells_file_reads_the_manifest_as_its_pr_left_it(request, monkeypatc
     (test_ouro_cell.py). So the plain read also leaves out the `per_layer`
     entries APPENDED after the cell's own: every entry that stands after the
     last one which, in the cut, lists the cell ALONE (the last metric its PR
-    brought). An entry moved, removed or put before that one still shows."""
+    brought). An entry moved, removed or put before that one still shows.
+
+    A file of `PINNED_AT` (metrics a PR added to the cells there were, with
+    exact `workloads` lists) reads the manifest cut back to the newest cell of
+    its PR: later cells out of those lists, later cells' own metrics out; the
+    metrics it pins, which stand after that cell's, stay."""
     module = request.module
+    path = getattr(module, "__file__", "")
     cell = getattr(module, "CELL", None)
-    if not isinstance(cell, str) or os.path.dirname(getattr(module, "__file__", "")) != _BENCH_TESTS:
+    pinned_at = PINNED_AT.get(os.path.basename(path)) if cell is None else None
+    if not isinstance(cell or pinned_at, str) or os.path.dirname(path) != _BENCH_TESTS:
         yield
         return
     spec = importlib.util.spec_from_file_location("_cut", os.path.join(_BENCH_TESTS, "conftest.py"))
@@ -61,6 +71,8 @@ def an_older_cells_file_reads_the_manifest_as_its_pr_left_it(request, monkeypatc
     spec.loader.exec_module(cut)
 
     def as_the_cells_pr_left_it(manifest):
+        if pinned_at:
+            return cut.cut_back_to(manifest, pinned_at)
         view = cut.cut_back_to(manifest, cell)
         own = [i for i, m in enumerate(view["per_layer"]) if m.get("workloads") == [cell]]
         return {**view, "per_layer": view["per_layer"][:own[-1] + 1]} if own else view

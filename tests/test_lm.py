@@ -552,34 +552,54 @@ def test_which_attention_calls_the_kernels_take(shape, dtype, expect):
 
 
 @pytest.mark.parametrize("shape, dtype, handed", [
-    ((16384, 512, 192, 128), jnp.bfloat16, 256),  # kimi_linear's one latent-attention layer: 128 + 64 filled to 256
-    ((8192, 512, 256, 256), jnp.bfloat16, 256),  # GLM's: fits as it is
-    ((16384, 512, 192, 128), jnp.float32, 192),  # no filling makes float32 fit: the loops, unfilled
-    ((32768, 512, 192, 128), jnp.bfloat16, 192),  # nor a sequence too long
-    ((32, 8, 12, 16), jnp.float32, 12),  # this file's toy heads
+    ((16384, 512, 192, 128), jnp.bfloat16, (256, 128)),  # kimi_linear's one latent-attention layer: 128 + 64 filled to 256
+    ((8192, 512, 256, 256), jnp.bfloat16, (256, 256)),  # GLM's: fits as it is
+    ((16384, 512, 192, 128), jnp.float32, (192, 128)),  # no filling makes float32 fit: the loops, unfilled
+    ((32768, 512, 192, 128), jnp.bfloat16, (192, 128)),  # nor a sequence too long
+    ((32, 8, 12, 16), jnp.float32, (12, 16)),  # this file's toy heads
 ], ids=str)
 def test_q_and_k_are_filled_with_zero_channels_only_where_that_makes_the_kernels_fit(shape, dtype, handed):
-    assert lm_attention.fitting_qk_dim(*shape, dtype) == handed
+    assert lm_attention.fitting_dims(*shape, dtype) == handed
 
 
 def test_zero_filled_channels_change_no_number(monkeypatch):
-    """`causal_attention` at a shape whose q/k head dim it fills (192 -> 256,
-    one 256-row tile, bfloat16) against the same call with the filling
-    switched off: output and the three gradients bit for bit on the CPU (the
-    loops either way: a zero channel adds 0.0 to every score)."""
+    """`causal_attention` at shapes whose head dims it fills (q/k 192 -> 256
+    with v 128 as it is; q/k and v 64 -> 128; one 256-row tile, bfloat16)
+    against the same call with the filling switched off, so that the loops
+    run at the dims as given, on the CPU: a zero channel adds 0.0 to every
+    score, and one of v is a zero channel of the output, cut off. With q and
+    k filled, output and the three gradients bit for bit; with v filled too,
+    the output bit for bit and the gradients within one bfloat16 rounding
+    (v's zero channels lengthen the float32 reduction that makes g v^T, so
+    its order)."""
     monkeypatch.setattr(ops, "ATTN_BLOCK", 256)
-    q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 256, 2, 192), jnp.bfloat16) for i in (0, 1))
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 256, 2, 128), jnp.bfloat16)
-    assert lm_attention.fitting_qk_dim(256, 256, 192, 128, jnp.bfloat16) == 256
+    as_filled = lm_attention.fitting_dims
 
-    def run():
-        fn = lambda *a: ops.causal_attention(*a, scale=192 ** -0.5)  # noqa: E731
-        return jax.jit(lambda *a: (fn(*a), jax.grad(lambda *b: jnp.sum(fn(*b).astype(jnp.float32) ** 2), (0, 1, 2))(*a)))(q, k, v)
+    def within_a_rounding(a, b):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return bool(jnp.all(jnp.abs(a - b) <= 2.0 ** -7 * jnp.maximum(jnp.abs(a), jnp.abs(b))))
 
-    filled = run()
-    monkeypatch.setattr(lm_attention, "fitting_qk_dim", lambda seq, block, qk, v_, dtype: qk)
-    plain = run()
-    assert all(a.shape == b.shape and bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(filled), jax.tree.leaves(plain)))
+    for qk_dim, v_dim, handed in ((192, 128, (256, 128)), (64, 64, (128, 128))):
+        q, k = (jax.random.normal(jax.random.PRNGKey(i), (1, 256, 2, qk_dim), jnp.bfloat16) for i in (0, 1))
+        v = jax.random.normal(jax.random.PRNGKey(2), (1, 256, 2, v_dim), jnp.bfloat16)
+        assert as_filled(256, 256, qk_dim, v_dim, jnp.bfloat16) == handed
+
+        def run():
+            fn = lambda *a: ops.causal_attention(*a, scale=qk_dim ** -0.5)  # noqa: E731
+            return jax.jit(lambda *a: (fn(*a), jax.grad(lambda *b: jnp.sum(fn(*b).astype(jnp.float32) ** 2),
+                                                        (0, 1, 2))(*a)))(q, k, v)
+
+        monkeypatch.setattr(lm_attention, "fitting_dims", as_filled)
+        filled = run()
+        handed_on = []
+        monkeypatch.setattr(lm_attention, "fitting_dims",
+                            lambda seq, block, qk, v_, dtype: handed_on.append((qk, v_)) or (qk, v_))
+        plain = run()
+        assert set(handed_on) == {(qk_dim, v_dim)}  # the unfilled side really reached the loops at the dims as given
+        (out, grads), (want, want_grads) = filled, plain
+        assert out.shape == want.shape and bool(jnp.all(out == want))
+        same = (lambda a, b: bool(jnp.all(a == b))) if v_dim % 128 == 0 else within_a_rounding
+        assert all(a.shape == b.shape and same(a, b) for a, b in zip(grads, want_grads))
 
 
 def test_train_step_reports_how_many_attention_layers_the_kernels_take(monkeypatch):

@@ -137,3 +137,43 @@ def test_three_steps_of_the_looped_arch_through_cli_train(tmp_path, capsys):
     step, net, _ = mgr.restore_spec()
     mgr.close()
     assert step == 3 and isinstance(net, TokenModel) and net.arch == "ouro" and net.loop_steps == 4
+
+
+GRANITE_APP = os.path.join(REPO, "yet_another_mobilenet_series_tpu", "apps", "granite_4_0_h_micro.yml")
+GRANITE_TOY = ["model.num_classes=256", "model.lm.hidden_size=64", "model.lm.num_hidden_layers=3",
+               "model.lm.layer_types=[mamba,attention,mamba]", "model.lm.first_k_dense_replace=3",
+               "model.lm.num_attention_heads=4", "model.lm.num_key_value_heads=2", "model.lm.head_dim=16",
+               "model.lm.intermediate_size=96", "model.lm.mamba_n_heads=8", "model.lm.mamba_d_head=16",
+               "model.lm.mamba_d_state=16", "model.lm.mamba_chunk_size=8", "model.lm.seq_len=32"]
+
+
+def test_three_steps_of_the_hybrid_arch_through_cli_train(tmp_path, capsys):
+    """The fourth arch through the same entry point: apps/granite_4_0_h_micro.yml
+    at a toy size (Mamba-2, attention, Mamba-2; a tied vocabulary of 256),
+    the banner of a model without an expert layer, the Mamba-2 gauges and the
+    step's lowest chunk decay at the log boundary, eval, a checkpoint that
+    restores as the same TokenModel."""
+    log_dir = str(tmp_path / "log")
+    final = cli_train.main([f"app:{GRANITE_APP}", *GRANITE_TOY, "data.fake_train_size=3", "train.epochs=1",
+                            "train.log_every=1", f"train.log_dir={log_dir}", "dist.num_devices=1"])
+    assert final["epoch"] == 1.0 and final["eval_n"] == 2 * 32 and np.isfinite(final["eval_loss"])
+    banner = [line for line in capsys.readouterr().out.splitlines() if "model granitemoehybrid" in line]
+    assert banner and "no expert layer; 3 layers run 1 times a step; 256 vocabulary rows" in banner[0]
+    with open(os.path.join(log_dir, "metrics.jsonl")) as f:
+        rows = [json.loads(line) for line in f if '"train/' in line]
+    assert len(rows) == 3
+    last = rows[-1]
+    # a tied head over unit-rms states, logits / 8: ln(vocabulary) and a little
+    assert abs(last["train/ce"] - np.log(256)) < 0.2 and last["train/loss"] == last["train/ce"]
+    assert last["train/ssd_min_chunk_log_decay"] < 0 and "train/gnorm/layer_0/mamba" in last
+    assert "train/gnorm/head" not in last and not [k for k in last if k.startswith("train/moe_")]
+    with open(os.path.join(log_dir, "obs_registry.json")) as f:
+        registry = json.load(f)
+    assert (registry["train.ssd_sites"], registry["train.ssd_kept_sites"], registry["train.ssd_conv_fused_sites"],
+            registry["train.attn_sites"], registry["train.moe_sites"]) == (2.0, 2.0, 0.0, 1.0, 0.0)
+    assert registry["train.ssd_min_chunk_log_decay"] == last["train/ssd_min_chunk_log_decay"]
+    mgr = CheckpointManager(log_dir + "/ckpt")
+    step, net, _ = mgr.restore_spec()
+    mgr.close()
+    assert step == 3 and isinstance(net, TokenModel) and net.arch == "granitemoehybrid"
+    assert [net.mixer(b) for b in net.block_names] == ["mamba", "attn", "mamba"] and net.lm.tie_word_embeddings

@@ -34,6 +34,7 @@ TOKEN_SCOPES = {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "moe_r
                 "moe_experts", "moe_combine", "mtp_merge", "lm_head"}
 KDA_SCOPES = {"kda_proj", "kda_conv", "kda_gate", "kda_core", "kda_norm"}
 LOOP_SCOPES = {"exit_gate"}  # a looped model's (`ouro`)
+SSD_SCOPES = {"ssd_proj", "ssd_conv", "ssd_gate", "ssd_core", "ssd_norm"}  # a Mamba-2 mixer's (`granitemoehybrid`)
 
 
 def lowered_step(*overrides, chips: int = 1):
@@ -111,7 +112,7 @@ def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
     # everything on the list except the collectives (one chip), AtomNAS (no
     # masks, no penalty), the guard (off) and the token models' scopes
     expect = (set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES - KDA_SCOPES
-              - LOOP_SCOPES)
+              - LOOP_SCOPES - SSD_SCOPES)
     assert expect <= seen, f"missing: {sorted(expect - seen)}"
     assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
 
@@ -384,6 +385,45 @@ def test_the_glm_step_is_the_program_it_was_before_kimi_linear(dtype, digest):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
+@pytest.mark.parametrize("arch, dtype, digest", [("kimi_linear", "float32", "7252fb0b8c1d3546"),
+                                                 ("kimi_linear", "bfloat16", "533db32afde7f000"),
+                                                 ("ouro", "float32", "fc4eb44195dfc711"),
+                                                 ("ouro", "bfloat16", "e6a9088a2aa12e80")])
+def test_the_kimi_and_ouro_steps_are_the_programs_they_were_before_granitemoehybrid(arch, dtype, digest):
+    """models/lm.py and ops/lm.py serve a fourth arch, `granitemoehybrid` (a hybrid's
+    mixer read from `layer_types`, `mha_attention` with grouped key/value
+    heads and no rotation, v filled for the kernels, the multipliers and a
+    tied head, the conv kernels with an optional bias). The toy `kimi_linear`
+    and `ouro` steps' lowered modules (StableHLO text, as
+    `test_the_glm_step_is_...` takes GLM's) are pinned by the digests their
+    parent commit, 8550b86, gives: none of that reaches them. A change that
+    means to alter one takes a new digest, and says so."""
+    import hashlib
+
+    from test_lm import KIMI
+    from test_lm_ouro import OURO
+
+    text = token_step(arch, {"kimi_linear": KIMI, "ouro": OURO}[arch], dtype).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+
+
+def test_a_hybrid_models_scopes_resolve():
+    """`granitemoehybrid`'s step on the CPU: every Mamba-2 scope is in its
+    table, the SSD core and the convolution under both phases, beside the
+    family's scopes this arch has (attention without `rope`; no expert layer,
+    no `mtp_merge`, no KDA, no exit gate) and the residual adds that carry the
+    multiplier."""
+    from test_lm_granite import GRANITE
+
+    text = token_step("granitemoehybrid", GRANITE).compile().as_text()
+    seen = set(scopes.scope_table(text).values())
+    names = {scope for scope, _ in seen}
+    assert SSD_SCOPES | {"embed", "norm", "attn_proj", "attn_core", "mlp", "lm_head", "loss", "optim", "residual"} <= names
+    assert not {"rope", "moe_router", "moe_dispatch", "moe_experts", "moe_combine", "mtp_merge", "exit_gate"} & names
+    assert not KDA_SCOPES & names
+    assert {(name, phase) for name in ("ssd_core", "ssd_conv", "ssd_proj", "attn_core") for phase in ("fwd", "bwd")} <= seen
+
+
 def test_kimi_linear_scopes_resolve():
     """`kimi_linear`'s step on the CPU: every KDA scope is in its table, the
     core's scan and its hand-written backward under both phases, beside the
@@ -425,7 +465,7 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         for name in files:
             if name.endswith(".py") and name != "scopes.py":
                 with open(os.path.join(root, name)) as f:
-                    found = re.findall(r'\bscope\((?:(?:self|conv)\.scope_name|"(\w+)")\)', f.read())
+                    found = re.findall(r'\bscope\((?:(?:self|conv)\.scope_name|name|"(\w+)")\)', f.read())
                 if found:
                     sites[os.path.relpath(os.path.join(root, name), pkg)] = sorted(found)
     assert (scopes.TAXONOMY_VERSION, sites) == (5, {
@@ -434,17 +474,21 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # PR 35 added `exit_gate` (two sites), two `residual` sites, an `embed` and a `rope` site in models/lm.py and
         # two `attn_proj` sites in ops/lm.py, all of them in the step of a NEW arch (`ouro`), whose cache key is new
         # anyway; no older step holds one of them: no bump (a bump would cost every cell one cold start)
+        # the hybrid arch added an `ssd_gate` site (the step's lowest chunk decay) and joined two `residual` sites into one
+        # (`_branch`, which carries the residual multiplier), and `ops/lm_mamba.py`'s five scopes, all of them
+        # new names in the step of a NEW arch (`granitemoehybrid`); no older step holds one or lost one: no bump
         "models/lm.py": ["embed", "embed", "exit_gate", "exit_gate", "kda_gate", "lm_head", "loss", "loss", "moe_combine",
-                         "moe_router", "mtp_merge", "residual", "residual", "residual", "residual", "residual", "rope",
-                         "rope"],
+                         "moe_router", "mtp_merge", "residual", "residual", "residual", "residual", "rope",
+                         "rope", "ssd_gate"],
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
         # version 2: the conv + BN pair's forward and custom backward (PR 26)
         # PR 34 added a `moe_combine` and a `moe_dispatch` site twice over (the expert layer's two branches) in a step
         # whose program changed with them, so its cache key moved anyway, and no other step holds them: no bump
-        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj", "attn_proj",
-                      "attn_proj", "mlp", "moe_combine",
+        # an `attn_core` site for the grouped key/value heads' repeat (granitemoehybrid's step alone)
+        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj",
+                      "attn_proj", "attn_proj", "mlp", "moe_combine",
                       "moe_combine", "moe_combine", "moe_dispatch", "moe_dispatch", "moe_dispatch", "moe_experts", "moe_router", "norm",
                       "rope"],
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name
@@ -454,8 +498,10 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # program changed with it, so its cache key moved anyway, and no other step holds it: no bump
         # the short convolutions' kernels added two `kda_conv` sites (the forward's and a `custom_vjp`'s backward; q's and
         # k's L2 norms move under `kda_conv` with them) in the one step whose program changed with them: no bump
-        "ops/lm_kda.py": ["kda_conv", "kda_conv", "kda_conv", "kda_core", "kda_core", "kda_gate", "kda_norm", "kda_norm",
-                          "kda_proj", "kda_proj"],
+        # the three `kda_conv` sites take the scope by name (`scope(name)`, "" here): `kda_conv` at every KDA
+        # site as before, `ssd_conv` where a Mamba-2 mixer hands its xBC convolution in
+        "ops/lm_kda.py": ["", "", "", "kda_core", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj", "kda_proj"],
+        "ops/lm_mamba.py": ["ssd_core", "ssd_gate", "ssd_gate", "ssd_norm", "ssd_proj", "ssd_proj"],
         "ops/layers.py": ["", "", "bn_apply", "bn_apply", "bn_apply", "bn_stats", "bn_stats", "bn_stats",
                           "bn_stats", "dense", "drop", "pool", "syncbn", "syncbn"],
         "parallel/zero.py": ["grad_sync", "grad_sync", "optim", "optim"],

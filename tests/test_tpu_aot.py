@@ -183,7 +183,7 @@ def test_the_192_channel_site_takes_the_kernels_with_q_and_k_filled_to_256(one_c
     from yet_another_mobilenet_series_tpu.ops import lm
 
     seq, heads = 16384, 32
-    assert lm.lm_attention.fitting_qk_dim(seq, lm.ATTN_BLOCK, 192, 128, jnp.bfloat16) == 256
+    assert lm.lm_attention.fitting_dims(seq, lm.ATTN_BLOCK, 192, 128, jnp.bfloat16) == (256, 128)
 
     def loss(q, k, v, ct):
         return jnp.sum(lm.causal_attention(q, k, v, scale=192 ** -0.5).astype(jnp.float32) * ct)
@@ -266,6 +266,46 @@ def test_a_kda_mixer_at_the_cells_shape_makes_q_k_v_in_the_conv_kernels_under_th
     monkeypatch.setattr(lm_kda, "conv_fuses", lambda *a: False)
     plain = compiled()
     assert "kda_conv_" not in plain.as_text()
+    assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
+
+
+def test_a_mamba_mixer_at_the_cells_shape_convolves_xbc_in_the_conv_kernels_under_its_scope(one_chip, monkeypatch):
+    """A whole Mamba-2 mixer of granitemoehybrid's cell (1 x 8,192 tokens,
+    hidden 2,048, 64 heads of 64, state 128, chunks of 256; float32 weights,
+    bfloat16 activations), its gradient compiled for the described chip: the
+    xBC convolution (4,352 channels: lanes of 256, which divide them) is ONE
+    `ssd_conv_fwd` custom call under `("ssd_conv", "fwd")` and one
+    `ssd_conv_bwd` under `("ssd_conv", "bwd")`, each with the bias among its
+    operands; no KDA kernel; the chunk scan's two loops and no other; and the
+    declared temporaries are not above those of the same mixer with the plain
+    convolution."""
+    from yet_another_mobilenet_series_tpu.ops import lm_mamba
+
+    hidden, heads, width, state, seq = 2048, 64, 64, 128, 8192
+    inner, channels = heads * width, heads * width + 2 * state
+    shapes = {"in_proj": (hidden, inner + channels + heads), "conv": (4, channels), "conv_bias": (channels,),
+              "A_log": (heads,), "D": (heads,), "dt_bias": (heads,), "norm": (inner,), "out_proj": (inner, hidden)}
+    params = {name: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip) for name, shape in shapes.items()}
+    x = jax.ShapeDtypeStruct((1, seq, hidden), jnp.bfloat16, sharding=one_chip)
+    assert lm_mamba.conv_fuses(seq, channels, 4, jnp.bfloat16)
+
+    def compiled():
+        loss = lambda p, x_: jnp.sum(lm_mamba.mamba_mixer(p, x_, heads=heads, head_dim=width, state=state, chunk=256,  # noqa: E731
+                                                          eps=1e-5)[0].astype(jnp.float32))
+        return jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile()
+
+    fused = compiled()
+    text = fused.as_text()
+    instructions, _ = _entry_instructions(text)
+    kernels = sorted((n.split(".")[0], *scopes.scope_of(op), len(operands))
+                     for n, (_, opcode, operands, op) in instructions.items()
+                     if opcode == "custom-call" and n.startswith(("ssd_", "kda_")))
+    # operands: the filter, the bias, z (and its halo's block); the backward's the cotangent twice more
+    assert kernels == [("ssd_conv_bwd", "ssd_conv", "bwd", 7), ("ssd_conv_fwd", "ssd_conv", "fwd", 4)], kernels
+    assert len(re.findall(r"\bwhile\(", text)) == 2  # the chunk scan, forward and backward
+    monkeypatch.setattr(lm_mamba, "conv_fuses", lambda *a: False)
+    plain = compiled()
+    assert "ssd_conv_" not in plain.as_text()
     assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
 
 

@@ -730,7 +730,8 @@ def _train_or_eval(cfg: Config, net: Network, log: Logger, mesh, is_coord: bool,
                         reg.gauge("train.tokens_per_s").set(
                             snap.get("images_per_sec", 0.0) * trainer.net.lm.seq_len)
                         for name in ("moe_assignments_here", "moe_load_max_over_mean", "moe_dropped", "moe_bounded_sites",
-                                     "kda_min_chunk_log_decay", "expected_exit_step", "exit_p_last", "exit_entropy"):
+                                     "kda_min_chunk_log_decay", "ssd_min_chunk_log_decay", "expected_exit_step",
+                                     "exit_p_last", "exit_entropy"):
                             if name in snap:
                                 reg.gauge("train." + name).set(snap[name])
                     if cfg.prune.enable:

@@ -119,7 +119,7 @@ class LinearAttnConfig:
 @dataclass(frozen=True)
 class LMConfig:
     """Shapes of a token model (token family: ``glm4_moe_lite``,
-    ``kimi_linear``, ``ouro``; models/lm.py), under the keys of the published
+    ``kimi_linear``, ``ouro``, ``granitemoehybrid``; models/lm.py), under the keys of the published
     ``config.json``. The defaults are GLM-4.7-Flash's widths.
     ``model.num_classes`` is the number of vocabulary rows held here
     (embedding, head, token ids and the loss are over that slice).
@@ -130,8 +130,9 @@ class LMConfig:
     holds ``n_routed_experts / expert_shares`` experts of every expert layer,
     those of index ``expert_share_index``, and computes their part of the
     result. What the absent experts would add is left out. A model without
-    one (``ouro``: ``first_k_dense_replace`` = every layer) is whole, layer by
-    layer: it reads none of the expert, latent or MTP keys."""
+    one (``ouro``, ``granitemoehybrid``: ``first_k_dense_replace`` = every
+    layer) is whole, layer by layer: it reads none of the expert, latent or
+    MTP keys."""
 
     hidden_size: int = 2048
     # dense + expert layers held here (the MTP module is counted apart)
@@ -173,6 +174,25 @@ class LMConfig:
     total_ut_steps: int = 1
     # beta: the loss is E_exit[CE] - beta * H(exit distribution)
     exit_entropy_weight: float = 0.1
+    # `granitemoehybrid` alone reads the keys below (Granite 4.0-H; models/lm.py, ops/lm_mamba.py). Each layer's
+    # mixer, in order: "mamba" (a Mamba-2 mixer: `mamba_n_heads` heads of `mamba_d_head` channels, ONE group of B and
+    # C of `mamba_d_state`, a convolution of `mamba_d_conv` taps with a bias, projections without one) or
+    # "attention" (grouped-query attention without rotation: `num_attention_heads` query heads and
+    # `num_key_value_heads` key/value heads of `head_dim` channels); every layer's MLP is dense, `intermediate_size`
+    layer_types: Sequence[str] = ()
+    mamba_n_heads: int = 64
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # attention scores are q.k times this (None: head_dim^-0.5); the embedding's rows are scaled by
+    # `embedding_multiplier`, each block's two branches by `residual_multiplier` before their residual add, and
+    # the logits divided by `logits_scaling`; `tie_word_embeddings`: the head is the embedding (no `head` tensor)
+    attention_multiplier: float | None = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
 
 
 @dataclass(frozen=True)

@@ -21,6 +21,15 @@ distribution less `exit_entropy_weight` times that distribution's entropy
 inference (`early_exit_threshold`), a key/value cache a (loop step, layer),
 and training the gate alone against a frozen model.
 
+`granitemoehybrid` (Granite 4.0-H) is a HYBRID: each layer's mixer is named by
+`layer_types`, a Mamba-2 state-space mixer (ops/lm_mamba.py) or grouped-query
+attention without rotation (the published "nope": query head i
+reads key/value head i // (heads / kv heads), scores times
+`attention_multiplier`); every MLP dense; the embedding's rows scaled by
+`embedding_multiplier`, each branch by `residual_multiplier` before its
+residual add, the logits divided by `logits_scaling`; the head IS the
+embedding (`tie_word_embeddings`: its gradient is the sum of both uses').
+
 A `TokenModel` with expert layers is one SHARE of an expert-parallel
 deployment (config.LMConfig): the mixers are whole, each expert layer holds
 `experts_held` of the `n_routed_experts` the router scores, embedding and head
@@ -51,12 +60,14 @@ from jax import lax
 from ..config import LMConfig, ModelConfig
 from ..obs.scopes import scope
 from ..ops import lm as ops
-from ..ops import lm_attention, lm_kda
+from ..ops import lm_attention, lm_kda, lm_mamba
 
-LM_ARCHS = ("glm4_moe_lite", "kimi_linear", "ouro")
+LM_ARCHS = ("glm4_moe_lite", "kimi_linear", "ouro", "granitemoehybrid")
 # What a layer's checkpoint keeps, by name, beside its input: attention's output and row log-sum-exp, the
-# KDA scan's output and chunk-boundary states: what the two cores' backwards read, so neither forward runs twice.
-KEPT_NAMES = (ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME, lm_kda.KDA_OUT_NAME, lm_kda.KDA_STATES_NAME)
+# KDA scan's output and chunk-boundary states, the SSD scan's chunk-boundary states: what the cores' backwards
+# read, so no forward of theirs runs twice.
+KEPT_NAMES = (ops.ATTN_OUT_NAME, ops.ATTN_LSE_NAME, lm_kda.KDA_OUT_NAME, lm_kda.KDA_STATES_NAME,
+              lm_mamba.SSD_STATES_NAME)
 # Tokens whose logits over the vocabulary slice are held at once (each such block is a jax.checkpoint).
 LOSS_BLOCK = 2048
 
@@ -91,6 +102,10 @@ class TokenModel:
         return self.arch == "ouro"
 
     @property
+    def hybrid(self) -> bool:
+        return self.arch == "granitemoehybrid"
+
+    @property
     def loop_steps(self) -> int:
         """How often a step runs the layer stack (`train.loop_steps`): `total_ut_steps` of a looped model, else 1."""
         return self.lm.total_ut_steps if self.looped else 1
@@ -105,7 +120,10 @@ class TokenModel:
 
     def mixer(self, block: str) -> str:
         """`kda` where `linear_attn_config.kda_layers` names the block's
-        layer (numbered from 1, as the source numbers them), else `attn`."""
+        layer (numbered from 1, as the source numbers them), `mamba` where a
+        hybrid's `layer_types` says "mamba" (numbered from 0), else `attn`."""
+        if self.hybrid:
+            return "mamba" if self.lm.layer_types[int(block.split("_")[1])] == "mamba" else "attn"
         kda_layers = self.lm.linear_attn_config.kda_layers
         return "kda" if block != "mtp" and int(block.split("_")[1]) + 1 in kda_layers else "attn"
 
@@ -143,6 +161,25 @@ class TokenModel:
                                  "linear_attn_config empty")
             if c.total_ut_steps < 1:
                 raise ValueError("total_ut_steps must be at least 1")
+        if self.hybrid:
+            self._validate_hybrid()
+        elif c.layer_types:
+            raise ValueError(f"arch {self.arch} reads no layer_types (granitemoehybrid's)")
+
+    def _validate_hybrid(self) -> None:
+        c = self.lm
+        if len(c.layer_types) != c.num_hidden_layers or set(c.layer_types) - {"mamba", "attention"}:
+            raise ValueError(f"layer_types must name each of the {c.num_hidden_layers} layers 'mamba' or 'attention', "
+                             f"not {list(c.layer_types)}")
+        if c.first_k_dense_replace != c.num_hidden_layers or c.num_nextn_predict_layers or (
+                c.linear_attn_config.kda_layers or c.linear_attn_config.full_attn_layers):
+            raise ValueError("arch granitemoehybrid has no expert layer, no MTP module and no KDA layer: "
+                             "first_k_dense_replace = num_hidden_layers, num_nextn_predict_layers = 0, "
+                             "linear_attn_config empty")
+        kv = c.num_key_value_heads or c.num_attention_heads
+        if not c.head_dim or c.num_attention_heads % kv:
+            raise ValueError(f"grouped-query attention needs a head_dim and num_key_value_heads ({kv}) dividing "
+                             f"num_attention_heads ({c.num_attention_heads})")
 
     def attention_sites(self, compute_dtype) -> tuple[int, int]:
         """(layers that mix by softmax attention, latent or `ouro`'s plain
@@ -155,9 +192,11 @@ class TokenModel:
         c = self.lm
         sites = len(self.blocks_mixing_by("attn"))
         block = min(ops.ATTN_BLOCK, c.seq_len)
-        qk_dim, v_dim = (c.head_dim, c.head_dim) if self.looped else (c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim)
-        wide = lm_attention.fitting_qk_dim(c.seq_len, block, qk_dim, v_dim, compute_dtype)  # as `ops.causal_attention` hands q, k on
-        fits = lm_attention.fuses(c.seq_len, block, wide, v_dim, compute_dtype)
+        plain = self.looped or self.hybrid
+        qk_dim, v_dim = (c.head_dim, c.head_dim) if plain else (c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim)
+        # as `ops.causal_attention` hands q, k and v on
+        fits = lm_attention.fuses(c.seq_len, block, *lm_attention.fitting_dims(c.seq_len, block, qk_dim, v_dim,
+                                                                                compute_dtype), compute_dtype)
         return sites, sites if fits else 0
 
     @property
@@ -181,6 +220,19 @@ class TokenModel:
         la = self.lm.linear_attn_config
         fits = lm_kda.conv_fuses(self.lm.seq_len, la.head_dim, la.short_conv_kernel_size, compute_dtype)
         return self.kda_sites if fits else 0
+
+    @property
+    def ssd_sites(self) -> int:
+        return len(self.blocks_mixing_by("mamba"))
+
+    def ssd_conv_fitting_sites(self, compute_dtype) -> int:
+        """The Mamba-2 layers whose xBC convolution the conv kernels of
+        ops/lm_kda_kernels.py take, by the predicate `lm_mamba.mamba_mixer`
+        dispatches on: what `train.ssd_conv_fused_sites` reports where the
+        step is lowered for a TPU (train/steps.py)."""
+        c = self.lm
+        channels = c.mamba_n_heads * c.mamba_d_head + 2 * c.mamba_d_state
+        return self.ssd_sites if lm_mamba.conv_fuses(c.seq_len, channels, c.mamba_d_conv, compute_dtype) else 0
 
     @property
     def expert_sites(self) -> int:
@@ -213,6 +265,15 @@ class TokenModel:
                     "down": w(name + "d", *lead, width, h)}
 
         p = {"attn_norm": jnp.ones((h,), jnp.float32), "mlp_norm": jnp.ones((h,), jnp.float32)}
+        if self.hybrid:  # a Mamba-2 or a grouped-query attention mixer, and a dense MLP
+            kv = (c.num_key_value_heads or heads) * c.head_dim
+            p["mlp"] = mlp("mlp", c.intermediate_size)
+            if self.mixer(block) == "mamba":
+                p["mamba"] = self._init_mamba(key, w)
+            else:
+                p["attn"] = {"q": w("q", h, heads * c.head_dim), "k": w("k", h, kv), "v": w("v", h, kv),
+                             "o": w("o", heads * c.head_dim, h)}
+            return p
         if self.looped:  # a sandwich block: a second gain a sub-layer, on its output; q, k, v, o whole
             wide = heads * c.head_dim
             p.update(attn_out_norm=jnp.ones((h,), jnp.float32), mlp_out_norm=jnp.ones((h,), jnp.float32),
@@ -262,6 +323,32 @@ class TokenModel:
             "o_norm": jnp.ones((la.head_dim,), jnp.float32), "o": w("kda_o", wide, h),
         }
 
+    def _init_mamba(self, key, w) -> dict:
+        """One Mamba-2 mixer's parameters (ops/lm_mamba.py `mamba_mixer`).
+        Beside the N(0, init_std) projections: the depthwise filter and its
+        bias U(-1/sqrt(taps), 1/sqrt(taps)), a Conv1d's own initialisation;
+        `A_log` = ln(U[1, 16]) and `dt_bias` = softplus^-1(dt) with dt
+        log-uniform in [1e-3, 1e-1], a head each; `D` and the gated norm's
+        gain 1."""
+        c = self.lm
+        h, heads = c.hidden_size, c.mamba_n_heads
+        inner = heads * c.mamba_d_head
+        channels = inner + 2 * c.mamba_d_state
+        bound = c.mamba_d_conv ** -0.5
+        draw = lambda name, shape, lo, hi: jax.random.uniform(  # noqa: E731
+            jax.random.fold_in(key, _key(name)), shape, jnp.float32, lo, hi)
+        dt = jnp.exp(draw("ssd_dt_bias", (heads,), math.log(1e-3), math.log(1e-1)))
+        return {
+            "in_proj": w("in_proj", h, inner + channels + heads),
+            "conv": draw("ssd_conv", (c.mamba_d_conv, channels), -bound, bound),
+            "conv_bias": draw("ssd_conv_bias", (channels,), -bound, bound),
+            "A_log": jnp.log(draw("ssd_A_log", (heads,), 1.0, 16.0)),
+            "D": jnp.ones((heads,), jnp.float32),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "norm": jnp.ones((inner,), jnp.float32),
+            "out_proj": w("out_proj", inner, h),
+        }
+
     def init(self, key) -> tuple[dict, dict]:
         """(params, state): float32 weights ~ N(0, init_std), norm gains 1 (a
         KDA mixer's decay parameters: `_init_kda`; a looped model's exit gate:
@@ -275,6 +362,8 @@ class TokenModel:
             "head": c.init_std * jax.random.normal(jax.random.fold_in(key, _key("head")), (h, self.vocab)),
             "final_norm": jnp.ones((h,), jnp.float32),
         }
+        if c.tie_word_embeddings:  # the head is the embedding, read transposed
+            del params["head"]
         for i, block in enumerate(self.block_names):
             params[block] = self._init_block(jax.random.fold_in(key, 1000 + i), block)
         if self.looped:
@@ -290,13 +379,25 @@ class TokenModel:
 
     # ---- forward ----------------------------------------------------------
 
+    def _branch(self, x, out):
+        """x + out, the branch scaled by `residual_multiplier` where it is not 1."""
+        m = self.lm.residual_multiplier
+        with scope("residual"):
+            return x + (out if m == 1.0 else out * m)
+
     def _mixed(self, p: dict, x, cos, sin):
-        """A block's first half, x + Mixer(norm(x)): (x, its KDA mixer's most
-        negative in-chunk log decay or None)."""
+        """A block's first half, x + Mixer(norm(x)): (x, its KDA or Mamba-2
+        mixer's most negative in-chunk log decay or None)."""
         c = self.lm
         normed = ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps)
         lowest = None
-        if "kda" in p:
+        if "mamba" in p:
+            a, lowest = lm_mamba.mamba_mixer(p["mamba"], normed, heads=c.mamba_n_heads, head_dim=c.mamba_d_head,
+                                             state=c.mamba_d_state, chunk=c.mamba_chunk_size, eps=c.rms_norm_eps)
+        elif self.hybrid:
+            a = ops.mha_attention(p["attn"], normed, None, None, heads=c.num_attention_heads, head_dim=c.head_dim,
+                                  kv_heads=c.num_key_value_heads, scale=c.attention_multiplier)
+        elif "kda" in p:
             la = c.linear_attn_config
             a, lowest = lm_kda.kda_attention(p["kda"], normed, heads=la.num_heads, head_dim=la.head_dim,
                                              eps=c.rms_norm_eps)
@@ -305,17 +406,14 @@ class TokenModel:
                 p["attn"], normed, cos, sin,
                 heads=c.num_attention_heads, nope=c.qk_nope_head_dim, rope=c.qk_rope_head_dim,
                 v_dim=c.v_head_dim, kv_rank=c.kv_lora_rank, eps=c.rms_norm_eps)
-        with scope("residual"):
-            return x + a, lowest
+        return self._branch(x, a), lowest
 
     def _fed(self, block: str, p: dict, bias, x):
         """A block's second half, x + FFN(norm(x)): (x, what its expert layer reports or None)."""
         c = self.lm
         y = ops.rms_norm(x, p["mlp_norm"], c.rms_norm_eps)
         if self.is_dense(block):
-            out = ops.gated_mlp(p["mlp"], y)
-            with scope("residual"):
-                return x + out, None
+            return self._branch(x, ops.gated_mlp(p["mlp"], y)), None
         routed, load, counters, ids = ops.expert_layer(
             p, bias, y, top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
             held=self.experts_held, share_index=c.expert_share_index)
@@ -328,12 +426,18 @@ class TokenModel:
             raise ValueError(f"a batch row holds {tokens.shape[1]} ids, model.lm.seq_len + 2 = {self.lm.seq_len + 2} expected")
         return self.lm.seq_len
 
+    def _head_of(self, params):
+        """The head (h, vocabulary rows): the embedding itself where it is tied."""
+        return params["embed"].T if self.lm.tie_word_embeddings else params["head"]
+
     def _head_loss(self, head_w, hidden, targets, per_token: bool = False):
         """Summed cross-entropy, and how many targets rank first and among the
-        first five, over a (tokens, h) block: float32 logits over the slice,
-        never more than `LOSS_BLOCK` tokens of them at once. `per_token`: and
-        every token's cross-entropy, (tokens,), which a looped model weights
-        by its exit distribution."""
+        first five, over a (tokens, h) block: float32 logits over the slice
+        (divided by `logits_scaling` where it is not 1), never more than
+        `LOSS_BLOCK` tokens of them at once. `per_token`: and every token's
+        cross-entropy, (tokens,), which a looped model weights by its exit
+        distribution."""
+        scaling = self.lm.logits_scaling
         tokens = hidden.shape[0]
         block = min(LOSS_BLOCK, tokens)
         if tokens % block:
@@ -343,6 +447,8 @@ class TokenModel:
             hid, tgt = xs
             with scope("lm_head"):
                 logits = jnp.dot(hid, head_w.astype(hid.dtype), preferred_element_type=jnp.float32)
+                if scaling != 1.0:
+                    logits = logits / scaling
             with scope("loss"):
                 own = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
                 nll = jax.nn.logsumexp(logits, axis=-1) - own
@@ -437,13 +543,16 @@ class TokenModel:
         if self.looped:  # the LAST loop step's head: every step runs (`early_exit_threshold` 1)
             return {"main": self._looped(params, tokens, compute_dtype)[0][-1]}, {}, {}, {}
         cos = sin = None
-        if not c.mla_use_nope:
+        if not (c.mla_use_nope or self.hybrid):  # a hybrid's attention rotates nothing
             with scope("rope"):
                 cos, sin = ops.rope_tables(seq, c.qk_rope_head_dim, c.rope_theta)
         with scope("embed"):
             # one gather for both heads' inputs: positions 0..seq of every row
             # (float32 rows, then the cast: a frequent token's gradient is summed in float32)
-            emb = params["embed"][tokens[:, :seq + 1]].astype(compute_dtype)
+            emb = params["embed"][tokens[:, :seq + 1]]
+            if c.embedding_multiplier != 1.0:
+                emb = emb * c.embedding_multiplier
+            emb = emb.astype(compute_dtype)
 
         # Across the step a layer keeps its input and, by name, its attention's output and row log-sum-exp
         # (B, H, S, Dv in the compute dtype; B, H, S float32). The backward runs the rest of the layer again
@@ -481,7 +590,7 @@ class TokenModel:
                 x = through(block, x)
         heads = {}
         hidden = ops.rms_norm(x, params["final_norm"], c.rms_norm_eps)
-        heads["main"] = self._head_loss(params["head"], hidden.reshape(-1, c.hidden_size),
+        heads["main"] = self._head_loss(self._head_of(params), hidden.reshape(-1, c.hidden_size),
                                         tokens[:, 1:seq + 1].reshape(-1))
         if c.num_nextn_predict_layers:
             m = params["mtp"]
@@ -501,9 +610,10 @@ class TokenModel:
                 "moe_load_max_over_mean": jnp.max(jnp.stack([b["load_max_over_mean"] for b in per_block])),
             } if per_block else {}
         if lowest_by_block:
-            with scope("kda_gate"):
+            name = "ssd_min_chunk_log_decay" if self.hybrid else "kda_min_chunk_log_decay"
+            with scope("ssd_gate") if self.hybrid else scope("kda_gate"):
                 lowest = jnp.min(jnp.stack(lowest_by_block))
-                counters["kda_min_chunk_log_decay"] = lowest if axis_name is None else lax.pmin(lowest, axis_name)
+                counters[name] = lowest if axis_name is None else lax.pmin(lowest, axis_name)
         return heads, new_state, counters, selected
 
     def loss(self, params, state, batch, *, compute_dtype=jnp.float32, axis_name: str | None = None):
@@ -538,19 +648,23 @@ class TokenModel:
 
     def grad_scalars(self, grads: dict) -> dict:
         """Gradient norms by group, as step scalars: embedding, head, `W_eh`,
-        a looped model's exit gate, and per block its mixer (`attn` or `kda`),
-        router, held experts, shared or dense MLP and norm gains (a sandwich
-        block's four). What the benchmark holds against the reference."""
+        a looped model's exit gate, and per block its mixer (`attn`, `kda` or
+        `mamba`), router, held experts, shared or dense MLP and norm gains (a
+        sandwich block's four). A tied vocabulary has no `head`: `embed` is the
+        sum of both uses' gradients. What the benchmark holds against the
+        reference."""
         def norm(tree):
             return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(tree)))
 
-        out = {"gnorm/embed": norm(grads["embed"]), "gnorm/head": norm(grads["head"]),
-               "gnorm/final_norm": norm(grads["final_norm"])}
+        out = {"gnorm/embed": norm(grads["embed"])}
+        if "head" in grads:
+            out["gnorm/head"] = norm(grads["head"])
+        out["gnorm/final_norm"] = norm(grads["final_norm"])
         if "exit_gate" in grads:
             out["gnorm/exit_gate"] = norm(grads["exit_gate"])
         for block in self.block_names:
             g = grads[block]
-            for name in ("attn", "kda", "mlp", "router", "shared", "experts", "eh_proj"):
+            for name in ("attn", "kda", "mamba", "mlp", "router", "shared", "experts", "eh_proj"):
                 if name in g:
                     out[f"gnorm/{block}/{name}"] = norm(g[name])
             out[f"gnorm/{block}/norms"] = norm([v for k, v in g.items() if k.endswith("norm")])

@@ -1,6 +1,7 @@
 """The plain reference of the token family (`glm4_moe_lite`: GLM-4.7-Flash;
 `kimi_linear`: Kimi-Linear-48B-A3B; `ouro`: Ouro-2.6B, the `ouro_*`
-functions at the end): forward, loss and, through `jax.grad`, gradients, in
+functions; `granitemoehybrid`: Granite 4.0-H, the `granite_*` functions at the
+end): forward, loss and, through `jax.grad`, gradients, in
 straightforward `jax.numpy`, float32, under
 `jax.default_matmul_precision("highest")`.
 
@@ -303,3 +304,97 @@ def ouro_loss_and_aux(params, tokens, d):
 def ouro_loss_and_grads(params, tokens, d):
     """((loss, aux), gradients of the loss by parameter)."""
     return jax.value_and_grad(ouro_loss_and_aux, has_aux=True)(params, tokens, d)
+
+
+# ---- `granitemoehybrid`: Mamba-2 mixers beside grouped-query attention (Granite 4.0-H) ------------------------------
+
+GRANITE_DIM_KEYS = ("hidden_size", "num_hidden_layers", "num_attention_heads", "num_key_value_heads", "head_dim",
+                    "rms_norm_eps", "mamba_n_heads", "mamba_d_head", "mamba_d_state", "attention_multiplier",
+                    "embedding_multiplier", "residual_multiplier", "logits_scaling")
+
+
+def granite_dims_of(lm_config) -> dict:
+    return {k: getattr(lm_config, k) for k in GRANITE_DIM_KEYS}
+
+
+def granite_mamba(p, x, d):
+    """One sequence x (S, h) through a Mamba-2 mixer, in the order of the Hugging
+    Face module's own (non-kernel) forward: in_proj -> [z | xBC | dt]; xBC <-
+    SiLU(causal depthwise conv + bias); split x, B, C (ONE group of
+    `mamba_d_state`); Delta = softplus(dt + dt_bias), A = -exp(A_log); the
+    RECURRENCE a token at a time (a `lax.scan` over the positions, never a
+    chunk): S_t = e^{Delta_t A} S_{t-1} + Delta_t x_t B_t^T, y_t = S_t C_t + D
+    x_t; y <- RMSNorm(y * SiLU(z)) * gain over all heads' channels; out_proj.
+    Departures: none in the equations (`time_step_limit` (0, inf) clamps
+    nothing and is left out)."""
+    seq = x.shape[0]
+    heads, width, n = d["mamba_n_heads"], d["mamba_d_head"], d["mamba_d_state"]
+    inner = heads * width
+    proj = x @ p["in_proj"]
+    z, xbc, dt = proj[:, :inner], proj[:, inner:2 * inner + 2 * n], proj[:, 2 * inner + 2 * n:]
+    taps = p["conv"].shape[0]
+    total = p["conv_bias"] + jnp.zeros_like(xbc)
+    for i in range(taps):
+        back = taps - 1 - i
+        total = total + p["conv"][i] * jnp.concatenate([jnp.zeros_like(xbc[:back]), xbc[:seq - back]], axis=0)
+    xbc = jax.nn.silu(total)
+    xs, b, c = xbc[:, :inner].reshape(seq, heads, width), xbc[:, inner:inner + n], xbc[:, inner + n:]
+    delta = jax.nn.softplus(dt + p["dt_bias"])  # (S, heads)
+    a = -jnp.exp(p["A_log"])
+
+    def token(state, inputs):  # state (heads, width, n)
+        x_t, b_t, c_t, dt_t = inputs
+        state = jnp.exp(dt_t * a)[:, None, None] * state + (dt_t[:, None] * x_t)[:, :, None] * b_t[None, None, :]
+        return state, jnp.einsum("hpn,n->hp", state, c_t) + p["D"][:, None] * x_t
+
+    _, y = jax.lax.scan(token, jnp.zeros((heads, width, n), x.dtype), (xs, b, c, delta))
+    y = rms_norm(y.reshape(seq, inner) * jax.nn.silu(z), p["norm"], d["rms_norm_eps"])
+    return y @ p["out_proj"]
+
+
+def granite_attention(p, x, d):
+    """One sequence x (S, h) through grouped-query attention without rotation:
+    query head i reads key/value head i // (heads / kv heads); scores q . k
+    times `attention_multiplier`; a dense S x S causal mask."""
+    seq = x.shape[0]
+    heads, kv, width = d["num_attention_heads"], d["num_key_value_heads"], d["head_dim"]
+    q = (x @ p["q"]).reshape(seq, heads, width)
+    k, v = ((x @ p[n]).reshape(seq, kv, width) for n in ("k", "v"))
+    reads = jnp.arange(heads) // (heads // kv)
+    scores = jnp.einsum("qhd,khd->hqk", q, k[:, reads]) * d["attention_multiplier"]
+    mask = jnp.tril(jnp.ones((seq, seq), bool))
+    probs = jax.nn.softmax(jnp.where(mask[None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", probs, v[:, reads]).reshape(seq, heads * width) @ p["o"]
+
+
+def granite_sequence(params, ids, d):
+    """One row of S + 2 ids (the last is not read) -> logits (S, V): the
+    embedding times `embedding_multiplier`; each layer x + r Mixer(N(x)), then
+    x + r MLP(N(x)) (r = `residual_multiplier`), the mixer Mamba-2 where the
+    layer holds a `mamba` group, else attention; logits = N(x) E^T /
+    `logits_scaling`, the head being the embedding."""
+    seq = ids.shape[0] - 2
+    eps, r = d["rms_norm_eps"], d["residual_multiplier"]
+    x = params["embed"][ids[:seq]] * d["embedding_multiplier"]
+    for i in range(d["num_hidden_layers"]):
+        p = params[f"layer_{i}"]
+        mixed = rms_norm(x, p["attn_norm"], eps)
+        x = x + r * (granite_mamba(p["mamba"], mixed, d) if "mamba" in p else granite_attention(p["attn"], mixed, d))
+        m = p["mlp"]
+        x = x + r * gated_mlp(m["gate"], m["up"], m["down"], rms_norm(x, p["mlp_norm"], eps))
+    return rms_norm(x, params["final_norm"], eps) @ params["embed"].T / d["logits_scaling"]
+
+
+def granite_loss_and_aux(params, tokens, d):
+    """tokens (B, S + 2) -> (loss, {"ce", "logits"}): the mean cross-entropy
+    of the next token over all B * S tokens."""
+    with jax.default_matmul_precision("highest"):
+        seq = tokens.shape[1] - 2
+        logits = jnp.stack([granite_sequence(params, ids, d) for ids in tokens])
+        ce = sum(cross_entropy(z, ids[1:seq + 1]) for z, ids in zip(logits, tokens)) / (tokens.shape[0] * seq)
+        return ce, {"ce": ce, "logits": logits}
+
+
+def granite_loss_and_grads(params, tokens, d):
+    """((loss, aux), gradients of the loss by parameter)."""
+    return jax.value_and_grad(granite_loss_and_aux, has_aux=True)(params, tokens, d)

@@ -78,6 +78,12 @@ SCOPES = (
     "kda_gate",     # the log decay of every key channel, the write strength beta; the step's lowest chunk decay
     "kda_core",     # the chunked gated delta rule: in-chunk decayed scores, the triangular solve, the scan over chunks
     "kda_norm",     # L2 norm of q and k, the sigmoid-gated RMSNorm of the output
+    # Mamba-2 (ops/lm_mamba.py; `granitemoehybrid`):
+    "ssd_proj",     # its two projections: in_proj (z, xBC, dt) and out_proj
+    "ssd_conv",     # the causal depthwise convolution over xBC, its bias and SiLU
+    "ssd_gate",     # softplus of dt, Delta A, the in-chunk cumulative sums; the step's lowest chunk decay
+    "ssd_core",     # the chunked scan: C B^T, in-chunk decays and products, chunk states, the scan over chunks, D x
+    "ssd_norm",     # the gated RMSNorm of the output (gate before the norm)
     "exit_gate",    # a looped model's exit gate (`ouro`): its projection after every loop step, the exit
                     # distribution over the steps, the expected loss and the entropy term
     "lm_head",      # the output head over the vocabulary slice, a block of tokens at a time

@@ -205,20 +205,26 @@ def causal_attention(q: Array, k: Array, v: Array, *, scale: float, block: int |
     """Causal softmax(q k^T * scale) v, float32 softmax, a tile of `block`
     query rows by `block` key rows at a time and never one kept, forward and
     backward (the backward recomputes each tile): in the fused TPU kernels of
-    ops/lm_attention.py where the shapes fit them (q and k filled with zero
-    channels to a multiple of 128 where that is all they lack:
-    `lm_attention.fitting_qk_dim`) and the step is lowered for a TPU, else in
-    the loops over tiles. q, k (B, S, H, D); v (B, S, H, Dv) -> (B, S, H, Dv)."""
+    ops/lm_attention.py where the shapes fit them (q, k and v filled with
+    zero channels to a multiple of 128 where that is all they lack:
+    `lm_attention.fitting_dims`) and the step is lowered for a TPU, else in
+    the loops over tiles. q, k (B, S, H, D); v (B, S, H, Dv) -> (B, S, H,
+    Dv)."""
     seq = q.shape[1]
     block = min(block or ATTN_BLOCK, seq)
     if seq % block:
         raise ValueError(f"sequence length {seq} is not a multiple of the attention block {block}")
     with scope("attn_core"):
-        wide = lm_attention.fitting_qk_dim(seq, block, q.shape[-1], v.shape[-1], q.dtype)
+        v_dim = v.shape[-1]
+        wide, v_wide = lm_attention.fitting_dims(seq, block, q.shape[-1], v_dim, q.dtype)
+        fill = lambda x, width: jnp.pad(x, [(0, 0)] * 3 + [(0, width - x.shape[-1])])  # noqa: E731
         if wide != q.shape[-1]:  # zero channels, so that the kernels take the call (192 -> 256): exact
-            q, k = (jnp.pad(x, [(0, 0)] * 3 + [(0, wide - x.shape[-1])]) for x in (q, k))
+            q, k = fill(q, wide), fill(k, wide)
+        if v_wide != v_dim:  # and v's (64 -> 128): the filled channels of the output are zeros, and are cut off
+            v = fill(v, v_wide)
         q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))  # heads lead: batch and head are the tiles' batch axes
-        return jnp.swapaxes(_blocked_attention(q, k, v, scale, block), 1, 2)
+        out = jnp.swapaxes(_blocked_attention(q, k, v, scale, block), 1, 2)
+        return out if v_wide == v_dim else out[..., :v_dim]
 
 
 def mla_attention(p: dict, x: Array, cos: Array | None, sin: Array | None, *, heads: int, nope: int, rope: int,
@@ -256,16 +262,29 @@ def mla_attention(p: dict, x: Array, cos: Array | None, sin: Array | None, *, he
         return out.reshape(b, s, heads * v_dim) @ p["o"].astype(cd)
 
 
-def mha_attention(p: dict, x: Array, cos: Array, sin: Array, *, heads: int, head_dim: int) -> Array:
-    """Plain multi-head attention (`ouro`): q, k, v three projections of
-    `heads` x `head_dim` channels, ALL channels of q and k rotated, no bias, no
-    q/k norm; the causal core is :func:`causal_attention`, as for the latent
-    archs. x (B, S, h) -> (B, S, h)."""
+def mha_attention(p: dict, x: Array, cos: Array | None, sin: Array | None, *, heads: int, head_dim: int,
+                  kv_heads: int | None = None, scale: float | None = None) -> Array:
+    """Plain multi-head attention (`ouro`) and grouped-query attention
+    (`granitemoehybrid`'s attention layers): q, k, v three projections, no
+    bias, no q/k norm; `heads` query heads and `kv_heads` (None: as many) key
+    and value heads of `head_dim` channels, query head i reading key/value
+    head i // (heads / kv_heads); ALL channels of q and k rotated, or none
+    where `cos` is None; scores times `scale` (None: head_dim^-0.5). The
+    causal core is :func:`causal_attention`, as for the latent archs, with the
+    key/value heads repeated before it (autodiff sums the copies' gradients
+    back). x (B, S, h) -> (B, S, h)."""
     cd = x.dtype
     b, s, _ = x.shape
+    kv_heads = kv_heads or heads
     with scope("attn_proj"):
-        q, k, v = ((x @ p[name].astype(cd)).reshape(b, s, heads, head_dim) for name in ("q", "k", "v"))
-    out = causal_attention(apply_rope(q, cos, sin), apply_rope(k, cos, sin), v, scale=head_dim ** -0.5)
+        q, k, v = ((x @ p[name].astype(cd)).reshape(b, s, n, head_dim)
+                   for name, n in (("q", heads), ("k", kv_heads), ("v", kv_heads)))
+    if cos is not None:
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    if kv_heads != heads:
+        with scope("attn_core"):
+            k, v = (jnp.repeat(t, heads // kv_heads, axis=2) for t in (k, v))
+    out = causal_attention(q, k, v, scale=head_dim ** -0.5 if scale is None else scale)
     with scope("attn_proj"):
         return out.reshape(b, s, heads * head_dim) @ p["o"].astype(cd)
 

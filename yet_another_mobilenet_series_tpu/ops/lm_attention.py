@@ -57,14 +57,16 @@ def fuses(seq: int, block: int, qk_dim: int, v_dim: int, dtype) -> bool:
             and jnp.dtype(dtype) == jnp.bfloat16 and seq * max(qk_dim, v_dim) * 2 <= RESIDENT_BYTES)
 
 
-def fitting_qk_dim(seq: int, block: int, qk_dim: int, v_dim: int, dtype) -> int:
-    """The q/k head dim the kernels are handed: `qk_dim` itself, or, where
-    that alone keeps them from taking the call, `qk_dim` filled with ZERO
-    channels to the next multiple of the 128 lanes (192 -> 256). Exact: a zero
-    channel adds 0 to every score, and its gradient is cut off with the
-    filling. A fit that needs more than that takes the loops, unfilled."""
-    filled = -(-qk_dim // 128) * 128
-    return filled if fuses(seq, block, filled, v_dim, dtype) else qk_dim
+def fitting_dims(seq: int, block: int, qk_dim: int, v_dim: int, dtype) -> tuple[int, int]:
+    """(q/k head dim, v head dim) the kernels are handed: each filled with
+    ZERO channels to the next multiple of the 128 lanes (192 / 128 -> 256 /
+    128, 64 / 64 -> 128 / 128; a dim that fills them is left as it is) where
+    that makes the kernels take the call, else both as they are (the loops,
+    unfilled). Exact: a zero channel of q and k adds 0 to every score, one of
+    v makes a zero channel of the output, which is cut off, and the filling
+    gets no gradient."""
+    filled_qk, filled_v = -(-qk_dim // 128) * 128, -(-v_dim // 128) * 128
+    return (filled_qk, filled_v) if fuses(seq, block, filled_qk, filled_v, dtype) else (qk_dim, v_dim)
 
 
 def features_lead(x):

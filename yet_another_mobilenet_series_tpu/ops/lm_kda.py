@@ -107,17 +107,21 @@ L2_EPS = 1e-6
 CONV_HALO = 16
 
 
-def short_conv(z: Array, w: Array) -> Array:
+def short_conv(z: Array, w: Array, bias: Array | None = None, name: str = "kda_conv") -> Array:
     """SiLU of a causal depthwise convolution, one filter a channel, zero
-    history before the first position, no bias: c_t = SiLU(sum_i w_i
-    z_{t - (k-1) + i}). z (B, S, D), w (k, D) -> (B, S, D)."""
-    with scope("kda_conv"):
+    history before the first position, and a bias a channel where one is given
+    (Mamba-2's xBC convolution, ops/lm_mamba.py): c_t = SiLU(sum_i w_i
+    z_{t - (k-1) + i} + b). z (B, S, D), w (k, D), bias (D,) -> (B, S, D),
+    under the scope `name`."""
+    with scope(name):
         taps, seq = w.shape[0], z.shape[1]
         # padded in the operand's dtype, summed in float32: 1.1 ms forward and 4.1 with the backward at the cell's
         # shape, where a float32 padded copy reads 2.5 and 7.7 and ONE depthwise lax convolution 3.4 and 10.1
         # (scripts/bench_kda.py; PERF.md, PR 33)
         padded = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
         acc = sum(padded[:, i:i + seq].astype(jnp.float32) * w[i].astype(jnp.float32) for i in range(taps))
+        if bias is not None:
+            acc = acc + bias.astype(jnp.float32)
         return jax.nn.silu(acc).astype(z.dtype)
 
 
@@ -128,18 +132,22 @@ def l2_normalise(x: Array, scale: float = 1.0) -> Array:
         return (x32 * lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + L2_EPS) * scale).astype(x.dtype)
 
 
-def _plain_conv(z: Array, w: Array, width: int, scale: float | None) -> Array:
+def _plain_conv(z: Array, w: Array, width: int, scale: float | None, bias: Array | None = None,
+                name: str = "kda_conv") -> Array:
     """`short_conv`, then unless `scale` is None `l2_normalise` over each head
     of `width` channels: z (B, S, H * width) -> the same shape."""
-    out = short_conv(z, w)
+    # without a bias, the call KDA's sites have always made (tests swap `short_conv` for a two-argument one)
+    out = short_conv(z, w) if bias is None else short_conv(z, w, bias, name)
     if scale is None:
         return out
     return l2_normalise(out.reshape(*z.shape[:2], -1, width), scale).reshape(z.shape)
 
 
-def _plain_conv_bwd(z, w, ct, width, scale):
-    """`_plain_conv`'s own vjp: (dz, dw)."""
-    return jax.vjp(functools.partial(_plain_conv, width=width, scale=scale), z, w)[1](ct)
+def _plain_conv_bwd(z, w, ct, width, scale, bias=None, name="kda_conv"):
+    """`_plain_conv`'s own vjp: (dz, dw), and dbias where there is a bias."""
+    if bias is None:
+        return jax.vjp(functools.partial(_plain_conv, width=width, scale=scale), z, w)[1](ct)
+    return jax.vjp(lambda z_, w_, b_: _plain_conv(z_, w_, width, scale, b_, name), z, w, bias)[1](ct)
 
 
 def conv_fuses(seq: int, width: int, taps: int, dtype) -> bool:
@@ -153,67 +161,78 @@ def conv_fuses(seq: int, width: int, taps: int, dtype) -> bool:
 
 
 # Sites of one shape share one trace and one lowering of each form (the twelve convolutions of a step are three kinds).
-@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret"))
-def conv_fwd(z, w, width, scale, interpret: bool = False):
-    """`_plain_conv` as ONE fused kernel: z read once with a halo of the rows
-    before each tile, the float32 sum, SiLU and norm in VMEM, the result
-    written once."""
+@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret", "name"))
+def conv_fwd(z, w, width, scale, interpret: bool = False, bias=None, name: str = "kda_conv"):
+    """`_plain_conv` as ONE fused kernel, instruction `<name>_fwd`: z read once
+    with a halo of the rows before each tile, the float32 sum (and bias), SiLU
+    and norm in VMEM, the result written once."""
     from . import lm_kda_kernels as kernels  # Pallas comes in HERE and nowhere earlier (module docstring)
 
-    return kernels.conv_fwd_call(z, w, width, scale, L2_EPS, interpret)
+    return kernels.conv_fwd_call(z, w, width, scale, L2_EPS, interpret, bias=bias, name=name)
 
 
-@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret"))
-def conv_bwd(z, w, ct, width, scale, interpret: bool = False):
-    """(dz, dw) from the forward's operands and the cotangent of its result:
-    the second kernel remakes the pre-activation and the norm's statistics in
-    VMEM; XLA sums its tiles' float32 dw."""
+@functools.partial(jax.jit, static_argnames=("width", "scale", "interpret", "name"))
+def conv_bwd(z, w, ct, width, scale, interpret: bool = False, bias=None, name: str = "kda_conv"):
+    """(dz, dw), and dbias where there is a bias, from the forward's operands
+    and the cotangent of its result: the second kernel remakes the
+    pre-activation and the norm's statistics in VMEM; XLA sums its tiles'
+    float32 dw (and db)."""
     from . import lm_kda_kernels as kernels  # as in conv_fwd
 
-    dz, dw = kernels.conv_bwd_call(z, w, ct, width, scale, L2_EPS, interpret)
-    return dz, jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+    dz, dw, *db = kernels.conv_bwd_call(z, w, ct, width, scale, L2_EPS, interpret, bias=bias, name=name)
+    dw = jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+    return (dz, dw) if bias is None else (dz, dw, jnp.sum(db[0], axis=(0, 1, 2)).astype(bias.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _fused_conv(z: Array, w: Array, width: int, scale: float | None) -> Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 5))
+def _fused_conv(z: Array, w: Array, width: int, scale: float | None, bias: Array | None = None,
+                name: str = "kda_conv") -> Array:
     """`_plain_conv` at a shape the kernels take (`conv_fuses`): the two
     kernels where the step is lowered for a TPU, else the plain form and its
     own vjp (`lax.platform_dependent` decides at lowering). What the backward
-    keeps is z and w alone."""
-    return _fused_conv_fwd(z, w, width, scale)[0]
+    keeps is z, w (and the bias) alone."""
+    return _fused_conv_fwd(z, w, width, scale, bias, name)[0]
 
 
 # the other platforms' branches, shared by the sites as the kernels' are
-_shared_plain_conv = jax.jit(_plain_conv, static_argnames=("width", "scale"))
-_shared_plain_conv_bwd = jax.jit(_plain_conv_bwd, static_argnames=("width", "scale"))
+_shared_plain_conv = jax.jit(_plain_conv, static_argnames=("width", "scale", "name"))
+_shared_plain_conv_bwd = jax.jit(_plain_conv_bwd, static_argnames=("width", "scale", "name"))
 
 
-def _fused_conv_fwd(z, w, width, scale):
-    made = lax.platform_dependent(z, w, tpu=functools.partial(conv_fwd, width=width, scale=scale),
-                                  default=functools.partial(_shared_plain_conv, width=width, scale=scale))
-    return made, (z, w)
+def _fused_conv_fwd(z, w, width, scale, bias, name):
+    statics = {"width": width, "scale": scale, "name": name}
+    made = lax.platform_dependent(z, w, bias, tpu=lambda *a: conv_fwd(*a[:2], bias=a[2], **statics),
+                                  default=lambda *a: _shared_plain_conv(*a[:2], bias=a[2], **statics))
+    return made, (z, w, bias)
 
 
-def _fused_conv_bwd(width, scale, kept, ct):
-    with scope("kda_conv"):
-        return lax.platform_dependent(*kept, ct, tpu=functools.partial(conv_bwd, width=width, scale=scale),
-                                      default=functools.partial(_shared_plain_conv_bwd, width=width, scale=scale))
+def _fused_conv_bwd(width, scale, name, kept, ct):
+    statics = {"width": width, "scale": scale, "name": name}
+    z, w, bias = kept
+    with scope(name):
+        grads = lax.platform_dependent(z, w, bias, ct, tpu=lambda *a: conv_bwd(*a[:2], a[3], bias=a[2], **statics),
+                                       default=lambda *a: _shared_plain_conv_bwd(*a[:2], a[3], bias=a[2], **statics))
+    return grads if bias is not None else (*grads, None)
 
 
 _fused_conv.defvjp(_fused_conv_fwd, _fused_conv_bwd)
 
 
-def conv_and_norm(z: Array, w: Array, width: int, scale: float | None = None) -> Array:
+def conv_and_norm(z: Array, w: Array, width: int, scale: float | None = None, bias: Array | None = None,
+                  name: str = "kda_conv") -> Array:
     """q, k or v of a KDA mixer from its projection: `short_conv` (causal,
     SiLU) and, unless `scale` is None, `l2_normalise` over each head of
     `width` channels, times `scale`. z (B, S, H * width), w (taps, H *
-    width) -> (B, S, H * width) in z's dtype. Where `conv_fuses` takes the
-    shapes and the lowering is a TPU's, one kernel each way (`_fused_conv`);
-    else the plain form."""
+    width) -> (B, S, H * width) in z's dtype. With a `bias` (H * width,) it
+    is added before the SiLU: Mamba-2's xBC convolution (ops/lm_mamba.py),
+    whose sites go under the scope `name` (`ssd_conv`) and whose kernels are
+    instructions `<name>_fwd` / `_bwd`. Where `conv_fuses` takes the shapes
+    and the lowering is a TPU's, one kernel each way (`_fused_conv`); else the
+    plain form."""
     if conv_fuses(z.shape[1], width, w.shape[0], z.dtype):
-        with scope("kda_conv"):
-            return _fused_conv(z, w, width, scale)
-    return _plain_conv(z, w, width, scale)
+        with scope(name):
+            return _fused_conv(z, w, width, scale, bias, name)
+    return _plain_conv(z, w, width, scale, bias, name)
 
 
 def _pairs_decay(big_g: Array) -> Array:

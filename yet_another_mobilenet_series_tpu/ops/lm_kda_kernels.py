@@ -1,5 +1,6 @@
 """The Pallas/Mosaic kernels behind `ops/lm_kda.py`: two for the in-chunk
-work, two for the short convolutions (the end of this module), and the calls
+work, two for the short convolutions (the end of this module; Mamba-2's xBC
+convolution, ops/lm_mamba.py, takes them too, with a bias), and the calls
 that build them. Imported only from inside `lm_kda.operands_fwd/operands_bwd`
 and `lm_kda.conv_fwd/conv_bwd`, that is while the `tpu` branch of a
 fitting KDA site is traced (or a test asks for interpret mode): the Pallas
@@ -426,6 +427,8 @@ def bwd_call(q, k, v, g, beta, cts, heads: int, chunk: int, interpret: bool = Fa
 # each head a band of its lanes; rows are shifted by sublane rotations (`pltpu.roll`) of the tile stacked under its halo.
 # A tile is CONV_ROWS rows (positions) by CONV_LANES lanes (whole heads) of (B, S, H * D), the halo a neighbour's
 # CONV_HALO rows (lm_kda's): one bfloat16 sublane tile, behind the tile (the filter's history) and, in the backward, ahead.
+# A Mamba-2 site (ops/lm_mamba.py) hands in a bias as one more operand, added before the SiLU, and no norm; its pair is
+# named after its scope (`ssd_conv_fwd` / `_bwd`). Without a bias a kernel has the operands it always had.
 CONV_ROWS = 512
 CONV_LANES = 512
 
@@ -453,7 +456,8 @@ def _history(ref, band, first_row):
     return x, first_row + lax.broadcasted_iota(jnp.int32, x.shape, 0)
 
 
-def _conv_fwd_kernel(w_ref, behind_ref, z_ref, out_ref, *, width, scale, eps):
+def _conv_fwd_kernel(*refs, width, scale, eps, biased):
+    w_ref, bias_ref, behind_ref, z_ref, out_ref = refs if biased else (refs[0], None, *refs[1:])
     rows, lanes = z_ref.shape
     taps = w_ref.shape[0]
     start = pl.program_id(1) * rows
@@ -462,13 +466,19 @@ def _conv_fwd_kernel(w_ref, behind_ref, z_ref, out_ref, *, width, scale, eps):
         history, at = _history(behind_ref, band, start - CONV_HALO)
         x = jnp.concatenate([jnp.where(at >= 0, history, 0.0), z_ref[:, band].astype(jnp.float32)], axis=0)
         pre = sum(w_ref[pl.ds(i, 1), band] * _behind(x, taps - 1 - i, rows) for i in range(taps))
+        if bias_ref is not None:
+            pre = pre + bias_ref[:, band]
         c = pre * jax.nn.sigmoid(pre)
         if scale is not None:
             c = c * (lax.rsqrt(jnp.sum(c * c, axis=1, keepdims=True) + eps) * scale)
         out_ref[:, band] = c.astype(out_ref.dtype)
 
 
-def _conv_bwd_kernel(w_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, dz_ref, dw_ref, *, width, scale, eps, seq):
+def _conv_bwd_kernel(*refs, width, scale, eps, seq, biased):
+    if biased:
+        w_ref, bias_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, dz_ref, dw_ref, db_ref = refs
+    else:
+        (w_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, dz_ref, dw_ref), bias_ref, db_ref = refs, None, None
     rows, lanes = z_ref.shape
     taps = w_ref.shape[0]
     start = pl.program_id(1) * rows
@@ -481,6 +491,8 @@ def _conv_bwd_kernel(w_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, 
                              jnp.where(later_at < seq, later, 0.0)], axis=0)
         shifted = [_behind(x, taps - 1 - i, reach) for i in range(taps)]  # z_{t - (taps - 1) + i}, t from the tile's first row
         pre = sum(w_ref[pl.ds(i, 1), band] * shifted[i] for i in range(taps))
+        if bias_ref is not None:
+            pre = pre + bias_ref[:, band]
         gate = jax.nn.sigmoid(pre)
         dy_later, dy_at = _history(dy_ahead_ref, band, start + rows)
         dc = jnp.concatenate([dy_ref[:, band].astype(jnp.float32), jnp.where(dy_at < seq, dy_later, 0.0)], axis=0)
@@ -495,6 +507,8 @@ def _conv_bwd_kernel(w_ref, behind_ref, z_ref, ahead_ref, dy_ref, dy_ahead_ref, 
         dz_ref[:, band] = dz.astype(dz_ref.dtype)
         for i in range(taps):
             dw_ref[pl.ds(i, 1), band] = jnp.sum(d_pre[:rows] * shifted[i][:rows], axis=0, keepdims=True)
+        if db_ref is not None:
+            db_ref[:, band] = jnp.sum(d_pre[:rows], axis=0, keepdims=True)
 
 
 def _conv_specs(seq, features, width, taps):
@@ -507,36 +521,48 @@ def _conv_specs(seq, features, width, taps):
     return rows, lanes, tile, behind, ahead, taps_spec
 
 
+def _bias_spec(lanes):
+    """A bias (1, H * D) float32: a tile's lanes of it."""
+    return pl.BlockSpec((1, lanes), lambda b, i, j: (0, j))
+
+
 def _conv_params():
     return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel"), vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
-def conv_fwd_call(z, w, width: int, scale, eps: float, interpret: bool = False):
-    """z (B, S, H * D) in the compute dtype, w (taps, H * D) -> SiLU of the
-    causal convolution, each head of `width` lanes L2-normalised and times
-    `scale` unless `scale` is None; in z's dtype."""
+def conv_fwd_call(z, w, width: int, scale, eps: float, interpret: bool = False, bias=None, name: str = "kda_conv"):
+    """z (B, S, H * D) in the compute dtype, w (taps, H * D), bias None or (H *
+    D,) -> SiLU of the causal convolution (plus the bias), each head of `width`
+    lanes L2-normalised and times `scale` unless `scale` is None; in z's dtype.
+    The instruction is `<name>_fwd`. Without a bias the kernel and its operands
+    are what they were before there was one."""
     batch, seq, features = z.shape
     rows, lanes, tile, behind, _, taps_spec = _conv_specs(seq, features, width, w.shape[0])
+    biased = bias is not None
     return pl.pallas_call(
-        functools.partial(_conv_fwd_kernel, width=width, scale=scale, eps=eps),
+        functools.partial(_conv_fwd_kernel, width=width, scale=scale, eps=eps, biased=biased),
         grid=(batch, seq // rows, features // lanes),
-        in_specs=[taps_spec, behind, tile], out_specs=tile,
+        in_specs=[taps_spec, *([_bias_spec(lanes)] if biased else []), behind, tile], out_specs=tile,
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
-        compiler_params=_conv_params(), interpret=interpret, name="kda_conv_fwd",
-    )(w.astype(jnp.float32), z, z)
+        compiler_params=_conv_params(), interpret=interpret, name=name + "_fwd",
+    )(w.astype(jnp.float32), *([bias.astype(jnp.float32).reshape(1, features)] if biased else []), z, z)
 
 
-def conv_bwd_call(z, w, ct, width: int, scale, eps: float, interpret: bool = False):
-    """(dz in z's dtype, float32 dw of each tile (B, S / rows, taps, H * D)) from
-    the forward's operands and the cotangent of its result."""
+def conv_bwd_call(z, w, ct, width: int, scale, eps: float, interpret: bool = False, bias=None, name: str = "kda_conv"):
+    """(dz in z's dtype, float32 dw of each tile (B, S / rows, taps, H * D)
+    and, with a bias, float32 db of each tile (B, S / rows, 1, H * D)) from the
+    forward's operands and the cotangent of its result."""
     batch, seq, features = z.shape
     taps = w.shape[0]
     rows, lanes, tile, behind, ahead, taps_spec = _conv_specs(seq, features, width, taps)
+    biased = bias is not None
+    per_tile = lambda n: pl.BlockSpec((None, None, n, lanes), lambda b, i, j: (b, i, 0, j))  # noqa: E731
+    sums = lambda n: jax.ShapeDtypeStruct((batch, seq // rows, n, features), jnp.float32)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_conv_bwd_kernel, width=width, scale=scale, eps=eps, seq=seq),
+        functools.partial(_conv_bwd_kernel, width=width, scale=scale, eps=eps, seq=seq, biased=biased),
         grid=(batch, seq // rows, features // lanes),
-        in_specs=[taps_spec, behind, tile, ahead, tile, ahead],
-        out_specs=[tile, pl.BlockSpec((None, None, taps, lanes), lambda b, i, j: (b, i, 0, j))],
-        out_shape=[jax.ShapeDtypeStruct(z.shape, z.dtype), jax.ShapeDtypeStruct((batch, seq // rows, taps, features), jnp.float32)],
-        compiler_params=_conv_params(), interpret=interpret, name="kda_conv_bwd",
-    )(w.astype(jnp.float32), z, z, z, ct, ct)
+        in_specs=[taps_spec, *([_bias_spec(lanes)] if biased else []), behind, tile, ahead, tile, ahead],
+        out_specs=[tile, per_tile(taps), *([per_tile(1)] if biased else [])],
+        out_shape=[jax.ShapeDtypeStruct(z.shape, z.dtype), sums(taps), *([sums(1)] if biased else [])],
+        compiler_params=_conv_params(), interpret=interpret, name=name + "_bwd",
+    )(w.astype(jnp.float32), *([bias.astype(jnp.float32).reshape(1, features)] if biased else []), z, z, z, ct, ct)

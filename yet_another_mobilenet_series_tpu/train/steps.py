@@ -260,6 +260,11 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     get_registry().gauge("train.kda_fused_sites").set(net.kda_fitting_sites(compute_dtype) if on_tpu else 0)
     # and those whose three short convolutions (with q's and k's L2 norms) go through its two conv kernels (`conv_fuses`)
     get_registry().gauge("train.kda_conv_fused_sites").set(net.kda_conv_fitting_sites(compute_dtype) if on_tpu else 0)
+    # the Mamba-2 layers (ops/lm_mamba.py), all of whose chunk-boundary states the layer checkpoint keeps by name, and
+    # those whose xBC convolution goes through the conv kernels (a prediction, as `train.kda_conv_fused_sites` is)
+    get_registry().gauge("train.ssd_sites").set(net.ssd_sites)
+    get_registry().gauge("train.ssd_kept_sites").set(net.ssd_sites)
+    get_registry().gauge("train.ssd_conv_fused_sites").set(net.ssd_conv_fitting_sites(compute_dtype) if on_tpu else 0)
     # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
     get_registry().gauge("train.moe_sites").set(net.expert_sites)
     # a looped model runs its layers `loop_steps` times a step with the same weights: the sites above are LAYERS,
@@ -310,7 +315,8 @@ def make_train_step(
     (`train.attn_fused_sites`: the attention layers through the kernels of
     ops/lm_attention.py; `train.kda_fused_sites` / `train.kda_conv_fused_sites`:
     the KDA layers whose in-chunk work / short convolutions go through those of
-    ops/lm_kda.py). A step built without
+    ops/lm_kda.py; `train.ssd_conv_fused_sites`: the Mamba-2 layers whose xBC
+    convolution does). A step built without
     it and then compiled ahead of time for another platform than the default
     backend's reports the default backend's count.
     """
