@@ -170,7 +170,8 @@ def test_three_steps_of_the_hybrid_arch_through_cli_train(tmp_path, capsys):
     with open(os.path.join(log_dir, "obs_registry.json")) as f:
         registry = json.load(f)
     assert (registry["train.ssd_sites"], registry["train.ssd_kept_sites"], registry["train.ssd_conv_fused_sites"],
-            registry["train.attn_sites"], registry["train.moe_sites"]) == (2.0, 2.0, 0.0, 1.0, 0.0)
+            registry["train.ssd_fused_sites"], registry["train.attn_sites"], registry["train.moe_sites"]) == (
+                2.0, 2.0, 0.0, 0.0, 1.0, 0.0)
     assert registry["train.ssd_min_chunk_log_decay"] == last["train/ssd_min_chunk_log_decay"]
     mgr = CheckpointManager(log_dir + "/ckpt")
     step, net, _ = mgr.restore_spec()

@@ -361,6 +361,203 @@ def test_on_the_cpu_the_xbc_convolution_is_the_plain_one_under_its_scope():
     assert {"ssd_proj", "ssd_gate", "ssd_norm"} <= {name for name, _ in seen}
 
 
+# -- the SSD kernels (ops/lm_mamba_kernels.py) in Pallas interpret mode, against the plain form ----------------------
+# a shape the kernels take: 2 chunks of 128, 4 heads of 64 (two to a 128-lane band), a state of 128, bfloat16
+KB, KS, KH, KP, KK, KC = 1, 256, 4, 64, 128, 128
+# the largest deviation allowed against each result's largest entry: bfloat16's ulp at the top of its range is 2^-8 for
+# y, dx, dB, dC and the start states; dDelta and dG are float32 sums of bfloat16 products; dD sums x dy in float32
+OUTPUT_LIMIT = 1e-2
+GRAD_LIMITS = (1.5e-2, 1e-2, 1e-2, 1e-2, 1e-2, 1e-2, 1e-4)  # x, delta, cum, b, c, starts, d_skip
+OWN_LIMIT = 1e-5  # the own contributions: float32 sums of the same bfloat16 products
+DECAYS = pytest.mark.parametrize("fast", [0.1, 4.0], ids=["fresh_decays", "decays_past_-88"])
+
+
+@pytest.fixture
+def ssd_kernels(monkeypatch):
+    """The kernels' lowering on the CPU (`lax.platform_dependent` takes its `tpu` branch, the kernels run in Pallas
+    interpret mode), with some of the kernels module's names set for one test: the kernels' calls are jitted,
+    so traces made before are dropped first, and the test's own after it."""
+    from yet_another_mobilenet_series_tpu.ops import lm_mamba_kernels as kernels
+
+    monkeypatch.setattr(jax.lax, "platform_dependent", lambda *args, tpu, default: tpu(*args))
+    for name in ("chunk_fwd", "chunk_bwd", "own_fwd", "own_bwd"):
+        monkeypatch.setattr(lm_mamba, name, functools.partial(getattr(lm_mamba, name), interpret=True))
+
+    def setting(**names):
+        for name, value in names.items():
+            monkeypatch.setattr(kernels, name, value)
+        jax.clear_caches()
+
+    setting()
+    yield setting
+    jax.clear_caches()
+
+
+def outputs_operands(fast, seed=0):
+    """The kernels' operands, flat as the mixer holds them: x, B, C in bfloat16, Delta in (0, 0.3), the in-chunk
+    cumulative Delta A scaled by `fast` (0.1 keeps every in-chunk sum above -88, 4 takes some past it), start states
+    as a chunk scan leaves them."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    delta = 0.3 * jax.nn.sigmoid(jax.random.normal(ks[1], (KB, KS, KH)))
+    rate = fast * jnp.exp(jax.random.uniform(ks[2], (KH,), minval=0.0, maxval=math.log(16.0)))
+    x = jax.random.normal(ks[0], (KB, KS, KH * KP)).astype(jnp.bfloat16)
+    b, c = (jax.random.normal(k, (KB, KS, KK)).astype(jnp.bfloat16) for k in ks[3:5])
+    starts = jax.random.normal(ks[5], (KS // KC, KB, KH, KP, KK)).astype(jnp.bfloat16)
+    cum = jnp.cumsum((-rate * delta).reshape(KB, KS // KC, KC, KH), axis=2).reshape(KB, KS, KH)
+    return x, delta, cum, b, c, starts, jax.random.normal(ks[6], (KH,))
+
+
+def _vjp_deviations(fused, plain, args, ct):
+    got, pull = jax.vjp(fused, *args)
+    want, want_pull = jax.vjp(plain, *args)
+    grads, want_grads = pull(ct), want_pull(ct)
+    assert (got.shape, got.dtype) == (want.shape, want.dtype)
+    assert [(g.shape, g.dtype) for g in grads] == [(a.shape, a.dtype) for a in args]
+    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))) / float(jnp.max(jnp.abs(b.astype(jnp.float32)))))
+            for a, b in zip((got, *grads), (want, *want_grads))]
+
+
+def kernel_deviations(args):
+    """The four kernels through their two custom_vjps against the plain forms and their vjps, from one cotangent
+    each, as shares of the plain results' largest entries: (y's, y's arguments' gradients; each chunk's own state
+    contribution's, its arguments' gradients)."""
+    x, delta, cum, b, _, _, _ = args
+    ct = jax.random.normal(jax.random.PRNGKey(11), x.shape).astype(jnp.bfloat16)
+    outputs = _vjp_deviations(lm_mamba._fused_outputs, lm_mamba._plain_outputs, args, ct)
+    own_ct = jax.random.normal(jax.random.PRNGKey(12), args[5].shape)
+    own = _vjp_deviations(lambda *a: lm_mamba._fused_own(*a, KC), functools.partial(lm_mamba._plain_own, chunk=KC),
+                          (x, delta, cum, b), own_ct)
+    return outputs[0], outputs[1:], own[0], own[1:]
+
+
+def holds(devs):
+    output, grads, own, own_grads = devs
+    return (output < OUTPUT_LIMIT and all(d < limit for d, limit in zip(grads, GRAD_LIMITS))
+            and own < OWN_LIMIT and all(d < limit for d, limit in zip(own_grads, GRAD_LIMITS)))
+
+
+@DECAYS
+def test_the_ssd_kernels_output_and_every_gradient_equal_the_plain_form(ssd_kernels, fast):
+    """`_fused_outputs` and `_fused_own` lowered as for a TPU (the forward
+    kernels; their vjps the backward kernels, which make the decays and
+    weights again in VMEM) against `_plain_outputs` (all heads' (t, s, h)
+    matrices in `lax`), `_plain_own` and their vjps: y and the gradients of x,
+    Delta, G, B, C, the start states and D; each chunk's own state
+    contribution and the gradients of x, Delta, G and B; finite where the
+    in-chunk sums pass -88."""
+    args = outputs_operands(fast)
+    assert lm_mamba.fuses(KS, KC, KH, KP, KK, jnp.bfloat16)
+    assert (float(jnp.min(args[2])) < -88.0) is (fast > 1.0)
+    devs = kernel_deviations(args)
+    assert holds(devs), devs
+
+
+def _product_of_exponentials(later, earlier, keep):
+    return jnp.where(keep, jnp.exp(later) * jnp.exp(-earlier), 0.0)
+
+
+def _masked_after_the_exp(later, earlier, keep):
+    return jnp.exp(later - earlier) * jnp.where(keep, 1.0, 0.0)  # a 0/1 factor (`* keep` is a select in jax.numpy)
+
+
+@pytest.mark.parametrize("planted", [_product_of_exponentials, _masked_after_the_exp],
+                         ids=["decay_as_a_product_of_exponentials", "mask_after_the_exp"])
+def test_a_decay_that_is_not_an_exp_of_a_masked_difference_is_refused_past_minus_88(ssd_kernels, planted):
+    """Plant in the kernels a decay written as `e^{G_t} e^{-G_s}`, or masked
+    by a product after the `exp`: the same numbers while every in-chunk sum
+    stays above -88, so the comparison above passes there; past it inf x 0
+    reaches the output and the gradients, and the comparison refuses both."""
+    ssd_kernels(decays=planted)
+    assert holds(kernel_deviations(outputs_operands(0.1, seed=3)))
+    assert not holds(kernel_deviations(outputs_operands(4.0, seed=3)))
+
+
+@pytest.mark.parametrize("seq, chunk, heads, width, state, dtype, takes", [
+    (256, 128, 4, 64, 128, jnp.bfloat16, True), (8192, 256, 64, 64, 128, jnp.bfloat16, True),
+    (320, 128, 4, 64, 128, jnp.bfloat16, False), (256, 64, 4, 64, 128, jnp.bfloat16, False),
+    (256, 128, 4, 64, 128, jnp.float32, False), (256, 128, 4, 48, 128, jnp.bfloat16, False),
+    (256, 128, 4, 64, 64, jnp.bfloat16, False), (256, 128, 1, 64, 128, jnp.bfloat16, False),
+    (8192, 2048, 64, 64, 128, jnp.bfloat16, False),
+], ids=["fits", "the_cells_shape", "sequence_not_whole_chunks", "chunk_of_64", "float32", "head_dim_48", "state_64",
+        "half_a_band", "chunk_too_large_for_vmem"])
+def test_the_ssd_dispatch_takes_the_kernels_by_the_shapes_alone(monkeypatch, seq, chunk, heads, width, state, dtype, takes):
+    """`ssd_core` asks `fuses` (whole chunks of whole 128-row tiles, heads that
+    fill whole 128-lane bands, a state of whole bands, bfloat16, what a program
+    instance holds within the VMEM it may plan for) and nothing else; what does
+    not fit takes the plain form, with no `custom_vjp` and no platform switch."""
+    assert lm_mamba.fuses(seq, chunk, heads, width, state, dtype) == takes
+    asked = []
+    fused = lm_mamba._fused_outputs
+    monkeypatch.setattr(lm_mamba, "_fused_outputs", lambda *a: asked.append(a[0].shape) or fused(*a))
+    x = jax.ShapeDtypeStruct((1, seq, heads, width), dtype)
+    per_head = jax.ShapeDtypeStruct((1, seq, heads), jnp.float32)
+    bc = jax.ShapeDtypeStruct((1, seq, state), dtype)
+    jaxpr = jax.make_jaxpr(lambda *a: lm_mamba.ssd_core(*a, chunk=chunk))(x, per_head, per_head, bc, bc,
+                                                                         jax.ShapeDtypeStruct((heads,), jnp.float32))
+    assert bool(asked) == takes
+    assert ("platform_index" in str(jaxpr)) == takes
+
+
+def test_on_a_cpu_the_fitting_ssd_runs_the_plain_form_and_its_vjp(monkeypatch):
+    """The kernels' shape lowered for a CPU: `lax.platform_dependent` keeps the
+    plain form and, in the backward, its own vjp, so y and every gradient of
+    `ssd_core` equal those of a dispatch that refuses the shape, to the bit."""
+    x, delta, log_decay, b, c, _, d_skip = outputs_operands(4.0, seed=5)
+    args = (x.reshape(KB, KS, KH, KP), delta, log_decay, b, c, d_skip)
+    ct = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def run():
+        loss = lambda *a: jnp.sum(lm_mamba.ssd_core(*a, chunk=KC)[0].astype(jnp.float32) * ct)  # noqa: E731
+        return jax.jit(jax.value_and_grad(loss, range(6)))(*args)
+
+    text = jax.jit(lambda *a: lm_mamba.ssd_core(*a, chunk=KC)).lower(*args).compile().as_text()
+    assert "pallas_call" not in text and "ssd_chunk_" not in text
+    through_the_dispatch = run()
+    monkeypatch.setattr(lm_mamba, "fuses", lambda *a: False)
+    plain = run()
+    assert all(bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(through_the_dispatch), jax.tree.leaves(plain)))
+
+
+_FRESH_PROCESS = """
+import json, sys
+import jax, jax.numpy as jnp
+from yet_another_mobilenet_series_tpu.models import lm
+from yet_another_mobilenet_series_tpu.ops import lm_mamba
+from yet_another_mobilenet_series_tpu.train import steps
+
+def pallas():
+    return sorted(m for m in sys.modules if m.startswith(("jax.experimental.pallas", "jax._src.pallas")))
+
+dtype = jnp.bfloat16 if sys.argv[1] == "fitting_site" else jnp.float32
+before = pallas()
+x = jax.ShapeDtypeStruct((1, 256, 4, 64), dtype)
+per_head = jax.ShapeDtypeStruct((1, 256, 4), jnp.float32)
+bc = jax.ShapeDtypeStruct((1, 256, 128), dtype)
+jax.eval_shape(lambda *a: lm_mamba.ssd_core(*a, chunk=128), x, per_head, per_head, bc, bc, jax.ShapeDtypeStruct((4,), jnp.float32))
+print(json.dumps({"fits": lm_mamba.fuses(256, 128, 4, 64, 128, dtype), "before": before, "pallas": pallas()}))
+"""
+
+
+@pytest.mark.parametrize("what, pays", [("plain_site", False), ("fitting_site", True)])
+def test_pallas_comes_in_where_a_fitting_ssd_site_is_traced_and_nowhere_else(what, pays):
+    """A fresh process that imports `ops.lm_mamba`, `models.lm` and
+    `train.steps` has no `jax.experimental.pallas*` module, and none after
+    tracing an SSD the kernels do not take; the `tpu` branch of a fitting
+    site, once traced, brings it in (the import costs every cell that runs
+    none of its code 1.2-1.5 s of `setup_s`)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", _FRESH_PROCESS, what], cwd=repo, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    said = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert said["fits"] == pays and not said["before"]
+    assert {"jax.experimental.pallas", "jax.experimental.pallas.tpu"} <= set(said["pallas"]) if pays else not said["pallas"], said
+
+
 # -- attention with v filled and the key/value heads repeated -------------------------------------------------------
 
 
@@ -418,10 +615,11 @@ def test_grouped_heads_with_v_filled_equal_plain_grouped_attention(monkeypatch):
 def test_the_train_step_reports_its_ssd_and_attention_sites(monkeypatch):
     """make_train_step sets `train.ssd_sites` / `ssd_kept_sites` (the Mamba-2
     layers, all of whose chunk states the checkpoint keeps) and
-    `train.ssd_conv_fused_sites` (those whose xBC convolution the conv kernels
-    take: a prediction from the shapes and the lowering's platform) beside
-    `train.attn_sites` / `attn_fused_sites`: 9 / 9 / 9 and 1 / 1 for the cell's
-    model on a TPU, 9 / 9 / 0 and 1 / 0 on the CPU; 2 / 2 / 0 and 1 / 0 for the
+    `train.ssd_conv_fused_sites` / `ssd_fused_sites` (those whose xBC
+    convolution / in-chunk SSD work the kernels take: predictions from the
+    shapes and the lowering's platform) beside `train.attn_sites` /
+    `attn_fused_sites`: 9 / 9 / 9 / 9 and 1 / 1 for the cell's model on a
+    TPU, 9 / 9 / 0 / 0 and 1 / 0 on the CPU; 2 / 2 / 0 / 0 and 1 / 0 for the
     float32 toy anywhere; 0 for another arch."""
     import os
 
@@ -434,8 +632,8 @@ def test_the_train_step_reports_its_ssd_and_attention_sites(monkeypatch):
                        "apps", "granite_4_0_h_micro.yml")
     cfg = load_config(app)
     lr_fn = schedules.make_lr_schedule(cfg.schedule, 1, 10, 1)
-    names = ("train.ssd_sites", "train.ssd_kept_sites", "train.ssd_conv_fused_sites", "train.attn_sites",
-             "train.attn_fused_sites")
+    names = ("train.ssd_sites", "train.ssd_kept_sites", "train.ssd_conv_fused_sites", "train.ssd_fused_sites",
+             "train.attn_sites", "train.attn_fused_sites")
 
     def gauges(net, **kw):
         params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
@@ -444,9 +642,9 @@ def test_the_train_step_reports_its_ssd_and_attention_sites(monkeypatch):
 
     monkeypatch.setattr(ops, "ATTN_BLOCK", 512)  # the tile as shipped, which this file's fixture shrinks
     cell = get_model(cfg.model)
-    assert cell.ssd_conv_fitting_sites(jnp.bfloat16) == 9
-    assert gauges(cell, platform="tpu") == (9.0, 9.0, 9.0, 1.0, 1.0)
-    assert gauges(cell, platform="cpu") == (9.0, 9.0, 0.0, 1.0, 0.0)
-    assert gauges(model(), platform="tpu") == (2.0, 2.0, 0.0, 1.0, 0.0)
+    assert cell.ssd_conv_fitting_sites(jnp.bfloat16) == cell.ssd_fitting_sites(jnp.bfloat16) == 9
+    assert gauges(cell, platform="tpu") == (9.0, 9.0, 9.0, 9.0, 1.0, 1.0)
+    assert gauges(cell, platform="cpu") == (9.0, 9.0, 0.0, 0.0, 1.0, 0.0)
+    assert gauges(model(), platform="tpu") == (2.0, 2.0, 0.0, 0.0, 1.0, 0.0)
     glm = get_model(ModelConfig(arch="glm4_moe_lite", num_classes=VOCAB, lm=LM))
-    assert gauges(glm, platform="tpu")[:3] == (0.0, 0.0, 0.0)
+    assert gauges(glm, platform="tpu")[:4] == (0.0, 0.0, 0.0, 0.0)

@@ -276,9 +276,13 @@ def test_a_mamba_mixer_at_the_cells_shape_convolves_xbc_in_the_conv_kernels_unde
     xBC convolution (4,352 channels: lanes of 256, which divide them) is ONE
     `ssd_conv_fwd` custom call under `("ssd_conv", "fwd")` and one
     `ssd_conv_bwd` under `("ssd_conv", "bwd")`, each with the bias among its
-    operands; no KDA kernel; the chunk scan's two loops and no other; and the
-    declared temporaries are not above those of the same mixer with the plain
-    convolution."""
+    operands; the in-chunk SSD work is ONE `ssd_chunk_fwd` custom call under
+    `("ssd_core", "fwd")` and one `ssd_chunk_bwd` under `("ssd_core", "bwd")`,
+    and each chunk's own state contribution one `ssd_own_fwd` / `ssd_own_bwd`
+    pair under the same scope; no KDA kernel; the chunk scan's two loops and
+    no other; and the declared temporaries are not above those of the same
+    mixer with the plain convolution and the plain SSD (0.41 against 0.93 GiB
+    when written), whose lowering holds no SSD kernel."""
     from yet_another_mobilenet_series_tpu.ops import lm_mamba
 
     hidden, heads, width, state, seq = 2048, 64, 64, 128, 8192
@@ -300,12 +304,17 @@ def test_a_mamba_mixer_at_the_cells_shape_convolves_xbc_in_the_conv_kernels_unde
     kernels = sorted((n.split(".")[0], *scopes.scope_of(op), len(operands))
                      for n, (_, opcode, operands, op) in instructions.items()
                      if opcode == "custom-call" and n.startswith(("ssd_", "kda_")))
-    # operands: the filter, the bias, z (and its halo's block); the backward's the cotangent twice more
-    assert kernels == [("ssd_conv_bwd", "ssd_conv", "bwd", 7), ("ssd_conv_fwd", "ssd_conv", "fwd", 4)], kernels
+    # operands: the conv's filter, bias, z (and its halo's block), the backward's the cotangent twice more; the SSD's
+    # outputs C, B, G by columns and by rows, Delta, x, D, the start states, the backward's the cotangent; its chunks'
+    # own states G, Delta, x, B, the backward's their cotangent
+    assert kernels == [("ssd_chunk_bwd", "ssd_core", "bwd", 9), ("ssd_chunk_fwd", "ssd_core", "fwd", 8),
+                       ("ssd_conv_bwd", "ssd_conv", "bwd", 7), ("ssd_conv_fwd", "ssd_conv", "fwd", 4),
+                       ("ssd_own_bwd", "ssd_core", "bwd", 5), ("ssd_own_fwd", "ssd_core", "fwd", 4)], kernels
     assert len(re.findall(r"\bwhile\(", text)) == 2  # the chunk scan, forward and backward
     monkeypatch.setattr(lm_mamba, "conv_fuses", lambda *a: False)
+    monkeypatch.setattr(lm_mamba, "fuses", lambda *a: False)
     plain = compiled()
-    assert "ssd_conv_" not in plain.as_text()
+    assert not re.search(r"ssd_(conv|chunk|own)_", plain.as_text())
     assert fused.memory_analysis().temp_size_in_bytes <= plain.memory_analysis().temp_size_in_bytes
 
 

@@ -234,6 +234,16 @@ class TokenModel:
         channels = c.mamba_n_heads * c.mamba_d_head + 2 * c.mamba_d_state
         return self.ssd_sites if lm_mamba.conv_fuses(c.seq_len, channels, c.mamba_d_conv, compute_dtype) else 0
 
+    def ssd_fitting_sites(self, compute_dtype) -> int:
+        """The Mamba-2 layers whose in-chunk SSD work the kernels of
+        ops/lm_mamba_kernels.py take, by the predicate `lm_mamba.ssd_core`
+        dispatches on: what `train.ssd_fused_sites` reports where the step is
+        lowered for a TPU (train/steps.py)."""
+        c = self.lm
+        chunk = min(c.mamba_chunk_size, c.seq_len)
+        fits = lm_mamba.fuses(c.seq_len, chunk, c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state, compute_dtype)
+        return self.ssd_sites if fits else 0
+
     @property
     def expert_sites(self) -> int:
         """The expert layers (`train.moe_sites`): what `moe_bounded_sites` reads on a

@@ -265,6 +265,8 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     get_registry().gauge("train.ssd_sites").set(net.ssd_sites)
     get_registry().gauge("train.ssd_kept_sites").set(net.ssd_sites)
     get_registry().gauge("train.ssd_conv_fused_sites").set(net.ssd_conv_fitting_sites(compute_dtype) if on_tpu else 0)
+    # and those whose in-chunk SSD work goes through ops/lm_mamba_kernels.py's two kernels (`lm_mamba.fuses`)
+    get_registry().gauge("train.ssd_fused_sites").set(net.ssd_fitting_sites(compute_dtype) if on_tpu else 0)
     # the expert layers, each one `lax.cond` between the rows it holds and every assignment (ops/lm.py)
     get_registry().gauge("train.moe_sites").set(net.expert_sites)
     # a looped model runs its layers `loop_steps` times a step with the same weights: the sites above are LAYERS,
@@ -315,8 +317,9 @@ def make_train_step(
     (`train.attn_fused_sites`: the attention layers through the kernels of
     ops/lm_attention.py; `train.kda_fused_sites` / `train.kda_conv_fused_sites`:
     the KDA layers whose in-chunk work / short convolutions go through those of
-    ops/lm_kda.py; `train.ssd_conv_fused_sites`: the Mamba-2 layers whose xBC
-    convolution does). A step built without
+    ops/lm_kda.py; `train.ssd_conv_fused_sites` / `train.ssd_fused_sites`: the
+    Mamba-2 layers whose xBC convolution / in-chunk SSD work does, the latter
+    through ops/lm_mamba_kernels.py). A step built without
     it and then compiled ahead of time for another platform than the default
     backend's reports the default backend's count.
     """
