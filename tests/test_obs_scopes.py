@@ -35,6 +35,7 @@ TOKEN_SCOPES = {"embed", "norm", "rope", "attn_proj", "attn_core", "mlp", "moe_r
 KDA_SCOPES = {"kda_proj", "kda_conv", "kda_gate", "kda_core", "kda_norm"}
 LOOP_SCOPES = {"exit_gate"}  # a looped model's (`ouro`)
 SSD_SCOPES = {"ssd_proj", "ssd_conv", "ssd_gate", "ssd_core", "ssd_norm"}  # a Mamba-2 mixer's (`granitemoehybrid`)
+WINDOW_SCOPES = {"attn_window", "attn_gate"}  # a sliding window's core and a per-head output gate (`laguna`)
 
 
 def lowered_step(*overrides, chips: int = 1):
@@ -112,7 +113,7 @@ def test_every_scope_the_toy_step_contains_is_in_its_table(toy_text):
     # everything on the list except the collectives (one chip), AtomNAS (no
     # masks, no penalty), the guard (off) and the token models' scopes
     expect = (set(scopes.SCOPES) - {"syncbn", "grad_sync", "nas_mask", "nas_penalty", "guard"} - TOKEN_SCOPES - KDA_SCOPES
-              - LOOP_SCOPES - SSD_SCOPES)
+              - LOOP_SCOPES - SSD_SCOPES - WINDOW_SCOPES)
     assert expect <= seen, f"missing: {sorted(expect - seen)}"
     assert seen <= set(scopes.SCOPES) | {scopes.UNSCOPED}
 
@@ -424,6 +425,21 @@ def test_a_hybrid_models_scopes_resolve():
     assert {(name, phase) for name in ("ssd_core", "ssd_conv", "ssd_proj", "attn_core") for phase in ("fwd", "bwd")} <= seen
 
 
+def test_a_laguna_models_scopes_resolve():
+    """`laguna`'s step on the CPU: the window's core and the per-head gate are
+    in its table under both phases, beside the full layers' causal core, the
+    rotary tables and rotation, and the expert layer's scopes (the softmax
+    router among them); no latent, KDA, Mamba-2 or loop scope."""
+    from test_lm_laguna import LAGUNA
+
+    text = token_step("laguna", LAGUNA).compile().as_text()
+    seen = set(scopes.scope_table(text).values())
+    names = {scope for scope, _ in seen}
+    assert WINDOW_SCOPES | (TOKEN_SCOPES - {"mtp_merge"}) | {"loss", "optim", "residual"} <= names
+    assert not (KDA_SCOPES | SSD_SCOPES | LOOP_SCOPES | {"mtp_merge"}) & names
+    assert {(name, phase) for name in ("attn_window", "attn_gate", "attn_core", "rope") for phase in ("fwd", "bwd")} <= seen
+
+
 def test_kimi_linear_scopes_resolve():
     """`kimi_linear`'s step on the CPU: every KDA scope is in its table, the
     core's scan and its hand-written backward under both phases, beside the
@@ -477,9 +493,11 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # the hybrid arch added an `ssd_gate` site (the step's lowest chunk decay) and joined two `residual` sites into one
         # (`_branch`, which carries the residual multiplier), and `ops/lm_mamba.py`'s five scopes, all of them
         # new names in the step of a NEW arch (`granitemoehybrid`); no older step holds one or lost one: no bump
-        "models/lm.py": ["embed", "embed", "exit_gate", "exit_gate", "kda_gate", "lm_head", "loss", "loss", "moe_combine",
-                         "moe_router", "mtp_merge", "residual", "residual", "residual", "residual", "rope",
-                         "rope", "ssd_gate"],
+        # `laguna` added a `rope` site (its tables by layer type) and an `mlp` site (the shared expert's gate), in the
+        # step of a NEW arch; no older step holds one: no bump
+        "models/lm.py": ["embed", "embed", "exit_gate", "exit_gate", "kda_gate", "lm_head", "loss", "loss", "mlp",
+                         "moe_combine", "moe_router", "mtp_merge", "residual", "residual", "residual", "residual",
+                         "rope", "rope", "rope", "ssd_gate"],
         "models/specs.py": ["drop"],
         "ops/activations.py": ["act"],
         "ops/blocks.py": ["drop", "nas_mask", "nas_mask", "residual", "se"],
@@ -487,8 +505,12 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # PR 34 added a `moe_combine` and a `moe_dispatch` site twice over (the expert layer's two branches) in a step
         # whose program changed with them, so its cache key moved anyway, and no other step holds them: no bump
         # an `attn_core` site for the grouped key/value heads' repeat (granitemoehybrid's step alone)
-        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_core", "attn_proj", "attn_proj", "attn_proj",
-                      "attn_proj", "attn_proj", "mlp", "moe_combine",
+        # `laguna`'s sliding window added four `attn_window` sites (the core's, the key/value repeat's, the window's
+        # custom_vjp forward and backward) and its per-head gate an `attn_gate` site: new names, reached only by the step
+        # of a NEW arch (the others' lowered modules are pinned unchanged in tests/test_lm_laguna.py): no bump
+        "ops/lm.py": ["attn_core", "attn_core", "attn_core", "attn_core", "attn_core", "attn_gate", "attn_proj", "attn_proj",
+                      "attn_proj", "attn_proj", "attn_proj", "attn_window", "attn_window", "attn_window", "attn_window",
+                      "mlp", "moe_combine",
                       "moe_combine", "moe_combine", "moe_dispatch", "moe_dispatch", "moe_dispatch", "moe_experts", "moe_router", "norm",
                       "rope"],
         # PR 31 took one `bn_apply` site out with the BatchNorm custom VJP no app or cell selected: no name

@@ -175,6 +175,37 @@ def test_attention_is_two_mosaic_kernels_under_its_scope(attention_hlo):
     assert not re.search(r"\bwhile\(", attention_hlo)
 
 
+def test_a_sliding_layers_core_at_the_cells_shape_is_the_window_kernels_under_their_scope(one_chip):
+    """A `laguna` sliding layer's core at the cell's shape (1 x 8,192 tokens,
+    72 heads of 128, window 512, tiles of 512), value and three gradients,
+    compiled for the described chip: one forward and one backward custom call,
+    named `window_attention_fwd` / `_bwd` (not the causal pair) and under
+    `attn_window` with their phases; no loop is left. The forward's bounds
+    meet 2 key tiles a query block past the first (the diagonal and the edge
+    tile before it), where the causal kernel meets i + 1 (8.5 on average over
+    16 blocks)."""
+    from yet_another_mobilenet_series_tpu.ops import lm, lm_attention_kernels
+
+    shape = (1, 8192, 72, 128)
+    assert lm.lm_attention.fuses(shape[1], lm.ATTN_BLOCK, 128, 128, jnp.bfloat16)
+    reach, whole = lm_attention_kernels._window_bounds(512, lm.ATTN_BLOCK)
+    blocks = shape[1] // lm.ATTN_BLOCK
+    assert (reach, whole) == (1, 0) and sum(min(i, reach) + 1 for i in range(blocks)) == 2 * blocks - 1
+    assert sum(i + 1 for i in range(blocks)) / blocks == 8.5
+
+    def loss(q, k, v, ct):
+        return jnp.sum(lm.causal_attention(q, k, v, scale=128 ** -0.5, window=512).astype(jnp.float32) * ct)
+
+    operand = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        operand, operand, operand, jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)).compile().as_text()
+    instructions, _ = _entry_instructions(text)
+    kernels = {n: scopes.scope_of(op) for n, (_, opcode, _, op) in instructions.items() if opcode == "custom-call"}
+    assert sorted(kernels.values()) == [("attn_window", "bwd"), ("attn_window", "fwd")], kernels
+    assert sorted(n.split(".")[0] for n in kernels) == ["window_attention_bwd", "window_attention_fwd"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 and not re.search(r"\bwhile\(", text)
+
+
 def test_the_192_channel_site_takes_the_kernels_with_q_and_k_filled_to_256(one_chip):
     """kimi_linear's latent-attention layer at the cell's shape (1 x 16,384
     tokens, 32 heads of 128 + 64 / 128): `causal_attention` fills q and k with

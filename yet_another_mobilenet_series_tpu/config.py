@@ -117,14 +117,45 @@ class LinearAttnConfig:
 
 
 @dataclass(frozen=True)
+class RopeSpec:
+    """One layer type's rotary embedding, under the keys of a published
+    ``rope_parameters`` entry (``laguna``; ops/lm.py ``rope_tables_of``):
+    ``default`` rotates at ``rope_theta``; ``yarn`` (arXiv:2309.00071) blends
+    each frequency between itself and itself / ``factor`` by a ramp between
+    the rotation counts ``beta_fast`` and ``beta_slow`` over
+    ``original_max_position_embeddings`` positions, and scales cos and sin by
+    ``attention_factor``. The first ``partial_rotary_factor`` of a head's
+    channels are rotated, the rest pass through."""
+
+    rope_type: str = "default"  # default | yarn
+    rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class RopeParameters:
+    """``model.lm.rope_parameters``: a rotary embedding by layer type, as
+    ``laguna``'s ``config.json`` has it (the layer's type is its
+    ``layer_types`` entry)."""
+
+    full_attention: RopeSpec = field(default_factory=RopeSpec)
+    sliding_attention: RopeSpec = field(default_factory=RopeSpec)
+
+
+@dataclass(frozen=True)
 class LMConfig:
     """Shapes of a token model (token family: ``glm4_moe_lite``,
-    ``kimi_linear``, ``ouro``, ``granitemoehybrid``; models/lm.py), under the keys of the published
+    ``kimi_linear``, ``ouro``, ``granitemoehybrid``, ``laguna``; models/lm.py), under the keys of the published
     ``config.json``. The defaults are GLM-4.7-Flash's widths.
     ``model.num_classes`` is the number of vocabulary rows held here
     (embedding, head, token ids and the loss are over that slice).
 
-    A model WITH expert layers (``glm4_moe_lite``, ``kimi_linear``) is ONE
+    A model WITH expert layers (``glm4_moe_lite``, ``kimi_linear``, ``laguna``) is ONE
     SHARE of an expert-parallel deployment: the router keeps its published
     width ``n_routed_experts`` and its ``num_experts_per_tok``; this share
     holds ``n_routed_experts / expert_shares`` experts of every expert layer,
@@ -193,6 +224,17 @@ class LMConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     tie_word_embeddings: bool = False
+    # `laguna` alone reads the keys below (Laguna-S-2.1; models/lm.py). `layer_types` names each layer's attention,
+    # "full_attention" or "sliding_attention" (a key position k is visible to query position q where
+    # q - sliding_window < k <= q), with `num_attention_heads_per_layer` query heads over `num_key_value_heads` key/value
+    # heads of `head_dim` channels, the rotary embedding of its type and a per-head output gate (each head's output
+    # times sigmoid(x W_g) before `o`). Its expert layers route by a softmax over every expert (no router state), the
+    # routed weights times `routed_scaling_factor` (the published `moe_routed_scaling_factor`), beside ONE shared
+    # expert of `shared_expert_intermediate_size` times sigmoid(x . w_s)
+    num_attention_heads_per_layer: Sequence[int] = ()
+    sliding_window: int | None = None
+    rope_parameters: RopeParameters = field(default_factory=RopeParameters)
+    shared_expert_intermediate_size: int = 0
 
 
 @dataclass(frozen=True)
@@ -1192,6 +1234,8 @@ def _build(dc_type, data: Mapping[str, Any], path: str = ""):
 _SECTION_TYPES = {
     "ModelConfig": ModelConfig,
     "LinearAttnConfig": LinearAttnConfig,
+    "RopeSpec": RopeSpec,
+    "RopeParameters": RopeParameters,
     "LMConfig": LMConfig,
     "DataConfig": DataConfig,
     "OptimConfig": OptimConfig,

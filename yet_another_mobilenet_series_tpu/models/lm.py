@@ -21,6 +21,17 @@ distribution less `exit_entropy_weight` times that distribution's entropy
 inference (`early_exit_threshold`), a key/value cache a (loop step, layer),
 and training the gate alone against a frozen model.
 
+`laguna` (Laguna-S-2.1) mixes by grouped-query attention in every layer, of
+the kind its `layer_types` entry names: `full_attention` (causal, YaRN rotary
+embedding on the first half of each head's channels) or `sliding_attention`
+(a window of `sliding_window` positions, the plain rotary embedding on the
+whole head), each layer with its own `num_attention_heads_per_layer` query
+heads over `num_key_value_heads` key/value heads and each head's output times
+sigmoid(x W_g) before `o` (a per-head gate); its expert layers route by a
+softmax over every expert (`router_scoring`; no router state) beside ONE
+shared expert scaled by sigmoid(x . w_s). Its first `first_k_dense_replace`
+layers' MLPs are dense (the published `mlp_only_layers`).
+
 `granitemoehybrid` (Granite 4.0-H) is a HYBRID: each layer's mixer is named by
 `layer_types`, a Mamba-2 state-space mixer (ops/lm_mamba.py) or grouped-query
 attention without rotation (the published "nope": query head i
@@ -57,12 +68,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..config import LMConfig, ModelConfig
+from ..config import LMConfig, ModelConfig, RopeParameters
 from ..obs.scopes import scope
 from ..ops import lm as ops
 from ..ops import lm_attention, lm_kda, lm_mamba
 
-LM_ARCHS = ("glm4_moe_lite", "kimi_linear", "ouro", "granitemoehybrid")
+LM_ARCHS = ("glm4_moe_lite", "kimi_linear", "ouro", "granitemoehybrid", "laguna")
+# What `laguna` reads and the other archs do not: each key's value where it means nothing (`TokenModel.validate`
+# refuses any other on another arch)
+LAGUNA_KEYS = {"num_attention_heads_per_layer": (), "sliding_window": None, "rope_parameters": RopeParameters(),
+               "shared_expert_intermediate_size": 0}
+# ... and what the other archs read that `laguna` does not, at the values that say so
+NOT_LAGUNAS = {"num_nextn_predict_layers": 0, "mla_use_nope": False, "total_ut_steps": 1, "attention_multiplier": None,
+               "embedding_multiplier": 1.0, "residual_multiplier": 1.0, "logits_scaling": 1.0,
+               "tie_word_embeddings": False, "n_shared_experts": 1}
 # What a layer's checkpoint keeps, by name, beside its input: attention's output and row log-sum-exp, the
 # KDA scan's output and chunk-boundary states, the SSD scan's chunk-boundary states: what the cores' backwards
 # read, so no forward of theirs runs twice.
@@ -104,6 +123,28 @@ class TokenModel:
     @property
     def hybrid(self) -> bool:
         return self.arch == "granitemoehybrid"
+
+    @property
+    def laguna(self) -> bool:
+        return self.arch == "laguna"
+
+    @property
+    def router_scoring(self) -> str:
+        """How the expert layers score (ops/lm.py `route`): `softmax` for `laguna`, `sigmoid_bias` for the others."""
+        return "softmax" if self.laguna else "sigmoid_bias"
+
+    def attention_kind(self, block: str) -> str:
+        """A `laguna` layer's `layer_types` entry: `full_attention` or `sliding_attention`."""
+        return self.lm.layer_types[int(block.split("_")[1])]
+
+    def heads_of(self, block: str) -> int:
+        """Query heads of a block's attention: its `num_attention_heads_per_layer` entry where the list is given."""
+        per_layer = self.lm.num_attention_heads_per_layer
+        return per_layer[int(block.split("_")[1])] if per_layer else self.lm.num_attention_heads
+
+    def window_of(self, block: str) -> int | None:
+        """The sliding window of a `laguna` block's attention, None for a full (causal) one and every other arch's."""
+        return self.lm.sliding_window if self.laguna and self.attention_kind(block) == "sliding_attention" else None
 
     @property
     def loop_steps(self) -> int:
@@ -163,8 +204,46 @@ class TokenModel:
                 raise ValueError("total_ut_steps must be at least 1")
         if self.hybrid:
             self._validate_hybrid()
+        elif self.laguna:
+            self._validate_laguna()
         elif c.layer_types:
-            raise ValueError(f"arch {self.arch} reads no layer_types (granitemoehybrid's)")
+            raise ValueError(f"arch {self.arch} reads no layer_types (granitemoehybrid's and laguna's)")
+        if not self.laguna:
+            theirs = sorted(k for k, none in LAGUNA_KEYS.items() if getattr(c, k) != none)
+            if theirs:
+                raise ValueError(f"arch {self.arch} reads none of {theirs} (laguna's)")
+
+    def _validate_laguna(self) -> None:
+        c = self.lm
+        n = c.num_hidden_layers
+        if len(c.layer_types) != n or set(c.layer_types) - {"full_attention", "sliding_attention"}:
+            raise ValueError(f"layer_types must name each of the {n} layers 'full_attention' or 'sliding_attention', "
+                             f"not {list(c.layer_types)}")
+        kv = c.num_key_value_heads
+        heads = c.num_attention_heads_per_layer
+        if len(heads) != n or not kv or not c.head_dim or any(h % kv for h in heads):
+            raise ValueError(f"num_attention_heads_per_layer must give each of the {n} layers a head count that "
+                             f"num_key_value_heads ({kv}) divides, with a head_dim; not {list(heads)}")
+        if "sliding_attention" in c.layer_types and not (c.sliding_window or 0) >= 1:
+            raise ValueError("a sliding_attention layer needs a sliding_window of at least one position")
+        for kind in set(c.layer_types):
+            spec = getattr(c.rope_parameters, kind)
+            rotated = c.head_dim * spec.partial_rotary_factor
+            if rotated != int(rotated) or int(rotated) % 2 or not 0 < rotated <= c.head_dim:
+                raise ValueError(f"rope_parameters.{kind}: partial_rotary_factor {spec.partial_rotary_factor} must "
+                                 f"rotate an even number of head_dim's {c.head_dim} channels")
+            if spec.rope_type not in ("default", "yarn") or (
+                    spec.rope_type == "yarn" and (spec.factor <= 0 or spec.original_max_position_embeddings < 1)):
+                raise ValueError(f"rope_parameters.{kind}: rope_type default, or yarn with a factor and "
+                                 f"original_max_position_embeddings, not {spec}")
+        if c.first_k_dense_replace < n and not c.shared_expert_intermediate_size:
+            raise ValueError("arch laguna's expert layers have a shared expert of shared_expert_intermediate_size")
+        if c.linear_attn_config.kda_layers or c.linear_attn_config.full_attn_layers:
+            raise ValueError("arch laguna has no KDA layer: linear_attn_config empty")
+        others = sorted(k for k, none in NOT_LAGUNAS.items() if getattr(c, k) != none)
+        if others:
+            raise ValueError(f"arch laguna reads none of {others}; they must say so: "
+                             f"{ {k: NOT_LAGUNAS[k] for k in others} }")
 
     def _validate_hybrid(self) -> None:
         c = self.lm
@@ -192,12 +271,25 @@ class TokenModel:
         c = self.lm
         sites = len(self.blocks_mixing_by("attn"))
         block = min(ops.ATTN_BLOCK, c.seq_len)
-        plain = self.looped or self.hybrid
+        plain = self.looped or self.hybrid or self.laguna
         qk_dim, v_dim = (c.head_dim, c.head_dim) if plain else (c.qk_nope_head_dim + c.qk_rope_head_dim, c.v_head_dim)
         # as `ops.causal_attention` hands q, k and v on
         fits = lm_attention.fuses(c.seq_len, block, *lm_attention.fitting_dims(c.seq_len, block, qk_dim, v_dim,
                                                                                 compute_dtype), compute_dtype)
         return sites, sites if fits else 0
+
+    @property
+    def window_sites(self) -> int:
+        """The attention layers within a sliding window (`train.attn_window_sites`): among `attention_sites`'."""
+        return sum(self.window_of(block) is not None for block in self.blocks_mixing_by("attn"))
+
+    def window_fitting_sites(self, compute_dtype) -> int:
+        """The windowed layers whose shapes the window's kernels of
+        ops/lm_attention_kernels.py take (the causal kernels' predicate, which
+        `ops.causal_attention` dispatches on for both): what
+        `train.attn_window_fused_sites` reports where the step is lowered for a
+        TPU (train/steps.py)."""
+        return self.window_sites if self.attention_sites(compute_dtype)[1] else 0
 
     @property
     def kda_sites(self) -> int:
@@ -251,11 +343,12 @@ class TokenModel:
         return sum(not self.is_dense(block) for block in self.block_names)
 
     def expert_capacity_rows(self, sequences: int) -> int:
-        """`ops.capacity_rows` of every expert layer for a batch of `sequences` on one replica."""
+        """`ops.site_capacity` of every expert layer for a batch of `sequences` on one replica."""
         c = self.lm
         if not self.expert_sites:
             return 0
-        return ops.capacity_rows(sequences * c.seq_len * c.num_experts_per_tok, self.experts_held, c.n_routed_experts)
+        return ops.site_capacity(sequences * c.seq_len * c.num_experts_per_tok, self.experts_held, c.n_routed_experts,
+                                 self.router_scoring)
 
     def param_count(self) -> int:
         shapes = jax.eval_shape(lambda: self.init(jax.random.PRNGKey(0))[0])
@@ -275,6 +368,8 @@ class TokenModel:
                     "down": w(name + "d", *lead, width, h)}
 
         p = {"attn_norm": jnp.ones((h,), jnp.float32), "mlp_norm": jnp.ones((h,), jnp.float32)}
+        if self.laguna:
+            return self._init_laguna(block, p, w, mlp)
         if self.hybrid:  # a Mamba-2 or a grouped-query attention mixer, and a dense MLP
             kv = (c.num_key_value_heads or heads) * c.head_dim
             p["mlp"] = mlp("mlp", c.intermediate_size)
@@ -309,6 +404,24 @@ class TokenModel:
         else:
             p["router"] = w("rout", h, c.n_routed_experts)
             p["shared"] = mlp("shar", c.moe_intermediate_size * c.n_shared_experts)
+            p["experts"] = mlp("exp", c.moe_intermediate_size, self.experts_held)
+        return p
+
+    def _init_laguna(self, block: str, p: dict, w, mlp) -> dict:
+        """A `laguna` block: grouped-query attention of the block's own head
+        count, with its per-head output gate `gate` (h, heads); then a dense
+        MLP, or the router over every expert, the held experts and the shared
+        expert with its gate `shared.sigmoid_gate` (h,): every tensor
+        N(0, init_std)."""
+        c = self.lm
+        h, heads, kv = c.hidden_size, self.heads_of(block), c.num_key_value_heads * c.head_dim
+        p["attn"] = {"q": w("q", h, heads * c.head_dim), "k": w("k", h, kv), "v": w("v", h, kv),
+                     "o": w("o", heads * c.head_dim, h), "gate": w("attn_gate", h, heads)}
+        if self.is_dense(block):
+            p["mlp"] = mlp("mlp", c.intermediate_size)
+        else:
+            p["router"] = w("rout", h, c.n_routed_experts)
+            p["shared"] = {**mlp("shar", c.shared_expert_intermediate_size), "sigmoid_gate": w("shar_gate", h)}
             p["experts"] = mlp("exp", c.moe_intermediate_size, self.experts_held)
         return p
 
@@ -384,7 +497,7 @@ class TokenModel:
                 h_norm=jnp.ones((h,), jnp.float32), e_norm=jnp.ones((h,), jnp.float32),
                 final_norm=jnp.ones((h,), jnp.float32))
         state = {block: {"router_bias": jnp.zeros((c.n_routed_experts,), jnp.float32)}
-                 for block in self.block_names if not self.is_dense(block)}
+                 for block in self.block_names if not self.is_dense(block) and self.router_scoring == "sigmoid_bias"}
         return params, state
 
     # ---- forward ----------------------------------------------------------
@@ -395,13 +508,17 @@ class TokenModel:
         with scope("residual"):
             return x + (out if m == 1.0 else out * m)
 
-    def _mixed(self, p: dict, x, cos, sin):
+    def _mixed(self, p: dict, x, cos, sin, window=None):
         """A block's first half, x + Mixer(norm(x)): (x, its KDA or Mamba-2
-        mixer's most negative in-chunk log decay or None)."""
+        mixer's most negative in-chunk log decay or None). A `laguna` block's
+        mixer reads the tables of its layer type, and its `window`."""
         c = self.lm
         normed = ops.rms_norm(x, p["attn_norm"], c.rms_norm_eps)
         lowest = None
-        if "mamba" in p:
+        if self.laguna:
+            a = ops.mha_attention(p["attn"], normed, cos, sin, heads=p["attn"]["q"].shape[1] // c.head_dim,
+                                  head_dim=c.head_dim, kv_heads=c.num_key_value_heads, window=window)
+        elif "mamba" in p:
             a, lowest = lm_mamba.mamba_mixer(p["mamba"], normed, heads=c.mamba_n_heads, head_dim=c.mamba_d_head,
                                              state=c.mamba_d_state, chunk=c.mamba_chunk_size, eps=c.rms_norm_eps)
         elif self.hybrid:
@@ -426,8 +543,12 @@ class TokenModel:
             return self._branch(x, ops.gated_mlp(p["mlp"], y)), None
         routed, load, counters, ids = ops.expert_layer(
             p, bias, y, top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
-            held=self.experts_held, share_index=c.expert_share_index)
+            held=self.experts_held, share_index=c.expert_share_index, scoring=self.router_scoring)
         shared = ops.gated_mlp(p["shared"], y)
+        if "sigmoid_gate" in p["shared"]:  # the shared expert times sigmoid(y . w_s), a scalar a token
+            with scope("mlp"):
+                gate = jnp.dot(y, p["shared"]["sigmoid_gate"].astype(y.dtype), preferred_element_type=jnp.float32)
+                shared = shared * jax.nn.sigmoid(gate)[..., None].astype(shared.dtype)
         with scope("residual"):
             return x + shared + routed, (load, counters, ids)
 
@@ -553,7 +674,12 @@ class TokenModel:
         if self.looped:  # the LAST loop step's head: every step runs (`early_exit_threshold` 1)
             return {"main": self._looped(params, tokens, compute_dtype)[0][-1]}, {}, {}, {}
         cos = sin = None
-        if not (c.mla_use_nope or self.hybrid):  # a hybrid's attention rotates nothing
+        tables = {}  # `laguna`'s: (cos, sin) by layer type, each made once a step
+        if self.laguna:
+            with scope("rope"):
+                tables = {kind: ops.rope_tables_of(seq, c.head_dim, getattr(c.rope_parameters, kind))
+                          for kind in sorted(set(c.layer_types))}
+        elif not (c.mla_use_nope or self.hybrid):  # a hybrid's attention rotates nothing
             with scope("rope"):
                 cos, sin = ops.rope_tables(seq, c.qk_rope_head_dim, c.rope_theta)
         with scope("embed"):
@@ -572,8 +698,11 @@ class TokenModel:
         kept = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
         def run(block, x, p, bias):
+            turn = tables[self.attention_kind(block)] if self.laguna else (cos, sin)
+            window = self.window_of(block)
+
             def fn(x_, p_, b_):
-                x_, lowest = self._mixed(p_, x_, cos, sin)
+                x_, lowest = self._mixed(p_, x_, *turn, window)
                 return (*self._fed(block, p_, b_, x_), lowest)
 
             return jax.checkpoint(fn, policy=kept)(x, p, bias)
@@ -587,10 +716,11 @@ class TokenModel:
                 lowest_by_block.append(lowest)
             if routed is not None:
                 load, counters, selected[block] = routed
-                with scope("moe_router"):
-                    if axis_name is not None:
-                        load = lax.psum(load, axis_name)
-                    new_state[block] = {"router_bias": bias + c.router_bias_rate * jnp.sign(jnp.mean(load) - load)}
+                if bias is not None:  # a softmax router (`laguna`) holds no state
+                    with scope("moe_router"):
+                        if axis_name is not None:
+                            load = lax.psum(load, axis_name)
+                        new_state[block] = {"router_bias": bias + c.router_bias_rate * jnp.sign(jnp.mean(load) - load)}
                 per_block.append(counters)
             return x
 

@@ -1,7 +1,7 @@
 """The plain reference of the token family (`glm4_moe_lite`: GLM-4.7-Flash;
 `kimi_linear`: Kimi-Linear-48B-A3B; `ouro`: Ouro-2.6B, the `ouro_*`
-functions; `granitemoehybrid`: Granite 4.0-H, the `granite_*` functions at the
-end): forward, loss and, through `jax.grad`, gradients, in
+functions; `granitemoehybrid`: Granite 4.0-H, the `granite_*` functions; `laguna`:
+Laguna-S-2.1, the `laguna_*` functions at the end): forward, loss and, through `jax.grad`, gradients, in
 straightforward `jax.numpy`, float32, under
 `jax.default_matmul_precision("highest")`.
 
@@ -33,6 +33,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 DIM_KEYS = ("hidden_size", "num_hidden_layers", "first_k_dense_replace", "num_attention_heads", "kv_lora_rank",
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "n_routed_experts", "num_experts_per_tok",
@@ -398,3 +399,142 @@ def granite_loss_and_aux(params, tokens, d):
 def granite_loss_and_grads(params, tokens, d):
     """((loss, aux), gradients of the loss by parameter)."""
     return jax.value_and_grad(granite_loss_and_aux, has_aux=True)(params, tokens, d)
+
+
+# ---- `laguna`: sliding-window and full grouped-query attention, per-head gates, softmax-routed experts ----------
+# (Laguna-S-2.1)
+
+LAGUNA_DIM_KEYS = ("hidden_size", "num_hidden_layers", "first_k_dense_replace", "num_key_value_heads", "head_dim",
+                   "rms_norm_eps", "layer_types", "num_attention_heads_per_layer", "sliding_window", "rope_parameters",
+                   "n_routed_experts", "num_experts_per_tok", "routed_scaling_factor", "expert_shares",
+                   "expert_share_index")
+
+
+def laguna_dims_of(lm_config) -> dict:
+    return {k: getattr(lm_config, k) for k in LAGUNA_DIM_KEYS}
+
+
+def laguna_inv_freq(spec, head_dim):
+    """The rotated channels' frequencies of a `rope_parameters` entry, in
+    float64 then float32: `default`, theta^(-2i/r) over the r rotated
+    channels; `yarn` (arXiv:2309.00071 section 3.2, "NTK-by-parts"), each
+    frequency theta^(-2i/r) kept where its wavelength is short (it turns more
+    than beta_fast times in the original context), divided by `factor` where
+    it is long (fewer than beta_slow turns), and blended linearly between the
+    two in the channels between, the channel bounds rounded outwards to whole
+    channels (floor below, ceil above)."""
+    r = int(head_dim * spec.partial_rotary_factor)
+    i = np.arange(r // 2, dtype=np.float64)
+    freq = spec.rope_theta ** (-2.0 * i / r)
+    if spec.rope_type == "default":
+        return freq.astype(np.float32)
+    turns = lambda n: r * math.log(spec.original_max_position_embeddings / (2 * math.pi * n)) / (2 * math.log(spec.rope_theta))  # noqa: E731
+    lo, hi = max(math.floor(turns(spec.beta_fast)), 0), min(math.ceil(turns(spec.beta_slow)), r - 1)
+    ramp = np.clip((i - lo) / max(hi - lo, 1e-3), 0.0, 1.0)  # 0: extrapolated (kept), 1: interpolated (/ factor)
+    return (freq * (1.0 - ramp) + freq / spec.factor * ramp).astype(np.float32)
+
+
+def laguna_rope(x, spec):
+    """x (S, heads, d): the first r = partial_rotary_factor x d channels
+    turned, channel i with i + r/2, position s by s x frequency i; cos and
+    sin times `attention_factor` where the type is `yarn`; the rest as they
+    are. ASSUMED: the rotated channels are the FIRST r of a head."""
+    seq, _, d = x.shape
+    freq = jnp.asarray(laguna_inv_freq(spec, d))
+    r = 2 * freq.shape[0]
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None, None] * freq[None, None, :]
+    scale = spec.attention_factor if spec.rope_type == "yarn" else 1.0
+    cos, sin = jnp.cos(angle) * scale, jnp.sin(angle) * scale
+    a, b = x[..., :r // 2], x[..., r // 2:r]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, x[..., r:]], -1)
+
+
+def laguna_attention(p, x, d, kind, heads):
+    """One sequence x (S, h) through a `laguna` attention layer: q, k, v three
+    projections (no bias, no q/k norm: ASSUMED), query head i reading
+    key/value head i // (heads / kv heads), the layer type's rotation of q
+    and k, scores q . k / sqrt(head_dim), a dense S x S mask (causal, and
+    within the window where `kind` is `sliding_attention`: key k visible to
+    query q where q - window < k <= q), each head's output times
+    sigmoid(x W_g), then o."""
+    seq = x.shape[0]
+    kv, width = d["num_key_value_heads"], d["head_dim"]
+    spec = getattr(d["rope_parameters"], kind)
+    q = laguna_rope((x @ p["q"]).reshape(seq, heads, width), spec)
+    k = laguna_rope((x @ p["k"]).reshape(seq, kv, width), spec)
+    v = (x @ p["v"]).reshape(seq, kv, width)
+    reads = jnp.arange(heads) // (heads // kv)
+    scores = jnp.einsum("qhd,khd->hqk", q, k[:, reads]) / math.sqrt(width)
+    at, of = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    mask = of <= at
+    if kind == "sliding_attention":
+        mask = mask & (of > at - d["sliding_window"])
+    probs = jax.nn.softmax(jnp.where(mask[None], scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("hqk,khd->qhd", probs, v[:, reads])
+    out = out * jax.nn.sigmoid(x @ p["gate"])[:, :, None]
+    return out.reshape(seq, heads * width) @ p["o"]
+
+
+def laguna_experts(p, x, d):
+    """(routed output of this share (S, h), assignments per expert (E,)):
+    softmax over ALL experts in float32, the top-k of those scores, their
+    weights the selected scores divided by their sum (`norm_topk_prob`) times
+    `routed_scaling_factor` (the published `moe_routed_scaling_factor`); no
+    selection bias. ASSUMED (the published config names the Qwen2-MoE
+    family's keys and no scoring function)."""
+    n, k = d["n_routed_experts"], d["num_experts_per_tok"]
+    held = n // d["expert_shares"]
+    first = d["expert_share_index"] * held
+    scores = jax.nn.softmax(x @ p["router"], axis=-1)
+    _, chosen = jax.lax.top_k(scores, k)
+    chosen = jax.nn.one_hot(chosen, n).sum(axis=1)  # (S, E) 0/1
+    weight = chosen * scores
+    weight = weight / weight.sum(axis=-1, keepdims=True) * d["routed_scaling_factor"]
+    out = jnp.zeros_like(x)
+    for j in range(held):
+        e = p["experts"]
+        out = out + weight[:, first + j, None] * gated_mlp(e["gate"][j], e["up"][j], e["down"][j], x)
+    return out, chosen.sum(axis=0)
+
+
+def laguna_sequence(params, ids, d):
+    """One row of S + 2 ids (the last is not read) -> (logits (S, V), load
+    by expert block): each layer x + Attn(N(x)) with the layer's own type
+    and head count, then x + MLP(N(x)) where the layer is dense, else x +
+    sigmoid(N(x) . w_s) Shared(N(x)) + Routed(N(x)); logits N(x) W_head."""
+    seq = ids.shape[0] - 2
+    eps = d["rms_norm_eps"]
+    x = params["embed"][ids[:seq]]
+    loads = {}
+    for i in range(d["num_hidden_layers"]):
+        name = f"layer_{i}"
+        p = params[name]
+        x = x + laguna_attention(p["attn"], rms_norm(x, p["attn_norm"], eps), d, d["layer_types"][i],
+                                 d["num_attention_heads_per_layer"][i])
+        y = rms_norm(x, p["mlp_norm"], eps)
+        if i < d["first_k_dense_replace"]:
+            x = x + gated_mlp(p["mlp"]["gate"], p["mlp"]["up"], p["mlp"]["down"], y)
+            continue
+        s = p["shared"]
+        routed, loads[name] = laguna_experts(p, y, d)
+        x = x + jax.nn.sigmoid(y @ s["sigmoid_gate"])[:, None] * gated_mlp(s["gate"], s["up"], s["down"], y) + routed
+    return rms_norm(x, params["final_norm"], eps) @ params["head"], loads
+
+
+def laguna_loss_and_aux(params, tokens, d):
+    """tokens (B, S + 2) -> (loss, {"ce", "logits", "loads"}): the mean
+    cross-entropy of the next token over all B * S tokens."""
+    with jax.default_matmul_precision("highest"):
+        seq = tokens.shape[1] - 2
+        ce, logits, loads = 0.0, [], {}
+        for ids in tokens:
+            z, load = laguna_sequence(params, ids, d)
+            ce = ce + cross_entropy(z, ids[1:seq + 1]) / (tokens.shape[0] * seq)
+            logits.append(z)
+            loads = {k: loads.get(k, 0.0) + v for k, v in load.items()}
+        return ce, {"ce": ce, "logits": jnp.stack(logits), "loads": loads}
+
+
+def laguna_loss_and_grads(params, tokens, d):
+    """((loss, aux), gradients of the loss by parameter)."""
+    return jax.value_and_grad(laguna_loss_and_aux, has_aux=True)(params, tokens, d)

@@ -14,7 +14,7 @@ from typing import Any
 
 import dataclasses
 
-from ..config import LinearAttnConfig, LMConfig
+from ..config import LinearAttnConfig, LMConfig, RopeParameters, RopeSpec
 from ..ops.blocks import ConvBNAct, InvertedResidual
 from ..ops.layers import Dense
 from .lm import TokenModel
@@ -100,8 +100,11 @@ def network_from_dict(d: dict[str, Any]) -> Network | TokenModel:
         lm = dict(d["lm"])
         pattern = lm.pop("linear_attn_config", {})  # absent in a spec written before the token family had one
         pattern = {k: tuple(v) if isinstance(v, list) else v for k, v in pattern.items()}
+        rope = {kind: RopeSpec(**spec) for kind, spec in lm.pop("rope_parameters", {}).items()}  # absent before laguna
+        lm = {k: tuple(v) if isinstance(v, list) else v for k, v in lm.items()}
         return TokenModel(arch=d["token_model"], vocab=d["vocab"],
-                          lm=LMConfig(**lm, linear_attn_config=LinearAttnConfig(**pattern)))
+                          lm=LMConfig(**lm, linear_attn_config=LinearAttnConfig(**pattern),
+                                      rope_parameters=RopeParameters(**rope)))
 
     def _blk(bd):
         bd = dict(bd)

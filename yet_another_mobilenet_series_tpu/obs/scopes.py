@@ -66,6 +66,8 @@ SCOPES = (
     "rope",         # rotary tables and the rotation of q_rope and the shared k_rope (`ouro`: of all of q and k)
     "attn_proj",    # MLA's matmuls: q_a, q_b, kv_a, kv_b, o; plain multi-head attention's: q, k, v, o
     "attn_core",    # scores, causal mask, float32 softmax, values: a query block at a time
+    "attn_window",  # the same within a sliding window (`laguna`'s sliding layers): the tiles the window reaches
+    "attn_gate",    # a per-head output gate (`laguna`): sigmoid(x W_g) and each head's output times it
     "mlp",          # SiLU-gated MLP: the dense layer's and every shared expert's
     "moe_router",   # gate matmul, sigmoid, top-k of scores + bias, weights, counts, the bias update
     "moe_dispatch", # sort of the assignments, held experts first, and the gather of their rows

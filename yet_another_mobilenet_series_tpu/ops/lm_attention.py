@@ -84,19 +84,22 @@ def heads_lead(x, heads: int):
     return jnp.swapaxes(x.reshape(b, heads, features // heads, seq), 2, 3)
 
 
-def attention_fwd(q, k, v, scale: float, block: int, interpret: bool = False):
+def attention_fwd(q, k, v, scale: float, block: int, interpret: bool = False, window: int | None = None):
     """q, k (B, H, S, D), v (B, H, S, Dv) -> (out (B, H, S, Dv), lse (B, H, S)
-    float32): the output, and what the backward keeps beside it."""
+    float32): the output, and what the backward keeps beside it. A `window`:
+    within it (ops/lm.py `causal_attention`), the window's kernel."""
     from . import lm_attention_kernels as kernels  # Pallas comes in HERE and nowhere earlier (module docstring)
 
     b, h, seq, _ = q.shape
-    out, lse = kernels.fwd_call(features_lead(q), features_lead(k), features_lead(v), h, scale, block, interpret)
+    out, lse = kernels.fwd_call(features_lead(q), features_lead(k), features_lead(v), h, scale, block, interpret,
+                                window)
     return heads_lead(out, h), lse.reshape(b, h, seq)
 
 
-def attention_bwd(q, k, v, out, lse, g, scale: float, block: int, interpret: bool = False):
+def attention_bwd(q, k, v, out, lse, g, scale: float, block: int, interpret: bool = False, window: int | None = None):
     """(dq, dk, dv) in the operands' shapes and dtype, from what the forward
-    kept and the output's cotangent g (B, H, S, Dv)."""
+    kept and the output's cotangent g (B, H, S, Dv); within a `window`, the
+    window's kernel."""
     from . import lm_attention_kernels as kernels  # as in attention_fwd
 
     b, h, seq, _ = q.shape
@@ -104,5 +107,5 @@ def attention_bwd(q, k, v, out, lse, g, scale: float, block: int, interpret: boo
     inner = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     stats = (b, h, seq // block, 1, block)
     grads = kernels.bwd_call(features_lead(q), features_lead(k), features_lead(v), features_lead(g), lse.reshape(stats),
-                             inner.reshape(stats), h, scale, block, interpret)
+                             inner.reshape(stats), h, scale, block, interpret, window)
     return tuple(heads_lead(x, h) for x in grads)

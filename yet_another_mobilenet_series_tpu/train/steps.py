@@ -253,6 +253,9 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     # attention's output and row log-sum-exp, a KDA scan's output and states (models/lm.py `forward`): all of
     # them, on every platform
     get_registry().gauge("train.attn_kept_sites").set(sites)
+    # of those, the layers within a sliding window, and those whose windowed core goes through the window's kernels
+    get_registry().gauge("train.attn_window_sites").set(net.window_sites)
+    get_registry().gauge("train.attn_window_fused_sites").set(net.window_fitting_sites(compute_dtype) if on_tpu else 0)
     get_registry().gauge("train.kda_sites").set(net.kda_sites)
     get_registry().gauge("train.kda_kept_sites").set(net.kda_sites)
     # the KDA layers whose in-chunk work this step lowers through ops/lm_kda_kernels.py's two fused kernels: the same
