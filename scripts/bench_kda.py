@@ -6,6 +6,7 @@ time moves with its three module constants. What chose them (PERF.md, PR 33).
 
     python scripts/bench_kda.py [--iters 5] [--chunks 64,128] [--subs 16] [--groups 8] [--core-only]
     python scripts/bench_kda.py --conv-only [--conv-tiles 512x512,1024x512]
+    python scripts/bench_kda.py --scan-only [--scan-heads 1,2,4,8] [--scan-chunks 8,16]
 
 Beside the plain pieces it times the two fused kernels of ops/lm_kda_kernels.py
 (PR 36) wherever `lm_kda.fuses` takes the shape: the forward kernel, the
@@ -13,7 +14,13 @@ kernel pair, and `kda_core` through each form (`--core-only` stops there).
 And the short convolution with its SiLU and, for q and k, the L2 norm of a
 head (`lm_kda.conv_and_norm`), plain and as the pair of conv kernels, at each
 tile of `--conv-tiles` (rows x lanes; `--conv-only` times nothing else), with
-the kernels' largest deviation from the plain form on the chip.
+the kernels' largest deviation from the plain form on the chip. And the scan
+over the chunks alone (`--scan-only`): the plain `lax` scan, the same with
+its output laid out as (B, S, H x D) as `kda_core` needs it, and the scan
+kernels (`lm_kda._fused_scan`) at each pair of heads and chunks a program
+instance carries, forward and forward + backward, with the kernels' largest
+deviation from the plain scan on the chip; then each with the gated output
+norm that reads it (over heads, and `lm_kda._banded_gated_norm` band by band).
 
 Measures on a TPU or exits 3. Prints one JSON line a piece: ms a call (host
 clock around `iters` calls ending in a sync).
@@ -66,6 +73,9 @@ def main() -> int:
     ap.add_argument("--core-only", action="store_true", help="kda_core and its operands through both forms, nothing else")
     ap.add_argument("--conv-only", action="store_true", help="the short convolution and L2 norm through both forms, nothing else")
     ap.add_argument("--conv-tiles", default="", help="rows x lanes of the conv kernels' tile, comma-separated (default: the module's)")
+    ap.add_argument("--scan-only", action="store_true", help="the scan over the chunks through both forms, nothing else")
+    ap.add_argument("--scan-heads", default="", help="heads a scan kernel's program instance carries, comma-separated (default: the module's)")
+    ap.add_argument("--scan-chunks", default="", help="chunks a program instance walks, comma-separated (default: the module's)")
     args = ap.parse_args()
     if jax.devices()[0].platform != "tpu":
         print(f"bench_kda: no TPU (platform {jax.devices()[0].platform!r}): this script measures on the chip", file=sys.stderr)
@@ -81,6 +91,8 @@ def main() -> int:
     it = args.iters
     if args.conv_only:
         return conv(args, ks, it)
+    if args.scan_only:
+        return scan(args, core_args, ks, it)
 
     for chunk in map(int, args.chunks.split(",")):
         for sub in map(int, args.subs.split(",")):
@@ -229,6 +241,65 @@ def conv(args, ks, it) -> int:
                                                        (z, w, ct), it), **tag)
             print(json.dumps({"piece": f"{name}, conv kernels against plain", **tag,
                               "deviation": deviation((jax.jit(fused)(z, w), *grads(fused)(z, w, ct)), want)}), flush=True)
+    return 0
+
+
+def scan(args, core_args, ks, it) -> int:
+    """One layer's scan over its 256 chunks (32 heads of 128), from the in-chunk kernels' six operands: the plain `lax`
+    scan, the same with `kda_core`'s layout change of its output, and the scan kernels at each (heads, chunks) cut."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    operands = jax.jit(lambda *a: lm_kda.operands_fwd(*a)[0])(*core_args)
+    ct = jax.random.normal(ks[7], (B, S, H * D), jnp.bfloat16)
+    by_chunk = jnp.moveaxis(ct.reshape(B, S // lm_kda.KDA_CHUNK, lm_kda.KDA_CHUNK, H, D), (1, 3), (0, 2))
+    # the cotangent an argument, not a constant the executable would carry
+    grads = lambda fn: jax.jit(jax.grad(lambda *a: total(fn(*a[:6]) * a[6]), argnums=tuple(range(6))))  # noqa: E731
+    deviation = lambda got, want: max(float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))  # noqa: E731
+                                            / jnp.max(jnp.abs(b.astype(jnp.float32)))) for a, b in zip(got, want))
+    plain = lambda *a: lm_kda._by_position(lm_kda._state_scan(*a))  # noqa: E731
+    say("scan, plain lax, fwd", timed(jax.jit(lm_kda._state_scan), operands, it), steps=S // lm_kda.KDA_CHUNK)
+    say("scan, plain lax, fwd+bwd", timed(grads(lm_kda._state_scan), (*operands, by_chunk), it))
+    say("scan, plain lax + layout to (B, S, H x D), fwd", timed(jax.jit(plain), operands, it))
+    say("scan, plain lax + layout to (B, S, H x D), fwd+bwd", timed(grads(plain), (*operands, ct), it))
+    want = (jax.jit(plain)(*operands), *grads(plain)(*operands, ct))
+    heads = [int(h) for h in args.scan_heads.split(",") if h] or [kernels.SCAN_HEADS]
+    chunks = [int(c) for c in args.scan_chunks.split(",") if c] or [kernels.CHUNKS_AT_ONCE]
+    for at_once in chunks:
+        for group in heads:
+            kernels.SCAN_HEADS, kernels.CHUNKS_AT_ONCE = group, at_once
+            jax.clear_caches()  # `lm_kda.scan_fwd` / `scan_bwd` are jitted: a trace of another cut would be reused
+            fused = lambda *a: lm_kda._fused_scan(*a)  # noqa: E731 - a new function a cut
+            tag = {"heads_at_once": group, "chunks_at_once": at_once}
+            try:
+                say("scan, kernels, fwd", timed(jax.jit(fused), operands, it), **tag)
+                say("scan, kernels, fwd+bwd", timed(grads(fused), (*operands, ct), it), **tag)
+                print(json.dumps({"piece": "scan, kernels against plain", **tag,
+                                  "deviation": deviation((jax.jit(fused)(*operands), *grads(fused)(*operands, ct)), want)}),
+                      flush=True)
+            except Exception as e:  # noqa: BLE001 - a cut the compiler refuses is a finding, not a crash
+                say("scan, kernels: refused", -1.0, **tag, error=str(e)[:400])
+    # with the gated output norm that reads the scan's output (`kda_attention`): over heads after the plain scan's layout
+    # change, and band by band on the kernels' (B, S, H x D)
+    gate = jax.random.normal(ks[5], (B, S, H * D), jnp.bfloat16)
+    gain = 1.0 + 0.1 * jax.random.normal(ks[6], (D,))
+
+    def by_heads(out):
+        o32 = out.reshape(B, S, H, D).astype(jnp.float32)
+        normed = o32 * lax.rsqrt(jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + 1e-5) * gain
+        return (normed * jax.nn.sigmoid(gate.reshape(B, S, H, D).astype(jnp.float32))).astype(jnp.bfloat16).reshape(B, S, H * D)
+
+    kernels.SCAN_HEADS, kernels.CHUNKS_AT_ONCE = heads[-1], chunks[-1]
+    jax.clear_caches()
+    normed = {"plain scan + the norm over heads": lambda *a: by_heads(plain(*a)),
+              "scan kernels + the norm over heads": lambda *a: by_heads(lm_kda._fused_scan(*a)),
+              "scan kernels + the banded norm": lambda *a: lm_kda._banded_gated_norm(lm_kda._fused_scan(*a), gate, gain, H,
+                                                                                    1e-5).astype(jnp.bfloat16)}
+    want = None
+    for name, fn in normed.items():
+        got = (jax.jit(fn)(*operands), *grads(fn)(*operands, ct))
+        want = want or got
+        say(f"{name}, fwd", timed(jax.jit(fn), operands, it), deviation=deviation(got, want))
+        say(f"{name}, fwd+bwd", timed(grads(fn), (*operands, ct), it))
     return 0
 
 

@@ -4,6 +4,7 @@ must equal, token by token, on the CPU at small sizes: 2 sequences, 2 heads of
 has and with decays whose in-chunk product underflows float32.
 """
 
+import functools
 import math
 
 import jax
@@ -166,8 +167,9 @@ def kernel_operands(seq: int, hard: bool, seed: int = 0):
 
 
 def deviations(got, want):
-    return [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))) / (jnp.max(jnp.abs(b.astype(jnp.float32))) + 1e-30))
-            for a, b in zip(got, want)]
+    """Each result's largest deviation, as a share of the wanted one's largest entry (on the host: no op to compile)."""
+    got, want = ([np.asarray(x).astype(np.float32) for x in xs] for xs in (got, want))
+    return [float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30)) for a, b in zip(got, want)]
 
 
 def cotangents(like):
@@ -242,18 +244,20 @@ def test_a_clamped_gate_in_the_kernel_path_is_caught_where_the_decays_underflow(
     ids=["fits", "sequence_not_whole_chunks", "float32_operands", "head_dim_64"])
 def test_the_dispatch_takes_the_kernels_form_by_the_shapes_alone(monkeypatch, seq, dtype, width, takes):
     """`kda_core` asks `fuses` (whole chunks, a head a whole number of 128-lane
-    bands, bfloat16) and nothing else; what does not fit takes the plain form,
-    head groups and all, with no `custom_vjp` and no platform switch around it."""
+    bands, bfloat16) and nothing else, for the in-chunk work and the scan
+    alike; what does not fit takes the plain forms, head groups and all, with
+    no `custom_vjp` and no platform switch around them."""
     assert lm_kda.fuses(seq, lm_kda.KDA_CHUNK, width, dtype) == takes
-    asked = []
-    fused = lm_kda._fused_operands
-    monkeypatch.setattr(lm_kda, "_fused_operands", lambda *a: asked.append(a[0].shape) or fused(*a))
+    asked = {"operands": [], "scan": []}
+    fused, fused_scan = lm_kda._fused_operands, lm_kda._fused_scan
+    monkeypatch.setattr(lm_kda, "_fused_operands", lambda *a: asked["operands"].append(a[0].shape) or fused(*a))
+    monkeypatch.setattr(lm_kda, "_fused_scan", lambda *a: asked["scan"].append(a[0].shape) or fused_scan(*a))
     shape = (1, seq, HEADS, width)
     q, k, v = (jax.ShapeDtypeStruct(shape, dtype) for _ in range(3))
     jaxpr = jax.make_jaxpr(lm_kda.kda_core)(q, k, v, jax.ShapeDtypeStruct(shape, jnp.float32),
                                             jax.ShapeDtypeStruct(shape[:3], jnp.float32))
-    assert bool(asked) == takes
-    assert ("platform_index" in str(jaxpr)) == takes
+    assert bool(asked["operands"]) == bool(asked["scan"]) == takes
+    assert str(jaxpr).count("platform_index") == (2 if takes else 0)
 
 
 def test_on_a_cpu_the_fitting_shape_runs_the_plain_form_and_its_vjp(monkeypatch):
@@ -268,6 +272,99 @@ def test_on_a_cpu_the_fitting_shape_runs_the_plain_form_and_its_vjp(monkeypatch)
     monkeypatch.setattr(lm_kda, "fuses", lambda *a: False)
     plain = run()
     assert all(bool(jnp.all(a == b)) for a, b in zip(jax.tree.leaves(through_the_dispatch), jax.tree.leaves(plain)))
+
+
+# ---- the scan's kernels (ops/lm_kda_kernels.py, `scan_*`) in Pallas interpret mode, against the plain scan ----------------
+# 8 chunks of 64, two a program instance: the state crosses three grid blocks of each sequence; one head or both a program
+SCAN_CUTS = pytest.mark.parametrize("hard, heads_at_once", [(False, 2), (True, 1)],
+                                    ids=["fresh_gates_2_heads_a_program", "gates_that_underflow_1_head_a_program"])
+# out, states; dqg, db, dw (the compute dtype: bfloat16's ulp at the top of its range is 2^-8), du0, dgamma (float32)
+SCAN_FWD_LIMITS = (1e-2, 1e-2)
+SCAN_BWD_LIMITS = (1e-2, 1e-2, 1e-2, 1e-4, 1e-2, 1e-4)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def scan_operands(hard: bool, seed: int):
+    """The scan's six operands for 8 chunks of 64, 2 sequences, 2 heads of 128, in the in-chunk form's shapes and
+    dtypes: qg, w, kh and a lower-triangular b in bfloat16, u0 and the chunks' decays gamma in float32. gamma in
+    [0.9, 1) (a state that lasts), or `hard`: at most e^-20 and exactly 0 in every third chunk."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shape = (8, 2, HEADS, lm_kda.KDA_CHUNK, KERNEL_WIDTH)
+    qg, w, kh = (0.1 * jax.random.normal(key, shape) for key in ks[:3])
+    b = jnp.tril(0.1 * jax.random.normal(ks[3], shape[:-1] + shape[-2:-1]))
+    u0 = jax.random.normal(ks[4], shape)
+    gamma = (jnp.exp(-jax.random.uniform(ks[5], shape[:3] + shape[-1:], minval=20.0, maxval=200.0)).at[::3].set(0.0) if hard
+             else jax.random.uniform(ks[5], shape[:3] + shape[-1:], minval=0.9, maxval=1.0))
+    return (*(x.astype(jnp.bfloat16) for x in (qg, b, w)), u0, kh.astype(jnp.bfloat16), gamma)
+
+
+def scan_deviations(hard, seed=0):
+    """(forward, backward) deviations of the scan kernels from `_state_scan_fwd` / `_bwd` (`_plain_scan`,
+    `_plain_scan_bwd`: the same with the output and its cotangent laid out as (B, S, H x D)): `out`, `states`, and
+    the six cotangents from one of `out`'s."""
+    operands = scan_operands(hard, seed)
+    want = jax.jit(lm_kda._plain_scan)(*operands)
+    got = jax.jit(lambda *a: lm_kda.scan_fwd(*a, interpret=True))(*operands)
+    assert [(x.shape, x.dtype) for x in got] == [(x.shape, x.dtype) for x in want]
+    ct = cotangents(want[:1])[0]
+    want_grads = jax.jit(lm_kda._plain_scan_bwd)(*operands, want[1], ct)
+    grads = jax.jit(lambda *a: lm_kda.scan_bwd(*a, interpret=True))(*operands, want[1], ct)
+    assert [(x.shape, x.dtype) for x in grads] == [(x.shape, x.dtype) for x in operands]
+    assert all(bool(jnp.all(jnp.isfinite(x.astype(jnp.float32)))) for x in (*got, *grads))
+    return deviations(got, want), deviations(grads, want_grads)
+
+
+@SCAN_CUTS
+def test_the_scan_kernels_equal_the_plain_scan_and_its_backward(conv_kernels, hard, heads_at_once):
+    """`scan_fwd` (ONE kernel: the state carried in VMEM across the chunks, the
+    output written as (B, S, H x D)) against `_state_scan_fwd`: the output,
+    reshaped, and the states at each chunk's start; `scan_bwd` (the chunks in
+    reverse, the state's cotangent carried) against `_state_scan_bwd`: the six
+    cotangents in the operands' shapes and dtypes. With decays a fresh model
+    has and with decays that underflow (gamma near 0)."""
+    conv_kernels(CHUNKS_AT_ONCE=2, SCAN_HEADS=heads_at_once)
+    fwd, bwd = scan_deviations(hard)
+    assert all(d < limit for d, limit in zip(fwd, SCAN_FWD_LIMITS)), fwd
+    assert all(d < limit for d, limit in zip(bwd, SCAN_BWD_LIMITS)), bwd
+
+
+def test_the_banded_gated_norm_equals_the_norm_over_each_head():
+    """`_banded_gated_norm` (each head's mean square and its broadcast back as
+    products with the 0/1 band matrix, on (B, S, H x D) as the scan kernels
+    write it) against the norm over the last axis of (B, S, H, D), times the
+    shared gain and the sigmoid gate: to float32 rounding."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    shape = (2, 16, HEADS, KERNEL_WIDTH)
+    o = (jax.random.normal(ks[0], shape) * jnp.array([0.01, 30.0])[:, None]).astype(jnp.bfloat16)  # heads far apart in scale
+    gate = jax.random.normal(ks[1], shape).astype(jnp.bfloat16)
+    gain = 1.0 + 0.1 * jax.random.normal(ks[2], (KERNEL_WIDTH,))
+    o32 = o.astype(jnp.float32)
+    want = (o32 * jax.lax.rsqrt(jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + 1e-5) * gain
+            * jax.nn.sigmoid(gate.astype(jnp.float32)))
+    flat = lambda x: x.reshape(*shape[:2], -1)  # noqa: E731
+    got = jax.jit(lambda *a: lm_kda._banded_gated_norm(*a, HEADS, 1e-5))(flat(o), flat(gate), gain)
+    assert got.shape == flat(want).shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(flat(want)), rtol=2e-6, atol=1e-6)
+
+
+def _planted_reverse_walk_forward(specs):
+    return lambda seq, chunk, heads, reverse: specs(seq, chunk, heads, False)
+
+
+@pytest.mark.parametrize("fault", ["carry_zeroed_every_block", "reverse_walk_taken_forward"])
+def test_a_scan_kernel_that_drops_its_carry_or_walks_the_wrong_way_is_refused(conv_kernels, fault):
+    """The comparison above refuses a pair whose carry is zeroed at every grid
+    block (right inside each block of chunks, wrong across them), and a
+    backward whose blocks come in forward order (its chunks inside a block
+    still from the last)."""
+    from yet_another_mobilenet_series_tpu.ops import lm_kda_kernels as kernels
+
+    planted = ({"_starts_a_sequence": lambda: True} if fault == "carry_zeroed_every_block"
+               else {"_scan_specs": _planted_reverse_walk_forward(kernels._scan_specs)})
+    conv_kernels(CHUNKS_AT_ONCE=2, **planted)
+    fwd, bwd = scan_deviations(False, seed=5)
+    holds = (all(d < limit for d, limit in zip(fwd, SCAN_FWD_LIMITS)), all(d < limit for d, limit in zip(bwd, SCAN_BWD_LIMITS)))
+    assert holds == ((False, False) if fault == "carry_zeroed_every_block" else (True, False)), (fwd, bwd)
 
 
 # ---- the short convolutions' kernels (ops/lm_kda_kernels.py, `conv_*`) in Pallas interpret mode, against the plain form ----
@@ -403,26 +500,29 @@ def _step_gauges(app, overrides, platform):
     params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0)))[0]
     steps.make_train_step(net, cfg, optim.make_optimizer(cfg.optim, lr_fn, params), lr_fn, platform=platform)
     return tuple(get_registry().gauge(name).value
-                 for name in ("train.kda_sites", "train.kda_kept_sites", "train.kda_fused_sites", "train.kda_conv_fused_sites"))
+                 for name in ("train.kda_sites", "train.kda_kept_sites", "train.kda_fused_sites", "train.kda_conv_fused_sites",
+                              "train.kda_scan_fused_sites"))
 
 
-@pytest.mark.parametrize("family, toy, cell", [("kimi", (4.0, 4.0, 0.0, 0.0), (4.0, 4.0, 4.0, 4.0)),
-                                               ("glm", (0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0)),
-                                               ("ouro", (0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0))])
+@pytest.mark.parametrize("family, toy, cell", [("kimi", (4.0, 4.0, 0.0, 0.0, 0.0), (4.0, 4.0, 4.0, 4.0, 4.0)),
+                                               ("glm", (0.0, 0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0, 0.0)),
+                                               ("ouro", (0.0, 0.0, 0.0, 0.0, 0.0), (0.0, 0.0, 0.0, 0.0, 0.0))])
 def test_train_step_reports_how_many_kda_layers_the_kernels_take(family, toy, cell):
-    """`train.kda_fused_sites` and `train.kda_conv_fused_sites` beside
-    `train.kda_sites` and `train.kda_kept_sites`, set where the step is built,
-    as `train.attn_fused_sites` is: the KDA layers whose shapes `fuses` (the
-    in-chunk work) / `conv_fuses` (the three short convolutions) take when the
-    step is lowered for a TPU, else 0. The toys (heads of 8 channels, float32)
-    take the plain forms anywhere; kimi's cell's model reads 4 / 4 / 4 / 4 for
-    a TPU and 4 / 4 / 0 / 0 for a CPU; GLM and Ouro hold no KDA layer."""
+    """`train.kda_fused_sites`, `train.kda_conv_fused_sites` and
+    `train.kda_scan_fused_sites` beside `train.kda_sites` and
+    `train.kda_kept_sites`, set where the step is built, as
+    `train.attn_fused_sites` is: the KDA layers whose shapes `fuses` (the
+    in-chunk work, and the scan over the chunks) / `conv_fuses` (the three
+    short convolutions) take when the step is lowered for a TPU, else 0. The
+    toys (heads of 8 channels, float32) take the plain forms anywhere; kimi's
+    cell's model reads 4 / 4 / 4 / 4 / 4 for a TPU and 4 / 4 / 0 / 0 / 0 for a
+    CPU; GLM and Ouro hold no KDA layer."""
     import test_lm_cli as cli
 
     app, overrides = {"kimi": (cli.KIMI_APP, cli.KIMI_TOY), "glm": (cli.APP, cli.TOY), "ouro": (cli.OURO_APP, cli.OURO_TOY)}[family]
     assert _step_gauges(app, overrides, None) == _step_gauges(app, overrides, "tpu") == toy
     assert _step_gauges(app, [], "tpu") == cell
-    assert _step_gauges(app, [], "cpu") == (*cell[:2], 0.0, 0.0)
+    assert _step_gauges(app, [], "cpu") == (*cell[:2], 0.0, 0.0, 0.0)
 
 
 _FRESH_PROCESS = """

@@ -522,7 +522,10 @@ def test_taxonomy_version_is_pinned_to_the_scope_sites():
         # k's L2 norms move under `kda_conv` with them) in the one step whose program changed with them: no bump
         # the three `kda_conv` sites take the scope by name (`scope(name)`, "" here): `kda_conv` at every KDA
         # site as before, `ssd_conv` where a Mamba-2 mixer hands its xBC convolution in
-        "ops/lm_kda.py": ["", "", "", "kda_core", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj", "kda_proj"],
+        # the scan's kernels added a third `kda_core` site (their `custom_vjp`'s backward) in the one step whose program
+        # changed with them, so its cache key moved anyway, and no other step holds it: no bump
+        "ops/lm_kda.py": ["", "", "", "kda_core", "kda_core", "kda_core", "kda_gate", "kda_norm", "kda_norm", "kda_proj",
+                          "kda_proj"],
         # the fused SSD kernels added two `ssd_core` sites (their `custom_vjp`s' backwards) in the one step whose program
         # changed with them, so its cache key moved anyway, and no other step holds them: no bump
         "ops/lm_mamba.py": ["ssd_core", "ssd_core", "ssd_core", "ssd_gate", "ssd_gate", "ssd_norm", "ssd_proj", "ssd_proj"],

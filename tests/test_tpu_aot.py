@@ -234,12 +234,14 @@ def test_a_kda_layer_at_the_cells_shape_is_two_mosaic_kernels_under_its_scope(on
     """kimi_linear's Kimi Delta Attention core at the cell's shape (1 x 16,384
     tokens, 32 heads of 128, bfloat16), forward + backward, compiled for the
     described chip from this CPU process: `lax.platform_dependent` takes the
-    kernels of ops/lm_kda_kernels.py and Mosaic compiles both; each custom call
+    kernels of ops/lm_kda_kernels.py and Mosaic compiles all four, the two of
+    the in-chunk work and the two of the scan over the chunks; each custom call
     keeps `kda_core` and its phase in its `op_name` and carries the kernel's
     name (so `scope_table` resolves its device events as it does
-    `causal_attention_fwd.N`); the only loops left are the scan's two; and the
-    declared temporaries are not above the plain form's (head groups, a
-    `lax.map` and a solve loop; ~3.2 GiB against ~2.0)."""
+    `causal_attention_fwd.N`); no loop is left to XLA (the scan's state is
+    carried inside its kernels); and the declared temporaries are not above
+    the plain form's (head groups, a `lax.map`, a solve loop and the scan's
+    two loops)."""
     from yet_another_mobilenet_series_tpu.ops import lm_kda
 
     shape = (1, 16384, 32, 128)
@@ -255,10 +257,11 @@ def test_a_kda_layer_at_the_cells_shape_is_two_mosaic_kernels_under_its_scope(on
     text = fused.as_text()
     instructions, _ = _entry_instructions(text)
     kernels = {n.split(".")[0]: scopes.scope_of(op) for n, (_, opcode, _, op) in instructions.items()
-               if opcode == "custom-call" and n.startswith("kda_operands")}  # the scan's buffers are custom calls too (`AllocateBuffer`)
-    assert kernels == {"kda_operands_fwd": ("kda_core", "fwd"), "kda_operands_bwd": ("kda_core", "bwd")}, kernels
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert len(re.findall(r"\bwhile\(", text)) == 2  # `_state_scan` forward and backward: no head groups, no solve loop
+               if opcode == "custom-call" and n.startswith("kda_")}
+    assert kernels == {"kda_operands_fwd": ("kda_core", "fwd"), "kda_operands_bwd": ("kda_core", "bwd"),
+                       "kda_scan_fwd": ("kda_core", "fwd"), "kda_scan_bwd": ("kda_core", "bwd")}, kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r"\bwhile\(", text)  # no head groups, no solve loop, no scan loop
     monkeypatch.setattr(lm_kda, "fuses", lambda *a: False)
     plain = compiled()
     assert "tpu_custom_call" not in plain.as_text()
@@ -293,7 +296,8 @@ def test_a_kda_mixer_at_the_cells_shape_makes_q_k_v_in_the_conv_kernels_under_th
     kernels = sorted((n.split(".")[0], *scopes.scope_of(op)) for n, (_, opcode, _, op) in instructions.items()
                      if opcode == "custom-call" and n.startswith("kda_"))
     assert kernels == ([("kda_conv_bwd", "kda_conv", "bwd")] * 3 + [("kda_conv_fwd", "kda_conv", "fwd")] * 3
-                       + [("kda_operands_bwd", "kda_core", "bwd"), ("kda_operands_fwd", "kda_core", "fwd")]), kernels
+                       + [("kda_operands_bwd", "kda_core", "bwd"), ("kda_operands_fwd", "kda_core", "fwd"),
+                          ("kda_scan_bwd", "kda_core", "bwd"), ("kda_scan_fwd", "kda_core", "fwd")]), kernels
     monkeypatch.setattr(lm_kda, "conv_fuses", lambda *a: False)
     plain = compiled()
     assert "kda_conv_" not in plain.as_text()

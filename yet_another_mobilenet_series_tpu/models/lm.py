@@ -299,7 +299,8 @@ class TokenModel:
         """The KDA layers whose shapes ops/lm_kda_kernels.py's fused kernels
         take, by the predicate `lm_kda.kda_core` dispatches on: what
         `train.kda_fused_sites` reports where the step is lowered for a TPU
-        (train/steps.py)."""
+        (train/steps.py), and `train.kda_scan_fused_sites` (the scan's kernels
+        take the same sites)."""
         la = self.lm.linear_attn_config
         fits = lm_kda.fuses(self.lm.seq_len, min(lm_kda.KDA_CHUNK, self.lm.seq_len), la.head_dim, compute_dtype)
         return self.kda_sites if fits else 0

@@ -3,8 +3,9 @@
 decay gate of one value a KEY CHANNEL; a write strength a head; the gated
 delta rule over a `head_dim x head_dim` state a head; a sigmoid-gated RMSNorm
 on the way out. Plain `jax.numpy`/`lax` on every platform, but for what
-depends on a chunk alone and for the short convolutions with their norms,
-which fused TPU kernels make where the shapes fit them (below).
+depends on a chunk alone, the scan over the chunks and the short convolutions
+with their norms, which fused TPU kernels make where the shapes fit them
+(below).
 
 The recurrence, a head at a time (state S, key x value, S_0 = 0)::
 
@@ -20,7 +21,7 @@ With `G_r` the in-chunk cumulative log decay and `u_j = beta_j (v_j - S'_j^T k_j
     S_C = Diag(e^{G_C}) S_0 + (K e^{G_C - G})^T U
 
 so everything that depends on the chunk alone (A, B, the unit-triangular
-solve) is computed for ALL chunks at once, and one `lax.scan` over the chunks
+solve) is computed for ALL chunks at once, and one scan over the chunks
 carries the state through three small matmuls a step.
 
 **No gate is clamped.** Every decayed product above has `r >= j`, so its
@@ -68,8 +69,28 @@ dz once and a float32 dw a tile. The backward keeps z and the filter alone.
 Its kernels come in inside `conv_fwd` / `conv_bwd`, as above;
 `train.kda_conv_fused_sites` says how many KDA layers a step lowers that way.
 
-**What the backward keeps.** The scan is a `custom_vjp`: its backward walks
-the chunks in reverse with the cotangent of the state, from the scan's
+**Two lowerings of the scan.** `_state_scan` is the scan in plain `lax`: a
+`lax.scan` over the chunks, all heads together, its output chunk-leading (N,
+B, H, C, D) and transposed to (B, S, H, D) after it: every other platform's
+path, any shape the kernels do not take, and the kernels' oracle in the tests.
+`_fused_scan` is the same function as two Pallas/Mosaic kernels
+(ops/lm_kda_kernels.py `scan_fwd_call` / `scan_bwd_call`) under one
+`custom_vjp`, taken at the shapes `fuses` takes (the in-chunk kernels' own)
+where the lowering is a TPU's, as `_fused_operands` is. A program instance of
+the forward walks a block of chunks of a few heads of one sequence in order,
+each head's state a float32 (K, V) VMEM scratch carried from block to block
+(zeroed at the sequence's first), and writes the output straight into (B, S,
+H x D), a head a band of lanes: the layout `kda_attention` and the gated norm
+hold, so no transpose is left either way. The backward walks the blocks in
+reverse through its index maps, the state's cotangent carried alike, reads the
+output's cotangent in that layout and writes the six operands' cotangents in
+their own shapes and dtypes. Per chunk both do what `_state_scan_fwd` /
+`_state_scan_bwd` do, in the same precisions. Their module comes in inside
+`scan_fwd` / `scan_bwd`, as above; `train.kda_scan_fused_sites` says how many
+KDA layers a step lowers that way.
+
+**What the backward keeps.** Either scan is a `custom_vjp`: its backward
+walks the chunks in reverse with the cotangent of the state, from the scan's
 operands and the state at each chunk's start; nothing else of the forward is
 kept. Output and states carry names (`KDA_OUT_NAME`, `KDA_STATES_NAME`): the
 layer checkpoint of models/lm.py saves those two, so the backward's second
@@ -485,6 +506,71 @@ def _fused_operands_bwd(kept, cts):
 _fused_operands.defvjp(_fused_operands_fwd, _fused_operands_bwd)
 
 
+def _by_position(out: Array) -> Array:
+    """(N, B, H, C, V) -> (B, N * C, H * V): a chunk-leading output as `kda_attention` holds it."""
+    n, batch, heads, chunk, values = out.shape
+    return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(batch, n * chunk, heads * values)
+
+
+def _plain_scan(qg, b, w, u0, kh, gamma):
+    """`_state_scan_fwd` with its output laid out as the kernel writes it: (out (B, S, H * V), states)."""
+    out, kept = _state_scan_fwd(qg, b, w, u0, kh, gamma)
+    return _by_position(out), kept[-1]
+
+
+def _plain_scan_bwd(qg, b, w, u0, kh, gamma, states, ct):
+    """`_state_scan_bwd` from the cotangent of `_plain_scan`'s output."""
+    n, batch, heads, chunk, values = u0.shape
+    by_chunk = jnp.moveaxis(ct.reshape(batch, n, chunk, heads, values), (1, 3), (0, 2))
+    return _state_scan_bwd((qg, b, w, u0, kh, gamma, states), by_chunk)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scan_fwd(qg, b, w, u0, kh, gamma, interpret: bool = False):
+    """`_plain_scan` as ONE kernel (ops/lm_kda_kernels.py): the state carried
+    in VMEM from chunk to chunk, the output written as (B, S, H * V)."""
+    from . import lm_kda_kernels as kernels  # Pallas comes in HERE and nowhere earlier (module docstring)
+
+    return kernels.scan_fwd_call(qg, b, w, u0, kh, gamma[..., None, :], interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scan_bwd(qg, b, w, u0, kh, gamma, states, ct, interpret: bool = False):
+    """`_plain_scan_bwd` as ONE kernel: the chunks in reverse, the state's
+    cotangent carried in VMEM; the six cotangents in the operands' shapes and dtypes."""
+    from . import lm_kda_kernels as kernels  # as in scan_fwd
+
+    *grads, d_gamma = kernels.scan_bwd_call(qg, b, w, u0, kh, gamma[..., None, :], states, ct, interpret)
+    return (*grads, d_gamma.reshape(gamma.shape))
+
+
+@jax.custom_vjp
+def _fused_scan(qg: Array, b: Array, w: Array, u0: Array, kh: Array, gamma: Array) -> Array:
+    """`_state_scan` at a shape the kernels take (`fuses`), its output as (B,
+    S, H * V): the kernel pair where the step is lowered for a TPU, else the
+    plain scan and its own backward (`lax.platform_dependent` decides at
+    lowering). What the backward keeps is what `_state_scan`'s keeps, under
+    the same names."""
+    return _fused_scan_fwd(qg, b, w, u0, kh, gamma)[0]
+
+
+def _fused_scan_fwd(qg, b, w, u0, kh, gamma):
+    from jax.ad_checkpoint import checkpoint_name  # as in _state_scan_fwd
+
+    operands = (qg, b, w, u0, kh, gamma)
+    out, states = lax.platform_dependent(*operands, tpu=scan_fwd, default=_plain_scan)
+    out, states = checkpoint_name(out, KDA_OUT_NAME), checkpoint_name(states, KDA_STATES_NAME)
+    return out, (*operands, states)
+
+
+def _fused_scan_bwd(kept, ct):
+    with scope("kda_core"):
+        return lax.platform_dependent(*kept, ct, tpu=scan_bwd, default=_plain_scan_bwd)
+
+
+_fused_scan.defvjp(_fused_scan_fwd, _fused_scan_bwd)
+
+
 def kda_core(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tuple[Array, Array]:
     """The gated delta rule in its chunked form (module docstring). q (already
     scaled), k, v (B, S, H, D) in the compute dtype; g (B, S, H, D) float32,
@@ -492,20 +578,39 @@ def kda_core(q: Array, k: Array, v: Array, g: Array, beta: Array) -> tuple[Array
     (o (B, S, H, D), the most negative in-chunk cumulative log decay met: a
     float32 scalar, no gradient). Any length (`_plain_operands`).
 
-    What depends on a chunk alone is made by the fused kernels where the
-    shapes fit them and the lowering is a TPU's (`fuses`, `_fused_operands`),
-    else in plain `lax` a head group at a time; the scan takes all heads
-    together either way."""
+    Where the shapes fit the kernels and the lowering is a TPU's (`fuses`),
+    what depends on a chunk alone is made by the in-chunk kernels
+    (`_fused_operands`) and the scan is the scan kernels (`_fused_scan`),
+    whose output is already (B, S, H x D); else both are plain `lax`, the
+    in-chunk work a head group at a time, the scan all heads together."""
     with scope("kda_core"):
         batch, seq, heads, width = q.shape
         chunk = min(KDA_CHUNK, seq)
         if chunk % min(KDA_SUBCHUNK, chunk):
             raise ValueError(f"a chunk of {chunk} positions is not a multiple of the sub-block {KDA_SUBCHUNK}")
-        make = _fused_operands if fuses(seq, chunk, width, q.dtype) else _plain_operands
-        operands, lowest = make(q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32))
+        g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+        if fuses(seq, chunk, width, q.dtype):
+            operands, lowest = _fused_operands(q, k, v, g, beta)
+            return _fused_scan(*operands).reshape(q.shape), lax.stop_gradient(lowest)
+        operands, lowest = _plain_operands(q, k, v, g, beta)
         out = _state_scan(*operands)  # (N, B, H, C, D)
         out = jnp.moveaxis(out, (0, 2), (1, 3)).reshape(batch, -1, heads, width)[:, :seq]
         return out, lax.stop_gradient(lowest)
+
+
+def _banded_gated_norm(o: Array, gate: Array, gain: Array, heads: int, eps: float) -> Array:
+    """The gated output norm of `kda_attention` on o, gate (B, S, H x D), a
+    head a band of lanes, as the scan kernels write o: each head's mean square
+    and its broadcast back to the band are products with the 0/1 (H x D, H)
+    matrix of the bands, at float32 precision. Reshaped to (B, S, H, D) for a
+    reduction over the last axis, the chip relays the whole float32 tensor out
+    and back, forward and backward (PERF.md, section 6). -> float32 (B, S, H x D)."""
+    width = o.shape[-1] // heads
+    bands = jnp.repeat(jnp.eye(heads, dtype=jnp.float32), width, axis=0)
+    o32 = o.astype(jnp.float32)
+    mean_square = jnp.dot(jnp.square(o32), bands, precision=lax.Precision.HIGHEST) / width
+    scale = jnp.dot(lax.rsqrt(mean_square + eps), bands.T, precision=lax.Precision.HIGHEST)
+    return o32 * scale * jnp.tile(gain.astype(jnp.float32), heads) * jax.nn.sigmoid(gate.astype(jnp.float32))
 
 
 def kda_attention(p: dict, x: Array, *, heads: int, head_dim: int, eps: float) -> tuple[Array, Array]:
@@ -531,8 +636,11 @@ def kda_attention(p: dict, x: Array, *, heads: int, head_dim: int, eps: float) -
         beta = jax.nn.sigmoid(write.astype(jnp.float32))
     out, lowest = kda_core(q, k, v, g, beta)
     with scope("kda_norm"):
-        o32 = out.astype(jnp.float32)
-        normed = o32 * lax.rsqrt(jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + eps) * p["o_norm"].astype(jnp.float32)
-        gated = (normed * jax.nn.sigmoid(by_head(gate.astype(jnp.float32)))).astype(cd)
+        if fuses(seq, min(KDA_CHUNK, seq), head_dim, cd):  # the scan kernels' output, (B, S, H x D) as it lies
+            gated = _banded_gated_norm(out.reshape(gate.shape), gate, p["o_norm"], heads, eps).astype(cd)
+        else:
+            o32 = out.astype(jnp.float32)
+            normed = o32 * lax.rsqrt(jnp.mean(jnp.square(o32), axis=-1, keepdims=True) + eps) * p["o_norm"].astype(jnp.float32)
+            gated = (normed * jax.nn.sigmoid(by_head(gate.astype(jnp.float32)))).astype(cd)
     with scope("kda_proj"):
         return gated.reshape(batch, seq, heads * head_dim) @ p["o"].astype(cd), lowest

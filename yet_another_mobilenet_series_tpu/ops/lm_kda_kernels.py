@@ -1,11 +1,12 @@
 """The Pallas/Mosaic kernels behind `ops/lm_kda.py`: two for the in-chunk
-work, two for the short convolutions (the end of this module; Mamba-2's xBC
-convolution, ops/lm_mamba.py, takes them too, with a bias), and the calls
-that build them. Imported only from inside `lm_kda.operands_fwd/operands_bwd`
-and `lm_kda.conv_fwd/conv_bwd`, that is while the `tpu` branch of a
-fitting KDA site is traced (or a test asks for interpret mode): the Pallas
-import costs 1.2-1.5 s on the chip's host, `train/steps.py` is imported by
-every process, and a step with no KDA layer must not pay it
+work, two for the scan over the chunks (after them), two for the short
+convolutions (the end of this module; Mamba-2's xBC convolution,
+ops/lm_mamba.py, takes them too, with a bias), and the calls that build them.
+Imported only from inside `lm_kda.operands_fwd/operands_bwd`,
+`lm_kda.scan_fwd/scan_bwd` and `lm_kda.conv_fwd/conv_bwd`, that is while
+the `tpu` branch of a fitting KDA site is traced (or a test asks for interpret
+mode): the Pallas import costs 1.2-1.5 s on the chip's host, `train/steps.py`
+is imported by every process, and a step with no KDA layer must not pay it
 (`tests/test_lm_kda.py` pins it; PERF.md, PR 28 + 29 + 36).
 
 **What a kernel makes.** From q, k, v (compute dtype), g (float32) and beta,
@@ -420,6 +421,143 @@ def bwd_call(q, k, v, g, beta, cts, heads: int, chunk: int, interpret: bool = Fa
         scratch_shapes=[pltpu.VMEM((blocks * tile, width), jnp.float32)] * 2,
         compiler_params=_params(), interpret=interpret, name="kda_operands_bwd",
     )(pattern, pattern_t, q, k, v, g, beta, *cts)
+
+
+# ---- the scan over the chunks (`lm_kda._fused_scan`): the state carried in VMEM, one kernel each way ----------------------
+# A program instance walks `CHUNKS_AT_ONCE` chunks of `SCAN_HEADS` heads of one sequence (the forward in order, the backward
+# in reverse, through its index maps), each head's state (K, V) a float32 scratch that outlives the grid step: zeroed at
+# the sequence's first block, carried across the others. Per chunk each does what `lm_kda._state_scan_fwd` / `_bwd` do, in
+# the same precisions; the heads' chains are independent, so their small matmuls interleave where one head's would wait on
+# the MXU. The output (and its cotangent) is (B, S, H * V), a head a band of lanes, as `kda_attention` holds it.
+SCAN_HEADS = 4
+
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+
+
+def scan_cut(seq: int, chunk: int, heads: int) -> tuple[int, int, int]:
+    """(chunks a program instance walks, program instances a sequence, heads
+    a program instance carries)."""
+    at_once, _, instances = _cut(seq, chunk)
+    return at_once, instances, max(h for h in range(1, min(SCAN_HEADS, heads) + 1) if heads % h == 0)
+
+
+def _starts_a_sequence():
+    """Whether this grid step is its sequence's first block (its carry starts at 0)."""
+    return pl.program_id(2) == 0
+
+
+def _by_row(gamma, values):
+    """gamma (1, K) -> (K, V) whose row k is gamma_k: the state's rows decay by key channel."""
+    return jnp.broadcast_to(gamma, (values, gamma.shape[1])).T
+
+
+def _scan_fwd_kernel(qg_ref, b_ref, w_ref, u0_ref, kh_ref, gamma_ref, out_ref, states_ref, state_ref, *, chunk, at_once):
+    @pl.when(_starts_a_sequence())
+    def _():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+
+    cd = out_ref.dtype
+    values = state_ref.shape[2]
+
+    def one(c, _):
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        for h in range(state_ref.shape[0]):
+            state = state_ref[h]
+            s = state.astype(cd)
+            states_ref[c, h] = s
+            u = (u0_ref[c, h] - jnp.dot(w_ref[c, h], s, **_F32)).astype(cd)
+            out = jnp.dot(qg_ref[c, h], s, **_F32) + jnp.dot(b_ref[c, h], u, **_F32)
+            out_ref[rows, h * values:(h + 1) * values] = out.astype(cd)
+            state_ref[h] = _by_row(gamma_ref[c, h], values) * state + lax.dot_general(kh_ref[c, h], u, _TN, **_F32)
+
+    lax.fori_loop(0, at_once, one, None)
+
+
+def _scan_bwd_kernel(qg_ref, b_ref, w_ref, u0_ref, kh_ref, gamma_ref, states_ref, ct_ref,
+                     dqg_ref, db_ref, dw_ref, du0_ref, dkh_ref, dgamma_ref, d_state_ref, *, chunk, at_once):
+    @pl.when(_starts_a_sequence())
+    def _():
+        d_state_ref[...] = jnp.zeros(d_state_ref.shape, jnp.float32)
+
+    cd = states_ref.dtype
+    values = d_state_ref.shape[2]
+
+    def one(i, _):
+        c = at_once - 1 - i  # the block's chunks from its last: the blocks come in reverse too
+        rows = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        for h in range(d_state_ref.shape[0]):
+            d_state = d_state_ref[h]  # the cotangent of the state at the chunk's END
+            s, ds, do = states_ref[c, h], d_state.astype(cd), ct_ref[rows, h * values:(h + 1) * values].astype(cd)
+            w = w_ref[c, h]
+            u = (u0_ref[c, h] - jnp.dot(w, s, **_F32)).astype(cd)
+            du = lax.dot_general(b_ref[c, h], do, _TN, **_F32) + jnp.dot(kh_ref[c, h], ds, **_F32)
+            duc = du.astype(cd)
+            d_state_ref[h] = (_by_row(gamma_ref[c, h], values) * d_state + lax.dot_general(qg_ref[c, h], do, _TN, **_F32)
+                              - lax.dot_general(w, duc, _TN, **_F32))
+            dqg_ref[c, h] = lax.dot_general(do, s, _NT, **_F32).astype(cd)
+            db_ref[c, h] = lax.dot_general(do, u, _NT, **_F32).astype(cd)
+            dw_ref[c, h] = (-lax.dot_general(duc, s, _NT, **_F32)).astype(cd)
+            du0_ref[c, h] = du
+            dkh_ref[c, h] = lax.dot_general(u, ds, _NT, **_F32).astype(cd)
+            dgamma_ref[c, h] = jnp.sum((s.astype(jnp.float32) * d_state).T, axis=0, keepdims=True)
+
+    lax.fori_loop(0, at_once, one, None)
+
+
+def _scan_specs(seq, chunk, heads, reverse):
+    """(at_once, instances, heads a program instance, a chunk-leading block of
+    (N, B, H, ...), a (B, S, H * width) block of `width` lanes a head)."""
+    at_once, instances, group = scan_cut(seq, chunk, heads)
+    at = (lambda n: instances - 1 - n) if reverse else (lambda n: n)  # noqa: E731
+    leading = lambda *tile: pl.BlockSpec((at_once, None, group, *tile), lambda b, h, n: (at(n), b, h, 0, 0))  # noqa: E731
+    band = lambda width: pl.BlockSpec((None, at_once * chunk, group * width), lambda b, h, n: (b, at(n), h))  # noqa: E731
+    return at_once, instances, group, leading, band
+
+
+def _scan_params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def scan_fwd_call(qg, b, w, u0, kh, gamma, interpret: bool = False):
+    """`_state_scan`'s operands, chunk-leading (N, B, H, ...): qg, w, kh (.., C,
+    K) and b (.., C, C) in the compute dtype, u0 (.., C, V) and gamma (.., 1, K)
+    float32 -> (out (B, N * C, H * V), states (N, B, H, K, V): each chunk's
+    start state), both in the compute dtype."""
+    n, batch, heads, chunk, width = qg.shape
+    values = u0.shape[-1]
+    at_once, instances, group, leading, band = _scan_specs(n * chunk, chunk, heads, reverse=False)
+    return pl.pallas_call(
+        functools.partial(_scan_fwd_kernel, chunk=chunk, at_once=at_once),
+        grid=(batch, heads // group, instances),
+        in_specs=[leading(chunk, width), leading(chunk, chunk), leading(chunk, width), leading(chunk, values),
+                  leading(chunk, width), leading(1, width)],
+        out_specs=[band(values), leading(width, values)],
+        out_shape=[jax.ShapeDtypeStruct((batch, n * chunk, heads * values), qg.dtype),
+                   jax.ShapeDtypeStruct((n, batch, heads, width, values), qg.dtype)],
+        scratch_shapes=[pltpu.VMEM((group, width, values), jnp.float32)],
+        compiler_params=_scan_params(), interpret=interpret, name="kda_scan_fwd",
+    )(qg, b, w, u0, kh, gamma)
+
+
+def scan_bwd_call(qg, b, w, u0, kh, gamma, states, ct, interpret: bool = False):
+    """The forward's operands, its states and the cotangent of its output (B,
+    S, H * V) -> the cotangents of the six operands in their own shapes and
+    dtypes (u0's and gamma's float32)."""
+    n, batch, heads, chunk, width = qg.shape
+    values = u0.shape[-1]
+    at_once, instances, group, leading, band = _scan_specs(n * chunk, chunk, heads, reverse=True)
+    operands = (qg, b, w, u0, kh, gamma)
+    specs = [leading(chunk, width), leading(chunk, chunk), leading(chunk, width), leading(chunk, values),
+             leading(chunk, width), leading(1, width)]
+    return pl.pallas_call(
+        functools.partial(_scan_bwd_kernel, chunk=chunk, at_once=at_once),
+        grid=(batch, heads // group, instances),
+        in_specs=[*specs, leading(width, values), band(values)],
+        out_specs=specs,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in operands],
+        scratch_shapes=[pltpu.VMEM((group, width, values), jnp.float32)],
+        compiler_params=_scan_params(), interpret=interpret, name="kda_scan_bwd",
+    )(*operands, states, ct)
 
 
 # ---- the short convolutions (`lm_kda.conv_and_norm`): causal conv, SiLU and a head's L2 norm, one pass each way ----------

@@ -263,6 +263,8 @@ def _token_loss(net: TokenModel, cfg: Config, axis_name: str | None, platform: s
     get_registry().gauge("train.kda_fused_sites").set(net.kda_fitting_sites(compute_dtype) if on_tpu else 0)
     # and those whose three short convolutions (with q's and k's L2 norms) go through its two conv kernels (`conv_fuses`)
     get_registry().gauge("train.kda_conv_fused_sites").set(net.kda_conv_fitting_sites(compute_dtype) if on_tpu else 0)
+    # and those whose scan over the chunks goes through its two scan kernels (the same shapes as the in-chunk work's)
+    get_registry().gauge("train.kda_scan_fused_sites").set(net.kda_fitting_sites(compute_dtype) if on_tpu else 0)
     # the Mamba-2 layers (ops/lm_mamba.py), all of whose chunk-boundary states the layer checkpoint keeps by name, and
     # those whose xBC convolution goes through the conv kernels (a prediction, as `train.kda_conv_fused_sites` is)
     get_registry().gauge("train.ssd_sites").set(net.ssd_sites)
@@ -318,9 +320,9 @@ def make_train_step(
     out). It moves nothing in the step: the gauges that PREDICT which
     lowering a platform-dependent piece takes read it
     (`train.attn_fused_sites`: the attention layers through the kernels of
-    ops/lm_attention.py; `train.kda_fused_sites` / `train.kda_conv_fused_sites`:
-    the KDA layers whose in-chunk work / short convolutions go through those of
-    ops/lm_kda.py; `train.ssd_conv_fused_sites` / `train.ssd_fused_sites`: the
+    ops/lm_attention.py; `train.kda_fused_sites` / `train.kda_conv_fused_sites` /
+    `train.kda_scan_fused_sites`: the KDA layers whose in-chunk work / short
+    convolutions / scan over the chunks go through those of ops/lm_kda.py; `train.ssd_conv_fused_sites` / `train.ssd_fused_sites`: the
     Mamba-2 layers whose xBC convolution / in-chunk SSD work does, the latter
     through ops/lm_mamba_kernels.py). A step built without
     it and then compiled ahead of time for another platform than the default
